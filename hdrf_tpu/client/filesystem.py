@@ -575,15 +575,13 @@ class HdrfClient:
                        token=alloc.get("token"), targets=targets[1:],
                        storage_type=targets[0].get("storage_type"),
                        _client=self.name)
-            npkts = dt.stream_bytes(sock, block, self.config.packet_size)
-            # Drain per-packet acks; the final one carries pipeline status.
-            # A shed ack's seqno field carries the DN's retry-after hint in
-            # ms (datatransfer.py ACK_SHED — the block was refused at
-            # admission, nothing was stored).
-            status = dt.ACK_SUCCESS
-            hint = 0
-            for _ in range(npkts):
-                hint, status = dt.read_ack(sock)
+            # Per-packet acks are read inside the send window; the final one
+            # carries pipeline status.  A shed ack's seqno field carries the
+            # DN's retry-after hint in ms (datatransfer.py ACK_SHED — the
+            # block was refused at admission, nothing was stored).
+            hint, status = dt.stream_bytes_acked(
+                sock, block, self.config.packet_size,
+                self.config.max_inflight_packets)
             if status == dt.ACK_SHED:
                 raise qos.ShedError(
                     f"block {alloc['block_id']} shed at admission",

@@ -104,10 +104,9 @@ class StripedWriter:
             dt.send_op(sock, dt.WRITE_BLOCK, block_id=blk["block_id"],
                        gen_stamp=gen_stamp, scheme="direct",
                        token=blk.get("token"), targets=[])
-            n = dt.stream_bytes(sock, shard, c.config.packet_size)
-            status = dt.ACK_SUCCESS
-            for _ in range(n):
-                _, status = dt.read_ack(sock)
+            _, status = dt.stream_bytes_acked(
+                sock, shard, c.config.packet_size,
+                c.config.max_inflight_packets)
             if status != dt.ACK_SUCCESS:
                 raise IOError(f"shard write returned {status}")
         finally:

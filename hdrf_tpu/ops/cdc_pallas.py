@@ -54,9 +54,11 @@ exceeds the static cut capacity sets ``overflow`` and the caller falls back
 to the XLA prep + host-select oracle path — boundaries are never silently
 truncated (tests/test_cdc_pallas.py pins this with a low-entropy corpus).
 
-``HDRF_CDC_PALLAS=0`` disables the fused path; ``=interpret`` forces the
-Pallas interpreter so the CPU test mesh executes the same kernel program
-Mosaic compiles on a chip (the ops/sort_pallas.py:59-64 gate pattern).
+The fused select kernel is OFF by default everywhere: Mosaic refuses it
+(``cdc_pallas_mode``), so it runs only through the Pallas interpreter on the
+CPU test mesh (``HDRF_CDC_PALLAS=interpret``) until it is repaired.  The
+scan-only kernel below does lower and is the TPU default of the sharded
+scan (``scan_pallas_mode``).
 """
 
 from __future__ import annotations
@@ -89,20 +91,40 @@ H_SURV, H_CANDS = 4, 5
 
 
 def cdc_pallas_mode() -> str:
-    """Trace-time gate: 'mosaic' on a real TPU backend, 'off' on the CPU
-    mesh, overridable via HDRF_CDC_PALLAS (``0`` = off everywhere,
-    ``interpret`` = run the kernel through the Pallas interpreter — the
-    tier-1 path that executes the same program Mosaic compiles)."""
+    """Gate of the fused select kernel: 'off' unless HDRF_CDC_PALLAS asks.
+
+    Mosaic does not lower ``_select_kernel`` (compiled for v5e, PR 22:
+    ``ValueError: Cannot store scalars to VMEM`` at the cut-table store;
+    with the tables in SMEM, ``Can only store scalars to SMEM`` at their
+    vector init; the select loop also scalar-reads VMEM summaries at
+    dynamic indices).  So the TPU default front end is the XLA one
+    (ops/resident.py: ``_prep`` scan + host cut select + Pallas gather +
+    Pallas SHA) and the platform alone never selects this kernel.
+    ``HDRF_CDC_PALLAS=1`` asks for it: through Mosaic on a TPU backend
+    (for whoever repairs the kernel), through the Pallas interpreter
+    elsewhere.  ``=interpret`` is the tier-1 setting that executes the
+    kernel program on the CPU mesh; on a chip it is refused — nothing runs
+    interpreted there."""
     env = os.environ.get("HDRF_CDC_PALLAS", "")
-    if env == "0":
+    if env in ("", "0"):
         return "off"
+    on_tpu = jax.default_backend() == "tpu"
     if env == "interpret":
+        if on_tpu:
+            raise RuntimeError("HDRF_CDC_PALLAS=interpret on a TPU backend: "
+                               "no kernel runs interpreted on a chip")
         return "interpret"
+    return "mosaic" if on_tpu else "interpret"
+
+
+def scan_pallas_mode() -> str:
+    """Gate of the scan-only kernel (``_scan_call``, the per-shard candidate
+    scan of parallel/sharded.py), which Mosaic does lower: 'mosaic' on a
+    TPU backend, otherwise what HDRF_CDC_PALLAS asks of the interpreter
+    ('off' by default on the CPU mesh)."""
     if jax.default_backend() == "tpu":
         return "mosaic"
-    if env == "1":  # forcing the fused path without a chip = interpreter
-        return "interpret"
-    return "off"
+    return cdc_pallas_mode()
 
 
 def cdc_skip_ahead() -> bool:
@@ -523,7 +545,7 @@ def chunks_fused(data: bytes | np.ndarray, mask: int, min_chunk: int,
     if a.size == 0:
         return np.empty(0, dtype=np.uint64), False
     if interpret is None:
-        interpret = cdc_pallas_mode() != "mosaic"
+        interpret = jax.default_backend() != "tpu"
     p = plan_for(a.size, mask, mask_bits, min_chunk, max_chunk,
                  b_small=1 << 30, b_big=1 << 30, skip_ahead=skip_ahead)
     buf = np.zeros(p.n_pad, dtype=np.uint8)

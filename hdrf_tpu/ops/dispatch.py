@@ -29,16 +29,16 @@ _M = _metrics.registry("ops_dispatch")
 
 
 def resolve_backend(backend: str) -> str:
+    """``auto`` -> ``tpu`` when a TPU is attached, else ``native``.  Asking
+    initialises JAX's backend in this process, so only a process that may
+    own the device asks (a DataNode that fronts a worker does not), and a
+    chip that is present but cannot be had raises here rather than
+    demoting the process to the host codec."""
     if backend != "auto":
         return backend
-    try:
-        import jax
+    import jax
 
-        if any(d.platform == "tpu" for d in jax.devices()):
-            return "tpu"
-    except Exception:
-        pass
-    return "native"
+    return "tpu" if jax.devices()[0].platform == "tpu" else "native"
 
 
 def gear_mask(cdc: CdcConfig) -> int:

@@ -110,3 +110,27 @@ def sha256_words_pallas(words: jax.Array, nblocks: jax.Array) -> jax.Array:
     o = jnp.stack([(st >> np.uint32(s)).astype(jnp.uint8)
                    for s in (24, 16, 8, 0)], axis=-1)
     return o.reshape(L, 32)
+
+
+def selfcheck_odd_lane_rows() -> bool:
+    """The kernel against hashlib at lane counts that are NOT a multiple of
+    a tile's rows (the stale-tail-rows regression above).  Only a real chip
+    runs the Mosaic kernel, so the device-owning worker runs this when
+    ``chip_smoke.py`` asks for its report."""
+    import hashlib
+
+    for L in (384, 3840):
+        data = np.random.default_rng(L).integers(0, 256, size=(L, 32),
+                                                 dtype=np.uint8)
+        w = np.zeros((L, 16), dtype=np.uint32)
+        be = data.reshape(L, 8, 4).astype(np.uint32)
+        w[:, :8] = (be[:, :, 0] << 24) | (be[:, :, 1] << 16) \
+            | (be[:, :, 2] << 8) | be[:, :, 3]
+        w[:, 8] = 0x80000000
+        w[:, 15] = 256
+        out = np.asarray(sha256_words_pallas(
+            jax.device_put(w), jax.device_put(np.ones(L, np.int32))))
+        if any(bytes(out[i]) != hashlib.sha256(data[i].tobytes()).digest()
+               for i in range(L)):
+            return False
+    return True

@@ -28,10 +28,15 @@ ties only among don't-care slots (invalid-record padding), which is why
 results are bit-identical to ``jax.lax.sort`` on the live data
 (tests/test_sort_pallas.py asserts both properties).
 
-Falls back to ``jax.lax.sort`` off-TPU (the 8-virtual-device CPU test
-mesh), for sub-1024-entry rows (tile underflow), and under
+``jax.lax.sort`` runs instead off-TPU (the 8-virtual-device CPU test
+mesh), for sub-1024-entry rows (tile underflow), for rows wider than
+``_MAX_E`` (compile time and VMEM, see there), and under
 ``HDRF_SORT_PALLAS=0``; ``interpret=True`` runs the same kernel through the
-Pallas interpreter so the CPU mesh can execute the network itself.
+Pallas interpreter so the CPU mesh can execute the network itself.  At the
+default geometry (32 MiB container, stride 2) that puts the L1 and L2 record
+pack sorts (e = 8192) on the kernel and the match-delta sorts (e = 65536),
+the L3 pack (e = 524288) and the escape packs (e = 131072) on
+``jax.lax.sort``.
 
 Re-expresses the sort stage the reference reaches through its JNI hash
 table (DataDeduplicator.java:770-781 codec path) in the TPU-native
@@ -52,6 +57,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _MIN_E = 1024          # below this the (R, 128) view loses whole-tile rows
+# Widest row the kernels take.  The networks are fully unrolled, so Mosaic's
+# compile time grows faster than e*log^2(e): compiled for v5e (PR 22),
+# sort_rows with one value took 11 s at e=32768, 39 s at 65536 and 149 s at
+# 131072; match_deltas took 35 s at 32768 and at 65536 ran out of VMEM after
+# 134 s.  Wider rows take ``jax.lax.sort``, chosen here by shape.
+_MAX_E = 32768
 _BIAS = np.uint32(0x80000000)
 _HASH_MUL = np.uint32(2654435761)   # golden-ratio multiplier (lz4.cpp hash4)
 
@@ -114,7 +125,9 @@ def _network(key, vals, e: int):
             # want_max = ascending XOR low-slot; low-slot = bit j clear.
             want_max = jnp.logical_xor(~_bit(key.shape, k),
                                        ~_bit(key.shape, j))
-            take = jnp.where(want_max, pk > key, pk < key)
+            # (Mosaic refuses a select over two i1 vectors — "Unsupported
+            # target bitwidth for truncation" — so the choice is and/or.)
+            take = (want_max & (pk > key)) | (~want_max & (pk < key))
             key = jnp.where(take, pk, key)
             vals = [jnp.where(take, pv, v) for pv, v in zip(pvs, vals)]
             j >>= 1
@@ -197,12 +210,13 @@ def sort_rows(key, *vals, impl: str | None = None, interpret: bool = False,
     if impl is None:
         impl = "pallas" if (use_pallas() or interpret) else "xla"
     e = key.shape[1]
-    if impl != "pallas" or e < _MIN_E:
+    ep = 1 << (e - 1).bit_length()
+    if impl != "pallas" or e < _MIN_E or ep > _MAX_E:
         return jax.lax.sort((key, *vals), dimension=1, num_keys=1)
-    if e & (e - 1):
+    if ep != e:
         assert pad_key is not None, "non-pow2 rows need a pad sentinel"
         key, vals = _pow2_pad(key, list(vals), pad_key, pad_vals)
-        e = key.shape[1]
+        e = ep
     return _sort_rows_call(e, len(vals), key.dtype == jnp.uint32,
                            interpret)(key, *vals)
 
@@ -316,6 +330,6 @@ def match_deltas(vals, posn, stride: int, pos_bits: int,
     if impl is None:
         impl = "pallas" if (use_pallas() or interpret) else "xla"
     e = vals.shape[1]
-    if impl != "pallas" or e < _MIN_E or e & (e - 1):
+    if impl != "pallas" or e < _MIN_E or e > _MAX_E or e & (e - 1):
         return match_deltas_xla(vals, posn, stride, pos_bits)
     return _match_deltas_call(e, stride, pos_bits, interpret)(vals)

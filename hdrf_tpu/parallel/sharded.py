@@ -30,12 +30,8 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from hdrf_tpu.ops import gear
 from hdrf_tpu.utils import device_ledger as _ledger
@@ -144,13 +140,14 @@ def candidate_words_sharded(mesh: Mesh, fused: str | None = None):
     ``fused`` routes the per-shard scan through the fused Pallas kernel
     (ops/cdc_pallas.py) instead of the XLA doubling scan — same halo, same
     packed-bitmap contract, asserted bit-identical in tests/test_cdc_pallas.py.
-    None resolves via cdc_pallas.cdc_pallas_mode() ('off' on the CPU mesh).
+    None resolves via cdc_pallas.scan_pallas_mode() ('mosaic' on a TPU
+    backend, 'off' on the CPU mesh).
     """
     from hdrf_tpu.ops import cdc_pallas
 
     n_seq = mesh.shape["seq"]
     if fused is None:
-        fused = cdc_pallas.cdc_pallas_mode()
+        fused = cdc_pallas.scan_pallas_mode()
 
     kw = {}
     if fused != "off":
@@ -158,7 +155,7 @@ def candidate_words_sharded(mesh: Mesh, fused: str | None = None):
         # shard_map has no replication rule for pallas_call; the psum below
         # makes the count output replicated by construction, so the check
         # is safely skipped on the fused route.
-        kw["check_rep"] = False
+        kw["check_vma"] = False
 
         def scan(block: jax.Array, mask: jax.Array):
             words, cnt = cdc_pallas.local_candidate_words_pallas(
@@ -281,9 +278,13 @@ def _sha_chunks_sharded(mesh: Mesh, bucket: int, pad_words: int):
                                  jnp.zeros(pad_words, jnp.uint32)])
         return _bucket_sha(words, ol, bucket)
 
+    # check_vma off: on a TPU backend _bucket_sha hashes with the Pallas
+    # SHA kernel, and pallas_call out_shapes carry no vma; every output is
+    # sharded over all axes, so there is no replication claim to check.
     fn = jax.jit(_shard_map(
         local, mesh=mesh,
-        in_specs=(P("seq"), P(None, axes)), out_specs=P(axes)))
+        in_specs=(P("seq"), P(None, axes)), out_specs=P(axes),
+        check_vma=False))
     _sha_fns.put(key, fn)
     return fn
 
@@ -327,7 +328,8 @@ def _sha_chunks_halo(mesh: Mesh, bucket: int, pad_words: int,
     fn = jax.jit(_shard_map(
         local, mesh=mesh,
         in_specs=(P("seq"), P("data", "seq")),
-        out_specs=P(("data", "seq"))))
+        out_specs=P(("data", "seq")),
+        check_vma=False))   # Pallas SHA inside, as in _sha_chunks_sharded
     _sha_halo_fns.put(key, fn)
     return fn
 
@@ -365,8 +367,8 @@ def reduce_sharded(data: bytes | np.ndarray, cdc, mesh: Mesh):
     buf = np.zeros(n + ((-n) % grid), dtype=np.uint8)
     buf[:n] = a
     block_sh = _put_global(buf, NamedSharding(mesh, P("seq")))
-    from hdrf_tpu.ops.cdc_pallas import cdc_pallas_mode
-    scan_mode = cdc_pallas_mode()
+    from hdrf_tpu.ops.cdc_pallas import scan_pallas_mode
+    scan_mode = scan_pallas_mode()
     ev = _ledger.dispatch("sharded.scan", key=(buf.size, n_seq, scan_mode))
     words, _ = candidate_words_sharded(mesh, fused=scan_mode)(
         block_sh, jnp.uint32(mask & 0xFFFFFFFF))
@@ -682,7 +684,7 @@ def _mesh_step(mesh: Mesh, Kl: int, n_pad: int, mn: int, mx: int,
         step, mesh=mesh,
         in_specs=(P("data", None), P("data"), P(), P("data", None, None)),
         out_specs=(P("data", None), P("data"), P("data", None), P()),
-        check_rep=False), donate_argnums=(0,))
+        check_vma=False), donate_argnums=(0,))
     _mesh_step_fns.put(key, fn)
     return fn
 
@@ -710,7 +712,7 @@ def _bucket_upd_fn(mesh: Mesh, R: int, S: int):
     fn = jax.jit(_shard_map(
         upd, mesh=mesh,
         in_specs=(P("data", None, None), P()),
-        out_specs=P("data", None, None), check_rep=False),
+        out_specs=P("data", None, None), check_vma=False),
         donate_argnums=(0,))
     _bucket_upd_fns.put(key, fn)
     return fn
@@ -976,7 +978,7 @@ def _lz4_scan_fn(mesh: Mesh, Kl: int, n_pad: int, stride: int,
 
     fn = jax.jit(_shard_map(
         scan, mesh=mesh, in_specs=(P("data", None),),
-        out_specs=P("data", None), check_rep=False), donate_argnums=(0,))
+        out_specs=P("data", None), check_vma=False), donate_argnums=(0,))
     _lz4_mesh_fns.put(key, fn)
     return fn
 

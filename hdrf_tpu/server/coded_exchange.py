@@ -48,12 +48,13 @@ _EC = metrics.registry("ec")
 _MIN_PACK = 64
 
 
-def backend_for(red) -> str:
-    """Codec backend for exchange intermediates: the reduction config's
-    backend when it resolves to the TPU (compress_many batches there),
-    the native host codec otherwise."""
-    b = dispatch.resolve_backend(getattr(red, "backend", "native"))
-    return b if b == "tpu" else "native"
+def backend_for(dn) -> str:
+    """Codec backend for exchange intermediates: the DataNode's resolved
+    in-process backend when that is the TPU (compress_many batches there),
+    the native host codec otherwise.  Read from the DN's reduction context,
+    never re-resolved: a DN that fronts a worker must not probe for a
+    device the worker owns."""
+    return "tpu" if dn.reduction_ctx.backend == "tpu" else "native"
 
 
 def pack_many(datas: list[bytes], backend: str = "native"
@@ -145,7 +146,7 @@ class CodedExchange:
 
     @property
     def backend(self) -> str:
-        return backend_for(self._dn.reduction_ctx.config)
+        return backend_for(self._dn)
 
     def lane(self):
         """The background control-lane context (re-exported so callers

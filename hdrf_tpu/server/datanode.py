@@ -137,7 +137,12 @@ class DataNode:
 
         storage_version.ensure_layout(config.data_dir, "datanode",
                                       storage_version.DN_UPGRADERS)
-        backend = ops_dispatch.resolve_backend(red.backend)
+        # A DN that fronts a worker never asks JAX which devices exist: the
+        # worker is the one process that owns the chip, and this process's
+        # own compute (degraded writes, host seals) is the native library.
+        backend = ("native" if red.backend == "auto"
+                   and (red.worker_spawn or red.worker_addr)
+                   else ops_dispatch.resolve_backend(red.backend))
         # Seal entropy stage (the reference's rollover LZ4,
         # DataDeduplicator.java:770-781), most-capable-first: the
         # co-located worker process (device-owning; the DN host stays
@@ -914,8 +919,18 @@ class DataNode:
 
         while not self._stop.wait(interval):
             fault_injection.point("datanode.heartbeat", dn_id=self.dn_id)
-            self._cdc_tick()
-            stats = self._stats()
+            try:
+                self._cdc_tick()
+                stats = self._stats()
+            except Exception as e:  # noqa: BLE001
+                # One failed tick must not end the thread: a DN that stops
+                # heartbeating is declared dead while it still serves (on
+                # the v5e host a stats race with a container seal did
+                # exactly that, PR 22).
+                _M.incr("heartbeat_failures")
+                self._log.warning("heartbeat tick failed", dn_id=self.dn_id,
+                                  error=f"{type(e).__name__}: {e}")
+                continue
             for nn in self._nns:
                 try:
                     resp = nn.call("heartbeat", dn_id=self.dn_id, stats=stats)

@@ -336,7 +336,7 @@ class MirrorPlane:
         wire, extra = seg, {}
         if getattr(red, "mirror_compress_segments", True):
             payload, enc = coded_exchange.pack(
-                seg, coded_exchange.backend_for(red))
+                seg, coded_exchange.backend_for(dn))
             if enc:
                 wire = payload
                 extra = {"seg_enc": 1, "seg_usize": len(seg)}

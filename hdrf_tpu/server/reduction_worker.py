@@ -26,6 +26,7 @@ Run standalone: ``python -m hdrf_tpu.server.reduction_worker --port 0``.
 
 from __future__ import annotations
 
+import os
 import socket
 import socketserver
 import threading
@@ -59,11 +60,24 @@ class ReductionWorker:
         from hdrf_tpu.ops import dispatch as ops_dispatch
 
         self.backend = ops_dispatch.resolve_backend(backend)
+        # What the device path runs on, as JAX reports it — carried by
+        # ``ping`` so a parent that must stay off JAX can report it.  The
+        # native backend never initialises JAX.
+        if self.backend == "tpu":
+            from hdrf_tpu.utils import device_env
+
+            self.device = device_env.device_info()
+        else:
+            self.device = {"platform": None, "kind": None, "count": 0}
         self._reducers: dict[tuple, Any] = {}
         self._lz4 = None
         self._stats_lock = threading.Lock()
+        # *_s: cumulative seconds of the streamed reduce's two legs (packets
+        # in + H2D strides; then assembly, scan, select, SHA and readbacks)
+        # and of compress jobs — host clock, this process
         self._stats = {"blocks_reduced": 0, "bytes_reduced": 0,
-                       "compress_jobs": 0}
+                       "compress_jobs": 0, "ingest_s": 0.0,
+                       "reduce_s": 0.0, "compress_s": 0.0}
         outer = self
 
         class Handler(socketserver.BaseRequestHandler):
@@ -129,10 +143,13 @@ class ReductionWorker:
                     else:
                         self._op_compress_batch(sock, req)
             elif op == "ping":
-                send_frame(sock, {"ok": True, "backend": self.backend})
+                send_frame(sock, {"ok": True, "backend": self.backend,
+                                  "device": self.device})
             elif op == "stats":
                 with self._stats_lock:
                     send_frame(sock, dict(self._stats))
+            elif op == "device_report":
+                send_frame(sock, self._device_report(bool(req.get("probe"))))
             elif op == "traces":
                 from hdrf_tpu.utils import device_ledger
 
@@ -148,6 +165,35 @@ class ReductionWorker:
         except Exception as e:  # noqa: BLE001 — errors cross the wire
             _M.incr("op_errors")
             send_frame(sock, {"error": type(e).__name__, "message": str(e)})
+
+    def _device_report(self, probe: bool) -> dict:
+        """Device-side facts for a parent that never touches JAX: dispatch
+        counters, awaited dispatches per op with their enqueue-to-readback
+        wait (``resident.prep_retry`` is the CDC candidate-overflow retry),
+        the LZ4 stage's give-way counters,
+        compile seconds per program and the cache directory; with
+        ``probe`` also the box (device_env.probe_box — runs device work)
+        and, on a chip, the Pallas SHA kernel's odd-lane-rows self-check."""
+        from hdrf_tpu.utils import device_env, device_ledger
+
+        hists = metrics.registry("device_ledger").snapshot()["histograms"]
+        out = {"backend": self.backend, "device": self.device,
+               "cache_dir": device_env.cache_dir(),
+               "compile_s": device_env.compile_seconds(),
+               "ledger": device_ledger.stamp(),
+               "ops": {k.split("op=", 1)[1]:
+                       {"n": h["count"], "mean_ms": h["mean"] / 1e3,
+                        "max_ms": h["max"] / 1e3}
+                       for k, h in hists.items() if "|op=" in k},
+               "lz4": metrics.registry("lz4_tpu").snapshot()["counters"]}
+        if probe and self.backend == "tpu":
+            from hdrf_tpu.ops import sha256_pallas
+
+            out["box"] = device_env.probe_box()
+            if self.device["platform"] == "tpu":   # Mosaic runs nowhere else
+                out["sha_odd_rows_ok"] = \
+                    sha256_pallas.selfcheck_odd_lane_rows()
+        return out
 
     def _reducer(self, cdc: CdcConfig):
         key = (cdc.mask_bits, cdc.min_chunk, cdc.max_chunk)
@@ -191,6 +237,7 @@ class ReductionWorker:
         pend: list[bytes] = []  # current stride accumulator
         pend_n = 0
         total = 0
+        t0 = time.perf_counter()
         for _seq, data, _last in dt.iter_packets(sock):
             if data:
                 pend.append(data)
@@ -211,20 +258,27 @@ class ReductionWorker:
         pad = (-total) % _PAD_GRID
         if pad:
             parts.append(jnp.zeros(pad, jnp.uint8))
+        t1 = time.perf_counter()
         block = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
         r = self._reducer(cdc)
         job = r.submit(block, n=total)
         r.start_sha(job)
-        return r.finish(job)
+        out = r.finish(job)
+        with self._stats_lock:
+            self._stats["ingest_s"] += t1 - t0
+            self._stats["reduce_s"] += time.perf_counter() - t1
+        return out
 
     def _op_compress(self, sock: socket.socket, req: dict) -> None:
         from hdrf_tpu.ops import dispatch as ops_dispatch
 
         data = dt.collect_packets(sock)
+        t0 = time.perf_counter()
         out = ops_dispatch.block_compress(req.get("codec", "lz4"), data,
                                           self.backend)
         with self._stats_lock:
             self._stats["compress_jobs"] += 1
+            self._stats["compress_s"] += time.perf_counter() - t0
         send_frame(sock, {"data": bytes(out)})
         _M.incr("compress_jobs")
         accounting.record_worker_bytes("compress", len(data))
@@ -382,7 +436,7 @@ class WorkerClient:
         The deadline budget accrues ``deadline_s_per_mb`` per streamed MiB
         (payload size is only known as it arrives).
 
-        Exception taxonomy: worker-side failures raise :class:`WorkerError`;
+        Exception classes: worker-side failures raise :class:`WorkerError`;
         anything the ``packets`` iterator itself raises (the caller's OWN
         stream — e.g. the DN's client connection dying) propagates
         unchanged, so the caller can tell the two apart."""
@@ -493,10 +547,12 @@ class WorkerClient:
                 self._fail(e)
             raise
 
-    def ping(self) -> dict:
+    def _poll(self, req: dict) -> dict:
+        """One ungated request/response (observability polls stay outside
+        the breaker, see the class docstring)."""
         s = self._conn(self._deadline(), gated=False)
         try:
-            send_frame(s, {"op": "ping"})
+            send_frame(s, req)
             out = self._checked(recv_frame(s))
             self._release(s)
             return out
@@ -504,29 +560,20 @@ class WorkerClient:
             s.close()
             raise
 
+    def ping(self) -> dict:
+        return self._poll({"op": "ping"})
+
     def stats(self) -> dict:
-        s = self._conn(self._deadline(), gated=False)
-        try:
-            send_frame(s, {"op": "stats"})
-            out = self._checked(recv_frame(s))
-            self._release(s)
-            return out
-        except BaseException:
-            s.close()
-            raise
+        return self._poll({"op": "stats"})
+
+    def device_report(self, probe: bool = False) -> dict:
+        """ReductionWorker._device_report over the wire."""
+        return self._poll({"op": "device_report", "probe": probe})
 
     def traces(self) -> dict:
         """Worker-process spans + device-ledger events (the DN proxies this
         through its own trace_spans op for the gateway merge)."""
-        s = self._conn(self._deadline(), gated=False)
-        try:
-            send_frame(s, {"op": "traces"})
-            out = self._checked(recv_frame(s))
-            self._release(s)
-            return out
-        except BaseException:
-            s.close()
-            raise
+        return self._poll({"op": "traces"})
 
     def close(self) -> None:
         with self._lock:
@@ -538,7 +585,8 @@ class WorkerClient:
 def spawn_local_worker(backend: str = "auto"):
     """Launch a worker as a real SEPARATE PROCESS (the co-located
     deployment shape); returns (Popen, (host, port)).  The caller owns the
-    process (terminate() when done)."""
+    process (terminate() when done).  The worker's stderr is the caller's:
+    a worker that cannot have its device says why where someone reads it."""
     import re
     import subprocess
     import sys
@@ -546,13 +594,31 @@ def spawn_local_worker(backend: str = "auto"):
     proc = subprocess.Popen(
         [sys.executable, "-m", "hdrf_tpu.server.reduction_worker",
          "--port", "0", "--backend", backend],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+        stdout=subprocess.PIPE, text=True)
     line = proc.stdout.readline()
     m = re.search(r"listening on ([\d.]+):(\d+)", line)
     if not m:
         proc.terminate()
-        raise RuntimeError(f"worker failed to start: {line!r}")
+        rc = proc.wait()
+        raise RuntimeError(
+            f"worker failed to start (rc={rc}, stdout={line!r}; "
+            "its stderr is above)")
     return proc, (m.group(1), int(m.group(2)))
+
+
+def stop_local_worker(proc, grace_s: float = 20.0) -> None:
+    """SIGTERM, then SIGKILL after ``grace_s``: a worker that holds a chip
+    takes several seconds to die of SIGTERM (measured on the v5e host,
+    PR 22: more than 5), and must be gone before its owner returns."""
+    import subprocess
+
+    if proc.poll() is None:
+        proc.terminate()
+        try:
+            proc.wait(timeout=grace_s)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
 
 
 class WorkerSupervisor:
@@ -639,12 +705,8 @@ class WorkerSupervisor:
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=5)
-        if self._proc is not None and self._proc.poll() is None:
-            self._proc.terminate()
-            try:
-                self._proc.wait(timeout=5)
-            except Exception:
-                self._proc.kill()
+        if self._proc is not None:
+            stop_local_worker(self._proc)
 
 
 def main(argv=None) -> int:
@@ -655,12 +717,26 @@ def main(argv=None) -> int:
     p.add_argument("--port", type=int, default=0)
     p.add_argument("--backend", default="auto")
     args = p.parse_args(argv)
+    import sys
+
+    from hdrf_tpu.utils import device_env
+
+    if args.backend != "native":
+        device_env.enable_compile_cache()
+    if args.backend == "tpu":
+        # Asked for the chip: anything else is a failure to start, said on
+        # stderr, never a quiet run of the device programs on XLA:CPU.
+        dev = device_env.device_info()
+        if dev["platform"] != "tpu":
+            print(f"reduction worker: --backend tpu but JAX reports "
+                  f"platform {dev['platform']!r} ({dev['kind']}, "
+                  f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}); "
+                  "refusing to start", file=sys.stderr)
+            return 3
     w = ReductionWorker(args.host, args.port, backend=args.backend).start()
     # Startup banner goes to STDOUT (spawn_local_worker regex-parses the
     # "listening on host:port" substring off the first line — present in
     # both the text and JSON log formats).
-    import sys
-
     from hdrf_tpu.utils import log
 
     log.get_logger("reduction_worker", stream=sys.stdout).info(
@@ -668,8 +744,6 @@ def main(argv=None) -> int:
         f"{w.addr[0]}:{w.addr[1]}", backend=w.backend)
     try:
         while True:
-            import time
-
             time.sleep(3600)
     except KeyboardInterrupt:
         w.stop()

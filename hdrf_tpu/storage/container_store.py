@@ -718,7 +718,10 @@ class ContainerStore:
         total = 0
         for name in os.listdir(self._dir):
             if name.endswith(".raw") or name.endswith(".sealed"):
-                total += os.path.getsize(os.path.join(self._dir, name))
+                try:
+                    total += os.path.getsize(os.path.join(self._dir, name))
+                except FileNotFoundError:
+                    pass   # sealed (raw unlinked) or deleted since listdir
         return total
 
     def container_sizes(self) -> dict[int, int]:
@@ -732,6 +735,9 @@ class ContainerStore:
             if stem.isdigit() and (name.endswith(".raw")
                                    or name.endswith(".sealed")):
                 cid = int(stem)
-                out[cid] = out.get(cid, 0) + os.path.getsize(
-                    os.path.join(self._dir, name))
+                try:
+                    size = os.path.getsize(os.path.join(self._dir, name))
+                except FileNotFoundError:
+                    continue   # as in physical_bytes
+                out[cid] = out.get(cid, 0) + size
         return out

@@ -52,9 +52,11 @@ class MiniCluster:
         reduction-worker PROCESS shared by every DN (the north-star
         out-of-process deployment; backend auto-resolves — native on the
         CPU test mesh, device on a real chip).  ``worker_backend`` pins
-        the worker's backend (e.g. ``"tpu"`` to force the jax path on a
-        virtual-device mesh); ``backend`` pins the DNs' in-process
-        reduction backend (default stays the deterministic native)."""
+        the worker's backend: ``"tpu"`` insists on a chip — the worker
+        exits non-zero without one (chip_smoke.py's layout), it does not
+        run the device programs on XLA:CPU; ``backend`` pins the DNs'
+        in-process reduction backend (default stays the deterministic
+        native)."""
         self.n_datanodes = n_datanodes
         self.ha = ha
         self.n_journal = journal_nodes
@@ -252,8 +254,9 @@ class MiniCluster:
             except Exception:  # noqa: BLE001 — may already be stopped
                 pass
         if self._worker_proc is not None:
-            self._worker_proc.terminate()
-            self._worker_proc.wait(timeout=5)
+            from hdrf_tpu.server.reduction_worker import stop_local_worker
+
+            stop_local_worker(self._worker_proc)
             self._worker_proc = None
         # drop per-edge circuit breakers (process-wide registry): a breaker
         # opened by THIS cluster's faults must not leak into the next test's

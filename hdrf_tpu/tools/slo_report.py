@@ -204,8 +204,8 @@ def changepoint(vals: list[float]) -> dict | None:
         s = _sse(vals[:k]) + _sse(vals[k:])
         if s < best_sse:
             best_k, best_sse = k, s
-    if best_k is None:
-        return None
+    if best_k is None or total - best_sse <= 1e-12:
+        return None   # constant series (a rounding-noise "gain" is none)
     before = sum(vals[:best_k]) / best_k
     after = sum(vals[best_k:]) / (n - best_k)
     return {"index": best_k, "before": before, "after": after,

@@ -153,6 +153,30 @@ class TestFailure:
                     pytest.fail("file unreadable after NN restart")
 
 
+class TestHeartbeatSurvives:
+    def test_one_failing_stats_tick_does_not_end_the_heartbeat(self):
+        """A raising _stats() used to kill the heartbeat thread: the NN then
+        declared a DN dead that was still serving."""
+        with MiniCluster(n_datanodes=1, replication=1) as mc:
+            dn = mc.datanodes[0]
+            real, calls = dn._stats, {"n": 0}
+
+            def flaky():
+                calls["n"] += 1
+                if calls["n"] == 1:
+                    raise FileNotFoundError("containers/4.raw")
+                return real()
+
+            dn._stats = flaky
+            deadline = time.monotonic() + 5.0
+            while calls["n"] < 3 and time.monotonic() < deadline:
+                time.sleep(0.05)
+            assert calls["n"] >= 3, "heartbeat thread died on the first error"
+            time.sleep(2.0)     # past dead_node_s: still heartbeating
+            with mc.client() as c:
+                assert [d["alive"] for d in c.datanode_report()] == [True]
+
+
 class TestPlacementAndTrash:
     def test_rack_aware_placement(self, tmp_path):
         from hdrf_tpu.config import DataNodeConfig, NameNodeConfig

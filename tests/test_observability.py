@@ -366,9 +366,34 @@ class TestTraceAssembly:
         event (the worker's resident-pipeline dispatches)."""
         base = blob(7, 32 * 1024)
         data = base * 3 + blob(8, 32 * 1024)   # dedup-friendly, 128 KiB
+        # ``--backend tpu`` refuses to start without a chip, so the worker
+        # that runs the device programs on the virtual mesh is started
+        # from here, around main()'s check — steering stays in the test.
+        code = ("import sys, time; "
+                "from hdrf_tpu.server.reduction_worker import "
+                "ReductionWorker; "
+                "w = ReductionWorker(backend='tpu').start(); "
+                "print('listening on %s:%d' % w.addr, flush=True); "
+                "time.sleep(600)")
+        proc = subprocess.Popen([sys.executable, "-c", code], cwd=REPO,
+                                stdout=subprocess.PIPE, text=True)
+        try:
+            m = re.search(r"listening on ([\d.]+):(\d+)",
+                          proc.stdout.readline())
+            assert m, "device-path worker failed to start"
+            doc, root = self._trace_one_write(
+                data, [m.group(1), int(m.group(2))])
+        finally:
+            proc.kill()
+            proc.wait()
+        self._check_trace(doc, root)
+
+    @staticmethod
+    def _trace_one_write(data, worker_addr):
         with MiniCluster(n_datanodes=1, replication=1,
-                         block_size=256 * 1024, tpu_worker=True,
-                         worker_backend="tpu") as mc:
+                         block_size=256 * 1024,
+                         reduction_overrides={"worker_addr": worker_addr}
+                         ) as mc:
             gw = HttpGateway(mc.namenode.addr).start()
             try:
                 tr = tracing.tracer("obs_e2e_client")
@@ -383,6 +408,11 @@ class TestTraceAssembly:
                 doc = json.loads(body)
             finally:
                 gw.stop()
+        return doc, root
+
+    @staticmethod
+    def _check_trace(doc, root):
+        tid = f"{root.trace_id:016x}"
         evs = doc["traceEvents"]
         spans = [e for e in evs if e.get("cat") == "span"]
         names = {e["name"] for e in spans}

@@ -114,6 +114,54 @@ class TestDataTransfer:
         assert dt.collect_packets(b) == data
         a.close(), b.close()
 
+    @pytest.mark.parametrize("window", [1, 4, 16])
+    def test_stream_bytes_acked_keeps_the_window(self, window):
+        """At most ``window`` packets are ever un-acked, and the LAST ack
+        (the one carrying pipeline status) is what comes back — a receiver
+        that acks a packet only as it consumes it never sees the sender
+        more than ``window`` ahead."""
+        a, b = self._pair()
+        data = bytes(range(256)) * 400           # 25 packets of 4096
+        seen = {"max_ahead": 0, "data": b""}
+
+        def receiver():
+            got = acked = 0
+            for seqno, pkt, last in dt.iter_packets(b):
+                got += 1
+                seen["max_ahead"] = max(seen["max_ahead"], got - acked)
+                seen["data"] += pkt
+                dt.send_ack(b, seqno,
+                            dt.ACK_ERROR if last else dt.ACK_SUCCESS)
+                acked += 1
+
+        t = threading.Thread(target=receiver)
+        t.start()
+        out = dt.stream_bytes_acked(a, data, 4096, window)
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert out == (25, dt.ACK_ERROR)         # the trailer's ack
+        assert seen["data"] == data
+        assert seen["max_ahead"] == 1            # receiver acks as it reads
+        a.close(), b.close()
+
+    def test_stream_bytes_acked_blocks_at_the_window(self):
+        """A receiver that withholds acks stops the sender after ``window``
+        packets instead of letting it run the whole block ahead."""
+        a, b = self._pair()
+        a.settimeout(0.5)
+        with pytest.raises(socket.timeout):
+            dt.stream_bytes_acked(a, b"x" * 4096 * 50, 4096, 3)
+        b.settimeout(0.5)
+        n = 0
+        try:
+            while True:
+                dt.read_packet(b)
+                n += 1
+        except socket.timeout:
+            pass
+        assert n == 3
+        a.close(), b.close()
+
     def test_op_header_roundtrip(self):
         a, b = self._pair()
         dt.send_op(a, dt.WRITE_BLOCK, block_id=5, targets=[{"addr": ["h", 1]}])

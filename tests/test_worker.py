@@ -4,7 +4,8 @@ BlockReceiver streams block packets to the worker; bytes land in HBM).
 
 On the CPU test mesh the worker backend auto-resolves to native — the
 plumbing (process boundary, streaming ingest, completion flow, fallback)
-is identical; the real-chip variant runs in test_tpu_e2e.py."""
+is identical; the real-chip variant is chip_smoke.py, run through the chip
+tool (its CPU rehearsal is tests/test_chip_smoke.py)."""
 
 from __future__ import annotations
 
@@ -90,7 +91,66 @@ class TestWorkerProtocol:
         c.close()
 
 
+    def test_ping_carries_the_device(self, worker):
+        """A parent that stays off JAX reports the device from ping: a
+        native worker owns none (and never initialises JAX to find out)."""
+        c = WorkerClient(worker.addr)
+        assert c.ping()["device"] == {"platform": None, "kind": None,
+                                      "count": 0}
+        c.close()
+
+    def test_device_report_of_the_device_path(self):
+        """backend="tpu" in-process on the CPU mesh (only main() insists on
+        a chip): ping names what JAX runs on, and the report carries
+        per-op awaited dispatches, the ledger and the LZ4 counters."""
+        w = ReductionWorker(backend="tpu").start()
+        try:
+            c = WorkerClient(w.addr)
+            dev = c.ping()["device"]
+            assert dev["platform"] == "cpu" and dev["count"] >= 1
+            # the ledger is process-wide: count what this reduce adds
+            before = c.device_report()
+            c.reduce(_bytes(200_000), CdcConfig())
+            rep = c.device_report()
+            assert rep["device"] == dev and rep["backend"] == "tpu"
+            assert rep["ledger"]["dispatch_total"] >= \
+                before["ledger"]["dispatch_total"] + 2
+            assert rep["ops"]["resident.prep"]["n"] == \
+                before["ops"].get("resident.prep", {"n": 0})["n"] + 1
+            assert rep["ops"]["resident.sha"]["mean_ms"] > 0
+            assert "box" not in rep and isinstance(rep["lz4"], dict)
+            st = c.stats()
+            assert st["reduce_s"] > 0 and st["ingest_s"] >= 0
+            c.close()
+        finally:
+            w.stop()
+
+
 class TestWorkerProcess:
+    def test_tpu_backend_without_a_chip_refuses_to_start(self, capfd):
+        """--backend tpu on a host where JAX finds no TPU: non-zero exit,
+        the reason on stderr, and spawn_local_worker lets that stderr
+        through instead of demoting to the host codec in silence."""
+        with pytest.raises(RuntimeError, match=r"rc=3"):
+            spawn_local_worker(backend="tpu")
+        err = capfd.readouterr().err
+        assert "--backend tpu but JAX reports platform 'cpu'" in err
+
+    def test_resolve_backend_raises_what_jax_raises(self, monkeypatch):
+        """auto no longer swallows: a chip that is present but cannot be
+        had is an error, not the native backend."""
+        import jax
+
+        from hdrf_tpu.ops import dispatch
+
+        def busy():
+            raise RuntimeError("TPU is already in use")
+
+        monkeypatch.setattr(jax, "devices", busy)
+        with pytest.raises(RuntimeError, match="already in use"):
+            dispatch.resolve_backend("auto")
+        assert dispatch.resolve_backend("native") == "native"
+
     def test_spawn_real_process(self):
         proc, addr = spawn_local_worker(backend="native")
         try:
@@ -105,6 +165,34 @@ class TestWorkerProcess:
 
 
 class TestClusterWithWorker:
+    def test_datanode_fronting_a_worker_never_asks_jax(self, tmp_path,
+                                                       monkeypatch):
+        """backend="auto" (the config default) + a configured worker: the
+        DN's own backend is native WITHOUT resolve_backend -> jax.devices()
+        — the worker is the one process that owns the chip."""
+        from hdrf_tpu.config import DataNodeConfig, NameNodeConfig
+        from hdrf_tpu.ops import dispatch
+        from hdrf_tpu.server.datanode import DataNode
+        from hdrf_tpu.server.namenode import NameNode
+
+        w = ReductionWorker(backend="native").start()
+        monkeypatch.setattr(
+            dispatch, "resolve_backend",
+            lambda b: pytest.fail("a DN with a worker resolved its backend"))
+        nn = NameNode(NameNodeConfig(meta_dir=str(tmp_path / "nn"),
+                                     replication=1)).start()
+        cfg = DataNodeConfig(data_dir=str(tmp_path / "dn"))
+        assert cfg.reduction.backend == "auto"
+        cfg.reduction.worker_addr = list(w.addr)
+        dn = DataNode(cfg, nn.addr, dn_id="dn-w").start()
+        try:
+            assert dn.reduction_ctx.backend == "native"
+            assert dn.coded.backend == "native"
+        finally:
+            dn.stop()
+            nn.stop()
+            w.stop()
+
     def test_out_of_process_reduction_e2e(self):
         """The MiniCluster flag the VERDICT asked for: every dedup write
         flows DN -> worker process; the worker's stats prove it served."""
