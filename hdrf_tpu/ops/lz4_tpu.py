@@ -94,6 +94,7 @@ import numpy as np
 
 from hdrf_tpu.utils import device_ledger as _ledger
 from hdrf_tpu.utils import metrics as _metrics
+from hdrf_tpu.utils import profiler as _profiler
 
 _M_FLOOD = _metrics.registry("lz4_tpu")
 
@@ -503,18 +504,22 @@ class TpuLz4:
                 self._bypass_left -= 1
                 _M_FLOOD.incr("bypassed_scans")
                 return Lz4Job(n=a.size, host=a, block=None, recs=None)
-        if device_image is not None:
-            assert device_image.shape[0] % _S == 0
-            block = device_image
-        else:
-            block = jax.device_put(self._pad(a))
-        p1, p2, p3 = self._shapes(block.shape[0])
-        ev = _ledger.dispatch(
-            "lz4.scan",
-            h2d_bytes=0 if device_image is not None else block.shape[0],
-            key=(block.shape[0], p1, p2, p3))
-        recs = _match_scan(block, self.stride, self.min_len, p1, p2, p3)
-        recs.copy_to_host_async()
+        # ``scan_wait`` (the worker's stage clock, utils/profiler.py): match
+        # scan dispatch -> records on the host, rescans included; a
+        # bypassed scan records none
+        with _profiler.phase("scan_wait"):
+            if device_image is not None:
+                assert device_image.shape[0] % _S == 0
+                block = device_image
+            else:
+                block = jax.device_put(self._pad(a))
+            p1, p2, p3 = self._shapes(block.shape[0])
+            ev = _ledger.dispatch(
+                "lz4.scan",
+                h2d_bytes=0 if device_image is not None else block.shape[0],
+                key=(block.shape[0], p1, p2, p3))
+            recs = _match_scan(block, self.stride, self.min_len, p1, p2, p3)
+            recs.copy_to_host_async()
         return Lz4Job(n=a.size, host=a, block=block, recs=recs, p1=p1, p2=p2,
                       p3=p3, ev=ev)
 
@@ -549,13 +554,14 @@ class TpuLz4:
         total, g, r, complete = self._unpack_packed(rec_row, job.p3)
         if not complete and job.block is not None:
             _M_FLOOD.incr("escape_rescans")
-            ev = _ledger.dispatch(
-                "lz4.rescan",
-                key=(job.block.shape[0], job.p1, job.p2, job.p3, "full"))
-            row = np.asarray(_match_scan(job.block, self.stride,
-                                         self.min_len, job.p1, job.p2,
-                                         job.p3, packed=False))
-            _ledger.readback(ev, d2h_bytes=row.nbytes)
+            with _profiler.phase("scan_wait"):
+                ev = _ledger.dispatch(
+                    "lz4.rescan",
+                    key=(job.block.shape[0], job.p1, job.p2, job.p3, "full"))
+                row = np.asarray(_match_scan(job.block, self.stride,
+                                             self.min_len, job.p1, job.p2,
+                                             job.p3, packed=False))
+                _ledger.readback(ev, d2h_bytes=row.nbytes)
             return self._unpack_full(row, job.p3)
         return total, g, r
 
@@ -599,11 +605,12 @@ class TpuLz4:
             if shapes == (job.p1, job.p2, job.p3):
                 break  # capacity exhausted: dropped records cost only ratio
             p1, p2, p3 = shapes
-            ev = _ledger.dispatch("lz4.rescan",
-                                  key=(job.block.shape[0], p1, p2, p3))
-            rec_row = np.asarray(_match_scan(
-                job.block, self.stride, self.min_len, p1, p2, p3))
-            _ledger.readback(ev, d2h_bytes=rec_row.nbytes)
+            with _profiler.phase("scan_wait"):
+                ev = _ledger.dispatch("lz4.rescan",
+                                      key=(job.block.shape[0], p1, p2, p3))
+                rec_row = np.asarray(_match_scan(
+                    job.block, self.stride, self.min_len, p1, p2, p3))
+                _ledger.readback(ev, d2h_bytes=rec_row.nbytes)
             job.p1, job.p2, job.p3 = p1, p2, p3
             total, g, r = self._records(job, rec_row)
         if total > g.size:
@@ -671,8 +678,9 @@ class TpuLz4:
         if job.recs is None:
             return (_lz4_compress_parallel(job.host)
                     if job.n else b"")
-        rows = np.asarray(job.recs)
-        _ledger.readback(job.ev, d2h_bytes=rows.nbytes)
+        with _profiler.phase("scan_wait"):
+            rows = np.asarray(job.recs)
+            _ledger.readback(job.ev, d2h_bytes=rows.nbytes)
         job.ev = None
         out = self._assemble(job, rows)
         job.block = None
@@ -705,14 +713,15 @@ class TpuLz4:
             shapes = {img.shape[0] for img in device_images}
             if (len(shapes) == 1 and len(arrs) > 1
                     and min(a.size for a in arrs) >= self.min_device):
-                blocks = jnp.stack(device_images)
-                p1, p2, p3 = self._shapes(blocks.shape[1])
-                ev = _ledger.dispatch(
-                    "lz4.scan_batch", batch=len(arrs),
-                    key=(len(arrs), blocks.shape[1], p1, p2, p3))
-                recs = _match_scan_batch(blocks, self.stride, self.min_len,
-                                         p1, p2, p3)
-                recs.copy_to_host_async()
+                with _profiler.phase("scan_wait"):
+                    blocks = jnp.stack(device_images)
+                    p1, p2, p3 = self._shapes(blocks.shape[1])
+                    ev = _ledger.dispatch(
+                        "lz4.scan_batch", batch=len(arrs),
+                        key=(len(arrs), blocks.shape[1], p1, p2, p3))
+                    recs = _match_scan_batch(blocks, self.stride,
+                                             self.min_len, p1, p2, p3)
+                    recs.copy_to_host_async()
                 return ([Lz4Job(n=a.size, host=a, block=blocks[k],
                                 recs=None, p1=p1, p2=p2, p3=p3)
                          for k, a in enumerate(arrs)], recs, ev)
@@ -722,15 +731,16 @@ class TpuLz4:
         if len(sizes) != 1 or arrs[0].size < self.min_device or len(arrs) == 1:
             return [self.submit(a) for a in arrs]
         n = arrs[0].size
-        stacked = np.stack([self._pad(a) for a in arrs])
-        blocks = jax.device_put(stacked)
-        p1, p2, p3 = self._shapes(stacked.shape[1])
-        ev = _ledger.dispatch(
-            "lz4.scan_batch", batch=len(arrs), h2d_bytes=stacked.nbytes,
-            key=(len(arrs), stacked.shape[1], p1, p2, p3))
-        recs = _match_scan_batch(blocks, self.stride, self.min_len, p1, p2,
-                                 p3)
-        recs.copy_to_host_async()
+        with _profiler.phase("scan_wait"):
+            stacked = np.stack([self._pad(a) for a in arrs])
+            blocks = jax.device_put(stacked)
+            p1, p2, p3 = self._shapes(stacked.shape[1])
+            ev = _ledger.dispatch(
+                "lz4.scan_batch", batch=len(arrs), h2d_bytes=stacked.nbytes,
+                key=(len(arrs), stacked.shape[1], p1, p2, p3))
+            recs = _match_scan_batch(blocks, self.stride, self.min_len, p1,
+                                     p2, p3)
+            recs.copy_to_host_async()
         return ([Lz4Job(n=n, host=a, block=blocks[k], recs=None, p1=p1,
                         p2=p2, p3=p3)
                  for k, a in enumerate(arrs)], recs, ev)
@@ -739,8 +749,9 @@ class TpuLz4:
         if isinstance(submitted, list):  # per-buffer fallback shape
             return [self.finish(j) for j in submitted]
         jobs, recs, ev = submitted
-        rows = np.asarray(recs)
-        _ledger.readback(ev, d2h_bytes=rows.nbytes)
+        with _profiler.phase("scan_wait"):
+            rows = np.asarray(recs)
+            _ledger.readback(ev, d2h_bytes=rows.nbytes)
         return [self._assemble(j, rows[k]) for k, j in enumerate(jobs)]
 
     def compress_many(self, datas: list) -> list[bytes]:

@@ -55,6 +55,7 @@ from hdrf_tpu.ops import gear
 from hdrf_tpu.ops.dispatch import gear_mask
 from hdrf_tpu.ops.sha256 import sha256_words
 from hdrf_tpu.utils import device_ledger as _ledger
+from hdrf_tpu.utils import profiler as _profiler
 
 
 # Block padding grid: lcm of the bitmap pack row (256 bytes) and the
@@ -518,11 +519,12 @@ class ResidentReducer:
         count = int(cand_row[0])
         if count > cap:
             cap = count
-            ev = _ledger.dispatch("resident.prep_retry",
-                                  key=(block.shape, cap))
-            _, cd = _prep(block, self.mask, cap, self.pad_words)
-            cand_row = np.asarray(cd)
-            _ledger.readback(ev, d2h_bytes=cand_row.nbytes)
+            with _profiler.phase("prep_wait"):
+                ev = _ledger.dispatch("resident.prep_retry",
+                                      key=(block.shape, cap))
+                _, cd = _prep(block, self.mask, cap, self.pad_words)
+                cand_row = np.asarray(cd)
+                _ledger.readback(ev, d2h_bytes=cand_row.nbytes)
             count = int(cand_row[0])
         idx = cand_row[1:1 + count].astype(np.uint32)
         vals = cand_row[1 + cap:1 + cap + count].view(np.uint32)
@@ -736,51 +738,67 @@ class ResidentReducer:
             return job
         cap = max(1, min(block.shape[0] // 32,
                          max(1024, (n >> max(self.cdc.mask_bits - 1, 0)) + 1024)))
-        ev = _ledger.dispatch(
-            "resident.prep",
-            h2d_bytes=0 if isinstance(data, jax.Array) else block.shape[0],
-            key=(block.shape, cap))
-        words, cand = _prep(block, self.mask, cap, self.pad_words)
-        cand.copy_to_host_async()
+        # Stage spans of the per-block path (the reduction worker's stage
+        # clock, utils/profiler.py): ``prep_wait`` from this dispatch to
+        # the candidates on the host, ``select`` the host cut selection and
+        # SHA bucket planning, ``sha_wait`` SHA dispatch to digests.
+        with _profiler.phase("prep_wait"):
+            ev = _ledger.dispatch(
+                "resident.prep",
+                h2d_bytes=(0 if isinstance(data, jax.Array)
+                           else block.shape[0]),
+                key=(block.shape, cap))
+            words, cand = _prep(block, self.mask, cap, self.pad_words)
+            cand.copy_to_host_async()
         return BlockJob(n=n, block=block, words=words, cand=cand, cap=cap,
                         _ev=ev)
 
     def start_sha(self, job: BlockJob) -> None:
         if job.cand is None:  # empty block prepared entirely in submit()
             return
-        cand = np.asarray(job.cand)
-        _ledger.readback(job._ev, d2h_bytes=cand.nbytes)
+        with _profiler.phase("prep_wait"):
+            cand = np.asarray(job.cand)
+            _ledger.readback(job._ev, d2h_bytes=cand.nbytes)
         job._ev = None
-        cuts = self._cuts_from_cand(cand, job.cap, job.block, job.n)
-        job.cuts = cuts
-        starts = np.concatenate([[0], cuts[:-1]]).astype(np.int64)
-        lens = (cuts - starts).astype(np.int64)
-        nb = (lens + 9 + 63) // 64
-        # TWO fixed buckets, not one per power of two (fewer dispatches and
-        # jit shapes); the small bucket covers the mass of the
-        # chunk-size distribution (~2x the mean), the big one the tail, and
-        # padded-lane waste stays comparable to pow2 bucketing.
-        order = np.arange(len(cuts))
+        with _profiler.phase("select"):
+            cuts = self._cuts_from_cand(cand, job.cap, job.block, job.n)
+            job.cuts = cuts
+            starts = np.concatenate([[0], cuts[:-1]]).astype(np.int64)
+            lens = (cuts - starts).astype(np.int64)
+            nb = (lens + 9 + 63) // 64
+            # TWO fixed buckets, not one per power of two (fewer dispatches
+            # and jit shapes); the small bucket covers the mass of the
+            # chunk-size distribution (~2x the mean), the big one the tail,
+            # and padded-lane waste stays comparable to pow2 bucketing.
+            order = np.arange(len(cuts))
         sels, parts, evs = [], [], []
+        # each bucket is planned and dispatched in turn, as before the stage
+        # clock: with both planned first, a worker's first block (the
+        # kernels' lowering) read 4-7 s longer on the chip, cause not found
+        # (my chip runs, PR 25; PERF.md section 7)
         for sel, B in ((order[nb <= self._b_small], self._b_small),
                        (order[nb > self._b_small], self._b_big)):
             if not sel.size:
                 continue
-            L = _lane_count(sel.size)
-            ol = np.zeros((2, L), dtype=np.int32)
-            ol[0, :sel.size] = starts[sel]
-            ol[1, :sel.size] = lens[sel]
-            evs.append(_ledger.dispatch("resident.sha", batch=sel.size,
-                                        h2d_bytes=ol.nbytes, key=(B, L)))
-            parts.append(_bucket_sha_best(job.words, ol, B))
-            sels.append(sel)
+            with _profiler.phase("select"):
+                L = _lane_count(sel.size)
+                ol = np.zeros((2, L), dtype=np.int32)
+                ol[0, :sel.size] = starts[sel]
+                ol[1, :sel.size] = lens[sel]
+            with _profiler.phase("sha_wait"):
+                evs.append(_ledger.dispatch("resident.sha", batch=sel.size,
+                                            h2d_bytes=ol.nbytes, key=(B, L)))
+                parts.append(_bucket_sha_best(job.words, ol, B))
+                sels.append(sel)
         # One device-side concat -> ONE digest readback (each extra D2H costs
         # a fixed round trip).
-        if parts:
-            alld = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
-            alld.copy_to_host_async()
-        else:  # empty block: no chunks, no digests
-            alld = None
+        with _profiler.phase("sha_wait"):
+            if parts:
+                alld = (jnp.concatenate(parts, axis=0) if len(parts) > 1
+                        else parts[0])
+                alld.copy_to_host_async()
+            else:  # empty block: no chunks, no digests
+                alld = None
         job._sha_parts = (sels, [p.shape[0] for p in parts], alld)
         job._ev_sha = evs
         job.block = None  # cuts are final; release the u8 image
@@ -791,14 +809,16 @@ class ResidentReducer:
         sels, lane_counts, digs_dev = job._sha_parts
         out = np.empty((len(job.cuts), 32), dtype=np.uint8)
         if digs_dev is not None:
-            digs = np.asarray(digs_dev)
-            for i, ev in enumerate(job._ev_sha or ()):
-                _ledger.readback(ev, d2h_bytes=digs.nbytes if i == 0 else 0)
-            job._ev_sha = None
-            at = 0
-            for sel, L in zip(sels, lane_counts):
-                out[sel] = digs[at:at + sel.size]
-                at += L
+            with _profiler.phase("sha_wait"):
+                digs = np.asarray(digs_dev)
+                for i, ev in enumerate(job._ev_sha or ()):
+                    _ledger.readback(ev,
+                                     d2h_bytes=digs.nbytes if i == 0 else 0)
+                job._ev_sha = None
+                at = 0
+                for sel, L in zip(sels, lane_counts):
+                    out[sel] = digs[at:at + sel.size]
+                    at += L
         job.words = None  # release the HBM word image
         return job.cuts, out
 

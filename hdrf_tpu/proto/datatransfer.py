@@ -32,7 +32,7 @@ from typing import Any, Iterator
 
 from hdrf_tpu import native
 from hdrf_tpu.proto.rpc import recv_exact, recv_frame, send_frame
-from hdrf_tpu.utils import retry, tracing
+from hdrf_tpu.utils import profiler, retry, tracing
 
 PKT_HDR = struct.Struct("<IQBI")
 FLAG_LAST = 0x1
@@ -117,7 +117,16 @@ def read_packet_ex(sock: socket.socket) -> tuple[int, bytes, int]:
     the receiver-side verify the reference does per checksum chunk."""
     ln, seqno, flags, crc = PKT_HDR.unpack(recv_exact(sock, PKT_HDR.size))
     data = recv_exact(sock, ln) if ln else b""
-    if native.crc32c(data) != crc:
+    # its own phase: a caller that times the whole call as a socket wait
+    # (the DataNode's ``recv``, the worker's ``ingest_wait``) would book
+    # this compute as transport.  Laps, one span a stride: a span a packet
+    # doubled what the receiving interpreter records
+    t0 = profiler.mark()
+    ok = native.crc32c(data) == crc
+    profiler.lap("packet_verify", t0)
+    if flags & FLAG_LAST:
+        profiler.flush_laps()
+    if not ok:
         raise IOError(f"packet {seqno}: checksum mismatch")
     return seqno, data, flags
 

@@ -250,7 +250,12 @@ class RpcServer:
         """Exclusive-phase partition of one request's service time
         (profiler.profile_spans — same sweep as the DN block timelines),
         observed as ``nn_rpc_phase_us|method=,phase=`` histograms plus the
-        cumulative attributed-fraction accountant."""
+        cumulative attributed-fraction accountant.  The request as a whole,
+        frame read to reply sent, also goes on the phase clock as ``nn_rpc``:
+        where NameNode and DataNode share an interpreter it lands in the
+        ring a DataNode window is partitioned from."""
+        now = profiler.mark()
+        profiler.record_span("nn_rpc", now - (t1 - t0), now)
         prof = profiler.profile_spans(spans, t0, t1)
         for name, s in prof["phases"].items():
             self._metrics.observe(f"nn_rpc_phase_us|method={method},"

@@ -920,8 +920,10 @@ class DataNode:
         while not self._stop.wait(interval):
             fault_injection.point("datanode.heartbeat", dn_id=self.dn_id)
             try:
-                self._cdc_tick()
-                stats = self._stats()
+                # once a heartbeat, in the interpreter that receives
+                with profiler.phase("heartbeat_stats"):
+                    self._cdc_tick()
+                    stats = self._stats()
             except Exception as e:  # noqa: BLE001
                 # One failed tick must not end the thread: a DN that stops
                 # heartbeating is declared dead while it still serves (on
@@ -1638,7 +1640,10 @@ class DataNode:
                     continue
                 bid = bids[cursor % len(bids)]
                 cursor += 1
-                bad = self.verify_block(bid)
+                # one replica re-read and re-checksummed per tick, in the
+                # interpreter that receives
+                with profiler.phase("block_scan"):
+                    bad = self.verify_block(bid)
                 if bad:
                     _M.incr("scanner_corrupt_found")
                     self._log.warning("scanner found corrupt replica",

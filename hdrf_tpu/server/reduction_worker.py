@@ -49,6 +49,21 @@ _TR = tracing.tracer("reduction_worker")
 # the network stream.
 _STRIDE = 4 << 20
 
+# The stage clock (utils/profiler.py, PR 25): a reduce op is a run of leaf
+# ``profiler.phase`` spans no finer than one stride under one covering span
+# (``block``), so their self seconds close on the op's wall clock.  ``stats``
+# exports each as ``<stage>_s``; the three legacy sums are made of them, per
+# op, on the handler thread.
+_INGEST_STAGES = ("ingest_wait", "packet_verify", "stage_h2d")
+_COMPRESS_STAGES = ("scan_wait", "emit")
+
+
+def _stage_seconds(before: dict) -> dict:
+    """Self seconds by stage this thread spent since ``before``
+    (``profiler.thread_cumulative()`` at the op's start)."""
+    return {k: v - before.get(k, 0.0)
+            for k, v in profiler.thread_cumulative().items()}
+
 
 class ReductionWorker:
     """The worker daemon.  Thread-per-connection like the DN xceiver; the
@@ -72,9 +87,10 @@ class ReductionWorker:
         self._reducers: dict[tuple, Any] = {}
         self._lz4 = None
         self._stats_lock = threading.Lock()
-        # *_s: cumulative seconds of the streamed reduce's two legs (packets
-        # in + H2D strides; then assembly, scan, select, SHA and readbacks)
-        # and of compress jobs — host clock, this process
+        # ingest_s / reduce_s / compress_s: the streamed reduce's two legs
+        # (packets in, verify, H2D strides; then scan, select, SHA and
+        # readbacks) and compress jobs (match scan + emit) — sums of the
+        # stage clock's seconds, kept because a metric and a test read them
         self._stats = {"blocks_reduced": 0, "bytes_reduced": 0,
                        "compress_jobs": 0, "ingest_s": 0.0,
                        "reduce_s": 0.0, "compress_s": 0.0}
@@ -146,8 +162,7 @@ class ReductionWorker:
                 send_frame(sock, {"ok": True, "backend": self.backend,
                                   "device": self.device})
             elif op == "stats":
-                with self._stats_lock:
-                    send_frame(sock, dict(self._stats))
+                send_frame(sock, self.stats())
             elif op == "device_report":
                 send_frame(sock, self._device_report(bool(req.get("probe"))))
             elif op == "traces":
@@ -165,6 +180,20 @@ class ReductionWorker:
         except Exception as e:  # noqa: BLE001 — errors cross the wire
             _M.incr("op_errors")
             send_frame(sock, {"error": type(e).__name__, "message": str(e)})
+
+    def stats(self) -> dict:
+        """Counters, flat and numeric (a reader takes deltas of whatever
+        keys it finds): ops and bytes; ``<stage>_s`` — cumulative self
+        seconds of every phase this process recorded, a stage its backend
+        does not run left out; the three sums of them; the process's CPU
+        seconds and a wall clock to set them against."""
+        with self._stats_lock:
+            out = dict(self._stats)
+        for name, secs in profiler.cumulative().items():
+            out[name + "_s"] = secs
+        out["cpu_s"] = time.process_time()
+        out["wall_s"] = time.perf_counter()
+        return out
 
     def _device_report(self, probe: bool) -> dict:
         """Device-side facts for a parent that never touches JAX: dispatch
@@ -207,25 +236,36 @@ class ReductionWorker:
     def _op_reduce(self, sock: socket.socket, req: dict) -> None:
         """Packet stream -> (cuts, digests).  TPU backend: packets stage to
         HBM in _STRIDE device uploads DURING the stream; the resident block
-        is assembled device-side."""
+        is assembled device-side.  ``block`` is the op's one covering span:
+        what it keeps as self seconds (the reply among them) is what no
+        stage explains."""
         cdc = CdcConfig(mask_bits=req["mask_bits"],
                         min_chunk=req["min_chunk"],
                         max_chunk=req["max_chunk"])
-        if self.backend == "tpu":
-            cuts, digs = self._reduce_streaming_tpu(sock, cdc)
-        else:
-            from hdrf_tpu.ops import dispatch as ops_dispatch
+        before = profiler.thread_cumulative()
+        with profiler.phase("block"):
+            if self.backend == "tpu":
+                cuts, digs = self._reduce_streaming_tpu(sock, cdc)
+            else:
+                from hdrf_tpu.ops import dispatch as ops_dispatch
 
-            data = dt.collect_packets(sock)
-            buf = np.frombuffer(data, dtype=np.uint8)
-            cuts, digs = ops_dispatch.chunk_and_fingerprint(
-                buf, cdc, self.backend)
-        nbytes = int(cuts[-1]) if len(cuts) else 0
-        with self._stats_lock:
-            self._stats["blocks_reduced"] += 1
-            self._stats["bytes_reduced"] += nbytes
-        send_frame(sock, {"cuts": np.asarray(cuts, np.int64).tobytes(),
-                          "digests": np.ascontiguousarray(digs).tobytes()})
+                with profiler.phase("ingest_wait"):
+                    data = dt.collect_packets(sock)
+                buf = np.frombuffer(data, dtype=np.uint8)
+                cuts, digs = ops_dispatch.chunk_and_fingerprint(
+                    buf, cdc, self.backend)      # phase "reduce_compute"
+            nbytes = int(cuts[-1]) if len(cuts) else 0
+            # the sums go in with the count, before the reply: a ``stats``
+            # call that sees the block sees its seconds
+            took = _stage_seconds(before)
+            ingest = sum(took.get(k, 0.0) for k in _INGEST_STAGES)
+            with self._stats_lock:
+                self._stats["blocks_reduced"] += 1
+                self._stats["bytes_reduced"] += nbytes
+                self._stats["ingest_s"] += ingest
+                self._stats["reduce_s"] += sum(took.values()) - ingest
+            send_frame(sock, {"cuts": np.asarray(cuts, np.int64).tobytes(),
+                              "digests": np.ascontiguousarray(digs).tobytes()})
         _M.incr("blocks_reduced")
         accounting.record_worker_bytes("reduce", nbytes)
 
@@ -234,51 +274,61 @@ class ReductionWorker:
         import jax.numpy as jnp
 
         parts: list = []        # resident device strides (uploads in flight)
-        pend: list[bytes] = []  # current stride accumulator
-        pend_n = 0
         total = 0
-        t0 = time.perf_counter()
-        for _seq, data, _last in dt.iter_packets(sock):
-            if data:
-                pend.append(data)
-                pend_n += len(data)
-                total += len(data)
-                if pend_n >= _STRIDE:
+        packets = dt.iter_packets(sock)
+        streaming = True
+        while streaming:
+            pend: list[bytes] = []  # current stride accumulator
+            pend_n = 0
+            with profiler.phase("ingest_wait"):   # one span per stride
+                for _seq, data, _last in packets:
+                    if data:
+                        pend.append(data)
+                        pend_n += len(data)
+                        if pend_n >= _STRIDE:
+                            break
+                else:
+                    streaming = False
+            if pend:
+                with profiler.phase("stage_h2d"):
                     blob = np.frombuffer(b"".join(pend), np.uint8)
                     parts.append(jax.device_put(blob))  # async H2D: lands
                     # in HBM while the next packets stream in
-                    pend, pend_n = [], 0
-        if pend:
-            parts.append(jax.device_put(
-                np.frombuffer(b"".join(pend), np.uint8)))
+                total += pend_n
         if not parts:
             return np.empty(0, np.int64), np.empty((0, 32), np.uint8)
         from hdrf_tpu.ops.resident import _PAD_GRID
 
-        pad = (-total) % _PAD_GRID
-        if pad:
-            parts.append(jnp.zeros(pad, jnp.uint8))
-        t1 = time.perf_counter()
-        block = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+        with profiler.phase("stage_h2d"):
+            pad = (-total) % _PAD_GRID
+            if pad:
+                parts.append(jnp.zeros(pad, jnp.uint8))
+            block = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+        # prep_wait, select and sha_wait are recorded where they happen
+        # (ops/resident.py)
         r = self._reducer(cdc)
         job = r.submit(block, n=total)
         r.start_sha(job)
-        out = r.finish(job)
+        return r.finish(job)
+
+    def _note_compress(self, jobs: int, before: dict) -> None:
+        took = _stage_seconds(before)
         with self._stats_lock:
-            self._stats["ingest_s"] += t1 - t0
-            self._stats["reduce_s"] += time.perf_counter() - t1
-        return out
+            self._stats["compress_jobs"] += jobs
+            self._stats["compress_s"] += sum(took.get(k, 0.0)
+                                             for k in _COMPRESS_STAGES)
 
     def _op_compress(self, sock: socket.socket, req: dict) -> None:
         from hdrf_tpu.ops import dispatch as ops_dispatch
 
         data = dt.collect_packets(sock)
-        t0 = time.perf_counter()
-        out = ops_dispatch.block_compress(req.get("codec", "lz4"), data,
-                                          self.backend)
-        with self._stats_lock:
-            self._stats["compress_jobs"] += 1
-            self._stats["compress_s"] += time.perf_counter() - t0
+        before = profiler.thread_cumulative()
+        # the device match scan inside records ``scan_wait`` (absent when
+        # the scan is bypassed); ``emit`` keeps the rest as self seconds
+        with profiler.phase("emit"):
+            out = ops_dispatch.block_compress(req.get("codec", "lz4"), data,
+                                              self.backend)
+        self._note_compress(1, before)
         send_frame(sock, {"data": bytes(out)})
         _M.incr("compress_jobs")
         accounting.record_worker_bytes("compress", len(data))
@@ -303,10 +353,11 @@ class ReductionWorker:
         for n in sizes:
             datas.append(blob[off:off + n])
             off += n
-        outs = ops_dispatch.block_compress_batch(
-            req.get("codec", "lz4"), datas, self.backend)
-        with self._stats_lock:
-            self._stats["compress_jobs"] += len(sizes)
+        before = profiler.thread_cumulative()
+        with profiler.phase("emit"):
+            outs = ops_dispatch.block_compress_batch(
+                req.get("codec", "lz4"), datas, self.backend)
+        self._note_compress(len(sizes), before)
         send_frame(sock, {"datas": [bytes(o) for o in outs]})
         _M.incr("compress_jobs", len(sizes))
         accounting.record_worker_bytes("compress", len(blob))
@@ -463,10 +514,16 @@ class WorkerClient:
                     dl.extend(self._per_mb * len(data) / float(1 << 20))
                     dl.check("worker reduce stream")
                     s.settimeout(dl.timeout())
+                    # the upload leg of the hop: a second CRC32C and a
+                    # sendall per client packet, on the receive thread
+                    # (laps: one span a stride, not one a packet)
+                    t0 = profiler.mark()
                     dt.write_packet(s, seq, data)
+                    profiler.lap("worker_send", t0)
                 except OSError as e:
                     raise WorkerError(f"worker send failed: {e}") from e
                 seq += 1
+            profiler.flush_laps()
             try:
                 dl.check("worker reduce")
                 s.settimeout(dl.timeout())
@@ -499,12 +556,14 @@ class WorkerClient:
         s = self._conn(dl)
         try:
             try:
-                send_frame(s, self._stamped({"op": "compress",
-                                             "codec": codec}, dl))
-                dt.stream_bytes(s, data, 1 << 20)
+                with profiler.phase("seal_send"):
+                    send_frame(s, self._stamped({"op": "compress",
+                                                 "codec": codec}, dl))
+                    dt.stream_bytes(s, data, 1 << 20)
                 dl.check("worker compress")
                 s.settimeout(dl.timeout())
-                out = bytes(self._checked(recv_frame(s))["data"])
+                with profiler.phase("seal_wait"):
+                    out = bytes(self._checked(recv_frame(s))["data"])
             except (OSError, ConnectionError) as e:
                 raise WorkerError(f"worker failed: {e}") from e
             self._release(s)
@@ -523,19 +582,21 @@ class WorkerClient:
         s = self._conn(dl)
         try:
             try:
-                send_frame(s, self._stamped(
-                    {"op": "compress_batch", "codec": codec,
-                     "sizes": [len(d) for d in datas]}, dl))
-                seq = 0
-                for d in datas:
-                    if d:
-                        dt.write_packet(s, seq, d)
-                        seq += 1
-                dt.write_packet(s, seq, b"", last=True)
+                with profiler.phase("seal_send"):
+                    send_frame(s, self._stamped(
+                        {"op": "compress_batch", "codec": codec,
+                         "sizes": [len(d) for d in datas]}, dl))
+                    seq = 0
+                    for d in datas:
+                        if d:
+                            dt.write_packet(s, seq, d)
+                            seq += 1
+                    dt.write_packet(s, seq, b"", last=True)
                 dl.check("worker compress_batch")
                 s.settimeout(dl.timeout())
-                outs = [bytes(v)
-                        for v in self._checked(recv_frame(s))["datas"]]
+                with profiler.phase("seal_wait"):
+                    outs = [bytes(v)
+                            for v in self._checked(recv_frame(s))["datas"]]
             except (OSError, ConnectionError) as e:
                 raise WorkerError(f"worker failed: {e}") from e
             self._release(s)

@@ -30,7 +30,7 @@ import threading
 from dataclasses import dataclass
 
 from hdrf_tpu.utils import codec as codecs
-from hdrf_tpu.utils import fault_injection, metrics
+from hdrf_tpu.utils import fault_injection, metrics, profiler
 
 _M = metrics.registry("container_store")
 
@@ -321,16 +321,21 @@ class ContainerStore:
         directly — there is no raw file to stamp or remove.  ``comp`` is
         the already-compressed payload when the caller ran the compressor
         itself (the grouped flush_open seal)."""
+        # ``seal_write`` spans cover the file work only (this interpreter's
+        # seconds); the compressor between them has its own phases
+        # (``seal_send`` / ``seal_wait`` when it is the worker's).
         raw = self._raw_path(cid)
         if have_raw is None:
             have_raw = os.path.exists(raw)
         if have_raw:
             with open(raw, "r+b") as f:
-                magic = _SEAL_HDR.unpack(f.read(_SEAL_HDR.size))[0]
-                if magic != _RAW_MAGIC:
-                    raise IOError(f"container {cid}: bad raw magic {magic:#x}")
-                if data is None:
-                    data = f.read()
+                with profiler.phase("seal_write"):
+                    magic = _SEAL_HDR.unpack(f.read(_SEAL_HDR.size))[0]
+                    if magic != _RAW_MAGIC:
+                        raise IOError(
+                            f"container {cid}: bad raw magic {magic:#x}")
+                    if data is None:
+                        data = f.read()
                 fault_injection.point("container.seal")
                 if comp is None:
                     comp = self._compress(data)
@@ -339,13 +344,14 @@ class ContainerStore:
                     # header in place and rename — no data copy.  The fsync
                     # (forcing the full container's writeback NOW) follows
                     # the block-data durability policy.
-                    f.seek(0)
-                    f.write(_SEAL_HDR.pack(_SEAL_MAGIC, len(data),
-                                           codecs.CODEC_IDS["none"]))
-                    f.flush()
-                    if self._fsync:
-                        os.fsync(f.fileno())
-                    os.replace(raw, self._sealed_path(cid))
+                    with profiler.phase("seal_write"):
+                        f.seek(0)
+                        f.write(_SEAL_HDR.pack(_SEAL_MAGIC, len(data),
+                                               codecs.CODEC_IDS["none"]))
+                        f.flush()
+                        if self._fsync:
+                            os.fsync(f.fileno())
+                        os.replace(raw, self._sealed_path(cid))
                     _M.incr("sealed")
                     return
         else:
@@ -356,16 +362,17 @@ class ContainerStore:
         codec = self._codec if len(comp) < len(data) else "none"
         out = comp if len(comp) < len(data) else data
         tmp = self._sealed_path(cid) + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(_SEAL_HDR.pack(_SEAL_MAGIC, len(data),
-                                   codecs.CODEC_IDS[codec]))
-            f.write(out)
-            f.flush()
-            if self._fsync:
-                os.fsync(f.fileno())
-        os.replace(tmp, self._sealed_path(cid))
-        if have_raw:
-            os.unlink(raw)
+        with profiler.phase("seal_write"):
+            with open(tmp, "wb") as f:
+                f.write(_SEAL_HDR.pack(_SEAL_MAGIC, len(data),
+                                       codecs.CODEC_IDS[codec]))
+                f.write(out)
+                f.flush()
+                if self._fsync:
+                    os.fsync(f.fileno())
+            os.replace(tmp, self._sealed_path(cid))
+            if have_raw:
+                os.unlink(raw)
         _M.incr("sealed")
 
     def _compress(self, data: bytes) -> bytes:

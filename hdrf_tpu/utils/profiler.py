@@ -42,6 +42,15 @@ observing ``phase_us|op=read,phase=<name>`` histograms plus read-side
 overlap gauges into the ``read_profiler`` registry — the serving-path twin
 the reference never decomposes (DataNodeMetrics.java:553-560 counts read
 ops, never where a read's time went).
+
+One clock for both processes of the served write path (PR 25): the same
+:func:`phase` call is the span in the DataNode, the stage span in the
+reduction worker, the cumulative per-name self seconds the worker exports
+as ``<stage>_s`` (:func:`cumulative`), and — in a process that has already
+imported JAX — a ``jax.profiler.TraceAnnotation("hdrf.<name>")`` on the
+device trace's timeline.  A phase too fine to record span by span
+(one CRC32C per 64 KiB packet) is timed with :func:`lap` instead: its
+seconds gather on the thread and land as one span a 4 MiB stride.
 """
 
 from __future__ import annotations
@@ -94,23 +103,57 @@ PHASE_CLASS = {
     "frame_read": TRANSPORT, "reply": TRANSPORT,
     "dispatch_queue": HOST, "lock_wait": HOST, "locked": HOST,
     "serialize": HOST, "handler": HOST,
+    # What ``recv`` hid and what nothing covered (PR 25).  packet_verify is
+    # the CRC32C inside read_packet_ex; worker_send the DN forwarding a
+    # packet to the worker; seal_* the background container seal (its two
+    # hop legs are waits, the file work is this interpreter's); nn_rpc one
+    # NameNode request, frame read to reply sent; heartbeat_stats /
+    # block_scan the two periodic DataNode ticks.
+    "packet_verify": HOST, "worker_send": TRANSPORT,
+    "seal_send": TRANSPORT, "seal_wait": TRANSPORT, "seal_write": HOST,
+    "nn_rpc": HOST, "heartbeat_stats": HOST, "block_scan": HOST,
+    # The reduction worker's stage clock (server/reduction_worker.py):
+    # leaf spans of one op, exported as ``<stage>_s``.  ``block`` is the
+    # covering span of a whole reduce op: its self seconds are what no
+    # stage explains (the reply among them), the closure reading.
+    "ingest_wait": TRANSPORT, "stage_h2d": HOST, "prep_wait": DEVICE,
+    "select": HOST, "sha_wait": DEVICE, "scan_wait": DEVICE, "emit": HOST,
+    "block": TRANSPORT,
 }
 
 # Deterministic attribution order when several phases of the winning class
 # overlap inside one elementary interval (rare: host phases are serial on
 # this host) — first match wins.  Nested read phases (index_lookup inside a
 # container_decode window) resolve to the innermost by listing it first.
-PHASE_ORDER = ("device_wait", "wal_commit", "container_io", "dedup_lookup",
-               "reduce_compute", "checksum", "buffer_assemble",
-               "pipeline_submit", "index_lookup", "cache_probe",
-               "container_decode",
+PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
+               "wal_commit", "container_io", "dedup_lookup",
+               "reduce_compute", "packet_verify", "checksum",
+               "buffer_assemble", "pipeline_submit", "seal_write",
+               "stage_h2d", "select", "emit",
+               "index_lookup", "cache_probe", "container_decode",
                # RPC phases: lock_wait/locked win attribution inside the
                # covering ``handler`` window; handler last among them so it
                # only owns the time no finer phase explains.
                "lock_wait", "locked", "dispatch_queue", "serialize",
                "handler",
-               "recv", "mirror_stream", "ack",
-               "ec_gather", "decode_wait", "net_send", "frame_read", "reply")
+               # periodic ticks and the NameNode's whole request: they own
+               # only host seconds no write-path phase claims
+               "heartbeat_stats", "block_scan", "nn_rpc",
+               "worker_send", "recv", "mirror_stream", "ack",
+               "seal_send", "seal_wait",
+               "ec_gather", "decode_wait", "net_send", "frame_read", "reply",
+               # the worker's covering span last: it claims only the
+               # seconds no stage does
+               "ingest_wait", "block")
+
+
+_ORDER_RANK = {name: i for i, name in enumerate(PHASE_ORDER)}
+
+
+def _phase_rank(name: str) -> tuple[int, str]:
+    """Attribution rank: place in PHASE_ORDER, phases outside it after all
+    of those, by name."""
+    return (_ORDER_RANK.get(name, len(_ORDER_RANK)), name)
 
 
 def phase_class(name: str) -> str:
@@ -118,10 +161,11 @@ def phase_class(name: str) -> str:
     return PHASE_CLASS.get(name, HOST)
 
 
-def _now() -> float:
-    # Wall clock: phase spans must share a time base with tracing.Span.t0
-    # and the device ledger's event t0 so one chrome export aligns them all.
-    return time.time()
+# Wall clock: phase spans must share a time base with tracing.Span.t0 and
+# the device ledger's event t0 so one chrome export aligns them all.  Bound
+# to the builtin itself (one call per read, not two); always looked up as
+# a module global so tests can substitute a settable clock.
+_now = time.time
 
 
 _PROC = f"{os.path.basename(sys.argv[0] or 'py')}:{os.getpid()}"
@@ -137,7 +181,53 @@ _span_ring: deque[tuple] = deque(maxlen=_SPAN_RING_MAX)
 _counter_ring: deque[dict[str, Any]] = deque(maxlen=_COUNTER_RING_MAX)
 _counters: dict[str, float] = {}
 _counter_id = [0]
-_thread_phase: dict[int, list[str]] = {}
+
+# Laps gathered into one span (:func:`lap`): 64 packets of 64 KiB are one
+# 4 MiB stride, the grain of the worker's other stages.
+_LAP_EVERY = 64
+
+
+class _ThreadState:
+    """One thread's open-phase stack, cumulative self seconds by phase name
+    and gathered laps.  Only its own thread writes it, so the hot path
+    takes no lock."""
+
+    __slots__ = ("tid", "stack", "cum", "laps")
+
+    def __init__(self, tid: int) -> None:
+        self.tid = tid
+        self.stack: list["phase"] = []
+        self.cum: dict[str, float] = {}
+        self.laps: dict[str, list] = {}   # name -> [seconds, count]
+
+
+_tls = threading.local()
+_threads: dict[int, _ThreadState] = {}   # tid -> state (watchdog probe,
+_cum_dead: dict[str, float] = {}         # cumulative()); dead threads fold
+_FOLD_AT = 64
+
+
+def _fold(cum: dict[str, float]) -> None:
+    for name, secs in cum.items():
+        _cum_dead[name] = _cum_dead.get(name, 0.0) + secs
+
+
+def _thread_state() -> _ThreadState:
+    """This thread's state, registered on first use.  Registration is the
+    one place that takes the lock; it also folds the totals of threads that
+    have ended (one connection = one thread in both daemons) so the
+    registry stays small."""
+    tid = threading.get_ident()
+    ts = _tls.state = _ThreadState(tid)
+    with _lock:
+        if len(_threads) >= _FOLD_AT:
+            alive = {t.ident for t in threading.enumerate()}
+            for dead in [k for k in _threads if k not in alive]:
+                _fold(_threads.pop(dead).cum)
+        if tid in _threads:              # a reused thread id: keep its sums
+            _fold(_threads[tid].cum)
+        _threads[tid] = ts
+    return ts
 
 _current: contextvars.ContextVar["BlockTimeline | None"] = \
     contextvars.ContextVar("hdrf_block_timeline", default=None)
@@ -211,45 +301,45 @@ def profile_spans(spans: Iterable, t0: float, t1: float,
             events.append((s1, -1, name))
     events.sort(key=lambda e: e[0])
 
-    active: dict[str, int] = {}
-    cls_active = {HOST: 0, DEVICE: 0, TRANSPORT: 0}
+    # phases open right now, by class (only those with a positive count):
+    # the winner of an interval is the open phase of the winning class that
+    # comes first in PHASE_ORDER (then by name) — found among the few open
+    # phases, not by walking the whole order, since this sweep runs at every
+    # block's end in the interpreter that receives
+    active: dict[str, dict[str, int]] = {HOST: {}, DEVICE: {}, TRANSPORT: {}}
     prev = t0
     i, n = 0, len(events)
     while i < n:
         t = events[i][0]
         if t > prev:
             dt = t - prev
-            if cls_active[HOST] > 0:
+            if active[HOST]:
                 win, wc = "host_busy", HOST
-            elif cls_active[DEVICE] > 0:
+            elif active[DEVICE]:
                 win, wc = "device_busy", DEVICE
-            elif cls_active[TRANSPORT] > 0:
+            elif active[TRANSPORT]:
                 win, wc = "transport_wait", TRANSPORT
             else:
                 win, wc = "idle", None
             classes[win] += dt
-            if cls_active[DEVICE] > 0 or cls_active[TRANSPORT] > 0:
+            if active[DEVICE] or active[TRANSPORT]:
                 hideable += dt
                 if win == "host_busy":
                     hidden += dt
             if wc is not None:
-                attr = None
-                for name in PHASE_ORDER:
-                    if active.get(name, 0) > 0 and phase_class(name) == wc:
-                        attr = name
-                        break
-                if attr is None:  # phase outside the canonical order
-                    for name in sorted(active):
-                        if active[name] > 0 and phase_class(name) == wc:
-                            attr = name
-                            break
-                if attr is not None:
-                    phases[attr] = phases.get(attr, 0.0) + dt
+                open_now = active[wc]
+                attr = (next(iter(open_now)) if len(open_now) == 1 else
+                        min(open_now, key=_phase_rank))
+                phases[attr] = phases.get(attr, 0.0) + dt
             prev = t
         while i < n and events[i][0] == t:
             _, kind, name = events[i]
-            active[name] = active.get(name, 0) + kind
-            cls_active[phase_class(name)] += kind
+            open_cls = active[phase_class(name)]
+            count = open_cls.get(name, 0) + kind
+            if count > 0:
+                open_cls[name] = count
+            else:
+                open_cls.pop(name, None)
             i += 1
     used = (classes["host_busy"] + classes["device_busy"]
             + classes["transport_wait"])
@@ -366,53 +456,158 @@ def _observe_finished(tl: BlockTimeline) -> None:
     _M.incr("blocks_profiled")
 
 
-def _record(name: str, t0: float, t1: float, thread: int) -> None:
+def _record(span: tuple) -> None:
+    """One finished span: onto the ambient timeline and into the ring.
+    ``list.append`` and ``deque.append`` are atomic in CPython — no lock."""
     tl = _current.get()
     if tl is not None:
-        tl.add_span(name, t0, t1, thread)
-    with _lock:
-        _span_ring.append((name, t0, t1, thread))
+        tl.spans.append(span)
+    _span_ring.append(span)
 
 
-@contextlib.contextmanager
-def phase(name: str) -> Iterator[None]:
-    """Record a named phase span (ambient timeline + global ring).  Cost is
-    two clock reads, one list append and one deque append — safe on the
-    per-packet path."""
-    tid = threading.get_ident()
-    stack = _thread_phase.setdefault(tid, [])
-    stack.append(name)
-    t0 = _now()
-    try:
-        yield
-    finally:
-        t1 = _now()
+def record_span(name: str, t0: float, t1: float) -> None:
+    """A span timed by its caller on :func:`_now` (the NameNode's request,
+    which starts before its handler thread knows there is one)."""
+    _record((name, t0, t1, threading.get_ident()))
+
+
+_trace_me = None    # jax.profiler.TraceAnnotation, once JAX is imported
+
+
+def _bind_trace_me():
+    """The device trace's annotation class if this process has ALREADY
+    imported JAX (never imports it: the DataNode beside a worker must stay
+    off JAX, and then pays one dict probe per span)."""
+    global _trace_me
+    cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                  "TraceAnnotation", None)
+    if cls is not None:
+        _trace_me = cls
+    return cls
+
+
+class phase:
+    """Record a named phase span (ambient timeline + global ring + this
+    thread's cumulative self seconds).  Cost is two clock reads, a list
+    append and a deque append — safe on the per-packet path.  While a
+    ``jax.profiler`` session is live the span is also a
+    ``TraceAnnotation("hdrf.<name>")`` on the trace's clock."""
+
+    __slots__ = ("name", "t0", "child", "_ts", "_ann")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+
+    def __enter__(self) -> "phase":
         try:
+            ts = _tls.state
+        except AttributeError:
+            ts = _thread_state()
+        self._ts = ts
+        ts.stack.append(self)
+        self.child = 0.0
+        self._ann = None
+        cls = _trace_me
+        if cls is None and "jax" in sys.modules:
+            cls = _bind_trace_me()
+        if cls is not None and cls.is_enabled():
+            self._ann = cls("hdrf." + self.name)
+            self._ann.__enter__()
+        self.t0 = _now()
+        return self
+
+    def _close(self) -> list:
+        """Off the thread's stack and off the trace; nothing recorded."""
+        stack = self._ts.stack
+        if stack and stack[-1] is self:
             stack.pop()
-        except IndexError:
-            pass
-        _record(name, t0, t1, tid)
+        elif self in stack:       # closed out of order (abandoned generator)
+            stack.remove(self)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        return stack
+
+    def __exit__(self, *exc) -> None:
+        t1 = _now()
+        stack = self._close()
+        ts = self._ts
+        name, t0 = self.name, self.t0
+        dur = t1 - t0
+        if stack:
+            stack[-1].child += dur
+        cum = ts.cum
+        cum[name] = cum.get(name, 0.0) + dur - self.child
+        span = (name, t0, t1, ts.tid)
+        tl = _current.get()       # _record, inlined on the per-packet path
+        if tl is not None:
+            tl.spans.append(span)
+        _span_ring.append(span)
+
+
+def lap(name: str, t0: float) -> None:
+    """One lap, [``t0`` (a :func:`mark`), now], of a phase too fine to record
+    span by span: a CRC32C or a forward per 64 KiB packet, 2 048 a block,
+    on the thread that receives.  The lap's seconds gather on the thread;
+    every ``_LAP_EVERY`` laps of a name (one 4 MiB stride) everything the
+    thread has gathered lands as spans (:func:`flush_laps`)."""
+    t1 = _now()
+    try:
+        ts = _tls.state
+    except AttributeError:
+        ts = _thread_state()
+    acc = ts.laps.get(name)
+    if acc is None:
+        acc = ts.laps[name] = [0.0, 0]
+    acc[0] += t1 - t0
+    acc[1] += 1
+    if acc[1] >= _LAP_EVERY:
+        flush_laps(t1)
+
+
+def flush_laps(end: float | None = None) -> None:
+    """Land this thread's gathered laps: ONE span per name, as long as the
+    name's laps together, laid end to end backwards from ``end``.  The laps
+    were disjoint intervals of one thread since its last flush, so the
+    spans fit in that stretch and overlap neither each other nor an
+    earlier flush: the partition books each name the seconds it measured,
+    placed within a stride of where they were spent.  They do lie over the
+    thread's own per-packet spans (``recv``, ``ack``), which lose those
+    seconds, while the moments the laps really ran stay uncovered: what
+    ``recv``/``ack`` lose reads as unattributed, their sum is kept."""
+    ts = getattr(_tls, "state", None)
+    if ts is None:
+        return
+    if end is None:
+        end = _now()
+    total = 0.0
+    for name, acc in ts.laps.items():
+        secs = acc[0]
+        if acc[1]:
+            acc[0], acc[1] = 0.0, 0
+            ts.cum[name] = ts.cum.get(name, 0.0) + secs
+            _record((name, end - total - secs, end - total, ts.tid))
+            total += secs
+    if total and ts.stack:
+        ts.stack[-1].child += total
 
 
 def timed_iter(name: str, it: Iterable) -> Iterator:
     """Wrap an iterator so each ``next()`` wait becomes one phase span —
     the per-packet ``recv`` attribution of the client-stream wait."""
     src = iter(it)
-    tid = threading.get_ident()
-    stack = _thread_phase.setdefault(tid, [])
     while True:
-        stack.append(name)
-        t0 = _now()
+        p = phase(name)
+        p.__enter__()
         try:
             item = next(src)
         except StopIteration:
+            p._close()          # the exhausted next() leaves no span
             return
-        finally:
-            try:
-                stack.pop()
-            except IndexError:
-                pass
-        _record(name, t0, _now(), tid)
+        except BaseException:
+            p.__exit__()
+            raise
+        p.__exit__()
         yield item
 
 
@@ -421,13 +616,36 @@ def thread_phase(thread_id: int | None = None) -> str | None:
     cross-thread stall attribution probe."""
     if thread_id is None:
         thread_id = threading.get_ident()
-    stack = _thread_phase.get(thread_id)
-    if not stack:
+    ts = _threads.get(thread_id)
+    if ts is None:
         return None
     try:
-        return stack[-1]
+        return ts.stack[-1].name
     except IndexError:
         return None
+
+
+def cumulative() -> dict[str, float]:
+    """Self seconds by phase name since process start, over every thread
+    (a span's self seconds are its wall less its nested spans', so leaf
+    stages under a covering span sum to the covering span's wall): the
+    reduction worker's ``<stage>_s`` counters."""
+    with _lock:
+        out = dict(_cum_dead)
+        states = list(_threads.values())
+    for ts in states:
+        for name, s in list(ts.cum.items()):
+            out[name] = out.get(name, 0.0) + s
+    return out
+
+
+def thread_cumulative() -> dict[str, float]:
+    """This thread's share of :func:`cumulative` — two of these bracket one
+    op on its handler thread."""
+    try:
+        return dict(_tls.state.cum)
+    except AttributeError:
+        return {}
 
 
 # ----------------------------------------------------------- device linkage
@@ -449,7 +667,7 @@ def note_device_wait(op: str, t0: float, t1: float,
     tl = _current.get()
     if tl is not None and event_id is not None:
         tl.ledger_ids.append(event_id)
-    _record("device_wait", t0, t1, threading.get_ident())
+    _record(("device_wait", t0, t1, threading.get_ident()))
 
 
 # ------------------------------------------------------------ counter tracks
@@ -496,10 +714,8 @@ def window_spans(t0: float, t1: float) -> list[tuple]:
     """Spans from ANY thread overlapping [t0, t1], clamped to it — the
     cross-thread view run-level accounting needs (the bench's commit worker
     records on its own thread; a contextvar would never see it)."""
-    with _lock:
-        spans = list(_span_ring)
     return [(p, max(s0, t0), min(s1, t1), tid)
-            for p, s0, s1, tid in spans if s1 > t0 and s0 < t1]
+            for p, s0, s1, tid in _span_ring.copy() if s1 > t0 and s0 < t1]
 
 
 def window_profile(t0: float, t1: float, nbytes: int = 0) -> dict[str, Any]:

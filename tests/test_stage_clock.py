@@ -1,0 +1,414 @@
+"""One clock down the served write path (PR 25): the phase clock's new
+DataNode phases, the per-stride laps of the per-packet ones and the worker's
+stage clock.
+
+Reference seam: DataNodeMetrics.java:553-560 counts write ops and packet
+round trips, never where a block's time went; BlockReceiver.java:877-897 is
+the receive loop the phases decompose.
+"""
+
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from hdrf_tpu.config import CdcConfig
+from hdrf_tpu.server.reduction_worker import ReductionWorker, WorkerClient
+from hdrf_tpu.testing.minicluster import MiniCluster
+from hdrf_tpu.utils import profiler
+
+BLOCK = 2 << 20
+DN_PHASES = ("packet_verify", "worker_send", "seal_send", "seal_wait",
+             "seal_write", "nn_rpc", "heartbeat_stats", "block_scan")
+
+
+def _payload(n: int, seed: int = 7) -> bytes:
+    return np.random.default_rng(seed).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+def _monotone(before: dict, after: dict, keys) -> bool:
+    return all(after[k] >= before.get(k, 0.0) for k in keys)
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One DataNode beside a native reduction worker PROCESS (the served
+    layout of chip_smoke.py and perfbench), two blocks written through it;
+    heartbeat and scanner fast enough to tick inside the test."""
+    profiler.reset()
+    t0 = profiler.mark()
+    with MiniCluster(n_datanodes=1, replication=1, block_size=BLOCK,
+                     container_size=1 << 20, tpu_worker=True,
+                     worker_backend="native",
+                     dn_config_overrides={"scan_interval_s": 0.2}) as mc:
+        dn = mc.datanodes[0]
+        first = dn._worker.stats()
+        with mc.client("stage-clock") as c:
+            c.write("/a", _payload(BLOCK, 1), scheme="dedup_lz4")
+            mid = dn._worker.stats()
+            c.write("/b", _payload(BLOCK, 2), scheme="dedup_lz4")
+            dn.containers.drain_seals()
+            deadline = time.time() + 10
+            while time.time() < deadline:          # one tick of each loop
+                names = {sp[0] for sp in profiler.window_spans(
+                    t0, float("inf"))}
+                if {"heartbeat_stats", "block_scan"} <= names:
+                    break
+                time.sleep(0.1)
+            assert c.read("/a") == _payload(BLOCK, 1)
+        last = dn._worker.stats()
+        yield {"t0": t0, "t1": profiler.mark(), "first": first, "mid": mid,
+               "last": last,
+               "timelines": profiler.timelines_snapshot()}
+
+
+class TestDataNodePhases:
+    @pytest.mark.parametrize("name", DN_PHASES)
+    def test_a_served_block_leaves_the_phase_in_the_window(self, served,
+                                                           name):
+        spans = profiler.window_spans(served["t0"], served["t1"])
+        assert any(sp[0] == name and sp[2] > sp[1] for sp in spans)
+        prof = profiler.window_profile(served["t0"], served["t1"])
+        if name in ("packet_verify", "worker_send"):
+            # on the block's own path.  A background wait (seal_*) or a
+            # millisecond tick owns only the seconds nothing ahead of it in
+            # the order claims, which in a busy test process may be none
+            assert prof["phases"].get(name, 0.0) > 0.0, sorted(prof["phases"])
+
+    def test_the_existing_phases_keep_their_names(self, served):
+        prof = profiler.window_profile(served["t0"], served["t1"])
+        assert {"recv", "ack", "device_wait", "dedup_lookup",
+                "container_io", "buffer_assemble"} <= set(prof["phases"])
+
+    def test_a_block_is_attributed(self, served):
+        tls = [t for t in served["timelines"] if t["nbytes"] == BLOCK]
+        assert len(tls) == 2
+        for tl in tls:
+            assert tl["profile"]["attributed_frac"] >= 0.9, tl["profile"]
+            names = {s[0] for s in tl["spans"]}
+            assert {"recv", "packet_verify", "worker_send",
+                    "device_wait"} <= names
+
+    def test_per_packet_phases_land_as_a_span_a_stride(self, served):
+        """32 packets of 64 KiB a block: far fewer ``packet_verify`` and
+        ``worker_send`` spans than ``recv`` ones, for the same seconds."""
+        for tl in [t for t in served["timelines"] if t["nbytes"] == BLOCK]:
+            n = {name: sum(1 for s in tl["spans"] if s[0] == name)
+                 for name in ("recv", "packet_verify", "worker_send")}
+            assert n["recv"] >= BLOCK // (64 << 10)
+            assert 1 <= n["packet_verify"] <= 3
+            assert 1 <= n["worker_send"] <= 3
+
+
+class TestWorkerStageClock:
+    NATIVE = ("ingest_wait_s", "packet_verify_s", "reduce_compute_s",
+              "block_s")
+
+    def test_stats_carry_the_stages_the_backend_runs(self, served):
+        st = served["last"]
+        for key in self.NATIVE + ("emit_s", "cpu_s", "wall_s", "ingest_s",
+                                  "reduce_s", "compress_s"):
+            assert key in st, sorted(st)
+        # a stage this backend does not run is left out, not zero; and no
+        # stage that no metric and none of the three sums reads
+        for key in ("stage_h2d_s", "prep_wait_s", "select_s", "sha_wait_s",
+                    "scan_wait_s", "idle_s", "reply_s", "seal_recv_s",
+                    "seal_reply_s"):
+            assert key not in st
+        assert all(isinstance(v, (int, float)) for v in st.values())
+
+    def test_every_stage_is_monotone(self, served):
+        keys = [k for k in served["last"] if k.endswith("_s")]
+        assert _monotone(served["first"], served["mid"],
+                         [k for k in keys if k in served["mid"]])
+        assert _monotone(served["mid"], served["last"], keys)
+        assert served["last"]["blocks_reduced"] == \
+            served["first"]["blocks_reduced"] + 2
+
+    def test_the_stages_close_on_the_legacy_sums(self, served):
+        st = served["last"]
+        legs = st["ingest_s"] + st["reduce_s"]
+        # every packet_verify of this process: the reduces' and the seals'
+        stages = (st["ingest_wait_s"] + st["packet_verify_s"]
+                  + st["reduce_compute_s"])
+        assert legs > 0 and abs(stages - legs) <= 0.1 * legs
+        # what no stage explains of a reduce op (the covering span's self
+        # seconds, the reply among them) is small beside the op — or, for
+        # two blocks as small as these, small
+        assert 0.0 < st["block_s"] <= max(0.1 * legs, 0.05)
+        assert abs(st["compress_s"] - st["emit_s"]) <= 0.1 * st["compress_s"]
+
+    def test_a_block_counted_has_its_seconds(self):
+        """The sums go in with ``blocks_reduced``, before the reply: the
+        caller's next ``stats`` cannot see a block without its seconds."""
+        w = ReductionWorker(backend="native").start()
+        try:
+            c = WorkerClient(w.addr)
+            a = c.stats()
+            c.reduce(_payload(200_000), CdcConfig())
+            b = c.stats()
+            c.close()
+        finally:
+            w.stop()
+        assert b["blocks_reduced"] == a["blocks_reduced"] + 1
+        assert b["ingest_s"] > a["ingest_s"] and b["reduce_s"] > a["reduce_s"]
+
+    def test_cpu_and_wall_make_a_busy_share(self, served):
+        a, b = served["first"], served["last"]
+        busy = (b["cpu_s"] - a["cpu_s"]) / (b["wall_s"] - a["wall_s"])
+        assert 0.0 < busy < os.cpu_count() + 1
+
+
+class TestDeviceStages:
+    def test_the_device_path_reports_its_own_stages(self):
+        """backend="tpu" in-process on the CPU mesh: the stages of the
+        streamed reduce, and their closure on ingest_s + reduce_s."""
+        w = ReductionWorker(backend="tpu").start()
+        try:
+            c = WorkerClient(w.addr)
+            a = c.stats()
+            c.reduce(_payload(300_000), CdcConfig())
+            b = c.stats()
+            d = {k: b[k] - a.get(k, 0.0) for k in b if k.endswith("_s")}
+            for key in ("ingest_wait_s", "packet_verify_s", "stage_h2d_s",
+                        "prep_wait_s", "select_s", "sha_wait_s"):
+                assert d[key] > 0.0, (key, d)
+            legs = d["ingest_s"] + d["reduce_s"]
+            stages = sum(d[k] for k in (
+                "ingest_wait_s", "packet_verify_s", "stage_h2d_s",
+                "prep_wait_s", "select_s", "sha_wait_s"))
+            assert abs(stages - legs) <= 0.1 * legs
+            c.close()
+        finally:
+            w.stop()
+
+    def test_a_device_compress_records_scan_wait_inside_emit(self):
+        from hdrf_tpu.ops.lz4_tpu import TpuLz4
+
+        before = profiler.thread_cumulative()
+        text = (b"the quick brown fox jumps over the lazy dog " * 8000)
+        with profiler.phase("emit"):
+            out = TpuLz4(min_device=1 << 16).compress(text)
+        from hdrf_tpu import native
+
+        assert bytes(native.lz4_decompress(out, len(text))) == text
+        after = profiler.thread_cumulative()
+        scan = after.get("scan_wait", 0.0) - before.get("scan_wait", 0.0)
+        emit = after.get("emit", 0.0) - before.get("emit", 0.0)
+        assert scan > 0.0 and emit > 0.0   # emit keeps only its own seconds
+
+
+class TestLaps:
+    def test_laps_gather_into_one_span_a_stride(self, monkeypatch):
+        profiler.reset()
+        clk = [10.0]
+        monkeypatch.setattr(profiler, "_now", lambda: clk[0])
+        before = profiler.thread_cumulative()
+        with profiler.phase("ingest_wait"):
+            for _ in range(profiler._LAP_EVERY):
+                t0 = profiler.mark()
+                clk[0] += 0.25                  # the lap
+                profiler.lap("packet_verify", t0)
+                clk[0] += 0.75                  # the wait beside it
+        spans = profiler.window_spans(0.0, float("inf"))
+        n = profiler._LAP_EVERY
+        assert [s[:3] for s in spans] == [
+            ("packet_verify", 10.0 + n - 0.75 - 0.25 * n, 10.0 + n - 0.75),
+            ("ingest_wait", 10.0, 10.0 + n)]
+        after = profiler.thread_cumulative()
+        d = {k: after[k] - before.get(k, 0.0) for k in after}
+        assert d["packet_verify"] == pytest.approx(0.25 * n)
+        assert d["ingest_wait"] == pytest.approx(0.75 * n)   # self seconds
+        prof = profiler.profile_spans(spans, 10.0, 10.0 + n)
+        assert prof["phases"] == pytest.approx(
+            {"packet_verify": 0.25 * n, "ingest_wait": 0.75 * n})
+
+    def test_two_names_are_laid_end_to_end_and_flushed_together(
+            self, monkeypatch):
+        """The DataNode's receive thread laps a verify and a forward per
+        packet: one flush lands both, side by side, so the exclusive
+        partition books each what it measured."""
+        profiler.reset()
+        clk = [0.0]
+        monkeypatch.setattr(profiler, "_now", lambda: clk[0])
+        for _ in range(10):
+            with profiler.phase("recv"):
+                clk[0] += 1.0
+                t0 = profiler.mark()
+                clk[0] += 0.5
+                profiler.lap("packet_verify", t0)
+            t0 = profiler.mark()
+            clk[0] += 2.0
+            profiler.lap("worker_send", t0)
+        profiler.flush_laps()
+        profiler.flush_laps()               # nothing gathered: no span
+        spans = profiler.window_spans(-1.0, float("inf"))
+        laid = {s[0]: (s[1], s[2]) for s in spans if s[0] != "recv"}
+        assert laid == {"packet_verify": (30.0, 35.0),
+                        "worker_send": (10.0, 30.0)}
+        assert sum(1 for s in spans if s[0] == "recv") == 10
+        prof = profiler.profile_spans(spans, 0.0, 35.0)
+        assert prof["phases"]["packet_verify"] == pytest.approx(5.0)
+        assert prof["phases"]["worker_send"] == pytest.approx(20.0)
+        # recv keeps the three spans ahead of the laid ones: [0, 1.5],
+        # [3.5, 5], [7, 8.5]; the gaps between them are in no phase
+        assert prof["phases"]["recv"] == pytest.approx(4.5)
+        assert prof["classes"]["idle"] == pytest.approx(5.5)
+
+    def test_the_last_packet_flushes_the_reader(self):
+        """``read_packet_ex`` laps every packet and lands them at the last
+        one, so an op's verify seconds are on the clock when it ends."""
+        import socket
+
+        from hdrf_tpu.proto import datatransfer as dt
+
+        profiler.reset()
+        t0 = profiler.mark()
+        a, b = socket.socketpair()
+        try:
+            for seq in range(5):
+                dt.write_packet(a, seq, _payload(4096, seq))
+            dt.write_packet(a, 5, b"", last=True)
+            got = b"".join(d for _, d, _ in dt.iter_packets(b))
+        finally:
+            a.close()
+            b.close()
+        assert len(got) == 5 * 4096
+        spans = [s for s in profiler.window_spans(t0, float("inf"))
+                 if s[0] == "packet_verify"]
+        assert len(spans) == 1 and spans[0][2] > spans[0][1]
+
+
+class TestPartitionStillExact:
+    SPANS3 = [("recv", 0.0, 4.0), ("wal_commit", 1.0, 2.0),
+              ("device_wait", 3.0, 6.0)]
+
+    @pytest.mark.parametrize("fields", [3, 4, 5, "mixed"])
+    def test_classes_sum_to_the_wall(self, fields):
+        def widen(i, sp):
+            n = fields if fields != "mixed" else 3 + i % 3
+            return sp + ((7,) if n >= 4 else ()) + ((0.25,) if n == 5
+                                                    else ())
+
+        spans = [widen(i, sp) for i, sp in enumerate(self.SPANS3)]
+        prof = profiler.profile_spans(spans, 0.0, 8.0)
+        assert sum(prof["classes"].values()) == pytest.approx(8.0, abs=1e-12)
+        assert prof["classes"] == {"host_busy": 1.0, "device_busy": 3.0,
+                                   "transport_wait": 2.0, "idle": 2.0}
+        assert prof["phases"] == {"recv": 2.0, "wal_commit": 1.0,
+                                  "device_wait": 3.0}
+        assert "host_stall_s" not in prof       # step 2 of ISSUE 25: cut
+
+    def test_a_clipped_span_counts_what_the_window_holds(self):
+        prof = profiler.profile_spans([("checksum", 0.0, 4.0, 1)], 2.0, 4.0)
+        assert prof["classes"]["host_busy"] == 2.0
+        assert prof["phases"] == {"checksum": 2.0}
+
+    def test_new_phases_have_the_classes_the_partition_needs(self):
+        host = ("packet_verify", "seal_write", "nn_rpc", "heartbeat_stats",
+                "block_scan")
+        waits = ("worker_send", "seal_send", "seal_wait")
+        assert all(profiler.phase_class(p) == profiler.HOST for p in host)
+        assert all(profiler.phase_class(p) == profiler.TRANSPORT
+                   for p in waits)
+        order = profiler.PHASE_ORDER
+        assert order.index("worker_send") < order.index("recv")
+        assert order.index("ack") < order.index("seal_send")
+        # a host phase takes its seconds from recv; a background wait
+        # claims only what no foreground phase does
+        prof = profiler.profile_spans(
+            [("recv", 0.0, 4.0), ("packet_verify", 1.0, 2.0),
+             ("worker_send", 3.0, 5.0), ("seal_wait", 0.0, 6.0)], 0.0, 8.0)
+        assert prof["phases"] == {"recv": 2.0, "packet_verify": 1.0,
+                                  "worker_send": 2.0, "seal_wait": 1.0}
+
+
+class TestRecorder:
+    def test_cumulative_self_seconds_close_on_the_covering_span(
+            self, monkeypatch):
+        clk = [50.0]
+        monkeypatch.setattr(profiler, "_now", lambda: clk[0])
+        before = profiler.thread_cumulative()
+        with profiler.phase("block"):
+            clk[0] += 1
+            with profiler.phase("ingest_wait"):
+                clk[0] += 2
+                with profiler.phase("stage_h2d"):
+                    clk[0] += 0.5
+            clk[0] += 0.25                  # the reply: no stage of its own
+        after = profiler.thread_cumulative()
+        d = {k: after[k] - before.get(k, 0.0) for k in after}
+        assert d["block"] == pytest.approx(1.25)
+        assert d["ingest_wait"] == pytest.approx(2.0)
+        assert d["stage_h2d"] == pytest.approx(0.5)
+        assert sum(d.values()) == pytest.approx(3.75)
+
+    def test_cumulative_sums_over_threads_that_have_ended(self):
+        base = profiler.cumulative().get("emit", 0.0)
+
+        def work():
+            with profiler.phase("emit"):
+                time.sleep(0.01)
+
+        for _ in range(profiler._FOLD_AT + 8):
+            th = threading.Thread(target=work)
+            th.start()
+            th.join()
+        total = profiler.cumulative()["emit"] - base
+        assert total >= 0.01 * (profiler._FOLD_AT + 8)
+        assert len(profiler._threads) <= profiler._FOLD_AT + 8
+
+    def test_spans_carry_no_lock_and_survive_concurrent_readers(self):
+        profiler.reset()
+        t0 = profiler.mark()
+        stop = threading.Event()
+
+        def write():
+            while not stop.is_set():
+                with profiler.phase("ack"):
+                    pass
+
+        ths = [threading.Thread(target=write) for _ in range(4)]
+        for th in ths:
+            th.start()
+        try:
+            for _ in range(20):
+                profiler.window_profile(t0, profiler.mark())
+        finally:
+            stop.set()
+            for th in ths:
+                th.join()
+
+    def test_a_trace_annotation_opens_only_under_a_live_session(self):
+        import jax  # noqa: F401 — the test process holds JAX already
+
+        with profiler.phase("select") as p:
+            assert p._ann is None         # no profiler session: a flag test
+        assert profiler._trace_me is jax.profiler.TraceAnnotation
+
+    def test_under_a_session_the_span_is_on_the_trace(self, tmp_path):
+        import glob
+
+        import jax
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with profiler.phase("block") as cover:
+                assert cover._ann is not None
+                with profiler.phase("ingest_wait"):
+                    time.sleep(0.01)
+        finally:
+            jax.profiler.stop_trace()
+        pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))[0]
+        names = set()
+        for plane in jax.profiler.ProfileData.from_file(pb).planes:
+            if plane.name.startswith("/host:CPU"):
+                for line in plane.lines:
+                    names.update(e.name for e in line.events)
+        assert any(n.startswith("hdrf.ingest_wait") for n in names), \
+            sorted(n for n in names if "hdrf" in n)
+        assert any(n.startswith("hdrf.block") for n in names)
