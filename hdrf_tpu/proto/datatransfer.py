@@ -18,6 +18,14 @@ Wire layout:
 - Ack:       ``[u64 seqno][u8 status]`` per packet, status 0 = SUCCESS; for
   pipelines the ack aggregates downstream status (worst wins), the analog of
   PipelineAck.
+- Stride:    ``[u32 nseg][u8 flags]`` + nseg x ``[u32 len][u32 crc32c]`` + the
+  segments back to back.  The upload leg of the DataNode -> reduction-worker
+  ``reduce`` op only (server/reduction_worker.py): one frame per device
+  upload stride instead of one per client packet.  A segment is a client
+  packet carried with the CRC32C its producer computed for it (the DataNode
+  verified it before the ack and does not compute it again), so the receiver
+  checks every byte against its origin's sum, one native call a frame.
+  ``FLAG_LAST`` ends the stream; the last frame may hold no segment.
 
 Ops (Receiver.java:101-135 op dispatch analog): WRITE_BLOCK, READ_BLOCK,
 TRANSFER_BLOCK, COPY_BLOCK, BLOCK_CHECKSUM — dispatched by the DataNode's
@@ -29,6 +37,8 @@ from __future__ import annotations
 import socket
 import struct
 from typing import Any, Iterator
+
+import numpy as np
 
 from hdrf_tpu import native
 from hdrf_tpu.proto.rpc import recv_exact, recv_frame, send_frame
@@ -112,9 +122,12 @@ def write_packet(sock: socket.socket, seqno: int, data: bytes,
         sock.sendall(data)
 
 
-def read_packet_ex(sock: socket.socket) -> tuple[int, bytes, int]:
-    """Returns (seqno, data, flags); raises IOError on checksum mismatch —
-    the receiver-side verify the reference does per checksum chunk."""
+def read_packet_crc(sock: socket.socket) -> tuple[int, bytes, int, int]:
+    """Returns (seqno, data, flags, crc32c); raises IOError on checksum
+    mismatch — the receiver-side verify the reference does per checksum
+    chunk.  The CRC is the sender's own, just verified: a receiver that
+    passes the packet on (the DataNode's hop to the reduction worker)
+    carries it instead of computing it again."""
     ln, seqno, flags, crc = PKT_HDR.unpack(recv_exact(sock, PKT_HDR.size))
     data = recv_exact(sock, ln) if ln else b""
     # its own phase: a caller that times the whole call as a socket wait
@@ -128,11 +141,15 @@ def read_packet_ex(sock: socket.socket) -> tuple[int, bytes, int]:
         profiler.flush_laps()
     if not ok:
         raise IOError(f"packet {seqno}: checksum mismatch")
-    return seqno, data, flags
+    return seqno, data, flags, crc
+
+
+def read_packet_ex(sock: socket.socket) -> tuple[int, bytes, int]:
+    return read_packet_crc(sock)[:3]
 
 
 def read_packet(sock: socket.socket) -> tuple[int, bytes, bool]:
-    seqno, data, flags = read_packet_ex(sock)
+    seqno, data, flags, _crc = read_packet_crc(sock)
     return seqno, data, bool(flags & FLAG_LAST)
 
 
@@ -140,6 +157,17 @@ def iter_packets(sock: socket.socket) -> Iterator[tuple[int, bytes, bool]]:
     while True:
         seqno, data, last = read_packet(sock)
         yield seqno, data, last
+        if last:
+            return
+
+
+def iter_packets_crc(
+        sock: socket.socket) -> Iterator[tuple[int, bytes, bool, int]]:
+    """``iter_packets`` with each packet's verified CRC32C beside it."""
+    while True:
+        seqno, data, flags, crc = read_packet_crc(sock)
+        last = bool(flags & FLAG_LAST)
+        yield seqno, data, last, crc
         if last:
             return
 
@@ -254,3 +282,72 @@ def collect_packets(sock: socket.socket, ack_sock: socket.socket | None = None,
         if ack_sock is not None:
             send_ack(ack_sock, seqno)
     return b"".join(parts)
+
+
+# ----------------------------------------------------------- stride frames
+
+STRIDE_HDR = struct.Struct("<IB")
+_IOV_MAX = 512      # buffers per sendmsg (the kernel takes 1024)
+
+
+def _sendmsg_all(sock: socket.socket, bufs: list) -> None:
+    """``sendall`` for a list of buffers: scatter-gather, no join."""
+    i, n = 0, len(bufs)
+    while i < n:
+        sent = sock.sendmsg(bufs[i:i + _IOV_MAX])
+        while i < n and sent >= len(bufs[i]):    # wholly sent (or empty)
+            sent -= len(bufs[i])
+            i += 1
+        if sent:                                 # a short write: the rest
+            bufs[i] = memoryview(bufs[i])[sent:]
+
+
+def write_stride(sock: socket.socket, segs: list, crcs: list[int],
+                 last: bool = False) -> None:
+    """One stride frame: the header and ``segs`` (bytes-likes, each with
+    its CRC32C in ``crcs``) leave in one ``sendmsg``."""
+    table = [v for seg, crc in zip(segs, crcs) for v in (len(seg), crc)]
+    hdr = struct.pack(f"<IB{len(table)}I", len(segs),
+                      FLAG_LAST if last else 0, *table)
+    _sendmsg_all(sock, [hdr, *segs])
+
+
+def read_stride(sock: socket.socket
+                ) -> tuple[np.ndarray, np.ndarray, np.ndarray, bool]:
+    """Receive one stride frame, unverified: ``(buf, lens, crcs, last)``
+    with the segments back to back in ``buf``, a fresh ``uint8`` array the
+    caller may hand on as it is (a device upload may still be reading the
+    one before)."""
+    nseg, flags = STRIDE_HDR.unpack(recv_exact(sock, STRIDE_HDR.size))
+    table = np.frombuffer(recv_exact(sock, 8 * nseg), "<u4").reshape(-1, 2)
+    lens, crcs = table[:, 0], table[:, 1]
+    buf = np.empty(int(lens.sum(dtype=np.int64)), np.uint8)
+    view, got = memoryview(buf), 0
+    while got < buf.size:
+        r = sock.recv_into(view[got:], buf.size - got, socket.MSG_WAITALL)
+        if r == 0:
+            raise ConnectionError("peer closed connection")
+        got += r
+    return buf, lens, crcs, bool(flags & FLAG_LAST)
+
+
+def verify_stride(buf: np.ndarray, lens: np.ndarray,
+                  crcs: np.ndarray) -> None:
+    """Every segment of a stride against its carried CRC32C.  A mismatch
+    raises ValueError, not IOError: the bytes are wrong, the connection is
+    not, and the worker answers an error frame for it instead of hanging
+    up.  Equal segments (all but a shorter last: every stride of 64 KiB
+    client packets) are one native call."""
+    if not len(lens):
+        return
+    seg = int(lens[0])
+    if (lens[:-1] == seg).all() and 0 < lens[-1] <= seg:
+        ok = native.crc32c_chunks(buf, seg) == crcs
+    else:
+        ends = np.cumsum(lens, dtype=np.int64)
+        ok = np.array([native.crc32c(buf[e - n:e]) == c
+                       for e, n, c in zip(ends.tolist(), lens.tolist(),
+                                          crcs.tolist())])
+    if not ok.all():
+        raise ValueError(f"stride segment {int(np.argmin(ok))} of "
+                         f"{len(lens)}: checksum mismatch")

@@ -253,7 +253,11 @@ class BlockReceiver:
         FORWARDED to the worker as they arrive (client -> DN -> worker ->
         HBM is one pipeline; the worker stages bytes to device mid-stream)
         and only (cuts, digests) come back; otherwise the block buffers
-        locally (bf1 analog) and reduces in-process.
+        locally (bf1 analog) and reduces in-process.  Each packet goes on
+        with the CRC32C the client sent for it, verified here before the
+        ack: ``reduce_stream`` carries it to the worker (one frame per
+        4 MiB stride) instead of summing the bytes a second time, so the
+        worker checks what it uploads against the client's own sum.
 
         Memory honesty (r3 verdict weak #7): even on the worker path the
         DN ALSO accumulates the block host-side (``parts``) — container
@@ -292,10 +296,10 @@ class BlockReceiver:
             parts: list[bytes] = []
             last_seqno = [0]
             # each next() wait on the client stream is one "recv" span
-            packets = profiler.timed_iter("recv", dt.iter_packets(sock))
+            packets = profiler.timed_iter("recv", dt.iter_packets_crc(sock))
 
             def stream():
-                for seqno, data, last in packets:
+                for seqno, data, last, crc in packets:
                     last_seqno[0] = seqno
                     # same per-packet crash window as the direct path (the
                     # resilience fault matrix kills the worker mid-stream
@@ -312,7 +316,7 @@ class BlockReceiver:
                             dt.send_ack(sock, seqno)
                     if data:
                         parts.append(data)
-                        yield data
+                        yield data, crc
 
             precomputed = None
             worker_down = False
@@ -448,7 +452,7 @@ class BlockReceiver:
                                     daemon=True)
             pump.start()
         try:
-            for seqno, data, last in packets:
+            for seqno, data, last, _crc in packets:
                 last_seqno[0] = seqno
                 fault_injection.point("block_receiver.packet",
                                       block_id=block_id, seqno=seqno,

@@ -7,11 +7,13 @@ equivalent of the reference's in-process JNI boundary (DataXceiver ->
 libnayuki/codecs), lifted into its own process so the DataNode host stays
 device-free:
 
-- **Streaming ingest**: the DataNode forwards block packets AS RECEIVED
-  over the owned framed protocol (same packet framing as DN<->DN transfer);
-  the worker stages them to HBM in stride-sized device uploads while later
-  packets are still arriving, then assembles the resident block
-  device-side — bytes land in HBM before the stream even finishes.
+- **Streaming ingest**: the DataNode forwards block packets as they are
+  received, gathered into one frame per device upload stride (the stride
+  wire of proto/datatransfer.py: the client's packets and the CRCs it
+  sent, one ``sendmsg``); the worker reads each frame into one buffer,
+  verifies it in one native call and stages it to HBM while later packets
+  are still arriving, then assembles the resident block device-side —
+  bytes land in HBM before the stream even finishes.
 - **Compute**: CDC candidate scan + bucketed SHA-256 via
   ops.resident.ResidentReducer on the resident image; LZ4 match discovery
   via ops.lz4_tpu.  Only cuts/digests/compressed bytes return to the DN —
@@ -35,6 +37,7 @@ from typing import Any
 
 import numpy as np
 
+from hdrf_tpu import native
 from hdrf_tpu.config import CdcConfig
 from hdrf_tpu.proto import datatransfer as dt
 from hdrf_tpu.proto.rpc import recv_frame, send_frame
@@ -46,7 +49,9 @@ _TR = tracing.tracer("reduction_worker")
 
 # Device upload stride for streaming ingest: big enough to amortize the
 # per-transfer cost, small enough that HBM staging overlaps the tail of
-# the network stream.
+# the network stream.  Also the frame of the reduce op's upload leg: the
+# DataNode sends one when this many bytes are pending, the worker uploads
+# each as it is.
 _STRIDE = 4 << 20
 
 # The stage clock (utils/profiler.py, PR 25): a reduce op is a run of leaf
@@ -56,6 +61,9 @@ _STRIDE = 4 << 20
 # op, on the handler thread.
 _INGEST_STAGES = ("ingest_wait", "packet_verify", "stage_h2d")
 _COMPRESS_STAGES = ("scan_wait", "emit")
+
+# (cuts, digests) of a reduce op that streamed no byte
+_NO_CHUNKS = (np.empty(0, np.int64), np.empty((0, 32), np.uint8))
 
 
 def _stage_seconds(before: dict) -> dict:
@@ -91,9 +99,13 @@ class ReductionWorker:
         # (packets in, verify, H2D strides; then scan, select, SHA and
         # readbacks) and compress jobs (match scan + emit) — sums of the
         # stage clock's seconds, kept because a metric and a test read them
+        # hop_frames / hop_packets: stride frames that carried bytes and
+        # the segments in them (64 a frame when a client sends 64 KiB
+        # packets; 1 when a whole buffer came through ``reduce``)
         self._stats = {"blocks_reduced": 0, "bytes_reduced": 0,
                        "compress_jobs": 0, "ingest_s": 0.0,
-                       "reduce_s": 0.0, "compress_s": 0.0}
+                       "reduce_s": 0.0, "compress_s": 0.0,
+                       "hop_frames": 0, "hop_packets": 0}
         outer = self
 
         class Handler(socketserver.BaseRequestHandler):
@@ -234,11 +246,14 @@ class ReductionWorker:
         return r
 
     def _op_reduce(self, sock: socket.socket, req: dict) -> None:
-        """Packet stream -> (cuts, digests).  TPU backend: packets stage to
-        HBM in _STRIDE device uploads DURING the stream; the resident block
-        is assembled device-side.  ``block`` is the op's one covering span:
-        what it keeps as self seconds (the reply among them) is what no
-        stage explains."""
+        """Stride frames -> (cuts, digests).  Both backends read the same
+        wire (``_strides``): a frame is one verified buffer.  TPU backend:
+        each goes to HBM as it is, one device upload DURING the stream; the
+        resident block is assembled device-side.  A frame that fails its
+        check raises out of here, so the DataNode gets the error frame every
+        worker failure gets and falls back in-process.  ``block`` is the
+        op's one covering span: what it keeps as self seconds (the reply
+        among them) is what no stage explains."""
         cdc = CdcConfig(mask_bits=req["mask_bits"],
                         min_chunk=req["min_chunk"],
                         max_chunk=req["max_chunk"])
@@ -249,11 +264,15 @@ class ReductionWorker:
             else:
                 from hdrf_tpu.ops import dispatch as ops_dispatch
 
-                with profiler.phase("ingest_wait"):
-                    data = dt.collect_packets(sock)
-                buf = np.frombuffer(data, dtype=np.uint8)
-                cuts, digs = ops_dispatch.chunk_and_fingerprint(
-                    buf, cdc, self.backend)      # phase "reduce_compute"
+                bufs = list(self._strides(sock))
+                if bufs:
+                    with profiler.phase("ingest_wait"):   # the join it held
+                        buf = (np.concatenate(bufs) if len(bufs) > 1
+                               else bufs[0])
+                    cuts, digs = ops_dispatch.chunk_and_fingerprint(
+                        buf, cdc, self.backend)  # phase "reduce_compute"
+                else:
+                    cuts, digs = _NO_CHUNKS
             nbytes = int(cuts[-1]) if len(cuts) else 0
             # the sums go in with the count, before the reply: a ``stats``
             # call that sees the block sees its seconds
@@ -269,34 +288,40 @@ class ReductionWorker:
         _M.incr("blocks_reduced")
         accounting.record_worker_bytes("reduce", nbytes)
 
+    def _strides(self, sock: socket.socket):
+        """The reduce op's upload leg: yields each stride frame's bytes as
+        one fresh ``uint8`` array, every segment checked against the CRC32C
+        it was carried with (the client's own for a served write).  One
+        ``ingest_wait`` and one ``packet_verify`` span a frame; a stream
+        read to its end adds its frames and segments to ``stats``."""
+        frames = segments = 0
+        last = False
+        while not last:
+            with profiler.phase("ingest_wait"):
+                buf, lens, crcs, last = dt.read_stride(sock)
+            if buf.size:
+                with profiler.phase("packet_verify"):
+                    dt.verify_stride(buf, lens, crcs)
+                frames += 1
+                segments += len(lens)
+                yield buf
+        with self._stats_lock:
+            self._stats["hop_frames"] += frames
+            self._stats["hop_packets"] += segments
+
     def _reduce_streaming_tpu(self, sock: socket.socket, cdc: CdcConfig):
         import jax
         import jax.numpy as jnp
 
         parts: list = []        # resident device strides (uploads in flight)
         total = 0
-        packets = dt.iter_packets(sock)
-        streaming = True
-        while streaming:
-            pend: list[bytes] = []  # current stride accumulator
-            pend_n = 0
-            with profiler.phase("ingest_wait"):   # one span per stride
-                for _seq, data, _last in packets:
-                    if data:
-                        pend.append(data)
-                        pend_n += len(data)
-                        if pend_n >= _STRIDE:
-                            break
-                else:
-                    streaming = False
-            if pend:
-                with profiler.phase("stage_h2d"):
-                    blob = np.frombuffer(b"".join(pend), np.uint8)
-                    parts.append(jax.device_put(blob))  # async H2D: lands
-                    # in HBM while the next packets stream in
-                total += pend_n
+        for buf in self._strides(sock):
+            with profiler.phase("stage_h2d"):
+                parts.append(jax.device_put(buf))  # async H2D: lands in
+                # HBM while the next frame streams in
+            total += buf.size
         if not parts:
-            return np.empty(0, np.int64), np.empty((0, 32), np.uint8)
+            return _NO_CHUNKS
         from hdrf_tpu.ops.resident import _PAD_GRID
 
         with profiler.phase("stage_h2d"):
@@ -484,8 +509,16 @@ class WorkerClient:
         """Forward an iterator of byte packets; returns (cuts, digests).
         This is the true streaming path: the DN calls it from inside its
         packet-receive loop, so client->DN->worker->HBM is one pipeline.
-        The deadline budget accrues ``deadline_s_per_mb`` per streamed MiB
-        (payload size is only known as it arrives).
+
+        The upload leg carries strides, not packets (the stride wire of
+        proto/datatransfer.py).  An item of ``packets`` is ``(data, crc)``
+        — bytes that arrived with a verified CRC32C, which is carried, not
+        computed again — or plain bytes, summed here once in segments of at
+        most ``_STRIDE``.  Whenever ``_STRIDE`` bytes are pending they
+        leave as ONE frame in one ``sendmsg`` (phase ``worker_send``); the
+        last frame takes what is left.  The deadline budget accrues
+        ``deadline_s_per_mb`` per streamed MiB (payload size is only known
+        as it arrives) and is checked once a frame.
 
         Exception classes: worker-side failures raise :class:`WorkerError`;
         anything the ``packets`` iterator itself raises (the caller's OWN
@@ -493,6 +526,24 @@ class WorkerClient:
         unchanged, so the caller can tell the two apart."""
         dl = self._deadline()
         s = self._conn(dl)
+        segs: list = []
+        crcs: list[int] = []
+        pending = 0
+
+        def send(last: bool = False) -> None:
+            nonlocal pending
+            try:
+                dl.extend(self._per_mb * pending / float(1 << 20))
+                dl.check("worker reduce stream")
+                s.settimeout(dl.timeout())
+                with profiler.phase("worker_send"):
+                    dt.write_stride(s, segs, crcs, last)
+            except OSError as e:
+                raise WorkerError(f"worker send failed: {e}") from e
+            segs.clear()
+            crcs.clear()
+            pending = 0
+
         try:
             try:
                 send_frame(s, self._stamped(
@@ -501,29 +552,24 @@ class WorkerClient:
                      "max_chunk": cdc.max_chunk}, dl))
             except OSError as e:
                 raise WorkerError(f"worker send failed: {e}") from e
-            seq = 0
-            it = iter(packets)
-            while True:
-                try:
-                    data = next(it)  # caller errors propagate UNWRAPPED
-                except StopIteration:
-                    break
-                if not data:
-                    continue
-                try:
-                    dl.extend(self._per_mb * len(data) / float(1 << 20))
-                    dl.check("worker reduce stream")
-                    s.settimeout(dl.timeout())
-                    # the upload leg of the hop: a second CRC32C and a
-                    # sendall per client packet, on the receive thread
-                    # (laps: one span a stride, not one a packet)
-                    t0 = profiler.mark()
-                    dt.write_packet(s, seq, data)
-                    profiler.lap("worker_send", t0)
-                except OSError as e:
-                    raise WorkerError(f"worker send failed: {e}") from e
-                seq += 1
-            profiler.flush_laps()
+            # caller errors propagate UNWRAPPED out of this loop
+            for part in packets:
+                if isinstance(part, tuple):
+                    cut = (part,)
+                else:
+                    view = memoryview(part)
+                    cut = ((seg, native.crc32c(seg)) for seg in (
+                        view[o:o + _STRIDE]
+                        for o in range(0, len(view), _STRIDE)))
+                for data, crc in cut:
+                    if not len(data):
+                        continue
+                    segs.append(data)
+                    crcs.append(crc)
+                    pending += len(data)
+                    if pending >= _STRIDE:
+                        send()
+            send(last=True)
             try:
                 dl.check("worker reduce")
                 s.settimeout(dl.timeout())
@@ -532,7 +578,6 @@ class WorkerClient:
                 # timeline books it as device_wait (its own ledger records
                 # nothing — the dispatches live in the worker process)
                 with profiler.phase("device_wait"):
-                    dt.write_packet(s, seq, b"", last=True)
                     resp = self._checked(recv_frame(s))
             except (OSError, ConnectionError) as e:
                 raise WorkerError(f"worker failed: {e}") from e

@@ -104,8 +104,8 @@ PHASE_CLASS = {
     "dispatch_queue": HOST, "lock_wait": HOST, "locked": HOST,
     "serialize": HOST, "handler": HOST,
     # What ``recv`` hid and what nothing covered (PR 25).  packet_verify is
-    # the CRC32C inside read_packet_ex; worker_send the DN forwarding a
-    # packet to the worker; seal_* the background container seal (its two
+    # the CRC32C inside read_packet_crc; worker_send the DN forwarding a
+    # stride frame to the worker; seal_* the background container seal (its two
     # hop legs are waits, the file work is this interpreter's); nn_rpc one
     # NameNode request, frame read to reply sent; heartbeat_stats /
     # block_scan the two periodic DataNode ticks.
@@ -547,8 +547,8 @@ class phase:
 
 def lap(name: str, t0: float) -> None:
     """One lap, [``t0`` (a :func:`mark`), now], of a phase too fine to record
-    span by span: a CRC32C or a forward per 64 KiB packet, 2 048 a block,
-    on the thread that receives.  The lap's seconds gather on the thread;
+    span by span: a CRC32C per 64 KiB packet, 2 048 a block, on the
+    thread that receives.  The lap's seconds gather on the thread;
     every ``_LAP_EVERY`` laps of a name (one 4 MiB stride) everything the
     thread has gathered lands as spans (:func:`flush_laps`)."""
     t1 = _now()

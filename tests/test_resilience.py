@@ -411,6 +411,65 @@ class TestWorkerFailover:
                 assert c.read("/fo/c") == c2
 
 
+class TestAlteredStride:
+    @pytest.mark.parametrize("damage", ["payload", "crc"])
+    def test_the_worker_refuses_and_the_datanode_falls_back(
+            self, monkeypatch, damage):
+        """A byte, or a carried CRC, altered between the DataNode and the
+        worker (the stride wire carries the client's own CRCs): the worker
+        answers an error, the block is reduced in-process and reads back
+        identical — what a killed worker gets, from the same counters."""
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.server.reduction_worker import ReductionWorker
+
+        br = metrics.registry("block_receiver")
+        wm = metrics.registry("reduction_worker")
+        real, hit = dt.write_stride, []
+
+        def altered(sock, segs, crcs, last=False):
+            if segs and not hit:
+                hit.append(len(segs))
+                if damage == "payload":
+                    bad = bytearray(segs[1])
+                    bad[7] ^= 0x40
+                    segs = [segs[0], bytes(bad), *segs[2:]]
+                else:
+                    crcs = [crcs[0], crcs[1] ^ 1, *crcs[2:]]
+            real(sock, segs, crcs, last)
+
+        w = ReductionWorker(backend="native").start()
+        try:
+            with MiniCluster(
+                    n_datanodes=1, replication=1, block_size=1 << 20,
+                    reduction_overrides={
+                        "worker_addr": list(w.addr),
+                        "worker_breaker_failures": 100}) as mc:
+                good, data = _bytes(300_000), _bytes(400_000)
+                with mc.client("alt") as c:
+                    c.write("/alt/good", good, scheme="dedup_lz4")
+                    before = (br.counter("worker_fallbacks"),
+                              br.counter("degraded_writes"),
+                              wm.counter("op_errors"),
+                              br.counter("worker_reduces"))
+                    monkeypatch.setattr(dt, "write_stride", altered)
+                    c.write("/alt/f", data, scheme="dedup_lz4")
+                    assert c.read("/alt/f") == data
+                    assert hit == [7]           # 400 000 bytes: 7 packets
+                    assert (br.counter("worker_fallbacks"),
+                            br.counter("degraded_writes"),
+                            wm.counter("op_errors"),
+                            br.counter("worker_reduces")) == (
+                        before[0] + 1, before[1] + 1, before[2] + 1,
+                        before[3])
+                    # the wire is sound again: the next block is the worker's
+                    c.write("/alt/g", data[::-1], scheme="dedup_lz4")
+                    assert c.read("/alt/g") == data[::-1]
+                    assert br.counter("worker_reduces") == before[3] + 1
+                    assert c.read("/alt/good") == good
+        finally:
+            w.stop()
+
+
 # ----------------------------------------- mirror failures reach the NN view
 
 

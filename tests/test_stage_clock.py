@@ -1,13 +1,15 @@
 """One clock down the served write path (PR 25): the phase clock's new
-DataNode phases, the per-stride laps of the per-packet ones and the worker's
-stage clock.
+DataNode phases, the per-stride laps of the per-packet verify, the span a
+stride frame of the hop (PR 26) and the worker's stage clock.
 
 Reference seam: DataNodeMetrics.java:553-560 counts write ops and packet
 round trips, never where a block's time went; BlockReceiver.java:877-897 is
 the receive loop the phases decompose.
 """
 
+import json
 import os
+import socket
 import threading
 import time
 
@@ -15,7 +17,9 @@ import numpy as np
 import pytest
 
 from hdrf_tpu.config import CdcConfig
-from hdrf_tpu.server.reduction_worker import ReductionWorker, WorkerClient
+from hdrf_tpu.proto import datatransfer as dt
+from hdrf_tpu.server.reduction_worker import (_STRIDE, ReductionWorker,
+                                              WorkerClient)
 from hdrf_tpu.testing.minicluster import MiniCluster
 from hdrf_tpu.utils import profiler
 
@@ -93,14 +97,115 @@ class TestDataNodePhases:
                     "device_wait"} <= names
 
     def test_per_packet_phases_land_as_a_span_a_stride(self, served):
-        """32 packets of 64 KiB a block: far fewer ``packet_verify`` and
-        ``worker_send`` spans than ``recv`` ones, for the same seconds."""
+        """32 packets of 64 KiB a block: far fewer ``packet_verify`` spans
+        (laps) and ``worker_send`` spans (one a stride frame: this block's
+        only frame is its last) than ``recv`` ones."""
         for tl in [t for t in served["timelines"] if t["nbytes"] == BLOCK]:
             n = {name: sum(1 for s in tl["spans"] if s[0] == name)
                  for name in ("recv", "packet_verify", "worker_send")}
             assert n["recv"] >= BLOCK // (64 << 10)
             assert 1 <= n["packet_verify"] <= 3
             assert 1 <= n["worker_send"] <= 3
+
+
+class TestStrideSpans:
+    def test_a_two_stride_block_sends_two_or_three_frames(self):
+        """``worker_send`` is one span a frame: two full strides, and the
+        last frame that says so (empty when the block ends on a stride)."""
+        block = 2 * _STRIDE
+        w = ReductionWorker(backend="native").start()
+        try:
+            with MiniCluster(n_datanodes=1, replication=1, block_size=block,
+                             reduction_overrides={
+                                 "worker_addr": list(w.addr)}) as mc:
+                a = w.stats()
+                with mc.client("two-strides") as c:
+                    c.write("/two", _payload(block, 3), scheme="dedup_lz4")
+                b = w.stats()
+        finally:
+            w.stop()
+        tl = [t for t in profiler.timelines_snapshot()
+              if t["nbytes"] == block][-1]
+        sends = [s for s in tl["spans"] if s[0] == "worker_send"]
+        assert 2 <= len(sends) <= 3 and all(s[2] > s[1] for s in sends)
+        assert (b["hop_frames"] - a["hop_frames"],
+                b["hop_packets"] - a["hop_packets"]) == (2, 128)
+
+    def test_the_worker_verifies_once_a_stride(self):
+        """``_strides``: one ``ingest_wait`` span a frame read, one
+        ``packet_verify`` span a frame that carried bytes."""
+        from hdrf_tpu import native
+
+        parts = [_payload(4096, i) for i in range(3)]  # fits the socketpair
+        crcs = [native.crc32c(p) for p in parts]
+        profiler.reset()
+        t0 = profiler.mark()
+        a, b = socket.socketpair()
+        try:
+            dt.write_stride(a, parts, crcs)
+            dt.write_stride(a, parts[:2] + [parts[2][:100]],
+                            crcs[:2] + [native.crc32c(parts[2][:100])])
+            dt.write_stride(a, [], [], last=True)
+            w = ReductionWorker(backend="native")       # never started
+            got = [bytes(s) for s in w._strides(b)]
+        finally:
+            a.close()
+            b.close()
+            w._server.server_close()
+        assert got == [b"".join(parts), parts[0] + parts[1] + parts[2][:100]]
+        assert (w._stats["hop_frames"], w._stats["hop_packets"]) == (2, 6)
+        names = [s[0] for s in profiler.window_spans(t0, float("inf"))]
+        assert names.count("ingest_wait") == 3
+        assert names.count("packet_verify") == 2
+
+
+class TestHopCounter:
+    """``hop_frames`` / ``hop_packets`` and the per-layer metric that reads
+    them (``perfbench/layers/hop.packets_per_frame.json``: data only)."""
+
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def _read(self, stats: dict):
+        import importlib.util
+
+        with open(os.path.join(self.REPO, "perfbench", "layers",
+                               "hop.packets_per_frame.json")) as f:
+            layer = json.load(f)
+        assert layer["metric"] == "hop.packets_per_frame"
+        spec = importlib.util.spec_from_file_location(
+            "stage_ratio", os.path.join(self.REPO, "perfbench", "readers",
+                                        layer["reader"] + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.read({"window": {"stats": stats}}, layer["params"])
+
+    def test_monotone_and_a_frame_a_block_under_one_stride(self, served):
+        first, mid, last = served["first"], served["mid"], served["last"]
+        packets = BLOCK // (64 << 10)
+        assert (mid["hop_frames"] - first["hop_frames"],
+                mid["hop_packets"] - first["hop_packets"]) == (1, packets)
+        assert (last["hop_frames"] - mid["hop_frames"],
+                last["hop_packets"] - mid["hop_packets"]) == (1, packets)
+
+    def test_the_metric_reads_packets_per_frame(self, served):
+        delta = {k: served["last"][k] - served["first"].get(k, 0)
+                 for k in served["last"]}
+        assert self._read(delta) == pytest.approx(BLOCK // (64 << 10))
+
+    def test_the_metric_reads_nothing_on_a_program_without_the_counter(self):
+        # the parent's stats, and a window in which no frame arrived
+        assert self._read({"blocks_reduced": 3, "ingest_wait_s": 0.6}) is None
+        assert self._read({"hop_frames": 0, "hop_packets": 0}) is None
+
+    def test_the_manifest_lists_the_metric(self):
+        with open(os.path.join(self.REPO, "BENCHMARK.json")) as f:
+            bench = json.load(f)
+        entry = bench["per_layer"][-1]
+        assert entry == {
+            "name": "hop.packets_per_frame", "unit": "packets",
+            "better": "higher", "source": "program_counter",
+            "layer": "DN to worker hop", "moves": "write_mb_s",
+            "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w"]}
 
 
 class TestWorkerStageClock:
@@ -181,6 +286,9 @@ class TestDeviceStages:
                 "ingest_wait_s", "packet_verify_s", "stage_h2d_s",
                 "prep_wait_s", "select_s", "sha_wait_s"))
             assert abs(stages - legs) <= 0.1 * legs
+            # one part under a stride: one frame of one segment
+            assert (b["hop_frames"] - a["hop_frames"],
+                    b["hop_packets"] - a["hop_packets"]) == (1, 1)
             c.close()
         finally:
             w.stop()

@@ -14,7 +14,8 @@ import pytest
 
 from hdrf_tpu.config import CdcConfig
 from hdrf_tpu.ops.dispatch import gear_mask
-from hdrf_tpu.server.reduction_worker import (ReductionWorker, WorkerClient,
+from hdrf_tpu.server.reduction_worker import (_STRIDE, ReductionWorker,
+                                              WorkerClient, WorkerError,
                                               spawn_local_worker)
 from hdrf_tpu.testing.minicluster import MiniCluster
 
@@ -25,6 +26,23 @@ def _bytes(n):
     return RNG.integers(0, 256, size=n, dtype=np.uint8).tobytes()
 
 
+PKT = 64 * 1024
+
+
+def _oracle(data: bytes, cdc: CdcConfig):
+    """Whole-buffer cuts and digests from the native codecs."""
+    from hdrf_tpu import native
+
+    if not data:
+        return np.empty(0, np.int64), np.empty((0, 32), np.uint8)
+    buf = np.frombuffer(data, np.uint8)
+    cuts = native.cdc_chunk(buf, gear_mask(cdc), cdc.min_chunk,
+                            cdc.max_chunk)
+    starts = np.concatenate([[0], cuts[:-1]]).astype(np.uint64)
+    return cuts.astype(np.int64), native.sha256_batch(
+        buf, starts, (cuts - starts).astype(np.uint64))
+
+
 class TestWorkerProtocol:
     @pytest.fixture(scope="class")
     def worker(self):
@@ -33,19 +51,13 @@ class TestWorkerProtocol:
         w.stop()
 
     def test_reduce_matches_oracle(self, worker):
-        from hdrf_tpu import native
-
         cdc = CdcConfig()
         data = _bytes(300_000)
         c = WorkerClient(worker.addr)
         cuts, digs = c.reduce(data, cdc)
-        wc = native.cdc_chunk(np.frombuffer(data, np.uint8), gear_mask(cdc),
-                              cdc.min_chunk, cdc.max_chunk)
-        starts = np.concatenate([[0], wc[:-1]]).astype(np.uint64)
-        wd = native.sha256_batch(np.frombuffer(data, np.uint8), starts,
-                                 (wc - starts).astype(np.uint64))
-        np.testing.assert_array_equal(cuts, wc.astype(np.int64))
-        np.testing.assert_array_equal(digs, wd)
+        want_cuts, want_digs = _oracle(data, cdc)
+        np.testing.assert_array_equal(cuts, want_cuts)
+        np.testing.assert_array_equal(digs, want_digs)
         c.close()
 
     def test_streaming_matches_whole(self, worker):
@@ -124,6 +136,96 @@ class TestWorkerProtocol:
             c.close()
         finally:
             w.stop()
+
+
+def _packets(data: bytes, sizes=(PKT,)):
+    """``data`` cut into parts of ``sizes``, cycled."""
+    out, off, i = [], 0, 0
+    while off < len(data):
+        out.append(data[off:off + sizes[i % len(sizes)]])
+        off += len(out[-1])
+        i += 1
+    return out
+
+
+def _with_crcs(parts):
+    from hdrf_tpu import native
+
+    return [(p, native.crc32c(p)) for p in parts]
+
+
+# name -> (bytes, parts of reduce_stream, frames, segments): a frame leaves
+# when a stride is pending and one more, the last, when the iterator ends
+# (it is counted only if it carries bytes)
+UNEVEN = (1, 70_000, 3, 1 << 20, PKT, 5_000_000, 17)
+WIRE_CASES = {
+    "empty": (0, lambda d: [], 0, 0),
+    "one-byte": (1, _packets, 1, 1),
+    "packet-less-one": (PKT - 1, _packets, 1, 1),
+    "one-stride": (_STRIDE, _packets, 1, 64),
+    "stride-plus-one": (_STRIDE + 1, _packets, 2, 65),
+    "three-strides-and-a-tail": (3 * _STRIDE + 1000, _packets, 4, 193),
+    "carried-crcs": (_STRIDE + 1, lambda d: _with_crcs(_packets(d)), 2, 65),
+    "uneven-carried": (2 * _STRIDE + 12_345,
+                       lambda d: _with_crcs(_packets(d, UNEVEN)), None, None),
+    "uneven-summed-here": (2 * _STRIDE + 12_345,
+                           lambda d: _packets(d, UNEVEN), None, None),
+    # the one-part path of WorkerClient.reduce: cut into stride segments,
+    # one a frame
+    "one-part": (3 * _STRIDE + 1000, lambda d: [d], 4, 4),
+}
+
+
+class TestStrideWire:
+    """The reduce op's upload leg carries strides (proto/datatransfer.py):
+    whatever the parts, both backends answer what the whole buffer gives."""
+
+    @pytest.fixture(scope="class", params=["native", "tpu"])
+    def client(self, request):
+        w = ReductionWorker(backend=request.param).start()
+        c = WorkerClient(w.addr)
+        yield c
+        c.close()
+        w.stop()
+
+    @pytest.mark.parametrize("case", sorted(WIRE_CASES))
+    def test_streamed_equals_the_whole_buffer_oracle(self, client, case):
+        n, cut, frames, segments = WIRE_CASES[case]
+        data = _bytes(n)
+        parts = cut(data)
+        assert b"".join(p[0] if isinstance(p, tuple) else p
+                        for p in parts) == data
+        cdc = CdcConfig()
+        before = client.stats()
+        cuts, digs = client.reduce_stream(iter(parts), cdc)
+        after = client.stats()
+        want_cuts, want_digs = _oracle(data, cdc)
+        np.testing.assert_array_equal(cuts, want_cuts)
+        np.testing.assert_array_equal(digs, want_digs)
+        got = (after["hop_frames"] - before["hop_frames"],
+               after["hop_packets"] - before["hop_packets"])
+        if frames is not None:
+            assert got == (frames, segments)
+        else:
+            # a carried part stays whole, one summed here is cut at a stride
+            assert got[0] >= 2 and got[1] >= len(parts)
+
+    @pytest.mark.parametrize("damage", ["payload", "crc"])
+    def test_an_altered_frame_is_refused(self, client, damage):
+        """A byte or a carried CRC changed on the way: the worker answers
+        with an error like any worker failure, and goes on serving."""
+        data = _bytes(_STRIDE + 5 * PKT)
+        parts = _with_crcs(_packets(data))
+        victim, crc = parts[66]              # in the second, last frame
+        if damage == "payload":
+            parts[66] = (victim[:100] + bytes([victim[100] ^ 1])
+                         + victim[101:], crc)
+        else:
+            parts[66] = (victim, crc ^ 0x10)
+        with pytest.raises(WorkerError, match="checksum mismatch"):
+            client.reduce_stream(iter(parts), CdcConfig())
+        cuts, _ = client.reduce(data, CdcConfig())
+        assert int(cuts[-1]) == len(data)
 
 
 class TestWorkerProcess:
@@ -209,6 +311,44 @@ class TestClusterWithWorker:
             st = wc.stats()
             assert st["blocks_reduced"] >= 3  # every dedup block offloaded
             wc.close()
+
+    def test_the_datanode_sums_a_served_packet_once(self, monkeypatch):
+        """The hop carries the client's CRC: for a block of P packets the
+        DataNode calls ``native.crc32c`` P times (and once for the empty
+        last packet) to verify them, and not again to forward them.  The
+        client writes from this process too, so calls are told apart by
+        their caller."""
+        import collections
+        import sys
+
+        from hdrf_tpu import native
+
+        by = collections.Counter()
+        real = native.crc32c
+
+        def counting(data, crc=0):
+            by[sys._getframe(1).f_code.co_name] += 1
+            return real(data, crc)
+
+        data = _bytes(1_000_000)
+        packets = -(-len(data) // PKT)
+        with MiniCluster(n_datanodes=1, replication=1, block_size=1 << 20,
+                         tpu_worker=True, worker_backend="native") as mc:
+            wc = WorkerClient(mc._worker_addr)
+            with mc.client("w") as c:
+                before = wc.stats()
+                monkeypatch.setattr(native, "crc32c", counting)
+                c.write("/crc/f", data, scheme="dedup_lz4")
+                monkeypatch.undo()
+                after = wc.stats()
+                assert c.read("/crc/f") == data
+            wc.close()
+        assert after["blocks_reduced"] == before["blocks_reduced"] + 1
+        assert after["hop_packets"] - before["hop_packets"] == packets
+        assert by["read_packet_crc"] == packets + 1     # the DataNode's
+        assert by["write_packet"] == packets + 1        # the client's own
+        hop = {"reduce_stream", "send", "write_stride", "_sendmsg_all"}
+        assert not hop & set(by), dict(by)
 
     def test_worker_death_falls_back_in_process(self):
         """Kill the worker mid-cluster: writes keep succeeding via the
