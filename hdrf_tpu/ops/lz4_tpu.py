@@ -475,7 +475,16 @@ class TpuLz4:
         self._lock = threading.Lock()
 
     def _pad(self, a: np.ndarray) -> np.ndarray:
-        pad = (-a.size) % _S
+        """Zero-pad to the scan's length: whole supertiles, their count
+        rounded up to a power of two.  The padded length is a jit-cache key
+        of the match scan (18-20 s a cold compile on the chip), so an open
+        lane's tail of any size finds one of log2 programs, and from half a
+        container up the full container's own; a full container keeps the
+        length it had.  Zero supertiles yield no record and the emit reads
+        the true ``n``, so the stream does not depend on the pad; it costs
+        at most a second scan's worth of device time (91 ms at 32 MiB)."""
+        units = -(-a.size // _S)
+        pad = (1 << (units - 1).bit_length()) * _S - a.size
         return np.concatenate([a, np.zeros(pad, np.uint8)]) if pad else a
 
     def _shapes(self, n_pad: int) -> tuple[int, int, int]:
@@ -580,7 +589,10 @@ class TpuLz4:
 
                 need = pow2(total)
                 e_cap = job.block.shape[0] // self.stride
-                if need > max(e_cap // 64, 1 << 16):
+                # the flood bound counts the input's own supertiles, not
+                # the pad: which encoder emits is a property of the bytes
+                e_own = -(-job.n // _S) * _S // self.stride
+                if need > max(e_own // 64, 1 << 16):
                     # Record flood (> ~8k records/MiB ~= a sequence every
                     # <128 B): short-match-dense data is the serial
                     # hash-table encoder's home turf and the sort scan's

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +56,12 @@ from hdrf_tpu.ops import gear
 from hdrf_tpu.ops.dispatch import gear_mask
 from hdrf_tpu.ops.sha256 import sha256_words
 from hdrf_tpu.utils import device_ledger as _ledger
+from hdrf_tpu.utils import metrics as _metrics
 from hdrf_tpu.utils import profiler as _profiler
+
+# prep_retries: reduce ops whose candidates overflowed _prep's capacity and
+# ran it again; prep_cap_words: the capacity of the latest dispatch (gauge)
+_M = _metrics.registry("resident")
 
 
 # Block padding grid: lcm of the bitmap pack row (256 bytes) and the
@@ -300,6 +306,7 @@ class BatchJob:
     cand: jax.Array           # (K, 1 + 2*cap) packed candidates (D2H async)
     cap: int
     true_n: int               # unpadded byte length per block
+    rung: int = 0             # doublings of the first-shot cap at dispatch
     cuts: list[np.ndarray] | None = None
     _sha_parts: tuple | None = None
     _ev: object = None        # ledger token: prep dispatch -> cand readback
@@ -327,6 +334,7 @@ class BlockJob:
     words: jax.Array          # resident BE word image
     cand: jax.Array           # packed candidate readback (D2H in flight)
     cap: int
+    rung: int = 0             # doublings of the first-shot cap at dispatch
     cuts: np.ndarray | None = None
     _sha_parts: tuple | None = None  # (sels, lane_counts, digests_dev)
     _ev: object = None        # ledger token: prep dispatch -> cand readback
@@ -380,6 +388,13 @@ class ResidentReducer:
                                             self._b_small,
                                             2 * self._b_small, max_nb)
                                 if 0 < b <= max_nb})
+        # Candidate capacity is a static argument of _prep, so it comes
+        # from a fixed ladder (``_cap``) and the highest rung a block needed
+        # sticks, as TpuLz4's slice widths do: a stream of zero-dense blocks
+        # (tar archives, sparse images) pays the overflow retry once, not a
+        # compile per block.  Handler threads share one reducer.
+        self._rung = 0
+        self._rung_lock = threading.Lock()
 
     # ----------------------------------------------------- batched pipeline
 
@@ -437,9 +452,9 @@ class ResidentReducer:
         # int32 flat-byte-offset headroom for the bucket gather
         assert k * (n + 4 * self.pad_words) < (1 << 31), \
             "batch too large for i32 flat offsets; split it"
-        cap = max(1, min(n // 32,
-                         max(1024, (n >> max(self.cdc.mask_bits - 1, 0))
-                             + 1024) + pad_extra))
+        rung = self._rung
+        cap = self._cap(n, n, rung, pad_extra)
+        _M.gauge("prep_cap_words", cap)
         ev = _ledger.dispatch(
             "resident.prep_batch", batch=k,
             h2d_bytes=0 if isinstance(datas, jax.Array) else k * n,
@@ -447,7 +462,8 @@ class ResidentReducer:
         words, cand = _prep_batch(stacked, self.mask, cap, self.pad_words)
         cand.copy_to_host_async()
         return BatchJob(k=k, n=n, blocks=stacked, words=words, cand=cand,
-                        cap=cap, true_n=true_n, true_ns=true_ns, _ev=ev)
+                        cap=cap, true_n=true_n, rung=rung, true_ns=true_ns,
+                        _ev=ev)
 
     def _submit_many_fused(self, datas) -> BatchJob:
         """Fused-kernel group submit: ONE program selects cuts on device
@@ -506,19 +522,42 @@ class ResidentReducer:
                         fused=True, tables=tables, plan=plan, _digs=digs,
                         _host=arrs, _ev=ev, _ev_sha=evs)
 
-    def _cuts_from_cand(self, cand_row: np.ndarray, cap: int, block,
-                        true_n: int) -> np.ndarray:
+    def _cap(self, n: int, n_pad: int, rung: int, pad_extra: int = 0) -> int:
+        """Candidate capacity (bitmap words) of ``_prep`` for an ``n``-byte
+        block padded to ``n_pad``, at ``rung`` of its ladder.  Rung 0 is the
+        first-shot size for content-like data (about 2x the expected
+        candidate words + slack); each further rung doubles it, up to the
+        hard ceiling ``n_pad // 32`` (every bitmap word non-zero, where no
+        block overflows): at most 8 rungs for a 128 MiB block.  ``cap`` is a
+        jit-cache key, so these are all the ``_prep`` programs a block
+        length can compile.  A high rung costs its readback (``1 + 2*cap``
+        int32) and a little device time: a 128 MiB block's ``_prep`` ran
+        54 ms at rung 0, 63 at rung 3, 96 at the ceiling (v5e, PR 27)."""
+        first = max(1024, (n >> max(self.cdc.mask_bits - 1, 0)) + 1024) \
+            + pad_extra
+        return max(1, min(n_pad // 32, first << rung))
+
+    def _cuts_from_cand(self, cand_row: np.ndarray, cap: int, rung: int,
+                        block, true_n: int) -> np.ndarray:
         """Candidate row -> selected cut points.  The packed layout is
         [count, idx x cap, vals x cap]; a dense-candidate overflow (count >
         cap, e.g. long zero runs where every position hashes to 0) retries
-        _prep once with exact capacity, after which 1+count == 1+cap.  The
-        ONE place that understands this layout — shared by the per-block
-        and batched paths."""
+        _prep once at the smallest rung of the capacity ladder (``_cap``)
+        that holds ``count``, and the reducer dispatches later blocks at
+        that rung.  The ONE place that understands this layout — shared by
+        the per-block and batched paths."""
         from hdrf_tpu import native
 
         count = int(cand_row[0])
         if count > cap:
-            cap = count
+            ceiling = block.shape[0] // 32
+            while cap < count:
+                rung += 1
+                cap = min(2 * cap, ceiling)
+            with self._rung_lock:
+                self._rung = max(self._rung, rung)
+            _M.incr("prep_retries")
+            _M.gauge("prep_cap_words", cap)
             with _profiler.phase("prep_wait"):
                 ev = _ledger.dispatch("resident.prep_retry",
                                       key=(block.shape, cap))
@@ -560,7 +599,8 @@ class ResidentReducer:
             nj = self._submit_many_xla(bj._host)
             bj.fused = False
             bj._host = None
-            bj.n, bj.true_n, bj.cap = nj.n, nj.true_n, nj.cap
+            bj.n, bj.true_n, bj.cap, bj.rung = (nj.n, nj.true_n, nj.cap,
+                                                nj.rung)
             bj.true_ns = nj.true_ns
             bj.blocks, bj.words, bj.cand = nj.blocks, nj.words, nj.cand
             bj._ev = nj._ev
@@ -595,7 +635,8 @@ class ResidentReducer:
         cuts_all, starts_all, lens_all = [], [], []
         for k in range(bj.k):
             tn = bj.true_ns[k] if bj.true_ns is not None else bj.true_n
-            cuts = self._cuts_from_cand(cand[k], bj.cap, bj.blocks[k], tn)
+            cuts = self._cuts_from_cand(cand[k], bj.cap, bj.rung,
+                                        bj.blocks[k], tn)
             starts = np.concatenate([[0], cuts[:-1]]).astype(np.int64)
             cuts_all.append(cuts)
             starts_all.append(starts)
@@ -736,8 +777,9 @@ class ResidentReducer:
                            cuts=np.empty(0, dtype=np.uint64))
             job._sha_parts = ([], [], None)
             return job
-        cap = max(1, min(block.shape[0] // 32,
-                         max(1024, (n >> max(self.cdc.mask_bits - 1, 0)) + 1024)))
+        rung = self._rung
+        cap = self._cap(n, block.shape[0], rung)
+        _M.gauge("prep_cap_words", cap)
         # Stage spans of the per-block path (the reduction worker's stage
         # clock, utils/profiler.py): ``prep_wait`` from this dispatch to
         # the candidates on the host, ``select`` the host cut selection and
@@ -751,7 +793,7 @@ class ResidentReducer:
             words, cand = _prep(block, self.mask, cap, self.pad_words)
             cand.copy_to_host_async()
         return BlockJob(n=n, block=block, words=words, cand=cand, cap=cap,
-                        _ev=ev)
+                        rung=rung, _ev=ev)
 
     def start_sha(self, job: BlockJob) -> None:
         if job.cand is None:  # empty block prepared entirely in submit()
@@ -761,7 +803,8 @@ class ResidentReducer:
             _ledger.readback(job._ev, d2h_bytes=cand.nbytes)
         job._ev = None
         with _profiler.phase("select"):
-            cuts = self._cuts_from_cand(cand, job.cap, job.block, job.n)
+            cuts = self._cuts_from_cand(cand, job.cap, job.rung, job.block,
+                                        job.n)
             job.cuts = cuts
             starts = np.concatenate([[0], cuts[:-1]]).astype(np.int64)
             lens = (cuts - starts).astype(np.int64)

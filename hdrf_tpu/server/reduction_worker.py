@@ -197,12 +197,20 @@ class ReductionWorker:
         """Counters, flat and numeric (a reader takes deltas of whatever
         keys it finds): ops and bytes; ``<stage>_s`` — cumulative self
         seconds of every phase this process recorded, a stage its backend
-        does not run left out; the three sums of them; the process's CPU
-        seconds and a wall clock to set them against."""
+        does not run left out; the three sums of them; once ``_prep`` has
+        run (a device backend's first block), ``prep_retries`` — reduce
+        ops whose candidates overflowed its capacity and ran it again — and
+        the gauge ``prep_cap_words``, the capacity rung in use (registry
+        ``resident``, ops/resident.py); the process's CPU seconds and a
+        wall clock to set them against."""
         with self._stats_lock:
             out = dict(self._stats)
         for name, secs in profiler.cumulative().items():
             out[name + "_s"] = secs
+        prep = metrics.registry("resident").snapshot()
+        if "prep_cap_words" in prep["gauges"]:
+            out["prep_retries"] = prep["counters"].get("prep_retries", 0)
+            out["prep_cap_words"] = prep["gauges"]["prep_cap_words"]
         out["cpu_s"] = time.process_time()
         out["wall_s"] = time.perf_counter()
         return out

@@ -34,3 +34,28 @@ def _clear_fault_injection():
     yield
     from hdrf_tpu.utils import fault_injection
     fault_injection.clear()
+
+
+PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench")
+
+
+@pytest.fixture(scope="session")
+def perfbench_file():
+    """``perfbench_file(rel)``: a file of ``perfbench/`` as a module, without
+    putting the benchmark on ``sys.path`` (its plain reference, readers and
+    corpus generators import nothing of the program);
+    ``perfbench_file.root`` is the directory."""
+    import importlib.util
+
+    def load(rel: str):
+        spec = importlib.util.spec_from_file_location(
+            "perfbench_" + os.path.basename(rel)[:-3],
+            os.path.join(PERFBENCH, rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    load.root = PERFBENCH
+    return load
+

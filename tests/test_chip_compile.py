@@ -107,11 +107,13 @@ def _match_deltas(t, e):
             [((t, e), jnp.uint32)], {})
 
 
-def _prep():
+def _prep(rung=0):
     from hdrf_tpu.ops import resident
 
     r = _reducer()
-    cap = max(1024, (BLOCK >> (CDC.mask_bits - 1)) + 1024)
+    cap = r._cap(BLOCK, BLOCK, rung)
+    if rung == 0:     # the first shot is the program every tree has compiled
+        assert cap == (BLOCK >> (CDC.mask_bits - 1)) + 1024
     return (resident._prep, [((BLOCK,), jnp.uint8)],
             dict(mask=r.mask, cap=cap, pad_words=r.pad_words))
 
@@ -148,6 +150,9 @@ ONE_CHIP = {
     "match-deltas": lambda: _match_deltas(8, 8192),
     # the worker's jitted steps around those kernels, at a full block
     "prep-128MiB": _prep,
+    # the candidate-capacity ladder at the rung a tar stream needs (270 336
+    # words); the other rungs differ from it by that one size
+    "prep-128MiB-rung3": lambda: _prep(3),
     "bucket-sha-small": lambda: _bucket_sha(16384, _B_SMALL),
     "bucket-sha-big": lambda: _bucket_sha(4096, _B_BIG),
 }

@@ -15,6 +15,7 @@ import pytest
 
 from hdrf_tpu import native
 from hdrf_tpu.ops import dispatch
+from hdrf_tpu.ops import lz4_tpu
 from hdrf_tpu.ops.lz4_tpu import _S, TpuLz4
 
 RNG = np.random.default_rng(11)
@@ -24,6 +25,16 @@ def _text(n: int) -> np.ndarray:
     vocab = [RNG.integers(97, 123, size=RNG.integers(2, 9),
                           dtype=np.uint8).tobytes() for _ in range(500)]
     out = b" ".join(vocab[i] for i in RNG.integers(0, 500, size=n // 5))
+    return np.frombuffer(out[:n], np.uint8)
+
+
+def _triples(n: int) -> np.ndarray:
+    """Random runs of 500-3000 bytes, each three times over: a few thousand
+    long matches a MiB, which the default slice widths hold."""
+    rng = np.random.default_rng(5)
+    out = b"".join(
+        rng.integers(0, 256, size=int(k), dtype=np.uint8).tobytes() * 3
+        for k in rng.integers(500, 3000, size=n // 5000 + 1))
     return np.frombuffer(out[:n], np.uint8)
 
 
@@ -71,6 +82,62 @@ class TestRoundTrip:
             a = _text(n)
             comp = TpuLz4().compress(a)
             assert native.lz4_decompress(comp, a.size) == a.tobytes()
+
+
+class TestPadLadder:
+    """The padded scan length is a jit-cache key: it comes from a ladder
+    (whole supertiles, a power of two of them), so an open lane's tail of
+    any size finds one of a few programs, and the stream is the input's."""
+
+    SIZES = sorted(int(v) for v in np.random.default_rng(27).integers(
+        2 * _S, 8 * _S + 1, size=20))
+
+    def test_twenty_tails_compile_at_most_the_rungs(self, perfbench_file):
+        decode = perfbench_file("reference/chunking.py").lz4_block_decode
+        data = _triples(8 * _S)
+        c = TpuLz4()
+        rungs = {len(c._pad(np.empty(n, np.uint8))) for n in self.SIZES}
+        assert rungs <= {2 * _S, 4 * _S, 8 * _S}
+        programs = lz4_tpu._match_scan._cache_size()
+        assert len(set(self.SIZES)) == 20
+        for n in self.SIZES:
+            a = data[:n]
+            job = c.submit(a)
+            assert job.block.shape[0] in rungs
+            comp = c.finish(job)
+            assert decode(comp, n) == a.tobytes()
+        assert lz4_tpu._match_scan._cache_size() - programs <= len(rungs)
+
+    @pytest.mark.parametrize("units", [2, 3, 5, 9, 17, 255, 256])
+    def test_pad_is_a_power_of_two_of_supertiles(self, units):
+        """A full 32 MiB container (256 supertiles, or 255 and a bit)
+        keeps the length it always had."""
+        n = units * _S - 77
+        padded = TpuLz4()._pad(np.ones(n, np.uint8))
+        top = padded.size // _S
+        assert padded.size % _S == 0 and top & (top - 1) == 0
+        assert n <= padded.size < 2 * n + _S
+        assert not padded[n:].any() and padded[:n].all()
+        if units >= 255:
+            assert padded.size == 256 * _S
+
+    def test_pad_yields_no_record(self):
+        """5 supertiles scanned at 8 give the records, and so the stream,
+        of the same bytes scanned at 5: zero supertiles match nothing and
+        the emit reads the true length."""
+        a = _triples(5 * _S - 1000)
+        ladder, plain = TpuLz4(), TpuLz4()
+        plain._pad = lambda x: np.concatenate(
+            [x, np.zeros((-x.size) % _S, np.uint8)])
+        jobs = [c.submit(a) for c in (ladder, plain)]
+        assert [j.block.shape[0] for j in jobs] == [8 * _S, 5 * _S]
+        (t1, g1, r1), (t0, g0, r0) = (
+            c._records(j, np.asarray(j.recs))
+            for c, j in zip((ladder, plain), jobs))
+        assert t1 == t0 == g1.size > 1000
+        np.testing.assert_array_equal(g1, g0)
+        np.testing.assert_array_equal(r1, r0)
+        assert ladder.finish(jobs[0]) == plain.finish(jobs[1])
 
 
 class TestSliceOverflow:

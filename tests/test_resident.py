@@ -2,13 +2,18 @@
 C++ oracle — including the degenerate inputs the verify skill calls out
 (zero runs make every position a Gear candidate; empty blocks are legal)."""
 
+import hashlib
+import os
+
 import numpy as np
 import pytest
 
 from hdrf_tpu import native
 from hdrf_tpu.config import CdcConfig
+from hdrf_tpu.ops import resident
 from hdrf_tpu.ops.dispatch import gear_mask
 from hdrf_tpu.ops.resident import ResidentReducer
+from hdrf_tpu.utils import metrics
 
 
 @pytest.fixture(scope="module")
@@ -189,3 +194,120 @@ def test_mixed_batch_distinct_blocks_match_oracle(reducer):
         wc, wd = _oracle(data, reducer.cdc)
         np.testing.assert_array_equal(cuts, wc)
         np.testing.assert_array_equal(digs, wd)
+
+
+# ---- the candidate-capacity ladder (tar streams, BASELINE config 3) ----
+
+
+@pytest.fixture(scope="module")
+def versions_tar(perfbench_file):
+    """``versions_tar(n, generations, seed=7)``: successive releases of one
+    seeded tree as ``n``-byte tar streams — BASELINE config 3's stand-in
+    corpus, made as the benchmark makes it."""
+    import json
+
+    with open(os.path.join(perfbench_file.root, "configs",
+                           "versions-dedup.json")) as f:
+        data = {k: v for k, v in json.load(f)["data"].items()
+                if k != "generator"}
+    source = perfbench_file("generators/versions.py").Source
+
+    def make(n: int, generations: int, seed: int = 7) -> list:
+        src = source(dict(data, file_bytes=n), seed, 0)
+        return [src.file(g) for g in range(generations)]
+
+    return make
+
+
+TAR_BYTES = 4 << 20     # about 5 100 candidate words against 2 048 first-shot
+
+
+def _reference(chunking, data: np.ndarray, cdc: CdcConfig):
+    """Cuts and SHA-256 digests by ``perfbench/reference/chunking.py``
+    (numpy window doubling, ``hashlib``): no line of the program."""
+    ends = chunking.cuts(
+        data, {"mask_bits": cdc.mask_bits, "min_chunk": cdc.min_chunk,
+               "max_chunk": cdc.max_chunk})
+    view, start, digs = memoryview(np.ascontiguousarray(data)), 0, []
+    for end in ends:
+        digs.append(hashlib.sha256(view[start:end]).digest())
+        start = end
+    return (np.asarray(ends, np.uint64),
+            np.frombuffer(b"".join(digs), np.uint8).reshape(-1, 32))
+
+
+def _prep_retries() -> int:
+    return metrics.registry("resident").counter("prep_retries")
+
+
+@pytest.mark.parametrize("path", ["submit", "submit_many"])
+@pytest.mark.parametrize("corpus", ["tar", "zeros"])
+def test_zero_dense_blocks_match_the_plain_reference(corpus, path,
+                                                     versions_tar,
+                                                     perfbench_file):
+    """Runs of zeros (tar padding; gear(0) == 0, so every position in one
+    is a candidate) overflow the first-shot capacity: both paths must give
+    the plain reference's cuts and digests exactly, having retried."""
+    blocks = (versions_tar(TAR_BYTES, 2) if corpus == "tar"
+              else [np.zeros(1 << 20, np.uint8)] * 2)
+    chunking = perfbench_file("reference/chunking.py")
+    r = ResidentReducer(CdcConfig())
+    before = _prep_retries()
+    if path == "submit":
+        jobs = [r.submit(b) for b in blocks]
+        for j in jobs:
+            r.start_sha(j)
+        got = [r.finish(j) for j in jobs]
+    else:
+        bj = r.submit_many(blocks)
+        r.start_sha_many(bj)
+        got = r.finish_many(bj)
+    assert _prep_retries() > before
+    for b, (cuts, digs) in zip(blocks, got):
+        wc, wd = _reference(chunking, b, r.cdc)
+        np.testing.assert_array_equal(cuts, wc)
+        np.testing.assert_array_equal(digs, wd)
+
+
+def test_tar_stream_compiles_rungs_not_blocks(versions_tar):
+    """Eight releases with eight different candidate counts: the retry is
+    paid once, later blocks are dispatched at the rung it found, and _prep
+    is compiled for two capacities (first shot, that rung), not per block."""
+    blocks = versions_tar(TAR_BYTES, 8, seed=11)
+    r = ResidentReducer(CdcConfig())
+    ceiling = TAR_BYTES // 32
+    ladder = sorted({r._cap(TAR_BYTES, TAR_BYTES, k) for k in range(32)})
+    assert ladder[0] == (TAR_BYTES >> 12) + 1024 and ladder[-1] == ceiling
+    assert len(ladder) <= 8
+    programs, retries = resident._prep._cache_size(), _prep_retries()
+    counts = set()
+    for i, b in enumerate(blocks):
+        job = r.submit(b)
+        counts.add(int(np.asarray(job.cand)[0]))
+        cuts, digs = r.finish(job)
+        wc, wd = _oracle(b, r.cdc)
+        np.testing.assert_array_equal(cuts, wc)
+        np.testing.assert_array_equal(digs, wd)
+        assert _prep_retries() == retries + 1, f"block {i}"
+        assert job.cap in ladder
+    assert len(counts) == 8
+    assert job.cap > ladder[0] and job.cap >= max(counts)
+    assert resident._prep._cache_size() - programs <= 2
+    assert metrics.registry("resident").snapshot()["gauges"][
+        "prep_cap_words"] == job.cap
+
+
+def test_random_block_stays_on_the_first_rung():
+    """Content-like data never overflows: it runs the first-shot program,
+    the one every earlier tree compiled (the persistent cache's key)."""
+    rng = np.random.default_rng(12)
+    a = rng.integers(0, 256, size=1 << 20, dtype=np.uint8)
+    r = ResidentReducer(CdcConfig())
+    before = _prep_retries()
+    job = r.submit(a)
+    cuts, digs = r.finish(job)
+    wc, wd = _oracle(a, r.cdc)
+    np.testing.assert_array_equal(cuts, wc)
+    np.testing.assert_array_equal(digs, wd)
+    assert job.rung == 0 and job.cap == (a.size >> 12) + 1024
+    assert _prep_retries() == before

@@ -33,6 +33,25 @@ def _payload(n: int, seed: int = 7) -> bytes:
         0, 256, n, dtype=np.uint8).tobytes()
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def read_layer(perfbench_file):
+    """``read_layer(metric, stats)``: what the benchmark's per-layer metric
+    reads from a ``stats`` delta — ``perfbench/layers/<metric>.json``
+    through the reader it names."""
+    def read(metric: str, stats: dict):
+        with open(os.path.join(perfbench_file.root, "layers",
+                               metric + ".json")) as f:
+            layer = json.load(f)
+        assert layer["metric"] == metric
+        reader = perfbench_file(f"readers/{layer['reader']}.py")
+        return reader.read({"window": {"stats": stats}}, layer["params"])
+
+    return read
+
+
 def _monotone(before: dict, after: dict, keys) -> bool:
     return all(after[k] >= before.get(k, 0.0) for k in keys)
 
@@ -163,21 +182,7 @@ class TestHopCounter:
     """``hop_frames`` / ``hop_packets`` and the per-layer metric that reads
     them (``perfbench/layers/hop.packets_per_frame.json``: data only)."""
 
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-    def _read(self, stats: dict):
-        import importlib.util
-
-        with open(os.path.join(self.REPO, "perfbench", "layers",
-                               "hop.packets_per_frame.json")) as f:
-            layer = json.load(f)
-        assert layer["metric"] == "hop.packets_per_frame"
-        spec = importlib.util.spec_from_file_location(
-            "stage_ratio", os.path.join(self.REPO, "perfbench", "readers",
-                                        layer["reader"] + ".py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read({"window": {"stats": stats}}, layer["params"])
+    METRIC = "hop.packets_per_frame"
 
     def test_monotone_and_a_frame_a_block_under_one_stride(self, served):
         first, mid, last = served["first"], served["mid"], served["last"]
@@ -187,25 +192,31 @@ class TestHopCounter:
         assert (last["hop_frames"] - mid["hop_frames"],
                 last["hop_packets"] - mid["hop_packets"]) == (1, packets)
 
-    def test_the_metric_reads_packets_per_frame(self, served):
+    def test_the_metric_reads_packets_per_frame(self, served, read_layer):
         delta = {k: served["last"][k] - served["first"].get(k, 0)
                  for k in served["last"]}
-        assert self._read(delta) == pytest.approx(BLOCK // (64 << 10))
+        assert read_layer(self.METRIC, delta) == \
+            pytest.approx(BLOCK // (64 << 10))
 
-    def test_the_metric_reads_nothing_on_a_program_without_the_counter(self):
+    def test_the_metric_reads_nothing_on_a_program_without_the_counter(
+            self, read_layer):
         # the parent's stats, and a window in which no frame arrived
-        assert self._read({"blocks_reduced": 3, "ingest_wait_s": 0.6}) is None
-        assert self._read({"hop_frames": 0, "hop_packets": 0}) is None
+        assert read_layer(self.METRIC, {"blocks_reduced": 3,
+                                        "ingest_wait_s": 0.6}) is None
+        assert read_layer(self.METRIC, {"hop_frames": 0,
+                                        "hop_packets": 0}) is None
 
     def test_the_manifest_lists_the_metric(self):
-        with open(os.path.join(self.REPO, "BENCHMARK.json")) as f:
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
             bench = json.load(f)
-        entry = bench["per_layer"][-1]
+        (entry,) = [m for m in bench["per_layer"]
+                    if m["name"] == "hop.packets_per_frame"]
         assert entry == {
             "name": "hop.packets_per_frame", "unit": "packets",
             "better": "higher", "source": "program_counter",
             "layer": "DN to worker hop", "moves": "write_mb_s",
-            "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w"]}
+            "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
+                          "versions-dedup.ingest"]}
 
 
 class TestWorkerStageClock:
@@ -292,6 +303,43 @@ class TestDeviceStages:
             c.close()
         finally:
             w.stop()
+
+    def test_a_zero_dense_block_retries_once_and_the_rung_sticks(
+            self, read_layer):
+        """``prep_retries`` and the gauge ``prep_cap_words`` in ``stats``
+        (PR 27), and ``worker.prep_retries_per_block`` that reads them: a
+        tar-like block overflows ``_prep``'s first rung once; the next is
+        dispatched at the rung that held it."""
+        rng = np.random.default_rng(27)
+        blocks = []
+        for _ in range(2):
+            a = rng.integers(0, 256, 600_000, dtype=np.uint8)
+            a[50_000:450_000] = 0
+            blocks.append(a.tobytes())
+        first_shot = (600_000 >> 12) + 1024
+        w = ReductionWorker(backend="tpu").start()
+        try:
+            c = WorkerClient(w.addr)
+            c.reduce(_payload(600_000), CdcConfig())
+            s0 = c.stats()
+            c.reduce(blocks[0], CdcConfig())
+            s1 = c.stats()
+            c.reduce(blocks[1], CdcConfig())
+            s2 = c.stats()
+            c.close()
+        finally:
+            w.stop()
+        assert s0["prep_cap_words"] == first_shot
+        assert s1["prep_retries"] == s0["prep_retries"] + 1
+        assert s2["prep_retries"] == s1["prep_retries"]
+        assert s2["prep_cap_words"] == s1["prep_cap_words"] == 16 * first_shot
+        delta = {k: s2[k] - s0.get(k, 0) for k in s2}
+        assert read_layer("worker.prep_retries_per_block", delta) == 0.5
+        # a program without the counter (the parent's), an empty window
+        assert read_layer("worker.prep_retries_per_block",
+                           {"blocks_reduced": 2}) is None
+        assert read_layer("worker.prep_retries_per_block",
+                           {"prep_retries": 0, "blocks_reduced": 0}) is None
 
     def test_a_device_compress_records_scan_wait_inside_emit(self):
         from hdrf_tpu.ops.lz4_tpu import TpuLz4
