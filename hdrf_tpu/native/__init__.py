@@ -94,6 +94,12 @@ def _load() -> ctypes.CDLL:
         lib.hdrf_gather_ranges.argtypes = [_u8p, ctypes.c_uint64, _u64p,
                                            _u64p, _u8p]
         lib.hdrf_gather_ranges.restype = ctypes.c_uint64
+        # addresses, not typed pointers: PacketUnpacker converts its arrays
+        # once a stream and not once a call
+        lib.hdrf_unpack_packets.argtypes = (
+            [ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p]
+            + [ctypes.c_uint64] * 3 + [ctypes.c_void_p] * 5)
+        lib.hdrf_unpack_packets.restype = ctypes.c_uint64
         _lib = lib
         return lib
 
@@ -315,6 +321,52 @@ def crc32c_chunks(data: bytes | np.ndarray, chunk_size: int) -> np.ndarray:
     out = np.empty(max(n, 1), dtype=np.uint32)
     _load().hdrf_crc32c_chunks(_ptr(a, _u8p), a.size, chunk_size, _ptr(out, _u32p))
     return out[:n]
+
+
+class PacketUnpacker:
+    """``hdrf_unpack_packets`` with the arrays it reads and fills held: the
+    staged wire bytes (``stage``) and, per unpacked packet, ``seqnos``,
+    ``lens``, ``flags`` and ``crcs``.  One call walks the whole
+    data-transfer packets in ``stage[:have]``, verifies each payload's
+    CRC32C against its header's and copies it to ``out`` at ``out_off``."""
+
+    PARTIAL, LAST, MISMATCH, OUT_FULL, MORE = range(5)
+
+    def __init__(self, stage_bytes: int, max_pkts: int = 1024):
+        self.max_pkts = max_pkts
+        self.seqnos = np.empty(max_pkts, np.uint64)
+        self.lens = np.empty(max_pkts, np.uint32)
+        self.flags = np.empty(max_pkts, np.uint8)
+        self.crcs = np.empty(max_pkts, np.uint32)
+        self._ret = np.zeros(3, np.uint64)
+        self._fixed = tuple(a.ctypes.data for a in (
+            self.seqnos, self.lens, self.flags, self.crcs, self._ret))
+        self._fn = _load().hdrf_unpack_packets
+        self._out = None
+        self.grow_stage(stage_bytes)
+
+    def grow_stage(self, nbytes: int, keep: int = 0) -> None:
+        """A stage of ``nbytes``, its first ``keep`` bytes carried over."""
+        stage = np.empty(nbytes, np.uint8)
+        if keep:
+            stage[:keep] = self.stage[:keep]
+        self.stage, self._stage_addr = stage, stage.ctypes.data
+
+    def __call__(self, have: int, out: np.ndarray,
+                 out_off: int) -> tuple[int, int, int, int]:
+        """Returns ``(n, used, need, why)``: packets unpacked, bytes of the
+        stage consumed, bytes the next call needs staged once the rest has
+        moved to the front (0: call again as it is), and one of the
+        constants above.  At MISMATCH and OUT_FULL the header fields of the
+        packet that stopped the run are at index ``n``."""
+        if out is not self._out:
+            self._out, self._out_addr = _as_u8(out), out.ctypes.data
+        if have > self.stage.size or out_off > out.size:
+            raise ValueError("staged bytes or offset beyond the buffer")
+        n = self._fn(self._stage_addr, have, self._out_addr, out_off,
+                     out.size, self.max_pkts, *self._fixed)
+        used, need, why = self._ret.tolist()
+        return n, used, need, why
 
 
 def gather_ranges(data: bytes | np.ndarray, starts: np.ndarray,

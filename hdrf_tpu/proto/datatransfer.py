@@ -27,6 +27,22 @@ Wire layout:
   checks every byte against its origin's sum, one native call a frame.
   ``FLAG_LAST`` ends the stream; the last frame may hold no segment.
 
+Readers of the packet stream.  ``read_packet_crc`` and the iterators over
+it take a packet at a time: two ``recv``s, one CRC32C call and two copies
+each (the direct write path, which needs a barrier at every FLUSH/SYNC
+packet, reads and the mirror legs).  ``iter_packet_runs`` — the DataNode's
+reduced-write ingest, ``BlockReceiver.receive_reduced`` — takes a RUN:
+every whole packet that has already arrived, in one ``recv_into``, then
+one native call (``hdrf_unpack_packets``) that parses the headers,
+verifies each payload against its header's CRC32C and copies it once to
+the block's buffer (``BlockBuffer``), where the block is read from
+afterwards.  It adapts to what it observes — the bytes already in the
+socket — so a receiver ahead of its sender sees runs of one and a busy one
+sees its clients' windows.  Nothing changes on the wire: the receiver
+answers one ``ACK`` a packet, in order, each after its packet's verify
+(a run's acks leave in one write), the last after the commit; a sender
+with a window of one completes as before.
+
 Ops (Receiver.java:101-135 op dispatch analog): WRITE_BLOCK, READ_BLOCK,
 TRANSFER_BLOCK, COPY_BLOCK, BLOCK_CHECKSUM — dispatched by the DataNode's
 xceiver loop.
@@ -36,7 +52,7 @@ from __future__ import annotations
 
 import socket
 import struct
-from typing import Any, Iterator
+from typing import Any, Iterator, NamedTuple
 
 import numpy as np
 
@@ -161,17 +177,6 @@ def iter_packets(sock: socket.socket) -> Iterator[tuple[int, bytes, bool]]:
             return
 
 
-def iter_packets_crc(
-        sock: socket.socket) -> Iterator[tuple[int, bytes, bool, int]]:
-    """``iter_packets`` with each packet's verified CRC32C beside it."""
-    while True:
-        seqno, data, flags, crc = read_packet_crc(sock)
-        last = bool(flags & FLAG_LAST)
-        yield seqno, data, last, crc
-        if last:
-            return
-
-
 def iter_packets_ex(sock: socket.socket) -> Iterator[tuple[int, bytes, int]]:
     """Flag-preserving packet run iterator (the write path needs FLUSH/SYNC
     markers; readers of whole runs use iter_packets)."""
@@ -182,8 +187,102 @@ def iter_packets_ex(sock: socket.socket) -> Iterator[tuple[int, bytes, int]]:
             return
 
 
+class BlockBuffer:
+    """One block's payload, landed once and read from where it landed.
+    ``np.empty`` pages cost nothing until written, so ``capacity`` may be
+    the deployment's block size whatever the block holds; a stream that
+    sends more moves to a buffer twice the size (views of the old one stay
+    good: they hold it, and its bytes do not change)."""
+
+    def __init__(self, capacity: int):
+        self.arr = np.empty(max(capacity, 1), np.uint8)
+        self.size = 0
+
+    def reserve(self, need: int) -> None:
+        if need > self.arr.size:
+            arr = np.empty(max(need, 2 * self.arr.size), np.uint8)
+            arr[:self.size] = self.arr[:self.size]
+            self.arr = arr      # swapped in whole: a reader sees old or new
+
+    def view(self, start: int = 0, end: int | None = None) -> memoryview:
+        return memoryview(self.arr)[start:self.size if end is None else end]
+
+
+class PacketRun(NamedTuple):
+    """Whole packets that had arrived together, verified: their header
+    fields as arrays, and the range ``[start, end)`` of the block's buffer
+    where their payloads lie back to back, in order."""
+    seqnos: np.ndarray
+    lens: np.ndarray
+    flags: np.ndarray
+    crcs: np.ndarray
+    start: int
+    end: int
+
+
+# the run reader's staging buffer: the hop's stride and a header, more than
+# a client's window of packets fills; a larger packet grows it
+_STAGE = (4 << 20) + PKT_HDR.size
+
+
+def iter_packet_runs(sock: socket.socket,
+                     out: BlockBuffer) -> Iterator[PacketRun]:
+    """The write stream a run at a time.  A run is every whole packet that
+    has already arrived: ONE ``recv_into`` of whatever the socket holds (it
+    returns with the first byte and never waits for a count; a packet still
+    in pieces takes another), then ONE native call that parses the staged
+    headers, verifies each payload against its header's CRC32C and copies
+    it to ``out`` (``native.PacketUnpacker``), then the trailing partial
+    packet moves to the front.  A run ends at the last whole packet staged
+    or at ``FLAG_LAST``; a run of one is what a receiver ahead of its
+    sender sees.  A mismatch raises what ``read_packet_crc`` raises, after
+    the run of the packets before it.  The verify and its copy are one
+    ``packet_verify`` lap a run."""
+    unpack = native.PacketUnpacker(_STAGE)
+    have, need = 0, PKT_HDR.size
+    while True:
+        view = memoryview(unpack.stage)
+        while have < need:
+            r = sock.recv_into(view[have:], len(view) - have)
+            if r == 0:
+                raise ConnectionError("peer closed connection")
+            have += r
+        t0 = profiler.mark()
+        n, used, need, why = unpack(have, out.arr, out.size)
+        profiler.lap("packet_verify", t0)
+        if why == unpack.LAST:
+            profiler.flush_laps()
+        if n:
+            start, out.size = out.size, out.size + int(unpack.lens[:n].sum())
+            yield PacketRun(unpack.seqnos[:n].copy(), unpack.lens[:n].copy(),
+                            unpack.flags[:n].copy(), unpack.crcs[:n].copy(),
+                            start, out.size)
+        if why == unpack.LAST:
+            return
+        if why == unpack.MISMATCH:
+            raise IOError(f"packet {int(unpack.seqnos[n])}: "
+                          "checksum mismatch")
+        if why == unpack.OUT_FULL:
+            out.reserve(out.size + int(unpack.lens[n]))
+        have -= used
+        if have:
+            unpack.stage[:have] = unpack.stage[used:used + have]
+        if need > unpack.stage.size:
+            unpack.grow_stage(need, keep=have)
+
+
 def send_ack(sock: socket.socket, seqno: int, status: int = ACK_SUCCESS) -> None:
     sock.sendall(ACK.pack(seqno, status))
+
+
+_ACK_DT = np.dtype([("seqno", "<u8"), ("status", "u1")])    # = ACK, packed
+
+
+def pack_acks(seqnos: np.ndarray, status: int = ACK_SUCCESS) -> bytes:
+    """One ``ACK`` a seqno, in order, as ``send_ack`` would write them."""
+    acks = np.empty(len(seqnos), _ACK_DT)
+    acks["seqno"], acks["status"] = seqnos, status
+    return acks.tobytes()
 
 
 def read_ack(sock: socket.socket) -> tuple[int, int]:

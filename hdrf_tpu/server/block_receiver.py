@@ -41,6 +41,7 @@ import time
 from typing import TYPE_CHECKING
 
 from hdrf_tpu import native
+from hdrf_tpu.config import NameNodeConfig
 from hdrf_tpu.proto import datatransfer as dt
 from hdrf_tpu.proto.rpc import recv_frame, send_frame
 from hdrf_tpu.utils import (fault_injection, log, metrics, profiler, qos,
@@ -89,6 +90,10 @@ def _connect(addr: list | tuple, dn=None, block_id: int | None = None,
 class BlockReceiver:
     def __init__(self, dn: "DataNode"):
         self._dn = dn
+        # capacity of a reduced write's buffer: the deployment default's
+        # block size (the DataNode is told no block size, and untouched
+        # pages cost nothing) or the largest block a client has sent
+        self._block_cap = NameNodeConfig.block_size
 
     def _note_peer(self, target: dict, seconds: float, nbytes: int) -> None:
         """Record a downstream-transfer latency sample for slow-peer
@@ -249,22 +254,35 @@ class BlockReceiver:
         failure mode SURVEY §7(b) warns about): at most
         ``max_concurrent_writes`` blocks are ever buffered.
 
+        The unit of the receive loop is a RUN — every whole packet that
+        has already arrived (``dt.iter_packet_runs``): one ``recv``, one
+        native call that parses, verifies each payload against the
+        client's CRC32C and copies it once into the block's buffer, each
+        packet's fault point in order, then the run's acks in one write.
+        On the wire nothing moved: one ack a packet, in order, none before
+        its packet's verify and fault point, the last after the commit.
+
         With a co-located reduction worker configured, packets are
         FORWARDED to the worker as they arrive (client -> DN -> worker ->
         HBM is one pipeline; the worker stages bytes to device mid-stream)
-        and only (cuts, digests) come back; otherwise the block buffers
-        locally (bf1 analog) and reduces in-process.  Each packet goes on
-        with the CRC32C the client sent for it, verified here before the
-        ack: ``reduce_stream`` carries it to the worker (one frame per
-        4 MiB stride) instead of summing the bytes a second time, so the
-        worker checks what it uploads against the client's own sum.
+        and only (cuts, digests) come back; otherwise the block reduces
+        in-process from its buffer (bf1 analog).  Each packet goes on with
+        the CRC32C the client sent for it, verified here before the ack:
+        ``reduce_stream`` carries it to the worker (one frame per 4 MiB
+        stride, the segments views of the block's buffer) instead of
+        summing the bytes a second time, so the worker checks what it
+        uploads against the client's own sum.
 
         Memory honesty (r3 verdict weak #7): even on the worker path the
-        DN ALSO accumulates the block host-side (``parts``) — container
-        appends need the unique chunks' bytes after the worker answers,
-        and re-fetching them from the worker would double the IPC.  So
-        "the DN host stays device-free" holds, but peak host memory is
-        ~2x block per in-flight write across the two processes, bounded
+        DN ALSO holds the block host-side — container appends need the
+        unique chunks' bytes after the worker answers, and re-fetching
+        them from the worker would double the IPC.  It holds it ONCE: one
+        ``dt.BlockBuffer`` a block, filled as the packets are verified
+        and handed on as views (no list of packets, no join), its
+        capacity the default block size or the largest block seen, of
+        which only the pages written cost memory.  So "the DN host stays
+        device-free" holds, and peak host memory is ~2x block per
+        in-flight write across the two processes (one in each), bounded
         by the admission slots acquired above."""
         dn = self._dn
         block_id, gen_stamp = fields["block_id"], fields["gen_stamp"]
@@ -293,30 +311,27 @@ class BlockReceiver:
         with profiler.block_timeline(block_id) as tl, \
                 dn.write_slot(), \
                 qos.bind_tenant(tenant):  # admission BEFORE buffering
-            parts: list[bytes] = []
-            last_seqno = [0]
+            out = dt.BlockBuffer(self._block_cap)
             # each next() wait on the client stream is one "recv" span
-            packets = profiler.timed_iter("recv", dt.iter_packets_crc(sock))
+            last_seqno = [0]
+            runs = self._admit_runs(
+                profiler.timed_iter("recv", dt.iter_packet_runs(sock, out)),
+                block_id, last_seqno)
 
             def stream():
-                for seqno, data, last, crc in packets:
-                    last_seqno[0] = seqno
-                    # same per-packet crash window as the direct path (the
-                    # resilience fault matrix kills the worker mid-stream
-                    # from here); a RAISING handler aborts the write like
-                    # any other client-stream error
-                    fault_injection.point("block_receiver.packet",
-                                          block_id=block_id, seqno=seqno,
-                                          dn_id=dn.dn_id)
-                    # ack (flow control) and buffer BEFORE yielding: a
-                    # consumer abandoning the generator mid-yield (worker
-                    # death) must lose neither the ack nor the bytes
-                    if not last:
+                for run, acks in runs:
+                    # ack (flow control) BEFORE yielding, the bytes being
+                    # in ``out`` already: a consumer abandoning the
+                    # generator mid-yield (worker death) must lose neither
+                    # the acks nor the bytes
+                    if acks:
                         with profiler.phase("ack"):
-                            dt.send_ack(sock, seqno)
-                    if data:
-                        parts.append(data)
-                        yield data, crc
+                            sock.sendall(acks)
+                    view, off = out.view(run.start, run.end), 0
+                    for ln, crc in zip(run.lens.tolist(), run.crcs.tolist()):
+                        if ln:
+                            yield view[off:off + ln], crc
+                            off += ln
 
             precomputed = None
             worker_down = False
@@ -353,15 +368,14 @@ class BlockReceiver:
                     for _ in stream():
                         pass
             elif pipelined:
-                data, crcs, precomputed = self._drain_pipelined(
-                    sock, tl, block_id, packets, parts, last_seqno)
+                crcs, precomputed = self._drain_pipelined(
+                    sock, tl, block_id, runs, out)
             else:
                 for _ in stream():
                     pass
-            if not pipelined:
-                with profiler.phase("buffer_assemble"):
-                    data = b"".join(parts)
-                tl.nbytes = len(data)
+            self._block_cap = max(self._block_cap, out.size)
+            data = out.view()
+            tl.nbytes = len(data)
             if worker_down:
                 # compute here WITHOUT re-trying the dead worker (the
                 # scheme would otherwise reconnect per block while the
@@ -395,8 +409,38 @@ class BlockReceiver:
                               latency_s=time.monotonic() - t_start)
         _M.incr("blocks_received_reduced")
 
+    def _admit_runs(self, runs, block_id: int, last_seqno: list):
+        """``(run, acks)`` for each run of the client stream once every
+        packet of it has passed its fault point, in order: the same
+        per-packet crash window as the direct path (the resilience fault
+        matrix kills the worker mid-stream from here; a RAISING handler
+        aborts the write like any other client-stream error, before the
+        ack of its packet or of any after it).  ``acks`` is the run's
+        flow-control acks as they go on the wire, one ``ACK`` a packet in
+        order; the last packet's is the caller's, after the commit, and
+        ``last_seqno[0]`` is the seqno it answers."""
+        dn_id = self._dn.dn_id
+        packets = n_runs = 0
+        try:
+            for run in runs:
+                seqnos = run.seqnos
+                for seqno in seqnos.tolist():
+                    fault_injection.point("block_receiver.packet",
+                                          block_id=block_id, seqno=seqno,
+                                          dn_id=dn_id)
+                last_seqno[0] = int(seqnos[-1])
+                packets += len(seqnos)
+                n_runs += 1
+                if run.flags[-1] & dt.FLAG_LAST:
+                    seqnos = seqnos[:-1]
+                yield run, dt.pack_acks(seqnos)
+        finally:
+            # packets a run = how often the run reader engaged (1.0: never)
+            _M.incr("recv_packets", packets)
+            _M.incr("recv_runs", n_runs)
+
     def _drain_pipelined(self, sock: socket.socket, tl, block_id: int,
-                         packets, parts: list[bytes], last_seqno: list):
+                         runs, out: dt.BlockBuffer):
         """Pipelined ingest (``pipeline_depth`` > 1, no co-located worker).
 
         Two moves off the connection thread's critical path:
@@ -413,14 +457,14 @@ class BlockReceiver:
 
         The pump is the sole socket writer until joined; the caller sends
         the final ack only after this returns.  Returns
-        ``(data, crcs, (cuts, digests))``."""
+        ``(crcs, (cuts, digests))``; the block is in ``out``."""
         dn = self._dn
         pump_q: queue.Queue = queue.Queue()
         crcs: list[int] = []
         pump_err: list[BaseException] = []
 
         def _pump():
-            tail = b""
+            done = end = 0      # bytes of ``out`` summed / landed
             cchunk = dn.checksum_chunk
             with profiler.bind_timeline(tl):
                 while True:
@@ -429,57 +473,44 @@ class BlockReceiver:
                         break
                     if pump_err:
                         continue  # drain so the recv loop never blocks
-                    seqno, part = item
+                    acks, end = item
                     try:
-                        if seqno is not None:
+                        if acks:
                             with profiler.phase("ack"):
-                                dt.send_ack(sock, seqno)
-                        if part:
+                                sock.sendall(acks)
+                        whole = (end - done) // cchunk * cchunk
+                        if whole:
                             with profiler.phase("checksum"):
-                                tail += part
-                                while len(tail) >= cchunk:
-                                    crcs.append(int(native.crc32c(
-                                        tail[:cchunk])))
-                                    tail = tail[cchunk:]
+                                crcs.extend(_checksums(
+                                    out.view(done, done + whole), cchunk))
+                            done += whole
                     except BaseException as e:  # noqa: BLE001 — re-raised
                         pump_err.append(e)
-                if not pump_err and tail:
+                if not pump_err and end > done:
                     with profiler.phase("checksum"):
-                        crcs.append(int(native.crc32c(tail)))
+                        crcs.append(int(native.crc32c(out.view(done, end))))
 
         with profiler.phase("pipeline_submit"):  # thread spawn is host work
             pump = threading.Thread(target=_pump, name="recv-pump",
                                     daemon=True)
             pump.start()
         try:
-            for seqno, data, last, _crc in packets:
-                last_seqno[0] = seqno
-                fault_injection.point("block_receiver.packet",
-                                      block_id=block_id, seqno=seqno,
-                                      dn_id=dn.dn_id)
-                # hand ack + CRC to the pump BEFORE buffering continues —
-                # same loss-safety as stream(): the bytes land in ``parts``
-                # on this thread regardless of what the pump does
-                pump_q.put((None if last else seqno, data))
-                if data:
-                    parts.append(data)
+            for run, acks in runs:
+                # hand acks + CRC to the pump: the bytes are in ``out``
+                # already, on this thread, whatever the pump does
+                pump_q.put((acks, run.end))
         finally:
             pump_q.put(None)  # pump exits even if the client stream died
-        with profiler.phase("buffer_assemble"):
-            data = b"".join(parts)
-        tl.nbytes = len(data)
-        import numpy as _np
-
+        tl.nbytes = out.size
         with profiler.phase("pipeline_submit"):
-            fut = dn.write_pipeline.submit(
-                block_id, _np.frombuffer(data, dtype=_np.uint8), tl)
+            fut = dn.write_pipeline.submit(block_id, out.arr[:out.size], tl)
         # residual pump work (tail CRC chunks) runs under the dispatch just
         # enqueued; the join wait is checksum time from this thread's view
         with profiler.phase("checksum"):
             pump.join()
         if pump_err:
             raise pump_err[0]
-        return data, crcs, fut.result()
+        return crcs, fut.result()
 
     def _store_and_mirror(self, block_id: int, gen_stamp: int, scheme_name: str,
                           data: bytes, targets: list,

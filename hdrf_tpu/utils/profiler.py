@@ -79,8 +79,7 @@ CLASSES = ("host_busy", "device_busy", "transport_wait", "idle")
 PHASE_CLASS = {
     "recv": TRANSPORT, "mirror_stream": TRANSPORT, "ack": TRANSPORT,
     "dedup_lookup": HOST, "wal_commit": HOST, "container_io": HOST,
-    "reduce_compute": HOST, "checksum": HOST, "buffer_assemble": HOST,
-    "pipeline_submit": HOST,
+    "reduce_compute": HOST, "checksum": HOST, "pipeline_submit": HOST,
     "device_wait": DEVICE,
     # Read-path phases (server/block_sender.py serve_read/read_logical):
     # index/cache/decode burn the single vCPU; stripe gathers and the
@@ -104,7 +103,8 @@ PHASE_CLASS = {
     "dispatch_queue": HOST, "lock_wait": HOST, "locked": HOST,
     "serialize": HOST, "handler": HOST,
     # What ``recv`` hid and what nothing covered (PR 25).  packet_verify is
-    # the CRC32C inside read_packet_crc; worker_send the DN forwarding a
+    # the CRC32C of arriving packets (a run's parse, verify and one copy in
+    # iter_packet_runs; read_packet_crc's); worker_send the DN forwarding a
     # stride frame to the worker; seal_* the background container seal (its two
     # hop legs are waits, the file work is this interpreter's); nn_rpc one
     # NameNode request, frame read to reply sent; heartbeat_stats /
@@ -128,7 +128,7 @@ PHASE_CLASS = {
 PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
                "wal_commit", "container_io", "dedup_lookup",
                "reduce_compute", "packet_verify", "checksum",
-               "buffer_assemble", "pipeline_submit", "seal_write",
+               "pipeline_submit", "seal_write",
                "stage_h2d", "select", "emit",
                "index_lookup", "cache_probe", "container_decode",
                # RPC phases: lock_wait/locked win attribution inside the

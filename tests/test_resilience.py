@@ -470,6 +470,159 @@ class TestAlteredStride:
             w.stop()
 
 
+# ------------------------------------- runs, not packets, and today's wire
+
+
+class TestRunsOnTheWire:
+    """``receive_reduced`` takes the client stream a run at a time (PR 28)
+    and the wire stays a packet at a time: one ack a packet, in order, none
+    before its packet's verify and fault point, the last after the commit —
+    through each branch that consumes the runs and the encrypted socket."""
+
+    BRANCHES = {
+        # pipeline_depth 4 (the default), no worker: _drain_pipelined
+        "pipelined": {},
+        "in-process": {"reduction_overrides": {"pipeline_depth": 1}},
+        "worker": {},           # worker_addr filled in by the fixture
+        "encrypted": {"secure": True},
+    }
+    PKT = 4096
+
+    @pytest.fixture(scope="class", params=sorted(BRANCHES))
+    def node(self, request):
+        from hdrf_tpu.server.reduction_worker import ReductionWorker
+
+        kw = dict(self.BRANCHES[request.param])
+        w = None
+        if request.param == "worker":
+            w = ReductionWorker(backend="native").start()
+            kw["reduction_overrides"] = {"worker_addr": list(w.addr)}
+        try:
+            with MiniCluster(n_datanodes=1, replication=1,
+                             block_size=1 << 20, **kw) as mc:
+                yield request.param, mc
+        finally:
+            if w is not None:
+                w.stop()
+
+    def _open(self, node, path):
+        """A client's WRITE_BLOCK op on a raw socket: (socket, block id)."""
+        from hdrf_tpu.proto import datatransfer as dt
+
+        branch, mc = node
+        nn = mc.namenode
+        nn.rpc_create(path, client="raw", scheme="dedup_lz4")
+        alloc = nn.rpc_add_block(path, client="raw")
+        s = socket.create_connection(mc.datanodes[0].addr, timeout=20)
+        s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        s = dt.secure_socket(s, alloc.get("token"), branch == "encrypted")
+        dt.send_op(s, dt.WRITE_BLOCK, block_id=alloc["block_id"],
+                   gen_stamp=alloc["gen_stamp"], scheme="dedup_lz4",
+                   token=alloc.get("token"), targets=[], _client="raw")
+        return s, alloc["block_id"]
+
+    @staticmethod
+    def _acks_until_closed(s) -> list:
+        from hdrf_tpu.proto import datatransfer as dt
+
+        acks = []
+        try:
+            while True:
+                acks.append(dt.read_ack(s))
+        except (ConnectionError, OSError):
+            return acks
+
+    @pytest.mark.parametrize("window", [1, 16])
+    def test_a_window_completes_and_the_last_ack_follows_the_commit(
+            self, node, window):
+        """A sender that waits for each ack before its next packet
+        (runs of one) and one with the default window both finish; the
+        last ack is not on the wire until the commit has returned."""
+        from hdrf_tpu.proto import datatransfer as dt
+
+        br = metrics.registry("block_receiver")
+        data = _bytes(300_000)
+        packets = -(-len(data) // self.PKT) + 1
+        committed = []
+
+        def slow_commit(**kw):
+            time.sleep(0.2)
+            committed.append(time.monotonic())
+
+        s, bid = self._open(node, f"/runs/w{window}")
+        before = (br.counter("recv_packets"), br.counter("recv_runs"))
+        try:
+            with fault_injection.inject("dedup.container_append",
+                                        slow_commit):
+                seqno, status = dt.stream_bytes_acked(s, data, self.PKT,
+                                                      window)
+                done = time.monotonic()
+        finally:
+            s.close()
+        assert (seqno, status) == (packets - 1, dt.ACK_SUCCESS)
+        assert len(committed) == 1 and done >= committed[0]
+        got = (br.counter("recv_packets") - before[0],
+               br.counter("recv_runs") - before[1])
+        assert got[0] == packets and 1 <= got[1] <= packets
+        if window == 1:         # the DataNode never saw two at once
+            assert got[1] == packets
+        dn = node[1].datanodes[0]
+        assert dn.replicas.get_meta(bid).logical_len == len(data)
+        assert bytes(dn._sender.read_logical(bid)) == data
+
+    @pytest.mark.parametrize("k", [0, 5])
+    def test_a_corrupted_packet_is_not_acked_nor_any_after_it(self, node, k):
+        """Packets 0..``k`` in one write, the last of them altered: acks
+        for the packets before ``k`` and for no other, then the DataNode
+        hangs up, and nothing is stored.  (Nothing is sent after ``k``: a
+        close over unread bytes is a reset, which may drop the acks on
+        their way.  Where a pump thread writes the acks they race the
+        close, so there only their order and bound are certain.)"""
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.testing.wire import frame_packets
+
+        wire = bytearray(frame_packets((i, _bytes(1000), 0)
+                                       for i in range(k + 1)))
+        wire[-1000 - 1] ^= 1        # the last header's CRC, its top byte
+        s, bid = self._open(node, f"/runs/bad{k}")
+        try:
+            s.sendall(wire)
+            acks = self._acks_until_closed(s)
+        finally:
+            s.close()
+        want = [(i, dt.ACK_SUCCESS) for i in range(k)]
+        assert acks == want[:len(acks)]
+        if node[0] in ("in-process", "worker"):
+            assert acks == want
+        assert node[1].datanodes[0].replicas.get_meta(bid) is None
+
+    def test_a_raising_fault_point_aborts_before_its_ack(self, node):
+        """Packets 0-2 sent and acked one by one, then 3-7 and the last at
+        once; a handler that raises at seqno 3 ends the write: no ack for
+        3 or after, though all of them had arrived and verify."""
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.testing.wire import frame_packets
+
+        s, bid = self._open(node, "/runs/boom")
+
+        def boom(**kw):
+            if kw["block_id"] == bid and kw["seqno"] == 3:
+                raise IOError("injected at seqno 3")
+
+        rest = frame_packets([(i, _bytes(1000), 0) for i in range(3, 8)]
+                             + [(8, b"", dt.FLAG_LAST)])
+        try:
+            with fault_injection.inject("block_receiver.packet", boom):
+                for i in range(3):
+                    dt.write_packet(s, i, _bytes(1000))
+                    assert dt.read_ack(s) == (i, dt.ACK_SUCCESS)
+                s.sendall(rest)
+                assert self._acks_until_closed(s) == []
+        finally:
+            s.close()
+        assert node[1].datanodes[0].replicas.get_meta(bid) is None
+
+
 # ----------------------------------------- mirror failures reach the NN view
 
 

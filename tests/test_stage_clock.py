@@ -21,6 +21,7 @@ from hdrf_tpu.proto import datatransfer as dt
 from hdrf_tpu.server.reduction_worker import (_STRIDE, ReductionWorker,
                                               WorkerClient)
 from hdrf_tpu.testing.minicluster import MiniCluster
+from hdrf_tpu.testing.wire import PiecedSocket, frame_packets
 from hdrf_tpu.utils import profiler
 
 BLOCK = 2 << 20
@@ -61,18 +62,24 @@ def served():
     """One DataNode beside a native reduction worker PROCESS (the served
     layout of chip_smoke.py and perfbench), two blocks written through it;
     heartbeat and scanner fast enough to tick inside the test."""
+    from hdrf_tpu.utils import metrics
+
     profiler.reset()
     t0 = profiler.mark()
+    reg = metrics.registry("block_receiver")
     with MiniCluster(n_datanodes=1, replication=1, block_size=BLOCK,
                      container_size=1 << 20, tpu_worker=True,
                      worker_backend="native",
                      dn_config_overrides={"scan_interval_s": 0.2}) as mc:
         dn = mc.datanodes[0]
         first = dn._worker.stats()
+        recv = (reg.counter("recv_packets"), reg.counter("recv_runs"))
         with mc.client("stage-clock") as c:
             c.write("/a", _payload(BLOCK, 1), scheme="dedup_lz4")
             mid = dn._worker.stats()
             c.write("/b", _payload(BLOCK, 2), scheme="dedup_lz4")
+            recv = (reg.counter("recv_packets") - recv[0],
+                    reg.counter("recv_runs") - recv[1])
             dn.containers.drain_seals()
             deadline = time.time() + 10
             while time.time() < deadline:          # one tick of each loop
@@ -84,7 +91,7 @@ def served():
             assert c.read("/a") == _payload(BLOCK, 1)
         last = dn._worker.stats()
         yield {"t0": t0, "t1": profiler.mark(), "first": first, "mid": mid,
-               "last": last,
+               "last": last, "recv": recv,
                "timelines": profiler.timelines_snapshot()}
 
 
@@ -104,7 +111,9 @@ class TestDataNodePhases:
     def test_the_existing_phases_keep_their_names(self, served):
         prof = profiler.window_profile(served["t0"], served["t1"])
         assert {"recv", "ack", "device_wait", "dedup_lookup",
-                "container_io", "buffer_assemble"} <= set(prof["phases"])
+                "container_io"} <= set(prof["phases"])
+        # the block lands once, where it is read from: no join to time
+        assert "buffer_assemble" not in prof["phases"]
 
     def test_a_block_is_attributed(self, served):
         tls = [t for t in served["timelines"] if t["nbytes"] == BLOCK]
@@ -116,13 +125,16 @@ class TestDataNodePhases:
                     "device_wait"} <= names
 
     def test_per_packet_phases_land_as_a_span_a_stride(self, served):
-        """32 packets of 64 KiB a block: far fewer ``packet_verify`` spans
-        (laps) and ``worker_send`` spans (one a stride frame: this block's
-        only frame is its last) than ``recv`` ones."""
+        """32 packets of 64 KiB a block: one ``recv`` span a run (at most
+        one a packet), and far fewer ``packet_verify`` spans (a lap a run)
+        and ``worker_send`` spans (one a stride frame: this block's only
+        frame is its last)."""
         for tl in [t for t in served["timelines"] if t["nbytes"] == BLOCK]:
             n = {name: sum(1 for s in tl["spans"] if s[0] == name)
-                 for name in ("recv", "packet_verify", "worker_send")}
-            assert n["recv"] >= BLOCK // (64 << 10)
+                 for name in ("recv", "ack", "packet_verify", "worker_send")}
+            assert 1 <= n["recv"] <= BLOCK // (64 << 10) + 1
+            # one write of acks a run, the NameNode's notice, the last ack
+            assert n["ack"] <= n["recv"] + 2
             assert 1 <= n["packet_verify"] <= 3
             assert 1 <= n["worker_send"] <= 3
 
@@ -217,6 +229,66 @@ class TestHopCounter:
             "layer": "DN to worker hop", "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
                           "versions-dedup.ingest"]}
+
+
+class TestRecvCounter:
+    """``recv_packets`` / ``recv_runs`` of the ``block_receiver`` registry:
+    packets a run, how often the run reader engaged (PR 28).  No per-layer
+    metric reads them yet (``perfbench`` takes no DataNode counter)."""
+
+    @staticmethod
+    def _block(nbytes: int, packet: int = 64 << 10):
+        """(wire, packet lengths on it) of a client's block."""
+        data = memoryview(_payload(nbytes, 28))
+        lens = [min(packet, nbytes - o) for o in range(0, nbytes, packet)]
+        lens.append(0)
+        offs = [0] + list(np.cumsum(lens[:-1]))
+        return frame_packets(
+            (seq, data[o:o + ln], dt.FLAG_LAST * (not ln))
+            for seq, (o, ln) in enumerate(zip(offs, lens))), lens
+
+    def _count(self, wire, pieces, capacity):
+        """Drain ``wire`` through the reader and ``_admit_runs``; returns
+        the counters' (packets, runs) and the acks that would be written."""
+        import types
+
+        from hdrf_tpu.server.block_receiver import BlockReceiver
+        from hdrf_tpu.utils import metrics
+
+        reg = metrics.registry("block_receiver")
+        before = (reg.counter("recv_packets"), reg.counter("recv_runs"))
+        out = dt.BlockBuffer(capacity)
+        rcv = BlockReceiver(types.SimpleNamespace(dn_id="dn-count"))
+        acks = b"".join(a for _, a in rcv._admit_runs(
+            dt.iter_packet_runs(PiecedSocket(wire, pieces), out), 7, [0]))
+        return (reg.counter("recv_packets") - before[0],
+                reg.counter("recv_runs") - before[1]), acks, out
+
+    def test_a_128_mib_block_counts_2049_packets_once_each(self):
+        nbytes = 128 << 20
+        wire, lens = self._block(nbytes)
+        rng = np.random.default_rng(5)
+        pieces = (int(p) for p in rng.integers(1, 2 << 20, 1 << 16))
+        (packets, runs), acks, out = self._count(wire, pieces, nbytes)
+        assert packets == len(lens) == 2049
+        assert 1 <= runs <= packets
+        assert out.size == nbytes and out.arr.size == nbytes   # never grown
+        # one ack a packet but the last, same bytes and order as send_ack's
+        assert acks == b"".join(dt.ACK.pack(q, dt.ACK_SUCCESS)
+                                for q in range(2048))
+
+    def test_a_packet_at_a_time_reads_exactly_one_packet_a_run(self):
+        wire, lens = self._block(2 << 20)
+        pieces = [dt.PKT_HDR.size + ln for ln in lens]
+        (packets, runs), _, _ = self._count(wire, pieces, 2 << 20)
+        assert packets == runs == len(lens) == 33
+        assert packets / runs == 1.0
+
+    def test_a_served_block_counts_its_packets(self, served):
+        """Through a live DataNode (the fixture's two 2 MiB blocks and
+        nothing else in this module's window before it)."""
+        assert served["recv"][0] == 2 * (BLOCK // (64 << 10) + 1)
+        assert 2 <= served["recv"][1] <= served["recv"][0]
 
 
 class TestWorkerStageClock:

@@ -9,6 +9,8 @@ tool (its CPU rehearsal is tests/test_chip_smoke.py)."""
 
 from __future__ import annotations
 
+import random
+
 import numpy as np
 import pytest
 
@@ -18,6 +20,8 @@ from hdrf_tpu.server.reduction_worker import (_STRIDE, ReductionWorker,
                                               WorkerClient, WorkerError,
                                               spawn_local_worker)
 from hdrf_tpu.testing.minicluster import MiniCluster
+from hdrf_tpu.testing.wire import PiecedSocket, frame_packets
+from hdrf_tpu.utils import metrics
 
 RNG = np.random.default_rng(51)
 
@@ -228,6 +232,196 @@ class TestStrideWire:
         assert int(cuts[-1]) == len(data)
 
 
+# ------------------------------------------------------ the run reader (PR 28)
+
+
+def _stream(sizes, flags=None, base=0):
+    """A block's packets: payloads of ``sizes``; ``flags`` by index, the
+    last packet FLAG_LAST."""
+    flags = dict(flags or {})
+    flags[len(sizes) - 1] = flags.get(len(sizes) - 1, 0) | 0x1
+    return [(base + i, _bytes(n), flags.get(i, 0))
+            for i, n in enumerate(sizes)]
+
+
+# what iter_packets_crc's callers sent, and what they did not
+RUN_STREAMS = {
+    # a client's block: full packets, a short one, the empty last trailer
+    "block": lambda: _stream([PKT, PKT, PKT, 12_345, 0]),
+    # bytes in the last packet, an empty packet mid-stream, a flush marker
+    "odd": lambda: _stream([1, 0, 70_000, 17, 3], {1: 0x2, 2: 0x4}, base=9),
+    "trailer-only": lambda: _stream([0]),
+    "a-window-of-small": lambda: _stream([100] * 40 + [0]),
+}
+RUN_PIECES = {
+    "1": [1], "17": [17], "65553": [PKT + 17], "whole": [1 << 30],
+    "random-a": random.Random(28).sample(range(1, 200_000), 64),
+    "random-b": random.Random(82).sample(range(1, 3_000), 64),
+}
+
+
+def _read_runs(wire, sizes, capacity=1 << 20):
+    from hdrf_tpu.proto import datatransfer as dt
+
+    out = dt.BlockBuffer(capacity)
+    sock = PiecedSocket(wire, sizes)
+    return list(dt.iter_packet_runs(sock, out)), out, sock
+
+
+class TestRunReader:
+    """``dt.iter_packet_runs`` (the DataNode's reduced-write ingest) against
+    ``dt.read_packet_crc`` on the same bytes, and ``hdrf_unpack_packets``
+    against a parse in Python."""
+
+    @pytest.mark.parametrize("pieces", sorted(RUN_PIECES))
+    @pytest.mark.parametrize("stream", sorted(RUN_STREAMS))
+    def test_runs_hold_what_the_packet_reader_reads(self, stream, pieces):
+        from hdrf_tpu.proto import datatransfer as dt
+
+        packets = RUN_STREAMS[stream]()
+        wire = frame_packets(packets)
+        one = PiecedSocket(wire, RUN_PIECES[pieces])
+        want = [dt.read_packet_crc(one) for _ in packets]
+        assert [(q, d, f) for q, d, f, _ in want] == packets
+        runs, out, sock = _read_runs(wire, RUN_PIECES[pieces])
+        got = [(int(q), int(n), int(f), int(c)) for r in runs
+               for q, n, f, c in zip(r.seqnos, r.lens, r.flags, r.crcs)]
+        assert got == [(q, len(d), f, c) for q, d, f, c in want]
+        # the payloads lie back to back in the block's buffer, run after run
+        assert bytes(out.view()) == b"".join(d for _, d, _ in packets)
+        edges = [0] + [r.end for r in runs]
+        assert [(r.start, r.end) for r in runs] == list(zip(edges, edges[1:]))
+        assert all(r.end - r.start == int(r.lens.sum()) for r in runs)
+        assert 1 <= len(runs) <= len(packets)
+        if pieces == "whole":       # all had arrived: one recv, one run
+            assert (len(runs), sock.calls) == (1, 1)
+        if pieces == "1":           # nothing ever waits behind a packet
+            assert len(runs) == len(packets)
+        assert sock.calls <= one.calls      # never a recv more than before
+
+    @pytest.mark.parametrize("damage", ["payload", "crc"])
+    @pytest.mark.parametrize("k", [0, 3, 6])
+    def test_a_mismatch_ends_the_run_before_it(self, k, damage):
+        """Packet ``k`` of a run altered on the way: the packets before it
+        are a run (they may be acked), then the reader raises what
+        ``read_packet_crc`` raises, naming ``k``'s seqno."""
+        from hdrf_tpu.proto import datatransfer as dt
+
+        packets = _stream([5_000] * 7 + [0], base=40)
+        seq, data, fl = packets[k]
+        at = len(frame_packets(packets[:k]))
+        wire = bytearray(frame_packets(packets))
+        wire[at + (13 if damage == "crc" else dt.PKT_HDR.size + 77)] ^= 0x20
+        out = dt.BlockBuffer(1 << 20)
+        runs = dt.iter_packet_runs(PiecedSocket(bytes(wire), [1 << 30]), out)
+        if k:
+            run = next(runs)
+            assert run.seqnos.tolist() == [q for q, _, _ in packets[:k]]
+        with pytest.raises(IOError, match=f"packet {seq}: checksum mismatch"):
+            next(runs)
+        assert bytes(out.view()) == b"".join(d for _, d, _ in packets[:k])
+        one = PiecedSocket(bytes(wire), [1 << 30])
+        with pytest.raises(IOError, match=f"packet {seq}: checksum mismatch"):
+            for _ in packets:
+                dt.read_packet_crc(one)
+
+    def test_a_packet_larger_than_the_stage_and_a_block_than_its_buffer(self):
+        """Both grow: a 5 MiB packet (the stage is a stride and a header)
+        into a buffer sized for less; a view taken before stays good."""
+        from hdrf_tpu.proto import datatransfer as dt
+
+        packets = _stream([1000, 5 << 20, 0])
+        out = dt.BlockBuffer(4096)
+        runs = dt.iter_packet_runs(PiecedSocket(frame_packets(packets), [1 << 20]), out)
+        first = next(runs)
+        early = out.view(first.start, first.end)
+        rest = list(runs)
+        assert [q for r in [first] + rest for q in r.seqnos.tolist()] == \
+            [0, 1, 2]
+        assert bytes(early) == packets[0][1]
+        assert bytes(out.view()) == packets[0][1] + packets[1][1]
+
+    def test_a_closed_stream_is_a_connection_error(self):
+        from hdrf_tpu.proto import datatransfer as dt
+
+        wire = frame_packets(_stream([1000, 1000, 0]))[:-5]
+        with pytest.raises(ConnectionError):
+            list(dt.iter_packet_runs(PiecedSocket(wire, [700]),
+                                     dt.BlockBuffer(4096)))
+
+    # have (bytes staged, None = all), out_cap, max_pkts -> what stops it
+    UNPACK_CASES = {
+        "zero-length-run": (0, 1 << 20, 64, "PARTIAL"),
+        "partial-header": (10, 1 << 20, 64, "PARTIAL"),
+        "partial-payload": (17 + 999, 1 << 20, 64, "PARTIAL"),
+        "whole-then-partial-header": (2 * 1017 + 16, 1 << 20, 64, "PARTIAL"),
+        "whole-then-partial-payload": (3 * 1017 - 1, 1 << 20, 64, "PARTIAL"),
+        "empty-last-trailer": (None, 1 << 20, 64, "LAST"),
+        "more-than-the-arrays-hold": (None, 1 << 20, 2, "MORE"),
+        "out-too-small": (None, 2500, 64, "OUT_FULL"),
+    }
+
+    @staticmethod
+    def _parse(buf: bytes, out_cap: int, max_pkts: int):
+        """``hdrf_unpack_packets`` in Python: (fields, payload, used, need,
+        why)."""
+        import struct
+
+        from hdrf_tpu import native
+
+        hdr = struct.Struct("<IQBI")
+        pos, fields, payload, need, why = 0, [], b"", hdr.size, "PARTIAL"
+        while len(buf) - pos >= hdr.size:
+            ln, seq, fl, crc = hdr.unpack_from(buf, pos)
+            if len(buf) - pos < hdr.size + ln:
+                need = hdr.size + ln
+                break
+            need = 0
+            if len(fields) == max_pkts:
+                why = "MORE"
+                break
+            body = buf[pos + hdr.size:pos + hdr.size + ln]
+            if len(payload) + ln > out_cap:
+                why = "OUT_FULL"
+                break
+            if native.crc32c(body) != crc:
+                why = "MISMATCH"
+                break
+            fields.append((seq, ln, fl, crc))
+            payload += body
+            pos += hdr.size + ln
+            if fl & 0x1:
+                why = "LAST"
+                break
+            need = hdr.size
+        return fields, payload, pos, need, why
+
+    @pytest.mark.parametrize("case", sorted(UNPACK_CASES))
+    def test_the_native_unpack_equals_a_parse_in_python(self, case):
+        from hdrf_tpu import native
+
+        have, out_cap, max_pkts, why = self.UNPACK_CASES[case]
+        # three data packets, the empty last trailer, and bytes after it
+        # that a run must leave alone
+        wire = frame_packets(_stream([1000, 1000, 1000, 0])) + b"\xff" * 40
+        have = len(wire) if have is None else have
+        unpack = native.PacketUnpacker(len(wire) + 1, max_pkts)
+        unpack.stage[:have] = np.frombuffer(wire[:have], np.uint8)
+        out = np.zeros(out_cap, np.uint8)
+        n, used, need, got = unpack(have, out, 0)
+        fields, payload, w_used, w_need, w_why = self._parse(
+            wire[:have], out_cap, max_pkts)
+        assert w_why == why and got == getattr(unpack, why)
+        assert (n, used, need) == (len(fields), w_used, w_need)
+        assert list(zip(unpack.seqnos[:n].tolist(), unpack.lens[:n].tolist(),
+                        unpack.flags[:n].tolist(),
+                        unpack.crcs[:n].tolist())) == fields
+        assert out[:len(payload)].tobytes() == payload
+        assert not out[len(payload):].any()         # and not a byte more
+        if why == "OUT_FULL":       # the packet that did not fit, by name
+            assert (int(unpack.seqnos[n]), int(unpack.lens[n])) == (2, 1000)
+
+
 class TestWorkerProcess:
     def test_tpu_backend_without_a_chip_refuses_to_start(self, capfd):
         """--backend tpu on a host where JAX finds no TPU: non-zero exit,
@@ -314,10 +508,11 @@ class TestClusterWithWorker:
 
     def test_the_datanode_sums_a_served_packet_once(self, monkeypatch):
         """The hop carries the client's CRC: for a block of P packets the
-        DataNode calls ``native.crc32c`` P times (and once for the empty
-        last packet) to verify them, and not again to forward them.  The
-        client writes from this process too, so calls are told apart by
-        their caller."""
+        DataNode verifies P (and the empty last packet) inside the run
+        reader's native unpack, counted once each by ``recv_packets``, and
+        calls ``native.crc32c`` for none of them, neither to verify nor to
+        forward.  The client writes from this process too, so calls are
+        told apart by their caller."""
         import collections
         import sys
 
@@ -335,20 +530,24 @@ class TestClusterWithWorker:
         with MiniCluster(n_datanodes=1, replication=1, block_size=1 << 20,
                          tpu_worker=True, worker_backend="native") as mc:
             wc = WorkerClient(mc._worker_addr)
+            recv = metrics.registry("block_receiver")
             with mc.client("w") as c:
                 before = wc.stats()
+                seen = recv.counter("recv_packets")
                 monkeypatch.setattr(native, "crc32c", counting)
                 c.write("/crc/f", data, scheme="dedup_lz4")
                 monkeypatch.undo()
                 after = wc.stats()
+                seen = recv.counter("recv_packets") - seen
                 assert c.read("/crc/f") == data
             wc.close()
         assert after["blocks_reduced"] == before["blocks_reduced"] + 1
         assert after["hop_packets"] - before["hop_packets"] == packets
-        assert by["read_packet_crc"] == packets + 1     # the DataNode's
+        assert seen == packets + 1                      # the DataNode's
         assert by["write_packet"] == packets + 1        # the client's own
-        hop = {"reduce_stream", "send", "write_stride", "_sendmsg_all"}
-        assert not hop & set(by), dict(by)
+        path = {"iter_packet_runs", "_admit_runs", "stream", "reduce_stream",
+                "send", "write_stride", "_sendmsg_all"}
+        assert not path & set(by), dict(by)
 
     def test_worker_death_falls_back_in_process(self):
         """Kill the worker mid-cluster: writes keep succeeding via the
