@@ -861,15 +861,13 @@ def _phase_profile(t0: float, t1: float) -> dict:
 
 
 def _pipeline_summary(phase_profile: dict) -> dict:
-    """Pipeline-depth stamp for the output line: the configured write
-    pipeline depth, WAL group-commit batches this run (chunk_index
-    registry), and the profile window's overlap efficiency."""
-    from hdrf_tpu.config import ReductionConfig
+    """Write-overlap stamp for the output line: WAL group-commit batches
+    this run (chunk_index registry) and the profile window's overlap
+    efficiency."""
     from hdrf_tpu.utils import metrics
 
     counters = metrics.registry("chunk_index").snapshot()["counters"]
     return {
-        "depth": ReductionConfig().pipeline_depth,
         "group_commit_batches": int(counters.get("group_commit_batches", 0)),
         "overlap_efficiency": phase_profile["overlap_efficiency"],
     }

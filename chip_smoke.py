@@ -4,7 +4,7 @@
 Default (one chip): the repo's north-star deployment through its normal
 entry points — ``MiniCluster(n_datanodes=1, replication=1, tpu_worker=True,
 worker_backend="tpu")`` with the config defaults of BASELINE config 1
-(128 MiB blocks, 32 MiB containers, pipeline_depth 4, ``dedup_lz4``, r=1).
+(128 MiB blocks, 32 MiB containers, ``dedup_lz4``, r=1).
 Client, NameNode and DataNode live in THIS process, which never initialises
 a JAX backend; the reduction worker is the one child that owns the chip.
 Phases: build the native library here (``make -B``), start the cluster,
@@ -198,7 +198,7 @@ def run_served(args) -> dict:
                  else red.container_size)
     cdc = CdcConfig()
     note(phase="config", block_bytes=block, container_bytes=container,
-         pipeline_depth=red.pipeline_depth, scheme="dedup_lz4", replication=1,
+         scheme="dedup_lz4", replication=1,
          blocks=args.blocks, seed=args.seed, nproc=os.cpu_count(),
          worker_backend=args.worker_backend)
 

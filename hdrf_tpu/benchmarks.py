@@ -386,79 +386,8 @@ def bench_nn(args) -> None:
             nn.stop()
 
 
-def _dfs_pipeline_ab(args) -> None:
-    """Paired write-pipeline A/B: ``--streams`` concurrent client streams
-    through one DN at pipeline_depth=1 (serial legacy) vs ``--depth``,
-    alternating the two builds each round and taking the MEDIAN of the
-    per-round ratios (the paired protocol PERF_NOTES.md's e2e verdicts
-    require — the VM's write-burst throttling stalls whichever pass draws
-    it).  Prints exactly ONE JSON line."""
-    import statistics
-    import threading
-
-    from hdrf_tpu.testing.minicluster import MiniCluster
-
-    rng = np.random.default_rng(42)
-    n = args.mb << 20
-    payloads = []
-    for _ in range(args.streams):
-        a = rng.integers(0, 256, size=n, dtype=np.uint8)
-        a[: n // 2] = rng.integers(97, 123, size=n // 2, dtype=np.uint8)
-        payloads.append(a.tobytes())
-
-    def one_pass(depth: int) -> float:
-        overrides = {"pipeline_depth": depth,
-                     "pipeline_max_inflight": max(args.streams, 4),
-                     "max_concurrent_writes": max(args.streams, 4)}
-        with MiniCluster(n_datanodes=1, replication=1,
-                         block_size=1 << 20, backend=args.backend,
-                         reduction_overrides=overrides) as mc:
-            with mc.client("ab-warm") as c:     # compile/page-in warmup
-                c.write("/ab/warm", payloads[0][: 1 << 20], scheme="dedup")
-            errs: list[BaseException] = []
-
-            def wr(s: int) -> None:
-                try:
-                    with mc.client(f"ab{s}") as c:
-                        c.write(f"/ab/{s}", payloads[s], scheme="dedup")
-                except BaseException as e:  # noqa: BLE001 — re-raised below
-                    errs.append(e)
-
-            ts = [threading.Thread(target=wr, args=(s,))
-                  for s in range(args.streams)]
-            t0 = time.perf_counter()
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join()
-            dt = time.perf_counter() - t0
-            if errs:
-                raise errs[0]
-            return args.streams * n / dt / 2**20
-
-    r1, rn, ratios = [], [], []
-    for _ in range(args.rounds):
-        a = one_pass(1)
-        b = one_pass(args.depth)
-        r1.append(a)
-        rn.append(b)
-        ratios.append(b / a)
-    print(json.dumps({
-        "op": "dfs write pipeline A/B (concurrent streams, paired)",
-        "backend": args.backend, "streams": args.streams,
-        "mb_per_stream": args.mb, "rounds": args.rounds,
-        "depth": args.depth,
-        "depth1_MBps": round(statistics.median(r1), 1),
-        "depthN_MBps": round(statistics.median(rn), 1),
-        "speedup": round(statistics.median(ratios), 3),
-    }))
-
-
 def bench_dfs(args) -> None:
     from hdrf_tpu.testing.minicluster import MiniCluster
-
-    if args.pipeline_ab:
-        return _dfs_pipeline_ab(args)
 
     rng = np.random.default_rng(42)
     n = args.mb << 20
@@ -1354,14 +1283,6 @@ def main(argv: list[str] | None = None) -> int:
     d.add_argument("--datanodes", type=int, default=3)
     d.add_argument("--replication", type=int, default=2)
     d.add_argument("--schemes", default="direct,lz4,dedup_lz4")
-    d.add_argument("--pipeline-ab", action="store_true",
-                   help="paired multi-stream A/B: pipeline_depth=1 vs "
-                        "--depth; one JSON line with the median speedup")
-    d.add_argument("--streams", type=int, default=4)
-    d.add_argument("--rounds", type=int, default=5)
-    d.add_argument("--depth", type=int, default=4)
-    d.add_argument("--backend", default="native",
-                   help="DN in-process backend for --pipeline-ab")
     d.set_defaults(fn=bench_dfs)
     d = sub.add_parser("ec")
     d.add_argument("--mb", type=int, default=48)

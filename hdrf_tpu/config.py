@@ -114,32 +114,10 @@ class ReductionConfig:
     # image staging; through a slow D2H transport the host path is faster
     # (measured — PERF_NOTES.md).
     device_recon: bool = False
-    # Async multi-block write pipeline (server/write_pipeline.py).
-    # pipeline_depth: how many in-flight blocks one shared device batch may
-    # coalesce (the ResidentReducer submit_many group bound); 1 = today's
-    # serial one-block-at-a-time path, every pipeline stage bypassed.
-    pipeline_depth: int = 4
     # Bounded WAL group-commit window (ms): concurrent commit_block calls
     # arriving within the window share ONE fsync (index/chunk_index.py).
-    # Only armed when pipeline_depth > 1; 0 disables grouping outright.
+    # 0 disables grouping outright.
     group_commit_window_ms: float = 2.0
-    # Admission bound on blocks simultaneously inside the pipeline
-    # (admitted-but-uncommitted); backpressures client streams beyond it.
-    pipeline_max_inflight: int = 8
-    # Mesh-sharded reduction plane (parallel/sharded.MeshReducer): when
-    # True and >1 device is attached, coalesced groups run CDC+SHA+dedup
-    # probe as ONE dispatch per mesh step, blocks data-parallel over the
-    # whole mesh, with the device-resident sharded fingerprint bucket
-    # table answering the dedup probe on-mesh.  The single-device serial
-    # path stays verbatim as the bit-identity oracle.
-    mesh_plane: bool = False
-    # Per-device lane capacity: a mesh step coalesces up to
-    # n_devices * mesh_lanes_per_device blocks.
-    mesh_lanes_per_device: int = 2
-    # Bucket slots PER DEVICE in the sharded fingerprint table (u32 pairs;
-    # 2^15 slots = 256 KiB/device).  Collisions only cost a host re-check
-    # or a duplicate append — never correctness.
-    mesh_bucket_slots: int = 1 << 15
     # Coded mirror plane (server/mirror_plane.py): number of RS parity
     # segments cut over the reduced mirror payload.  0 = today's serial
     # relay through targets[0] (byte-identical path); m > 0 splits the
@@ -167,8 +145,8 @@ class ReductionConfig:
     # 0 decodes inline on the reader's thread (today's serial behavior).
     read_batch_window_ms: float = 2.0
     # Admission bound on plans simultaneously inside the read plane's
-    # fetch stage (the read-side sibling of pipeline_max_inflight; the
-    # DN-level max_concurrent_reads gate still applies outside it).
+    # fetch stage (the DN-level max_concurrent_reads gate still applies
+    # outside it).
     read_max_inflight: int = 16
     # Per-tenant QoS admission (utils/qos.py): token-bucket refill rate in
     # MB/s and burst depth in MB, per tenant, shared across the DN's write

@@ -121,7 +121,6 @@ class ChunkIndex:
         self._ops_since_ckpt = 0
         self._checkpoint_every = checkpoint_every
         # group-commit window: 0 = every commit_block fsyncs on its own
-        # (the serial pipeline_depth=1 behavior)
         self._group_window_s = group_window_s
         self._group_max = max(group_max, 1)
         self._gc_cv = threading.Condition()

@@ -84,21 +84,12 @@ def fingerprints(data: bytes | np.ndarray, cuts: np.ndarray,
 
 _resident_cache: dict = {}
 _mesh_cache: list = []
-_mesh_plane: list = []
 _mesh_plane_mesh_cache: list = []
-_mesh_reducer_cache: dict = {}
-
-
-def set_mesh_plane(flag: bool) -> None:
-    """Process-wide switch for the mesh-sharded reduction plane
-    (parallel/sharded.MeshReducer).  Set by the datanode from
-    ReductionConfig.mesh_plane; default falls back to HDRF_MESH_PLANE=1."""
-    _mesh_plane[:] = [bool(flag)]
 
 
 def mesh_plane_enabled() -> bool:
-    if _mesh_plane:
-        return _mesh_plane[0]
+    """Mesh-sharded batched lz4 seals (``block_compress_batch``):
+    ``HDRF_MESH_PLANE=1``."""
     import os
 
     return os.environ.get("HDRF_MESH_PLANE", "") == "1"
@@ -119,27 +110,6 @@ def _mesh_plane_mesh():
             make_mesh(n_data=len(devs), n_seq=1, devices=devs)
             if len(devs) > 1 else None)
     return _mesh_plane_mesh_cache[0]
-
-
-def mesh_reducer(cdc: CdcConfig, lanes_per_device: int = 2,
-                 bucket_slots: int = 1 << 15):
-    """Shared parallel/sharded.MeshReducer for this CDC geometry, or None
-    when fewer than 2 devices are attached.  Shared (not per-pipeline) so
-    the device bucket table sees every ChunkIndex commit exactly once and
-    the jitted mesh-step programs are built once per geometry."""
-    mesh = _mesh_plane_mesh()
-    if mesh is None:
-        return None
-    key = (cdc.mask_bits, cdc.min_chunk, cdc.max_chunk,
-           int(lanes_per_device), int(bucket_slots))
-    r = _mesh_reducer_cache.get(key)
-    if r is None:
-        from hdrf_tpu.parallel.sharded import MeshReducer
-
-        r = _mesh_reducer_cache[key] = MeshReducer(
-            cdc, mesh=mesh, lanes_per_device=lanes_per_device,
-            bucket_slots=bucket_slots)
-    return r
 
 
 def _multichip_mesh():

@@ -819,7 +819,7 @@ class MeshJob:
 class MeshReducer:
     """Mesh-sharded group-reduction front end: the multi-chip counterpart
     of ops.resident.ResidentReducer's batched pipeline (same submit /
-    start / finish shape, so server/write_pipeline.py drives either).
+    start / finish shape).
 
     ``finish_many`` returns per block ``(cuts u64, digests u8[nc, 32],
     probe frozenset)`` — the extra third element is the set of chunk

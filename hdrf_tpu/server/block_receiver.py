@@ -28,15 +28,14 @@ Every ingest path opens a utils/profiler.py BlockTimeline and attributes its
 wall time to named phases (``recv``/``checksum``/``container_io``/
 ``mirror_stream``/``ack`` here; ``dedup_lookup``/``wal_commit`` land from
 reduction/dedup.py and index/chunk_index.py; ``device_wait`` from the device
-ledger) — the decomposition the gap-attribution report and ROADMAP item 1's
-pipeline refactor are measured by.
+ledger) — the decomposition the gap-attribution report and perfbench's
+``dn.*_pct`` metrics read.  Everything of one block — recv, acks, CRCs,
+reduction hand-off, commit — runs on its connection's thread.
 """
 
 from __future__ import annotations
 
-import queue
 import socket
-import threading
 import time
 from typing import TYPE_CHECKING
 
@@ -262,16 +261,20 @@ class BlockReceiver:
         On the wire nothing moved: one ack a packet, in order, none before
         its packet's verify and fault point, the last after the commit.
 
-        With a co-located reduction worker configured, packets are
+        The stream then goes one of two ways.  With a co-located reduction
+        worker configured (and a container-codec scheme), packets are
         FORWARDED to the worker as they arrive (client -> DN -> worker ->
         HBM is one pipeline; the worker stages bytes to device mid-stream)
-        and only (cuts, digests) come back; otherwise the block reduces
-        in-process from its buffer (bf1 analog).  Each packet goes on with
-        the CRC32C the client sent for it, verified here before the ack:
+        and only (cuts, digests) come back.  Each packet goes on with the
+        CRC32C the client sent for it, verified here before the ack:
         ``reduce_stream`` carries it to the worker (one frame per 4 MiB
         stride, the segments views of the block's buffer) instead of
         summing the bytes a second time, so the worker checks what it
-        uploads against the client's own sum.
+        uploads against the client's own sum.  With no worker, or when the
+        worker just failed mid-block (degraded write), this thread drains
+        what is left of the stream into the buffer (bf1 analog) and the
+        block reduces in-process through
+        ``dispatch.chunk_and_fingerprint``.
 
         Memory honesty (r3 verdict weak #7): even on the worker path the
         DN ALSO holds the block host-side — container appends need the
@@ -335,17 +338,8 @@ class BlockReceiver:
 
             precomputed = None
             worker_down = False
-            crcs = None
-            use_worker = (dn.reduction_ctx.worker is not None
-                          and getattr(scheme, "container_codec", None)
-                          is not None)
-            # multi-block pipeline (pipeline_depth > 1): acks + CRC move to
-            # a pump thread, the device dispatch to the shared coalescer
-            pipelined = (not use_worker
-                         and dn.write_pipeline is not None
-                         and getattr(scheme, "container_codec", None)
-                         is not None)
-            if use_worker:
+            if (dn.reduction_ctx.worker is not None
+                    and getattr(scheme, "container_codec", None) is not None):
                 from hdrf_tpu.server.reduction_worker import WorkerError
 
                 try:
@@ -365,12 +359,9 @@ class BlockReceiver:
                                  trace=tracing.current_context(),
                                  error=f"{type(e).__name__}: {e}")
                     worker_down = True
-                    for _ in stream():
-                        pass
-            elif pipelined:
-                crcs, precomputed = self._drain_pipelined(
-                    sock, tl, block_id, runs, out)
-            else:
+            if precomputed is None:
+                # no worker, or it failed: what is left of the stream
+                # lands in ``out`` on this thread
                 for _ in stream():
                     pass
             self._block_cap = max(self._block_cap, out.size)
@@ -399,7 +390,7 @@ class BlockReceiver:
                 sp.annotate("scheme", scheme_name)
                 status = self._store_and_mirror(
                     block_id, gen_stamp, scheme_name, data, targets,
-                    precomputed=precomputed, crcs=crcs)
+                    precomputed=precomputed)
             with profiler.phase("ack"):
                 dt.send_ack(sock, last_seqno[0], status)
             if tenant is not None:
@@ -439,101 +430,24 @@ class BlockReceiver:
             _M.incr("recv_packets", packets)
             _M.incr("recv_runs", n_runs)
 
-    def _drain_pipelined(self, sock: socket.socket, tl, block_id: int,
-                         runs, out: dt.BlockBuffer):
-        """Pipelined ingest (``pipeline_depth`` > 1, no co-located worker).
-
-        Two moves off the connection thread's critical path:
-
-        - flow-control acks and incremental CRC run on a per-connection
-          pump thread bound to this block's timeline (the inline ``ack``
-          slice was 5.1% of smoke wall; the CRC now overlaps the client-
-          stream ``recv`` waits — the transport-hiding PERF_NOTES round 4
-          says is the only host overlap available);
-        - the fully-buffered block goes to the DN's shared WritePipeline;
-          its device dispatch is ENQUEUED before the pump join below, so
-          block K+1's device work is in flight while block K's host
-          commit runs on its own connection thread.
-
-        The pump is the sole socket writer until joined; the caller sends
-        the final ack only after this returns.  Returns
-        ``(crcs, (cuts, digests))``; the block is in ``out``."""
-        dn = self._dn
-        pump_q: queue.Queue = queue.Queue()
-        crcs: list[int] = []
-        pump_err: list[BaseException] = []
-
-        def _pump():
-            done = end = 0      # bytes of ``out`` summed / landed
-            cchunk = dn.checksum_chunk
-            with profiler.bind_timeline(tl):
-                while True:
-                    item = pump_q.get()
-                    if item is None:
-                        break
-                    if pump_err:
-                        continue  # drain so the recv loop never blocks
-                    acks, end = item
-                    try:
-                        if acks:
-                            with profiler.phase("ack"):
-                                sock.sendall(acks)
-                        whole = (end - done) // cchunk * cchunk
-                        if whole:
-                            with profiler.phase("checksum"):
-                                crcs.extend(_checksums(
-                                    out.view(done, done + whole), cchunk))
-                            done += whole
-                    except BaseException as e:  # noqa: BLE001 — re-raised
-                        pump_err.append(e)
-                if not pump_err and end > done:
-                    with profiler.phase("checksum"):
-                        crcs.append(int(native.crc32c(out.view(done, end))))
-
-        with profiler.phase("pipeline_submit"):  # thread spawn is host work
-            pump = threading.Thread(target=_pump, name="recv-pump",
-                                    daemon=True)
-            pump.start()
-        try:
-            for run, acks in runs:
-                # hand acks + CRC to the pump: the bytes are in ``out``
-                # already, on this thread, whatever the pump does
-                pump_q.put((acks, run.end))
-        finally:
-            pump_q.put(None)  # pump exits even if the client stream died
-        tl.nbytes = out.size
-        with profiler.phase("pipeline_submit"):
-            fut = dn.write_pipeline.submit(block_id, out.arr[:out.size], tl)
-        # residual pump work (tail CRC chunks) runs under the dispatch just
-        # enqueued; the join wait is checksum time from this thread's view
-        with profiler.phase("checksum"):
-            pump.join()
-        if pump_err:
-            raise pump_err[0]
-        return crcs, fut.result()
-
     def _store_and_mirror(self, block_id: int, gen_stamp: int, scheme_name: str,
                           data: bytes, targets: list,
-                          precomputed=None, crcs=None) -> int:
+                          precomputed=None) -> int:
         dn = self._dn
         scheme = dn.scheme(scheme_name)
-        if crcs is None:
-            with profiler.phase("checksum"):
-                crcs = _checksums(data, dn.checksum_chunk)
+        with profiler.phase("checksum"):
+            crcs = _checksums(data, dn.checksum_chunk)
         with metrics.registry("datanode").time("reduce_us"):
             # no host phase around reduce itself: the native path records
             # "reduce_compute" at the dispatch choke point, the worker path
             # records "device_wait" at its final drain, and the in-process
             # jax path is attributed by the device ledger
             if precomputed is not None:
-                # (cuts, digests) from the worker/pipeline path; the mesh
-                # plane adds a third element — the on-device dedup-probe
-                # verdict set that lets dedup_commit skip the host index
-                # walk for probe-negative chunks.
-                cuts, digs, *rest = precomputed
+                # (cuts, digests) from the worker, or computed here after
+                # the worker failed
+                cuts, digs = precomputed
                 stored = scheme.reduce_with(block_id, data, cuts, digs,
-                                            dn.reduction_ctx,
-                                            probe=rest[0] if rest else None)
+                                            dn.reduction_ctx)
             else:
                 stored = scheme.reduce(block_id, data, dn.reduction_ctx)
         with profiler.phase("container_io"):

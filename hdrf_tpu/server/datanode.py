@@ -228,13 +228,11 @@ class DataNode:
         else:
             self.replicas = self.volumes
         self.containers = self.volumes.containers
-        # WAL group-commit window: armed only when the multi-block pipeline
-        # is on (depth > 1) — serial writes would just pay the window wait
+        # WAL group-commit window: concurrent blocks' commits arriving
+        # within it share one fsync (0 disables grouping)
         self.index = ChunkIndex(
             os.path.join(config.data_dir, "index"),
-            group_window_s=(red.group_commit_window_ms / 1000.0
-                            if red.pipeline_depth > 1 else 0.0),
-            group_max=red.pipeline_max_inflight)
+            group_window_s=red.group_commit_window_ms / 1000.0)
         recon = None
         if red.device_recon and backend == "tpu" and self._worker is None:
             from hdrf_tpu.ops.reconstruct import DeviceReconstructor
@@ -279,33 +277,9 @@ class DataNode:
         from hdrf_tpu.server.coded_exchange import CodedExchange
 
         self.coded = CodedExchange(self)
-        # Multi-block write pipeline (server/write_pipeline.py): shared
-        # device batches + overlap scheduling when depth > 1; None keeps
-        # the one-block-at-a-time serial path exactly as before.
-        self.write_pipeline = None
-        # Mesh-sharded reduction plane (parallel/sharded.py): flips the
-        # dispatch-layer routing (batched lz4 seals included) and arms the
-        # coalescer's MeshReducer below.
-        ops_dispatch.set_mesh_plane(red.mesh_plane)
-        if red.pipeline_depth > 1:
-            from hdrf_tpu.server.write_pipeline import WritePipeline
-
-            self.write_pipeline = WritePipeline(
-                red.cdc, backend, depth=red.pipeline_depth,
-                max_inflight=red.pipeline_max_inflight,
-                mesh_plane=red.mesh_plane,
-                mesh_lanes=red.mesh_lanes_per_device,
-                mesh_bucket_slots=red.mesh_bucket_slots,
-                qos_ctrl=self.qos)
-            if self.write_pipeline.mesh_reducer is not None:
-                # the device bucket table tracks the authoritative index
-                # incrementally: every commit's first-seen fingerprints
-                # flow into the next mesh step's refresh dispatch
-                self.index.add_commit_listener(
-                    self.write_pipeline.mesh_reducer.table.note_new)
-            # seal compression off the commit critical path too: an
-            # unlucky rollover must not stall the blocks queued behind it
-            self.containers.enable_async_seals()
+        # seal compression off the commit critical path: an unlucky
+        # rollover must not stall the blocks queued behind it
+        self.containers.enable_async_seals()
         # Content-adaptive chunk sizing (reduction/accounting.py
         # AdaptiveChunkController): the heartbeat tick feeds it the dedup
         # hit/miss counters; the steps it emits are applied through
@@ -535,12 +509,9 @@ class DataNode:
         self._sever_connections()
         for t in self._threads:
             t.join(timeout=5)
-        if self.write_pipeline is not None:
-            self.write_pipeline.close()   # before flush: no new dispatches
         self.read_plane.close()           # drain the coalescer's worker
         self.containers.flush_open(on_seal=self.index.seal_container)
-        if hasattr(self.containers, "close_async_seals"):
-            self.containers.close_async_seals()
+        self.containers.close_async_seals()
         self.index.close()
         if self._worker_supervisor is not None:
             self._worker_supervisor.stop()

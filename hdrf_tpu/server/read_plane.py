@@ -17,10 +17,10 @@ dedup reached, and a hit books zero decode bytes in the read-amplification
 ledger (reduction/accounting.py:118 record_container_decode never fires) —
 the compounding win ROADMAP item 1 chases.
 
-The :class:`ReadCoalescer` re-applies server/write_pipeline.py's
-group-commit discipline (:149-226: bounded admission, drain-up-to-depth,
-lead-timeline binding with mirrored spans) to the read side: concurrent
-readers' container-decode misses group into ONE
+The :class:`ReadCoalescer` is a group-commit discipline for reads (bounded
+admission, a short window, drain up to ``depth`` requests, lead-timeline
+binding with mirrored spans): concurrent readers' container-decode misses
+group into ONE
 ``ops/dispatch.block_decompress_batch`` call per window, so a container
 wanted by N readers decodes once and the per-call dispatch overhead
 amortizes across the group.  LZ4 decode itself is byte-serial host work by
@@ -235,14 +235,12 @@ class _Req:
 
 
 class ReadCoalescer:
-    """Bounded batching of container-decode misses (write_pipeline.py's
-    coalescer + group-commit window, applied to reads): concurrent
-    readers' misses that land within one ``read_batch_window_ms`` window
-    decode through ONE grouped ``block_decompress_batch`` dispatch, each
-    distinct container once.  Admission is bounded by the
-    ``read_max_inflight`` semaphore (the same bounded-slots discipline as
-    pipeline_max_inflight).  ``batched=False`` (depth 1 / non-TPU backend)
-    decodes inline on the caller's thread."""
+    """Bounded batching of container-decode misses: concurrent readers'
+    misses that land within one ``read_batch_window_ms`` window decode
+    through ONE grouped ``block_decompress_batch`` dispatch on the
+    coalescer's thread, each distinct container once.  Admission is
+    bounded by the ``read_max_inflight`` semaphore.  ``batched=False``
+    (depth 1 / non-TPU backend) decodes inline on the caller's thread."""
 
     def __init__(self, containers, window_ms: float = 2.0,
                  max_inflight: int = 16, depth: int = 8,
@@ -336,8 +334,7 @@ class ReadCoalescer:
         t0 = profiler.mark()
         try:
             # the lead reader's timeline is ambient for the real decode
-            # spans; the shared window is mirrored to the rest below — the
-            # same attribution contract as write_pipeline's device batches
+            # spans; the shared window is mirrored to the rest below
             with profiler.bind_timeline(lead), \
                     profiler.phase("container_decode"):
                 datas = self._containers.read_containers(

@@ -8,6 +8,7 @@ it as BlockReceiver.java:877-897 does, from whatever pieces arrive)."""
 from __future__ import annotations
 
 import itertools
+import socket
 from typing import Iterable
 
 from hdrf_tpu import native
@@ -37,3 +38,22 @@ class PiecedSocket:
         self._off += take
         self.calls += 1
         return take
+
+
+def open_write_block(mc, path: str, tenant: str | None = "raw",
+                     encrypted: bool = False) -> tuple[socket.socket, int]:
+    """A client's ``dedup_lz4`` WRITE_BLOCK op on a raw socket to a
+    MiniCluster's first DataNode, the file created and its block allocated
+    at the NameNode first: ``(socket, block id)``.  ``tenant`` is the op's
+    ``_client`` field; ``None`` sends none, as an internal relay does."""
+    nn = mc.namenode
+    nn.rpc_create(path, client="raw", scheme="dedup_lz4")
+    alloc = nn.rpc_add_block(path, client="raw")
+    s = socket.create_connection(mc.datanodes[0].addr, timeout=20)
+    s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+    s = dt.secure_socket(s, alloc.get("token"), encrypted)
+    fields = {} if tenant is None else {"_client": tenant}
+    dt.send_op(s, dt.WRITE_BLOCK, block_id=alloc["block_id"],
+               gen_stamp=alloc["gen_stamp"], scheme="dedup_lz4",
+               token=alloc.get("token"), targets=[], **fields)
+    return s, alloc["block_id"]

@@ -79,7 +79,7 @@ CLASSES = ("host_busy", "device_busy", "transport_wait", "idle")
 PHASE_CLASS = {
     "recv": TRANSPORT, "mirror_stream": TRANSPORT, "ack": TRANSPORT,
     "dedup_lookup": HOST, "wal_commit": HOST, "container_io": HOST,
-    "reduce_compute": HOST, "checksum": HOST, "pipeline_submit": HOST,
+    "reduce_compute": HOST, "checksum": HOST,
     "device_wait": DEVICE,
     # Read-path phases (server/block_sender.py serve_read/read_logical):
     # index/cache/decode burn the single vCPU; stripe gathers and the
@@ -127,8 +127,7 @@ PHASE_CLASS = {
 # container_decode window) resolve to the innermost by listing it first.
 PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
                "wal_commit", "container_io", "dedup_lookup",
-               "reduce_compute", "packet_verify", "checksum",
-               "pipeline_submit", "seal_write",
+               "reduce_compute", "packet_verify", "checksum", "seal_write",
                "stage_h2d", "select", "emit",
                "index_lookup", "cache_probe", "container_decode",
                # RPC phases: lock_wait/locked win attribution inside the
@@ -432,13 +431,12 @@ def current_timeline() -> BlockTimeline | None:
 def bind_timeline(tl: BlockTimeline | None) -> Iterator[BlockTimeline | None]:
     """Adopt an EXISTING timeline as this thread's ambient one.
 
-    Contextvars do not propagate into worker threads, so the write
-    pipeline's helper threads (the ack/checksum pump, the device-batch
-    coalescer — server/write_pipeline.py) would otherwise record their
-    spans ring-only and the per-block overlap accountant would never see
-    the work they hid.  Binding does NOT finish the timeline or touch the
-    inflight counter — ownership stays with the opening
-    :func:`block_timeline` frame."""
+    Contextvars do not propagate into worker threads, so a helper thread
+    doing one block's work (the read coalescer's batched decode —
+    server/read_plane.py) would otherwise record its spans ring-only and
+    the per-block overlap accountant would never see the work it hid.
+    Binding does NOT finish the timeline or touch the inflight counter —
+    ownership stays with the opening :func:`block_timeline` frame."""
     tok = _current.set(tl)
     try:
         yield tl

@@ -30,9 +30,9 @@ Three cooperating pieces:
 - :class:`FairQueue` — a queue.Queue-compatible weighted-fair dequeue
   (put / get / get_nowait, queue.Empty, ``None`` close sentinel) whose
   per-tenant lanes drain round-robin (FairCallQueue.java:214
-  ``MultiplexedProcessor``), so the coalescer queues in
-  server/write_pipeline.py and server/read_plane.py serve a light tenant's
-  items interleaved with — not behind — a flood.
+  ``MultiplexedProcessor``), so the read coalescer's queue
+  (server/read_plane.py) serves a light tenant's items interleaved with —
+  not behind — a flood.
 
 The ambient-tenant contextvar (``bind_tenant`` / ``current_tenant``)
 threads attribution through call stacks that cannot carry a parameter

@@ -41,21 +41,6 @@ def test_dfs_throughput():
     assert len(out) == 2 and all(o["write_MBps"] > 0 for o in out)
 
 
-def test_dfs_pipeline_ab_one_json_line():
-    """`benchmarks dfs --pipeline-ab` contract: EXACTLY one JSON line with
-    the paired depth-1 vs depth-N multi-stream rates and their median
-    ratio (the ISSUE 7 acceptance shape).  Tiny corpus, one round — this
-    asserts the protocol and line shape, not the speedup bar."""
-    out = run(["dfs", "--pipeline-ab", "--mb", "1", "--streams", "2",
-               "--rounds", "1", "--depth", "4"])
-    assert len(out) == 1
-    (o,) = out
-    assert o["op"].startswith("dfs write pipeline A/B")
-    assert o["streams"] == 2 and o["depth"] == 4
-    assert o["depth1_MBps"] > 0 and o["depthN_MBps"] > 0
-    assert o["speedup"] > 0
-
-
 def test_ec_throughput():
     # PR 8 contract: the ec harness prints ONE JSON line — the paired
     # encode/intact/degraded slope report, oracle-pinned before timing

@@ -1,5 +1,7 @@
 """Config system tests (replaces reference's hardcoded DataNode.java:412-458 statics)."""
 
+import pytest
+
 from hdrf_tpu.config import HdrfConfig
 
 
@@ -44,10 +46,16 @@ def test_type_coercion():
     assert cfg.namenode.heartbeat_interval_s == 2.0
 
 
-def test_unknown_key():
+@pytest.mark.parametrize("keys", [
+    ["nope.nothing"],
+    # the write pipeline's options, removed with it (PR 29): a deployment
+    # file that still sets one is told so, not silently obeyed
+    ["datanode.reduction." + k for k in (
+        "pipeline_depth", "pipeline_max_inflight", "mesh_plane",
+        "mesh_lanes_per_device", "mesh_bucket_slots")],
+], ids=["no-such-section", "removed-write-pipeline-options"])
+def test_unknown_key(keys):
     cfg = HdrfConfig()
-    try:
-        cfg.set("nope.nothing", 1)
-        assert False
-    except KeyError:
-        pass
+    for key in keys:
+        with pytest.raises(KeyError, match="unknown config key"):
+            cfg.set(key, 1)

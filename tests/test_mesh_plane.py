@@ -12,8 +12,8 @@ round-trips in the probe), stale-bucket safety (false positive resolved
 by the authoritative index re-check, false negative degrades to a
 compactable duplicate append — never corruption; the
 "sharded.bucket_refresh" fault point re-queues on failure), the
-ContainerStore true-LRU decode cache, and the write-pipeline mixed-size
-coalescer (server/write_pipeline.py:_pad_bucket).
+ContainerStore true-LRU decode cache, and a mesh step over blocks of
+mixed sizes.
 """
 
 import numpy as np
@@ -275,44 +275,6 @@ class TestContainerCacheLru:
 
 
 class TestMixedSizeCoalescer:
-    def test_pad_bucket_steps(self):
-        from hdrf_tpu.server.write_pipeline import WritePipeline
-
-        pb = WritePipeline._pad_bucket
-        assert pb(1) == pb(4096) == 4096         # floor bucket
-        for n in (5000, 70_000, 1 << 20, (1 << 20) + 1, 3_000_000):
-            b = pb(n)
-            top = 1 << (n - 1).bit_length()
-            assert b >= n                        # never truncates
-            assert b - n < max(top // 8, 4096)   # bounded padding
-            assert b % 4096 == 0
-
-    def test_group_buckets_by_lane_size_and_counts_padding(self):
-        """Mixed-size submissions coalesce within a lane-size bucket (one
-        device program per group, padded to the longest member) instead
-        of one group per distinct size; the wasted bytes are surfaced as
-        coalesce_pad_bytes."""
-        from concurrent.futures import Future
-
-        from hdrf_tpu.server.write_pipeline import WritePipeline, _Item
-
-        class _FakeReducer:
-            def max_group(self, n: int = 0) -> int:
-                return 8
-
-        wp = WritePipeline.__new__(WritePipeline)   # grouping only
-        wp._depth = 8
-        sizes = [10_000, 11_000, 12_000, 40_000]    # 3 share bucket 12288
-        items = [_Item(i, np.zeros(s, np.uint8), None, Future())
-                 for i, s in enumerate(sizes)]
-        m0 = metrics.registry("write_pipeline").counter("coalesce_pad_bytes")
-        groups = wp._group(_FakeReducer(), items)
-        by_len = sorted(len(g) for g in groups)
-        assert by_len == [1, 3]                      # bucketed, not per-size
-        pad = metrics.registry("write_pipeline").counter(
-            "coalesce_pad_bytes") - m0
-        assert pad == (12_000 - 10_000) + (12_000 - 11_000)
-
     def test_mesh_reducer_handles_mixed_size_group(self):
         """One mesh step over blocks of different lengths: per-block
         true_n drives cut selection, so padding to the group max never
@@ -325,40 +287,3 @@ class TestMixedSizeCoalescer:
             ref_cuts, ref_digs = _oracle(blk, 0x3FF, 256, 4096)
             np.testing.assert_array_equal(cuts, ref_cuts)
             np.testing.assert_array_equal(digs, ref_digs)
-
-
-class TestWritePipelineMeshPlane:
-    def test_pipeline_routes_groups_through_mesh(self):
-        """The product wiring (ReductionConfig.mesh_plane -> WritePipeline
-        mesh_reducer): submitted blocks resolve (cuts, digests, probe)
-        3-tuples computed by ONE sharded.step dispatch per coalesced
-        group, and the mesh_batches counters tick."""
-        from hdrf_tpu.server.write_pipeline import WritePipeline
-
-        cdc = CdcConfig(mask_bits=10, min_chunk=256, max_chunk=4096)
-        wp = WritePipeline(cdc, "tpu", depth=4, mesh_plane=True,
-                           mesh_lanes=1)
-        assert wp.mesh_reducer is not None, "8-device mesh must engage"
-        try:
-            rng = np.random.default_rng(23)
-            blocks = [rng.integers(0, 256, 16_000, np.uint8)
-                      for _ in range(8)]
-            wp.submit(900, blocks[0]).result(120)   # warm compile
-            id0 = _last_id()
-            m0 = metrics.registry("write_pipeline").counter("mesh_batches")
-            futs = [wp.submit(1000 + i, b) for i, b in enumerate(blocks)]
-            for blk, fut in zip(blocks, futs):
-                cuts, digs, probe = fut.result(120)
-                ref_cuts, ref_digs = _oracle(blk, wp.mesh_reducer.mask,
-                                             256, 4096)
-                np.testing.assert_array_equal(cuts, ref_cuts)
-                np.testing.assert_array_equal(digs, ref_digs)
-                assert probe == frozenset()
-            enq = [e for e in _enqueues_after(id0)
-                   if e["op"] == "sharded.step"]
-            assert 1 <= len(enq) <= len(blocks) // \
-                wp.mesh_reducer.ndata + 1   # coalesced, not per-block
-            assert metrics.registry("write_pipeline").counter(
-                "mesh_batches") > m0
-        finally:
-            wp.close()

@@ -330,7 +330,7 @@ class TestGapReport:
         prof = _golden_timelines()[0]["profile"]
         bench_line = {"metric": "x", "value": 1.0, "unit": "MB/s",
                       "phase_profile": prof,
-                      "pipeline": {"depth": 4, "group_commit_batches": 2,
+                      "pipeline": {"group_commit_batches": 2,
                                    "overlap_efficiency":
                                        prof["overlap_efficiency"]}}
         import io
@@ -376,25 +376,21 @@ class TestE2E:
         assert agg["overlap_efficiency"] > 0.0, agg
         assert agg["hidden_wait_s"] > 0.0
 
-    def test_pipeline_enqueues_next_block_under_container_io(self):
-        """Overlap-scheduling contract, pinned deterministically: while
-        block K is parked inside its container append (the
-        ``dedup.container_append`` fault point), block K+1's write runs to
-        completion — so K+1's device prep dispatch (a ledger ``enqueue``
-        ring event) lands BEFORE K's container_io finishes."""
+    def test_next_block_reduces_under_a_parked_container_append(self):
+        """Overlap contract on the served route, pinned deterministically:
+        while block K is parked inside its container append (the
+        ``dedup.container_append`` fault point), block K+1's write — its
+        hop to the reduction worker, its commit, its last ack — runs to
+        completion on its own connection thread, so its
+        ``worker_reduces`` is counted BEFORE K's container_io finishes."""
         import random
         import threading
 
+        from hdrf_tpu.server.reduction_worker import ReductionWorker
         from hdrf_tpu.testing.minicluster import MiniCluster
-        from hdrf_tpu.utils import fault_injection
+        from hdrf_tpu.utils import fault_injection, metrics
 
-        def prep_enqueues() -> int:
-            return sum(1 for e in device_ledger.events_snapshot()
-                       if e["kind"] == "enqueue"
-                       and e["op"] in ("resident.prep_batch",
-                                       "resident.cdc_fused"))
-
-        profiler.reset()
+        br = metrics.registry("block_receiver")
         parked = threading.Event()
         release = threading.Event()
         seen: dict = {}
@@ -405,30 +401,45 @@ class TestE2E:
                 if "first" in seen:
                     return  # only block K parks; K+1 sails through
                 seen["first"] = block_id
-                seen["enqueues_before"] = prep_enqueues()
+                seen["reduces_before"] = br.counter("worker_reduces")
             parked.set()
             release.wait(30)
-            # still inside K's container_io phase: count K+1's dispatches
-            seen["enqueues_during"] = prep_enqueues()
+            # still inside K's container_io phase: K+1 has come and gone
+            seen["reduces_during"] = br.counter("worker_reduces")
+            seen["k1_done"] = k1_done.is_set()
 
+        k1_done = threading.Event()
         pay_k = random.Random(11).randbytes(1 << 20)
         pay_k1 = random.Random(12).randbytes(1 << 20)
-        with MiniCluster(n_datanodes=1, replication=1,
-                         block_size=1 << 20, backend="tpu") as mc:
-            def write_k():
-                with mc.client("k") as c:
-                    c.write("/ov/k", pay_k, scheme="dedup")
+        w = ReductionWorker(backend="native").start()
+        try:
+            with MiniCluster(n_datanodes=1, replication=1,
+                             block_size=1 << 20,
+                             reduction_overrides={
+                                 "worker_addr": list(w.addr)}) as mc:
+                def write_k():
+                    with mc.client("k") as c:
+                        c.write("/ov/k", pay_k, scheme="dedup")
 
-            with fault_injection.inject("dedup.container_append", park):
-                t = threading.Thread(target=write_k)
-                t.start()
-                assert parked.wait(30), "block K never reached its append"
-                with mc.client("k1") as c2:   # runs while K is parked
-                    c2.write("/ov/k1", pay_k1, scheme="dedup")
-                release.set()
-                t.join(30)
-                assert not t.is_alive()
-        assert seen["enqueues_during"] > seen["enqueues_before"], seen
+                with fault_injection.inject("dedup.container_append", park):
+                    t = threading.Thread(target=write_k)
+                    t.start()
+                    assert parked.wait(30), "block K never reached its append"
+                    with mc.client("k1") as c2:   # runs while K is parked
+                        c2.write("/ov/k1", pay_k1, scheme="dedup")
+                    k1_done.set()
+                    release.set()
+                    t.join(30)
+                    assert not t.is_alive()
+                with mc.client("rd") as c3:
+                    assert c3.read("/ov/k") == pay_k
+                    assert c3.read("/ov/k1") == pay_k1
+        finally:
+            w.stop()
+        # K's own reduce was counted before it parked; K+1's landed while
+        # K was still inside its append
+        assert seen["k1_done"], seen
+        assert seen["reduces_during"] == seen["reduces_before"] + 1, seen
 
     def test_minicluster_tpu_backend_links_ledger(self):
         """A write through the jax reduction path (virtual-device mesh)

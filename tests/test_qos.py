@@ -4,7 +4,7 @@ deadline-aware load shedding, and k+δ straggler-proof EC stripe reads.
 
 Covers utils/qos.py (TenantBucket deficit math, AdmissionController
 bucket/deadline sheds, FairQueue round-robin + close-sentinel contract),
-the admission wiring through server/write_pipeline.py and
+the admission wiring through server/block_receiver.py and
 server/read_plane.py (including the semaphore permit-leak regressions),
 the ShedError wire round-trip (proto/datatransfer.py ACK_SHED, error
 frames), the noisy-neighbor acceptance matrix on a two-tenant
@@ -20,7 +20,6 @@ from queue import Empty
 import numpy as np
 import pytest
 
-from hdrf_tpu.config import CdcConfig
 from hdrf_tpu.utils import fault_injection, metrics, qos, retry
 
 _QOS = metrics.registry("qos")
@@ -211,47 +210,63 @@ class TestPermitLeaks:
         ctrl.charge("hog", "write", 1 << 40)  # bucket never recovers
         return ctrl
 
-    def test_write_pipeline_sheds_leak_no_permits(self):
-        """100 shed admissions must not consume pipeline permits, and an
-        admitted tenant must still get through afterward (the flood
-        cannot starve the pipeline by leaking its semaphore)."""
-        from hdrf_tpu.server.write_pipeline import WritePipeline
+    @staticmethod
+    def _write_block(mc, path: str, tenant, payload: bytes) -> list:
+        """One WRITE_BLOCK op on a raw socket — ``payload`` in one packet
+        and the empty last one — and every ack the DataNode answered
+        before it hung up or the last ack came."""
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.testing.wire import open_write_block
 
-        ctrl = self._shedding_ctrl()
-        p = WritePipeline(CdcConfig(), "native", max_inflight=4,
-                          qos_ctrl=ctrl)
-        before = p._sem._value
-        data = np.zeros(1 << 12, dtype=np.uint8)
-        for _ in range(100):
-            with pytest.raises(qos.ShedError):
-                p.submit(1, data, tenant="hog")
-        assert p._sem._value == before
-        # an admitted tenant's submit still succeeds
-        fut = p.submit(2, data, tenant="light")
-        cuts, _digs = fut.result(timeout=30)[:2]
-        assert len(cuts) >= 1
-        assert p._sem._value == before
+        s, _bid = open_write_block(mc, path, tenant)
+        acks = []
+        with s:
+            dt.write_packet(s, 0, payload)
+            dt.write_packet(s, 1, b"", last=True)
+            try:
+                while len(acks) < 2:
+                    acks.append(dt.read_ack(s))
+            except (ConnectionError, OSError):
+                pass
+        return acks
 
-    def test_write_pipeline_queue_failure_releases_permit(self):
-        """A raise between permit acquire and enqueue (the audited
-        window) must hand the permit back through the future's done
-        callback — 100 failures leave the semaphore intact."""
-        from hdrf_tpu.server.write_pipeline import WritePipeline
+    @staticmethod
+    def _spend(dn, *tenants) -> None:
+        for t in tenants:
+            dn.qos.admit(t, "write")
+            dn.qos.charge(t, "write", 1 << 40)  # bucket never recovers
 
-        p = WritePipeline(CdcConfig(), "native", max_inflight=4)
-        p._thread = threading.current_thread()  # force the queue path
+    def test_shed_write_blocks_hold_no_write_slot(self):
+        """100 WRITE_BLOCK ops of an over-rate tenant are answered
+        ``ACK_SHED`` packet for packet, carry a retry-after hint, and
+        take no ``DataNode.write_slot()``: a light tenant's write then
+        completes and every slot is free again (the flood cannot starve
+        the gate that remains by leaking its semaphore)."""
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.testing.minicluster import MiniCluster
 
-        class _Boom:
-            def put(self, item):
-                raise RuntimeError("injected enqueue failure")
-
-        p._q = _Boom()
-        before = p._sem._value
-        data = np.zeros(1 << 10, dtype=np.uint8)
-        for _ in range(100):
-            with pytest.raises(RuntimeError):
-                p.submit(1, data)
-        assert p._sem._value == before
+        br = metrics.registry("block_receiver")
+        data = np.random.default_rng(29).integers(
+            0, 256, size=64 * 1024, dtype=np.uint8).tobytes()
+        with MiniCluster(n_datanodes=1, replication=1, block_size=1 << 20,
+                         reduction_overrides={
+                             "qos_tenant_rate_mb_s": 1.0,
+                             "qos_tenant_burst_mb": 1.0}) as mc:
+            dn = mc.datanodes[0]
+            self._spend(dn, "hog")
+            free = dn._write_sem._value
+            sheds0 = br.counter("write_sheds")
+            for i in range(100):
+                acks = self._write_block(mc, f"/shed/{i}", "hog", data)
+                assert [st for _, st in acks] == [dt.ACK_SHED] * 2
+                assert all(hint_ms > 0 for hint_ms, _ in acks)
+            assert dn.await_xceivers()
+            assert br.counter("write_sheds") - sheds0 == 100
+            assert dn._write_sem._value == free
+            with mc.client("light") as c:
+                c.write("/shed/light", data, scheme="dedup_lz4")
+                assert c.read("/shed/light") == data
+            assert dn._write_sem._value == free
 
     def test_read_coalescer_sheds_and_failures_leak_no_permits(self):
         from hdrf_tpu.server.read_plane import ReadCoalescer
@@ -273,18 +288,28 @@ class TestPermitLeaks:
                 rc.fetch([1], tenant="light")
         assert rc._sem._value == before
 
-    def test_unattributed_traffic_is_never_shed(self):
+    def test_unattributed_write_block_is_never_shed(self):
         """Internal relays (mirror ingest, scrub, EC fan-in) carry no
-        tenant and bypass admission — a tenant flood must not starve
-        housekeeping into unavailability."""
-        from hdrf_tpu.server.write_pipeline import WritePipeline
+        tenant and bypass admission — a WRITE_BLOCK with no ``_client``
+        commits though every bucket, the default lane's too, is spent."""
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.testing.minicluster import MiniCluster
 
-        ctrl = self._shedding_ctrl()
-        ctrl.charge("anon", "write", 1 << 40)  # even the default lane
-        p = WritePipeline(CdcConfig(), "native", qos_ctrl=ctrl)
-        data = np.zeros(1 << 10, dtype=np.uint8)
-        fut = p.submit(3, data, tenant=None)  # internal: no attribution
-        assert fut.result(timeout=30) is not None
+        br = metrics.registry("block_receiver")
+        with MiniCluster(n_datanodes=1, replication=1, block_size=1 << 20,
+                         reduction_overrides={
+                             "qos_tenant_rate_mb_s": 1.0,
+                             "qos_tenant_burst_mb": 1.0}) as mc:
+            dn = mc.datanodes[0]
+            self._spend(dn, "hog", "anon", "raw")
+            sheds0 = br.counter("write_sheds")
+            acks = self._write_block(mc, "/shed/internal", None,
+                                     b"relay" * 1000)
+            assert acks == [(0, dt.ACK_SUCCESS), (1, dt.ACK_SUCCESS)]
+            assert br.counter("write_sheds") == sheds0
+            # the same op under a spent tenant's name is refused
+            acks = self._write_block(mc, "/shed/named", "raw", b"x" * 5000)
+            assert [st for _, st in acks] == [dt.ACK_SHED] * 2
 
 
 # --------------------------------------------------- noisy neighbor e2e

@@ -480,10 +480,11 @@ class TestRunsOnTheWire:
     through each branch that consumes the runs and the encrypted socket."""
 
     BRANCHES = {
-        # pipeline_depth 4 (the default), no worker: _drain_pipelined
-        "pipelined": {},
-        "in-process": {"reduction_overrides": {"pipeline_depth": 1}},
+        "in-process": {},       # no worker: drained and reduced here
         "worker": {},           # worker_addr filled in by the fixture
+        # a worker_addr that refuses every connection, the breaker held
+        # shut: each block goes through the worker_down arm
+        "degraded": {},
         "encrypted": {"secure": True},
     }
     PKT = 4096
@@ -493,10 +494,18 @@ class TestRunsOnTheWire:
         from hdrf_tpu.server.reduction_worker import ReductionWorker
 
         kw = dict(self.BRANCHES[request.param])
-        w = None
+        w = refuser = None
         if request.param == "worker":
             w = ReductionWorker(backend="native").start()
             kw["reduction_overrides"] = {"worker_addr": list(w.addr)}
+        elif request.param == "degraded":
+            # bound and never listening: connects are refused, and the
+            # port is nobody else's while the class runs
+            refuser = socket.socket()
+            refuser.bind(("127.0.0.1", 0))
+            kw["reduction_overrides"] = {
+                "worker_addr": list(refuser.getsockname()),
+                "worker_breaker_failures": 100}
         try:
             with MiniCluster(n_datanodes=1, replication=1,
                              block_size=1 << 20, **kw) as mc:
@@ -504,22 +513,16 @@ class TestRunsOnTheWire:
         finally:
             if w is not None:
                 w.stop()
+            if refuser is not None:
+                refuser.close()
 
-    def _open(self, node, path):
+    @staticmethod
+    def _open(node, path):
         """A client's WRITE_BLOCK op on a raw socket: (socket, block id)."""
-        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.testing.wire import open_write_block
 
         branch, mc = node
-        nn = mc.namenode
-        nn.rpc_create(path, client="raw", scheme="dedup_lz4")
-        alloc = nn.rpc_add_block(path, client="raw")
-        s = socket.create_connection(mc.datanodes[0].addr, timeout=20)
-        s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-        s = dt.secure_socket(s, alloc.get("token"), branch == "encrypted")
-        dt.send_op(s, dt.WRITE_BLOCK, block_id=alloc["block_id"],
-                   gen_stamp=alloc["gen_stamp"], scheme="dedup_lz4",
-                   token=alloc.get("token"), targets=[], _client="raw")
-        return s, alloc["block_id"]
+        return open_write_block(mc, path, encrypted=branch == "encrypted")
 
     @staticmethod
     def _acks_until_closed(s) -> list:
@@ -549,8 +552,9 @@ class TestRunsOnTheWire:
             time.sleep(0.2)
             committed.append(time.monotonic())
 
+        before = (br.counter("recv_packets"), br.counter("recv_runs"),
+                  br.counter("degraded_writes"))
         s, bid = self._open(node, f"/runs/w{window}")
-        before = (br.counter("recv_packets"), br.counter("recv_runs"))
         try:
             with fault_injection.inject("dedup.container_append",
                                         slow_commit):
@@ -566,6 +570,9 @@ class TestRunsOnTheWire:
         assert got[0] == packets and 1 <= got[1] <= packets
         if window == 1:         # the DataNode never saw two at once
             assert got[1] == packets
+        # the refused worker, and no other branch, took the worker_down arm
+        assert (br.counter("degraded_writes") - before[2]
+                == (node[0] == "degraded"))
         dn = node[1].datanodes[0]
         assert dn.replicas.get_meta(bid).logical_len == len(data)
         assert bytes(dn._sender.read_logical(bid)) == data
@@ -576,8 +583,7 @@ class TestRunsOnTheWire:
         for the packets before ``k`` and for no other, then the DataNode
         hangs up, and nothing is stored.  (Nothing is sent after ``k``: a
         close over unread bytes is a reset, which may drop the acks on
-        their way.  Where a pump thread writes the acks they race the
-        close, so there only their order and bound are certain.)"""
+        their way.)"""
         from hdrf_tpu.proto import datatransfer as dt
         from hdrf_tpu.testing.wire import frame_packets
 
@@ -590,10 +596,7 @@ class TestRunsOnTheWire:
             acks = self._acks_until_closed(s)
         finally:
             s.close()
-        want = [(i, dt.ACK_SUCCESS) for i in range(k)]
-        assert acks == want[:len(acks)]
-        if node[0] in ("in-process", "worker"):
-            assert acks == want
+        assert acks == [(i, dt.ACK_SUCCESS) for i in range(k)]
         assert node[1].datanodes[0].replicas.get_meta(bid) is None
 
     def test_a_raising_fault_point_aborts_before_its_ack(self, node):
