@@ -19,13 +19,21 @@ Wire layout:
   pipelines the ack aggregates downstream status (worst wins), the analog of
   PipelineAck.
 - Stride:    ``[u32 nseg][u8 flags]`` + nseg x ``[u32 len][u32 crc32c]`` + the
-  segments back to back.  The upload leg of the DataNode -> reduction-worker
-  ``reduce`` op only (server/reduction_worker.py): one frame per device
-  upload stride instead of one per client packet.  A segment is a client
+  segments back to back.  The upload legs of the DataNode -> reduction-worker
+  ops only (server/reduction_worker.py).  ``reduce``: one frame per device
+  upload stride instead of one per client packet; a segment is a client
   packet carried with the CRC32C its producer computed for it (the DataNode
-  verified it before the ack and does not compute it again), so the receiver
-  checks every byte against its origin's sum, one native call a frame.
-  ``FLAG_LAST`` ends the stream; the last frame may hold no segment.
+  verified it before the ack and does not compute it again).  ``compress`` /
+  ``compress_batch``: a sealed container's bytes in frames of views of the
+  one buffer that holds them, segments of 1 MiB summed in one native call a
+  frame, landed by the worker in one buffer of the size the request states.
+  Either way the receiver checks every byte against its origin's sum, one
+  native call a frame.  ``FLAG_LAST`` ends the stream; the last frame may
+  hold no segment.
+- Raw reply: one msgpack frame (a header that states lengths) and the
+  payloads behind it as they are, no msgpack around them
+  (``send_with_payloads`` / ``recv_payload``): the worker's answer to a
+  compress op.  An error is a plain msgpack frame, as everywhere.
 
 Readers of the packet stream.  ``read_packet_crc`` and the iterators over
 it take a packet at a time: two ``recv``s, one CRC32C call and two copies
@@ -57,7 +65,8 @@ from typing import Any, Iterator, NamedTuple
 import numpy as np
 
 from hdrf_tpu import native
-from hdrf_tpu.proto.rpc import recv_exact, recv_frame, send_frame
+from hdrf_tpu.proto.rpc import (pack_frame, recv_exact, recv_frame,
+                                send_frame)
 from hdrf_tpu.utils import profiler, retry, tracing
 
 PKT_HDR = struct.Struct("<IQBI")
@@ -411,22 +420,37 @@ def write_stride(sock: socket.socket, segs: list, crcs: list[int],
     _sendmsg_all(sock, [hdr, *segs])
 
 
-def read_stride(sock: socket.socket
+def _recv_all_into(sock: socket.socket, buf) -> None:
+    """Fill ``buf`` (any writable buffer) from the socket, in place."""
+    view, got = memoryview(buf), 0
+    while got < len(view):
+        r = sock.recv_into(view[got:], len(view) - got, socket.MSG_WAITALL)
+        if r == 0:
+            raise ConnectionError("peer closed connection")
+        got += r
+
+
+def read_stride(sock: socket.socket, out: np.ndarray | None = None
                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, bool]:
     """Receive one stride frame, unverified: ``(buf, lens, crcs, last)``
     with the segments back to back in ``buf``, a fresh ``uint8`` array the
     caller may hand on as it is (a device upload may still be reading the
-    one before)."""
+    one before) — or, given ``out``, the front of that array: a caller who
+    knows the stream's size lands every frame in one buffer.  A frame
+    longer than ``out`` is an IOError before a byte of it is read: the
+    stream cannot go on, and whoever serves it hangs up."""
     nseg, flags = STRIDE_HDR.unpack(recv_exact(sock, STRIDE_HDR.size))
     table = np.frombuffer(recv_exact(sock, 8 * nseg), "<u4").reshape(-1, 2)
     lens, crcs = table[:, 0], table[:, 1]
-    buf = np.empty(int(lens.sum(dtype=np.int64)), np.uint8)
-    view, got = memoryview(buf), 0
-    while got < buf.size:
-        r = sock.recv_into(view[got:], buf.size - got, socket.MSG_WAITALL)
-        if r == 0:
-            raise ConnectionError("peer closed connection")
-        got += r
+    size = int(lens.sum(dtype=np.int64))
+    if out is None:
+        buf = np.empty(size, np.uint8)
+    elif size > out.size:
+        raise IOError(f"stride of {size} bytes: {out.size} left of the "
+                      "size the stream stated")
+    else:
+        buf = out[:size]
+    _recv_all_into(sock, buf)
     return buf, lens, crcs, bool(flags & FLAG_LAST)
 
 
@@ -450,3 +474,22 @@ def verify_stride(buf: np.ndarray, lens: np.ndarray,
     if not ok.all():
         raise ValueError(f"stride segment {int(np.argmin(ok))} of "
                          f"{len(lens)}: checksum mismatch")
+
+
+# -------------------------------------------------------------- raw replies
+
+
+def send_with_payloads(sock: socket.socket, header: Any,
+                       payloads: list) -> None:
+    """A msgpack frame and, behind it, ``payloads`` (bytes-likes whose
+    lengths the header states) as they are: one ``sendmsg``, nothing packed
+    or joined."""
+    _sendmsg_all(sock, [pack_frame(header), *payloads])
+
+
+def recv_payload(sock: socket.socket, n: int) -> bytearray:
+    """``n`` raw bytes into one buffer, handed on as it is (``recv_exact``
+    would copy it to ``bytes``)."""
+    buf = bytearray(n)
+    _recv_all_into(sock, buf)
+    return buf

@@ -76,11 +76,15 @@ def recv_exact(sock: socket.socket, n: int) -> bytes:
     return bytes(buf)
 
 
-def send_frame(sock: socket.socket, body: Any) -> None:
+def pack_frame(body: Any) -> bytes:
     payload = msgpack.packb(body)
     if len(payload) > MAX_FRAME:
         raise ValueError(f"frame too large: {len(payload)}")
-    sock.sendall(_LEN.pack(len(payload)) + payload)
+    return _LEN.pack(len(payload)) + payload
+
+
+def send_frame(sock: socket.socket, body: Any) -> None:
+    sock.sendall(pack_frame(body))
 
 
 def recv_frame(sock: socket.socket) -> Any:

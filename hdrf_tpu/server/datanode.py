@@ -180,7 +180,7 @@ class DataNode:
                 deadline_s_per_mb=red.worker_deadline_s_per_mb,
                 breaker=self._worker_breaker)
 
-            def _worker_seal(data: bytes) -> bytes:
+            def _worker_seal(data):
                 try:
                     return self._worker.compress("lz4", data)
                 except (WorkerError, retry.DeadlineExceeded):

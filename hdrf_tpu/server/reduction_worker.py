@@ -53,6 +53,11 @@ _TR = tracing.tracer("reduction_worker")
 # DataNode sends one when this many bytes are pending, the worker uploads
 # each as it is.
 _STRIDE = 4 << 20
+# A sealed container crosses the hop in frames of the same length, cut into
+# segments of this many bytes, each with a CRC32C of its own (the packet
+# wire's granularity of the check): the sender sums frame k+1 while the
+# worker reads and verifies frame k.
+_SEAL_SEGMENT = 1 << 20
 
 # The stage clock (utils/profiler.py, PR 25): a reduce op is a run of leaf
 # ``profiler.phase`` spans no finer than one stride under one covering span
@@ -102,10 +107,13 @@ class ReductionWorker:
         # hop_frames / hop_packets: stride frames that carried bytes and
         # the segments in them (64 a frame when a client sends 64 KiB
         # packets; 1 when a whole buffer came through ``reduce``)
+        # seal_frames / seal_segments: the same of the compress ops' upload
+        # leg (4 segments of 1 MiB a full frame)
         self._stats = {"blocks_reduced": 0, "bytes_reduced": 0,
                        "compress_jobs": 0, "ingest_s": 0.0,
                        "reduce_s": 0.0, "compress_s": 0.0,
-                       "hop_frames": 0, "hop_packets": 0}
+                       "hop_frames": 0, "hop_packets": 0,
+                       "seal_frames": 0, "seal_segments": 0}
         outer = self
 
         class Handler(socketserver.BaseRequestHandler):
@@ -351,10 +359,43 @@ class ReductionWorker:
             self._stats["compress_s"] += sum(took.get(k, 0.0)
                                              for k in _COMPRESS_STAGES)
 
+    def _seal_payload(self, sock: socket.socket, size: int) -> np.ndarray:
+        """The compress ops' upload leg: every stride frame landed in ONE
+        buffer of the ``size`` the request stated — no parts, no join — and
+        each frame's segments checked against their CRC32Cs in one native
+        call, all under the stage ``seal_ingest``.  A frame that fails its
+        check is remembered while the rest of the stream is read, so the
+        error frame leaves on a connection that is still in step."""
+        buf = np.empty(size, np.uint8)
+        got = frames = segments = 0
+        bad: ValueError | None = None
+        last = False
+        with profiler.phase("seal_ingest"):
+            while not last:
+                part, lens, crcs, last = dt.read_stride(sock, buf[got:])
+                if not part.size:
+                    continue
+                if bad is None:
+                    try:
+                        dt.verify_stride(part, lens, crcs)
+                    except ValueError as e:
+                        bad = e
+                got += part.size
+                frames += 1
+                segments += len(lens)
+        with self._stats_lock:
+            self._stats["seal_frames"] += frames
+            self._stats["seal_segments"] += segments
+        if bad is not None:
+            raise bad
+        if got != size:
+            raise ValueError(f"stated {size} bytes, streamed {got}")
+        return buf
+
     def _op_compress(self, sock: socket.socket, req: dict) -> None:
         from hdrf_tpu.ops import dispatch as ops_dispatch
 
-        data = dt.collect_packets(sock)
+        data = self._seal_payload(sock, int(req["size"]))
         before = profiler.thread_cumulative()
         # the device match scan inside records ``scan_wait`` (absent when
         # the scan is bypassed); ``emit`` keeps the rest as self seconds
@@ -362,38 +403,31 @@ class ReductionWorker:
             out = ops_dispatch.block_compress(req.get("codec", "lz4"), data,
                                               self.backend)
         self._note_compress(1, before)
-        send_frame(sock, {"data": bytes(out)})
+        dt.send_with_payloads(sock, {"sizes": [len(out)]}, [out])
         _M.incr("compress_jobs")
         accounting.record_worker_bytes("compress", len(data))
 
     def _op_compress_batch(self, sock: socket.socket, req: dict) -> None:
         """N payloads in one round trip (a DN sealing several container
-        lanes at once): req["sizes"] splits the single concatenated packet
-        stream.  On the TPU backend equal-size payloads compress as ONE
-        device program with one grouped readback (block_compress_batch) —
-        without this op each lane pays its own dispatch + readback round
+        lanes at once): req["sizes"] splits the one buffer the frames land
+        in into views.  On the TPU backend equal-size payloads compress as
+        ONE device program with one grouped readback (block_compress_batch)
+        — without this op each lane pays its own dispatch + readback round
         trip through the transport."""
         from hdrf_tpu.ops import dispatch as ops_dispatch
 
         sizes = [int(v) for v in req.get("sizes", [])]
-        blob = dt.collect_packets(sock)
-        if sum(sizes) != len(blob):
-            send_frame(sock, {"error": "ValueError",
-                              "message": f"sizes sum {sum(sizes)} != "
-                                         f"stream length {len(blob)}"})
-            return
-        datas, off = [], 0
-        for n in sizes:
-            datas.append(blob[off:off + n])
-            off += n
+        blob = self._seal_payload(sock, sum(sizes))
+        ends = np.cumsum(sizes, dtype=np.int64).tolist()
+        datas = [blob[e - n:e] for e, n in zip(ends, sizes)]
         before = profiler.thread_cumulative()
         with profiler.phase("emit"):
             outs = ops_dispatch.block_compress_batch(
                 req.get("codec", "lz4"), datas, self.backend)
         self._note_compress(len(sizes), before)
-        send_frame(sock, {"datas": [bytes(o) for o in outs]})
+        dt.send_with_payloads(sock, {"sizes": [len(o) for o in outs]}, outs)
         _M.incr("compress_jobs", len(sizes))
-        accounting.record_worker_bytes("compress", len(blob))
+        accounting.record_worker_bytes("compress", blob.size)
 
 
 # ------------------------------------------------------------------ client
@@ -604,52 +638,40 @@ class WorkerClient:
     def reduce(self, data: bytes, cdc: CdcConfig):
         return self.reduce_stream([data], cdc)
 
-    def compress(self, codec: str, data: bytes) -> bytes:
-        dl = self._deadline(len(data))
-        s = self._conn(dl)
-        try:
-            try:
-                with profiler.phase("seal_send"):
-                    send_frame(s, self._stamped({"op": "compress",
-                                                 "codec": codec}, dl))
-                    dt.stream_bytes(s, data, 1 << 20)
-                dl.check("worker compress")
-                s.settimeout(dl.timeout())
-                with profiler.phase("seal_wait"):
-                    out = bytes(self._checked(recv_frame(s))["data"])
-            except (OSError, ConnectionError) as e:
-                raise WorkerError(f"worker failed: {e}") from e
-            self._release(s)
-            self._ok()
-            return out
-        except BaseException as e:
-            s.close()
-            if isinstance(e, (WorkerError, retry.DeadlineExceeded)):
-                self._fail(e)
-            raise
+    def _seal_round_trip(self, req: dict, datas: list, what: str) -> list:
+        """One compress op: the request, ``datas`` up the hop in stride
+        frames (phase ``seal_send``), then the reply — the compressed
+        lengths in a frame and the payloads behind it, raw, each read into
+        a buffer of its own (phase ``seal_wait``).
 
-    def compress_batch(self, codec: str, datas: list) -> list:
-        """Batched compress: one round trip, one worker-side device program
-        for the group (see ReductionWorker._op_compress_batch)."""
+        Upload leg: every frame is up to ``_STRIDE`` bytes of ONE payload,
+        as views of it — segments of ``_SEAL_SEGMENT``, their CRC32Cs from
+        one native call, one ``sendmsg``; nothing is sliced into a copy.
+        The last frame carries ``FLAG_LAST``; payloads that hold no byte
+        send one empty frame."""
         dl = self._deadline(sum(len(d) for d in datas))
         s = self._conn(dl)
         try:
             try:
                 with profiler.phase("seal_send"):
-                    send_frame(s, self._stamped(
-                        {"op": "compress_batch", "codec": codec,
-                         "sizes": [len(d) for d in datas]}, dl))
-                    seq = 0
-                    for d in datas:
-                        if d:
-                            dt.write_packet(s, seq, d)
-                            seq += 1
-                    dt.write_packet(s, seq, b"", last=True)
-                dl.check("worker compress_batch")
+                    send_frame(s, self._stamped(req, dl))
+                    frames = [view[o:o + _STRIDE]
+                              for view in (memoryview(d).cast("B")
+                                           for d in datas)
+                              for o in range(0, len(view), _STRIDE)]
+                    for k, frame in enumerate(frames):
+                        crcs = native.crc32c_chunks(frame, _SEAL_SEGMENT)
+                        dt.write_stride(
+                            s, [frame[o:o + _SEAL_SEGMENT] for o in
+                                range(0, len(frame), _SEAL_SEGMENT)],
+                            crcs.tolist(), last=k == len(frames) - 1)
+                    if not frames:
+                        dt.write_stride(s, [], [], last=True)
+                dl.check(what)
                 s.settimeout(dl.timeout())
                 with profiler.phase("seal_wait"):
-                    outs = [bytes(v)
-                            for v in self._checked(recv_frame(s))["datas"]]
+                    sizes = self._checked(recv_frame(s))["sizes"]
+                    outs = [dt.recv_payload(s, n) for n in sizes]
             except (OSError, ConnectionError) as e:
                 raise WorkerError(f"worker failed: {e}") from e
             self._release(s)
@@ -660,6 +682,22 @@ class WorkerClient:
             if isinstance(e, (WorkerError, retry.DeadlineExceeded)):
                 self._fail(e)
             raise
+
+    def compress(self, codec: str, data) -> bytearray:
+        """``data`` (any bytes-like, read where it lies) through the
+        worker's compressor."""
+        (out,) = self._seal_round_trip(
+            {"op": "compress", "codec": codec, "size": len(data)}, [data],
+            "worker compress")
+        return out
+
+    def compress_batch(self, codec: str, datas: list) -> list:
+        """Batched compress: one round trip, one worker-side device program
+        for the group (see ReductionWorker._op_compress_batch)."""
+        return self._seal_round_trip(
+            {"op": "compress_batch", "codec": codec,
+             "sizes": [len(d) for d in datas]}, datas,
+            "worker compress_batch")
 
     def _poll(self, req: dict) -> dict:
         """One ungated request/response (observability polls stay outside

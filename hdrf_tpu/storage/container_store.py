@@ -65,6 +65,11 @@ class _Lane:
     fh: object | None = None
     image: bytearray | None = None  # in-memory mirror of the open container
 
+    def snapshot(self) -> bytes:
+        """A reader's copy of the open container (under ``lock``): the
+        mirror goes on growing, and at the seal it is handed on as it is."""
+        return bytes(self.image)
+
 
 class ContainerStore:
     """Append-only chunk containers with compress-on-seal and compaction."""
@@ -76,14 +81,17 @@ class ContainerStore:
         """``compress_fn`` overrides the seal-time compressor while keeping
         the frame codec id (the TPU LZ4 stage produces format-identical
         output, so readers decode with the stock codec either way).
-        ``compress_batch_fn(list[bytes]) -> list[bytes]`` is its grouped
+        ``compress_batch_fn(list) -> list`` is its grouped
         form: when set, ``flush_open`` seals all open lanes through ONE
         call (one device program + one grouped readback on the TPU
         backend) instead of a compressor round trip per lane.
         ``on_roll(cid, payload)`` observes each container's full
-        uncompressed payload at seal time (from the open-lane memory
-        mirror) — the hook an async seal pipeline hangs off, sparing a disk
-        read-back."""
+        uncompressed payload at seal time (the open-lane memory mirror
+        itself) — the hook an async seal pipeline hangs off, sparing a disk
+        read-back.  All three are handed the lane's ``bytearray`` as it is,
+        not a copy, and may give back any bytes-like: a rolled-over lane
+        opens a new buffer, so nothing writes to the old one again, and
+        they must not either."""
         self._dir = directory
         os.makedirs(directory, exist_ok=True)
         self._container_size = container_size
@@ -289,8 +297,11 @@ class ContainerStore:
         if had_raw:
             lane.fh.close()
         # the in-memory mirror spares the seal a full read-back of the file
-        # (measured ~10% of ingest host cost at 32 MiB containers)
-        payload = bytes(lane.image)
+        # (measured ~10% of ingest host cost at 32 MiB containers).  It is
+        # handed on as it is — a copy here is 32 MiB of fresh pages under
+        # the lane's lock, on the commit thread — and dropped from the lane
+        # below: ``_open_locked`` starts the next container in a new buffer
+        payload = lane.image
         if self._on_roll is not None:
             self._on_roll(lane.container_id, payload)
         if self._seal_q is not None:
@@ -311,12 +322,13 @@ class ContainerStore:
         lane.fh = None
         lane.image = None
 
-    def seal(self, cid: int, data: bytes | None = None,
-             have_raw: bool | None = None, comp: bytes | None = None) -> None:
+    def seal(self, cid: int, data=None, have_raw: bool | None = None,
+             comp=None) -> None:
         """Compress a raw container into the sealed format (the rollover LZ4
         pass, DataDeduplicator.java:770-781).  ``data`` carries the
         container's chunk bytes when the caller already holds them (the
-        open-lane mirror); otherwise they are read from the raw file.
+        open-lane mirror, any bytes-like); otherwise they are read from the
+        raw file.
         ``have_raw=False`` (memory-resident lane) writes the sealed file
         directly — there is no raw file to stamp or remove.  ``comp`` is
         the already-compressed payload when the caller ran the compressor
@@ -375,7 +387,7 @@ class ContainerStore:
                 os.unlink(raw)
         _M.incr("sealed")
 
-    def _compress(self, data: bytes) -> bytes:
+    def _compress(self, data):
         if self._codec == "none":
             return data
         if self._compress_fn is not None:
@@ -405,7 +417,7 @@ class ContainerStore:
             if (self._compress_batch_fn is not None and len(sealable) > 1
                     and self._codec != "none"):
                 comps = self._compress_batch_fn(
-                    [bytes(l.image) for l in sealable])
+                    [l.image for l in sealable])
                 _M.incr("batch_seals", len(sealable))
             for lane, comp in zip(sealable, comps or [None] * len(sealable)):
                 self._seal_locked(lane, on_seal, comp=comp)
@@ -489,7 +501,7 @@ class ContainerStore:
             with lane.lock:
                 if lane.container_id == cid and lane.image is not None:
                     accounting.record_container_decode(len(lane.image))
-                    return bytes(lane.image)  # open lane: serve from memory
+                    return lane.snapshot()  # open lane: serve from memory
         try:
             # Still-open container: read raw bytes directly
             # (DataConstructor.java:482-490's skip-decompress path).  Open

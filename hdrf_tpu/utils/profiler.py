@@ -119,6 +119,13 @@ PHASE_CLASS = {
     "ingest_wait": TRANSPORT, "stage_h2d": HOST, "prep_wait": DEVICE,
     "select": HOST, "sha_wait": DEVICE, "scan_wait": DEVICE, "emit": HOST,
     "block": TRANSPORT,
+    # A compress op's upload leg in the worker: a sealed container's stride
+    # frames read into one buffer and each verified (one span an op; the
+    # reduce op's strides stay ``ingest_wait`` + ``packet_verify``).  HOST:
+    # the CRC32C and the copy out of the loopback socket are this
+    # process's seconds, and the sender's sum of the next frame runs
+    # beside them.
+    "seal_ingest": HOST,
 }
 
 # Deterministic attribution order when several phases of the winning class
@@ -128,7 +135,7 @@ PHASE_CLASS = {
 PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
                "wal_commit", "container_io", "dedup_lookup",
                "reduce_compute", "packet_verify", "checksum", "seal_write",
-               "stage_h2d", "select", "emit",
+               "stage_h2d", "select", "emit", "seal_ingest",
                "index_lookup", "cache_probe", "container_decode",
                # RPC phases: lock_wait/locked win attribution inside the
                # covering ``handler`` window; handler last among them so it

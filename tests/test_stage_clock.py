@@ -231,6 +231,60 @@ class TestHopCounter:
                           "versions-dedup.ingest"]}
 
 
+class TestSealWireMetrics:
+    """``seal_ingest_s`` and ``seal_frames`` / ``seal_segments`` of a worker
+    snapshot, and the two per-layer metrics that read them (data files for
+    the ``stage_ratio`` reader, PR 30)."""
+
+    ENTRIES = {
+        "seal.ingest_ms_per_job": {
+            "unit": "ms", "better": "lower", "layer": "worker"},
+        "seal.segments_per_frame": {
+            "unit": "segments", "better": "higher", "layer": "DN commit"},
+    }
+
+    def test_the_served_seals_left_the_stage_and_the_counters(self, served):
+        """1 MiB containers: every seal is one frame of one segment, and
+        its read + verify has seconds of its own."""
+        first, last = served["first"], served["last"]
+        jobs = last["compress_jobs"] - first["compress_jobs"]
+        assert jobs >= 2
+        assert last["seal_frames"] - first["seal_frames"] == jobs
+        assert last["seal_segments"] - first["seal_segments"] == jobs
+        assert last["seal_ingest_s"] > first.get("seal_ingest_s", 0.0)
+
+    def test_the_metrics_read_the_snapshot(self, served, read_layer):
+        delta = {k: served["last"][k] - served["first"].get(k, 0)
+                 for k in served["last"]}
+        assert read_layer("seal.ingest_ms_per_job", delta) == pytest.approx(
+            1000.0 * delta["seal_ingest_s"] / delta["compress_jobs"])
+        assert read_layer("seal.segments_per_frame", delta) == \
+            pytest.approx(delta["seal_segments"] / delta["seal_frames"])
+        assert read_layer("seal.segments_per_frame",
+                          {"seal_frames": 8, "seal_segments": 32}) == 4.0
+
+    @pytest.mark.parametrize("metric", sorted(ENTRIES))
+    def test_a_snapshot_without_the_keys_reads_absent_not_zero(
+            self, read_layer, metric):
+        # the parent's stats (its seal keeps the packet wire), and a window
+        # in which nothing was sealed
+        assert read_layer(metric, {"compress_jobs": 5, "emit_s": 0.1,
+                                   "packet_verify_s": 0.2}) is None
+        assert read_layer(metric, {"compress_jobs": 0, "seal_frames": 0,
+                                   "seal_segments": 0}) is None
+
+    @pytest.mark.parametrize("metric", sorted(ENTRIES))
+    def test_the_manifest_lists_the_metric(self, metric):
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+            bench = json.load(f)
+        (entry,) = [m for m in bench["per_layer"] if m["name"] == metric]
+        assert entry == {
+            "name": metric, "source": "program_counter",
+            "moves": "write_mb_s",
+            "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w"],
+            **self.ENTRIES[metric]}
+
+
 class TestRecvCounter:
     """``recv_packets`` / ``recv_runs`` of the ``block_receiver`` registry:
     packets a run, how often the run reader engaged (PR 28).  No per-layer

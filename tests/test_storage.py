@@ -77,6 +77,131 @@ class TestContainerStore:
         assert cs.read_chunks(locs) == [b"q" * 90]
 
 
+class TestSealHandOff:
+    """A rolled-over lane's ``bytearray`` goes to the seal as it is
+    (``_seal_locked``): no copy on the committing thread, and nothing the
+    lane does afterwards reaches it."""
+
+    @staticmethod
+    def _store(tmp_path, seen, async_seals, **kw):
+        def compress_fn(data):
+            from hdrf_tpu.utils import codec as codecs
+
+            seen.append((type(data), bytes(data), data))
+            return codecs.compress("lz4", data)
+
+        cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
+                            codec="lz4", compress_fn=compress_fn, **kw)
+        if async_seals:
+            cs.enable_async_seals()
+        return cs
+
+    @pytest.mark.parametrize("async_seals", [False, True],
+                             ids=["inline", "async"])
+    @pytest.mark.parametrize("append", ["chunks", "ranges"])
+    def test_the_seal_gets_the_lanes_buffer_and_the_lane_a_new_one(
+            self, tmp_path, async_seals, append):
+        import numpy as np
+
+        seen, rolled = [], []
+        cs = self._store(tmp_path, seen, async_seals,
+                         on_roll=lambda cid, p: rolled.append((cid, p)))
+
+        def put(blob: bytes):
+            if append == "chunks":
+                return cs.append_chunks([blob[:400], blob[400:]])
+            return cs.append_ranges(np.frombuffer(blob, np.uint8),
+                                    [0, 400], [400, len(blob) - 400])
+
+        first, second = b"x" * 300 + b"y" * 400, b"z" * 650
+        locs1 = put(first)
+        open_image = cs._lanes[0].image
+        locs2 = put(second)                       # rolls the first over
+        later = cs.append_chunks([b"w" * 100])    # same lane, new buffer
+        cs.drain_seals()
+        (kind, sealed_bytes, handed), = seen
+        assert kind is bytearray and handed is open_image
+        assert rolled == [(locs1[0][0], open_image)]
+        assert rolled[0][1] is open_image
+        # what was appended before the rollover, and only that
+        assert sealed_bytes == first and bytes(handed) == first
+        assert cs._lanes[0].image is not handed
+        assert bytes(cs._lanes[0].image) == second + b"w" * 100
+        assert cs.read_container(locs1[0][0]) == first
+        assert cs.read_chunks(locs2 + later) == \
+            [second[:400], second[400:], b"w" * 100]
+        assert os.path.exists(tmp_path / f"{locs1[0][0]}.sealed")
+        cs.close_async_seals()
+
+    @pytest.mark.parametrize("batch", [False, True],
+                             ids=["one-by-one", "compress_batch_fn"])
+    def test_flush_open_hands_every_lane_on(self, tmp_path, batch):
+        from hdrf_tpu.utils import codec as codecs
+
+        single, grouped = [], []
+
+        def batch_fn(datas):
+            grouped.append([type(d) for d in datas])
+            return [codecs.compress("lz4", d) for d in datas]
+
+        def one(data):
+            single.append(type(data))
+            return codecs.compress("lz4", data)
+
+        cs = ContainerStore(str(tmp_path), container_size=1 << 20, lanes=3,
+                            codec="lz4", compress_fn=one,
+                            compress_batch_fn=batch_fn if batch else None)
+        chunks = [b"a" * 5000, b"b" * 7000, b"c" * 100]
+        locs = [cs.append_chunks([c])[0] for c in chunks]
+        images = [lane.image for lane in cs._lanes]
+        cs.flush_open()
+        if batch:
+            assert grouped == [[bytearray] * 3] and not single
+        else:
+            assert single == [bytearray] * 3 and not grouped
+        assert [bytes(i) for i in images] == chunks      # left as they were
+        assert all(lane.image is None for lane in cs._lanes)
+        assert cs.read_chunks(locs) == chunks
+        for cid, _, _ in locs:
+            assert os.path.exists(tmp_path / f"{cid}.sealed")
+
+    def test_a_memory_resident_lane_seals_from_its_buffer(self, tmp_path):
+        """``have_raw=False``: no raw file to stamp or remove, the sealed
+        file is written from the ``bytearray`` alone."""
+        seen = []
+        cs = self._store(tmp_path, seen, async_seals=False)
+        locs = cs.append_chunks([b"m" * 900])
+        lane = cs._lanes[0]
+        lane.fh.close()
+        os.unlink(tmp_path / f"{locs[0][0]}.raw")
+        lane.fh = None
+        cs.append_chunks([b"n" * 900])            # rolls the first over
+        assert seen[0][0] is bytearray and seen[0][1] == b"m" * 900
+        assert os.path.exists(tmp_path / f"{locs[0][0]}.sealed")
+        assert not os.path.exists(tmp_path / f"{locs[0][0]}.raw")
+        assert cs.read_chunks(locs) == [b"m" * 900]
+
+    def test_an_incompressible_buffer_stamps_the_raw_file(self, tmp_path):
+        """The compressor's answer as long as its input (a ``bytearray``
+        from the worker's reply, say): header stamp and rename, no rewrite."""
+        cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
+                            codec="lz4",
+                            compress_fn=lambda d: bytearray(d) + b"!")
+        data = os.urandom(900)
+        locs = cs.append_chunks([data])
+        cs.append_chunks([b"k" * 900])
+        codec, usize, payload = cs._sealed_parse(locs[0][0])
+        assert (codec, usize, payload) == ("none", 900, data)
+        assert cs.read_chunks(locs) == [data]
+
+    def test_a_reader_of_the_open_lane_gets_a_copy(self, tmp_path):
+        cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1)
+        locs = cs.append_chunks([b"r" * 300])
+        got = cs.read_container(locs[0][0])
+        cs.append_chunks([b"s" * 300])
+        assert type(got) is bytes and got == b"r" * 300
+
+
 class TestReplicaStore:
     def test_rbw_to_finalized(self, tmp_path):
         rs = ReplicaStore(str(tmp_path))

@@ -232,6 +232,208 @@ class TestStrideWire:
         assert int(cuts[-1]) == len(data)
 
 
+# ------------------------------------------- the seal's wire (compress ops)
+
+SEG = 1 << 20
+CONTAINER = 32 << 20
+
+
+def _teragen(n: int) -> bytes:
+    """TeraGen rows, as the benchmark's north-star cell writes them."""
+    import chip_smoke
+
+    return chip_smoke.teragen_rows(RNG, n).tobytes()
+
+
+def _frames_and_segments(sizes) -> tuple[int, int]:
+    """What the upload leg sends for payloads of ``sizes``: frames of one
+    stride, none across two payloads, in segments of 1 MiB."""
+    frames = segments = 0
+    for n in sizes:
+        for off in range(0, n, _STRIDE):
+            frames += 1
+            segments += -(-min(_STRIDE, n - off) // SEG)
+    return frames, segments
+
+
+# name -> a payload of the compress op
+SEAL_PAYLOADS = {
+    "empty": lambda: b"",
+    "shorter-than-a-segment": lambda: _teragen(70_001),
+    "no-multiple-of-segment-or-frame": lambda: _teragen(_STRIDE + 2 * SEG
+                                                        + 12_345),
+    "teragen-container": lambda: _teragen(CONTAINER),
+    "incompressible-container": lambda: _bytes(CONTAINER),
+    # a lane hands over its bytearray, and a view is as good
+    "bytearray": lambda: bytearray(_teragen(3 * SEG + 5)),
+    "memoryview": lambda: memoryview(_teragen(_STRIDE)),
+}
+SEAL_BATCHES = {
+    "unequal-and-empty": lambda: [_teragen(_STRIDE + 17), b"", _bytes(30_000),
+                                  bytearray(_teragen(2 * SEG)), b""],
+    "all-empty": lambda: [b"", b""],
+    "no-member": lambda: [],
+    "equal": lambda: [_teragen(SEG + 1), _teragen(SEG + 1)],
+}
+
+
+def _flip_in_flight(monkeypatch, frame: int, only_thread: str | None = None):
+    """One byte of the ``frame``-th stride frame that carries bytes changes
+    between the sender's sum and the wire (on every thread, or the named
+    one only)."""
+    import threading
+
+    from hdrf_tpu.proto import datatransfer as dt
+
+    real, seen = dt.write_stride, [0]
+
+    def write_stride(sock, segs, crcs, last=False):
+        mine = only_thread in (None, threading.current_thread().name)
+        if mine and segs:
+            seen[0] += 1
+            if seen[0] == frame + 1:
+                bad = bytearray(segs[-1])
+                bad[len(bad) // 2] ^= 0x20
+                segs = [*segs[:-1], bad]
+        return real(sock, segs, crcs, last)
+
+    monkeypatch.setattr(dt, "write_stride", write_stride)
+
+
+class TestSealWire:
+    """``compress`` / ``compress_batch``: the payload goes up in stride
+    frames of views of the caller's buffer and lands in one buffer of the
+    stated size; the answer comes back raw behind a frame of lengths."""
+
+    @pytest.fixture(scope="class", params=["native", "tpu"])
+    def client(self, request):
+        w = ReductionWorker(backend=request.param).start()
+        c = WorkerClient(w.addr)
+        yield c
+        c.close()
+        w.stop()
+
+    @pytest.fixture(scope="class")
+    def native_client(self):
+        w = ReductionWorker(backend="native").start()
+        c = WorkerClient(w.addr)
+        yield c
+        c.close()
+        w.stop()
+
+    @pytest.mark.parametrize("case", sorted(SEAL_PAYLOADS))
+    def test_compress_round_trip(self, native_client, case):
+        from hdrf_tpu.utils import codec as codecs
+
+        data = SEAL_PAYLOADS[case]()
+        before = native_client.stats()
+        comp = native_client.compress("lz4", data)
+        after = native_client.stats()
+        assert codecs.decompress("lz4", comp, len(data)) == bytes(data)
+        # the same encoder as in process: the sealed files do not change
+        assert comp == codecs.compress("lz4", data)
+        if case == "incompressible-container":
+            assert len(comp) >= len(data)
+        assert (after["seal_frames"] - before["seal_frames"],
+                after["seal_segments"] - before["seal_segments"]) == \
+            _frames_and_segments([len(data)])
+        assert after["compress_jobs"] == before["compress_jobs"] + 1
+
+    def test_compress_round_trip_on_the_device_path(self, client):
+        """Both backends read the same wire: views of the landed buffer go
+        to ``block_compress`` whichever encoder it picks."""
+        from hdrf_tpu.utils import codec as codecs
+
+        data = _teragen(2 * SEG + 999)
+        comp = client.compress("lz4", data)
+        assert codecs.decompress("lz4", comp, len(data)) == data
+
+    @pytest.mark.parametrize("case", sorted(SEAL_BATCHES))
+    def test_compress_batch_round_trip(self, native_client, case):
+        from hdrf_tpu.utils import codec as codecs
+
+        datas = SEAL_BATCHES[case]()
+        before = native_client.stats()
+        outs = native_client.compress_batch("lz4", datas)
+        after = native_client.stats()
+        assert len(outs) == len(datas)
+        for d, comp in zip(datas, outs):
+            assert codecs.decompress("lz4", comp, len(d)) == bytes(d)
+        assert outs == [native_client.compress("lz4", d) for d in datas]
+        assert (after["seal_frames"] - before["seal_frames"],
+                after["seal_segments"] - before["seal_segments"]) == \
+            _frames_and_segments([len(d) for d in datas])
+        assert after["compress_jobs"] == before["compress_jobs"] + len(datas)
+
+    def test_the_seal_has_a_stage_of_its_own(self, native_client):
+        """A compress op's read and verify are ``seal_ingest_s``; the
+        reduce op's ``packet_verify_s`` and ``ingest_wait_s`` do not grow."""
+        native_client.reduce(_bytes(2 * SEG), CdcConfig())
+        before = native_client.stats()
+        native_client.compress("lz4", _teragen(_STRIDE + SEG))
+        native_client.compress_batch("lz4", [_teragen(SEG), _teragen(99)])
+        after = native_client.stats()
+        assert after["seal_ingest_s"] > before.get("seal_ingest_s", 0.0)
+        for stage in ("packet_verify_s", "ingest_wait_s"):
+            assert after[stage] == before[stage]
+        assert (after["hop_frames"], after["hop_packets"]) == \
+            (before["hop_frames"], before["hop_packets"])
+
+    @pytest.mark.parametrize("op,frame", [("compress", 0), ("compress", 1),
+                                          ("compress_batch", 2)])
+    def test_a_byte_flipped_in_flight_is_an_error_frame(
+            self, native_client, monkeypatch, op, frame):
+        """Whichever frame it is in, the worker reads the stream to its
+        end, answers an error frame (``WorkerError`` here) and serves the
+        next op on a connection that is still in step."""
+        data = _teragen(2 * _STRIDE + 5)
+        _flip_in_flight(monkeypatch, frame)
+        with pytest.raises(WorkerError, match="checksum mismatch"):
+            if op == "compress":
+                native_client.compress("lz4", data)
+            else:
+                native_client.compress_batch("lz4", [data[:SEG], data])
+        monkeypatch.undo()
+        from hdrf_tpu.utils import codec as codecs
+
+        comp = native_client.compress("lz4", data)
+        assert codecs.decompress("lz4", comp, len(data)) == data
+
+    def test_more_bytes_than_stated_hang_up(self, native_client):
+        """A stream longer than the request said cannot be landed: the
+        worker hangs up, which the client reports as a worker failure."""
+        import socket
+
+        from hdrf_tpu import native
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.proto.rpc import send_frame
+
+        s = socket.create_connection(native_client._addr, timeout=10)
+        try:
+            send_frame(s, {"op": "compress", "codec": "lz4", "size": 10})
+            seg = _bytes(11)
+            dt.write_stride(s, [seg], [native.crc32c(seg)], last=True)
+            assert s.recv(1) == b""
+        finally:
+            s.close()
+
+    def test_fewer_bytes_than_stated_are_an_error_frame(self, native_client):
+        import socket
+
+        from hdrf_tpu import native
+        from hdrf_tpu.proto import datatransfer as dt
+        from hdrf_tpu.proto.rpc import recv_frame, send_frame
+
+        s = socket.create_connection(native_client._addr, timeout=10)
+        try:
+            send_frame(s, {"op": "compress", "codec": "lz4", "size": 10})
+            seg = _bytes(9)
+            dt.write_stride(s, [seg], [native.crc32c(seg)], last=True)
+            assert recv_frame(s)["error"] == "ValueError"
+        finally:
+            s.close()
+
+
 # ------------------------------------------------------ the run reader (PR 28)
 
 
@@ -587,3 +789,40 @@ class TestClusterWithWorker:
                 c.write("/f2", second, scheme="dedup_lz4")
                 assert c.read("/f2") == second
                 assert c.read("/f1") == data
+
+
+    def test_a_seal_damaged_in_flight_falls_back_to_the_host_codec(
+            self, monkeypatch):
+        """A byte of a container changes on its way to the worker: the
+        worker refuses it, the DataNode counts ``worker_fallbacks`` and
+        seals with the host codec — the same file the worker's encoder
+        would have written."""
+        import os
+
+        from hdrf_tpu.utils import codec as codecs
+
+        reg = metrics.registry("datanode")
+        with MiniCluster(n_datanodes=1, replication=1, block_size=1 << 20,
+                         tpu_worker=True, worker_backend="native",
+                         reduction_overrides={"container_size": 256 << 10}
+                         ) as mc:
+            dn = mc.datanodes[0]
+            data = _teragen(1 << 20)
+            before = reg.counter("worker_fallbacks")
+            _flip_in_flight(monkeypatch, 0, only_thread="container-seal")
+            with mc.client("w") as c:
+                c.write("/f", data, scheme="dedup_lz4")
+                dn.containers.drain_seals()
+                assert reg.counter("worker_fallbacks") == before + 1
+                monkeypatch.undo()
+                store = dn.volumes.volumes[0].containers
+                sealed = sorted(n for n in os.listdir(store._dir)
+                                if n.endswith(".sealed"))
+                assert len(sealed) >= 2
+                for name in sealed:
+                    cid = int(name.split(".")[0])
+                    codec, usize, payload = store._sealed_parse(cid)
+                    raw = codecs.decompress(codec, payload, usize)
+                    assert codec == "lz4" and \
+                        bytes(payload) == codecs.compress("lz4", raw)
+                assert c.read("/f") == data
