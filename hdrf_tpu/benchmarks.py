@@ -1010,6 +1010,7 @@ def bench_cdc(args) -> None:
             acc = jnp.uint32(0)
             for i in range(k):
                 words, cand = resident._prep_impl(b ^ jnp.uint8(i & 0xFF),
+                                                  jnp.uint32(b.shape[0]),
                                                   r.mask, cap_x,
                                                   r.pad_words)
                 acc += jnp.max(words) + cand[0].astype(jnp.uint32)

@@ -104,7 +104,8 @@ _PACK_ROW = 256  # mask bits packed per matmul row -> 32 output bytes
 
 
 def candidate_bitmap_words(block_u8: jax.Array, mask: jax.Array,
-                           pos1_base: jax.Array | None = None) -> jax.Array:
+                           pos1_base: jax.Array | None = None,
+                           n_valid: jax.Array | None = None) -> jax.Array:
     """Packed all-position candidate bitmap of a resident block.
 
     The one implementation of the gear-scan hot path, shared by the
@@ -112,6 +113,10 @@ def candidate_bitmap_words(block_u8: jax.Array, mask: jax.Array,
     (ops/resident._prep), the seq-sharded scan (parallel/sharded), and the
     graft entry.  block_u8: u8[n], n % _PACK_ROW == 0.  ``pos1_base`` offsets
     the 1-based positions for shards of a larger block (uint32 scalar).
+    ``n_valid`` (uint32 scalar, traced) is the block's true length where the
+    array carries pad: no position past it is a candidate, so a zero pad of
+    any size (gear(0) == 0: every position in one would be) adds no bitmap
+    word; zeros inside the true length stay the candidates they are.
     Returns u32[n/32] little-endian bitmap words (bit k of word w = position
     32w + k is a candidate cut *end*, i.e. cut-point = bit index + 1).
     """
@@ -122,6 +127,8 @@ def candidate_bitmap_words(block_u8: jax.Array, mask: jax.Array,
     if pos1_base is not None:
         pos1 = pos1 + pos1_base
     is_cand = ((h & mask) == 0) & (pos1 >= MIN_CANDIDATE_POS1)
+    if n_valid is not None:
+        is_cand &= pos1 <= n_valid
     return pack_bitmap_words(is_cand)
 
 

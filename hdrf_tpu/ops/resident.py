@@ -68,6 +68,31 @@ _M = _metrics.registry("resident")
 # 128-word (512-byte) row tiling of the Pallas DMA gather's word image.
 _PAD_GRID = 512
 
+# Block-length ladder.  A block's length is a shape: of ``_prep``, of the
+# two bucket SHA programs (through the word image) and of the upload that
+# lands it.  The last block of a file has whatever length its bytes left
+# over, and a stream of small files has one a file, so blocks are padded
+# with zeros up to a fixed rung and the true length rides along as a traced
+# scalar (``_prep`` clears the candidates past it).  Two rungs an octave,
+# ``top`` and ``3/4 top``, above a 1 MiB floor: 15 rungs up to a 128 MiB
+# block.  A pad byte costs H2D and device time, under a microsecond a KiB;
+# a program costs seconds to trace, lower and load in every process that
+# meets it, cache or no cache (PERF.md section 6, PR 31) — so few rungs,
+# and under 1 MiB, where a program's device time is below one awaited
+# dispatch, one.  Every power of two from the floor up is a rung of its
+# own, so a full block runs unpadded.
+_RUNG_FLOOR = 1 << 20
+
+
+def block_rung(n: int) -> int:
+    """The smallest rung of the block-length ladder that holds ``n`` bytes:
+    a function of the length alone, a multiple of ``_PAD_GRID``."""
+    if n <= _RUNG_FLOOR:
+        return _RUNG_FLOOR
+    top = 1 << int(n - 1).bit_length()
+    mid = top // 4 * 3
+    return mid if n <= mid else top
+
 
 def _bucket_of(nb: int) -> int:
     """Bucket = next power of two of the padded SHA block count (<=2x waste)."""
@@ -123,16 +148,20 @@ def be_word_image(block: jax.Array) -> jax.Array:
             | lo.astype(jnp.uint32)).reshape(-1)
 
 
-def _prep_impl(block: jax.Array, mask: int, cap: int, pad_words: int):
+def _prep_impl(block: jax.Array, n: jax.Array, mask: int, cap: int,
+               pad_words: int):
     """One pass over the resident block: BE word image + candidate scan.
 
-    Returns (words u32[N/4 + pad_words], cand i32[1 + 2*cap]) where cand
+    ``n`` (uint32 scalar, traced: no program per length) is the block's
+    true length; the bytes past it are the zero pad up to its rung
+    (``block_rung``) and give no candidate.  Returns
+    (words u32[N/4 + pad_words], cand i32[1 + 2*cap]) where cand
     packs [count, word_idx..., word_val...] into a single D2H transfer.
     """
     words = be_word_image(block)
     words = jnp.concatenate([words, jnp.zeros(pad_words, jnp.uint32)])
 
-    cw = gear.candidate_bitmap_words(block, jnp.uint32(mask))
+    cw = gear.candidate_bitmap_words(block, jnp.uint32(mask), n_valid=n)
     nz = cw != 0
     (idx,) = jnp.nonzero(nz, size=cap, fill_value=cw.shape[0])
     vals = jnp.take(cw, idx, fill_value=0)
@@ -147,8 +176,10 @@ _prep = functools.partial(jax.jit, static_argnames=("mask", "cap",
 
 
 @functools.partial(jax.jit, static_argnames=("mask", "cap", "pad_words"))
-def _prep_batch(blocks: jax.Array, mask: int, cap: int, pad_words: int):
-    """Per-block _prep over K equal-length blocks in ONE device program:
+def _prep_batch(blocks: jax.Array, ns: jax.Array, mask: int, cap: int,
+                pad_words: int):
+    """Per-block _prep over K blocks padded to one length (``ns``: u32[K]
+    true lengths) in ONE device program:
     one dispatch and one candidate readback for the whole group.  The loop
     is UNROLLED (K is a shape, so a jit-cache key): measured 8.5x faster
     than ``lax.map`` (whose per-iteration staging defeats cross-stage
@@ -156,7 +187,7 @@ def _prep_batch(blocks: jax.Array, mask: int, cap: int, pad_words: int):
     batch layouts that OOM at group scale.  Where an awaited round trip
     is dear, dispatch count dominates device time and stage batching is the
     lever (PERF_NOTES.md; ~1 ms per awaited dispatch on the v5e host, PR 22)."""
-    outs = [_prep_impl(blocks[k], mask, cap, pad_words)
+    outs = [_prep_impl(blocks[k], ns[k], mask, cap, pad_words)
             for k in range(blocks.shape[0])]
     return (jnp.stack([w for w, _ in outs]),
             jnp.stack([c for _, c in outs]))
@@ -395,6 +426,11 @@ class ResidentReducer:
         # compile per block.  Handler threads share one reducer.
         self._rung = 0
         self._rung_lock = threading.Lock()
+        # (padded length, capacity) pairs the per-block ``_prep`` has been
+        # dispatched at, first shots and retries: each a program this
+        # process had to trace, lower and compile or load (the worker's
+        # ``prep_shapes`` counter).
+        self.prep_shapes: set[tuple[int, int]] = set()
 
     # ----------------------------------------------------- batched pipeline
 
@@ -419,7 +455,6 @@ class ResidentReducer:
         return self._submit_many_xla(datas)
 
     def _submit_many_xla(self, datas) -> BatchJob:
-        pad_extra = 0
         true_ns = None
         if isinstance(datas, jax.Array):
             k, n = datas.shape
@@ -438,28 +473,25 @@ class ResidentReducer:
                         else np.concatenate(
                             [a, np.zeros(n_pad - a.size, np.uint8)])
                         for a in arrs]
-            if min(true_ns) != true_n:
-                # A shorter member's zero tail is a DENSE candidate region
-                # (the gear hash of zeros is zero, and 0 & mask == 0): one
-                # candidate word per 32 pad bytes must fit the packed
-                # readback, or every mixed group would pay the prep_retry
-                # round trip the capacity formula exists to avoid.
-                pad_extra = (n_pad - min(true_ns)) // 32 + 2
-            else:
-                true_ns = None
             stacked = jax.device_put(np.stack(arrs))
             k, n = stacked.shape
         # int32 flat-byte-offset headroom for the bucket gather
         assert k * (n + 4 * self.pad_words) < (1 << 31), \
             "batch too large for i32 flat offsets; split it"
         rung = self._rung
-        cap = self._cap(n, n, rung, pad_extra)
+        cap = self._cap(n, rung)
         _M.gauge("prep_cap_words", cap)
         ev = _ledger.dispatch(
             "resident.prep_batch", batch=k,
             h2d_bytes=0 if isinstance(datas, jax.Array) else k * n,
             key=(k, n, cap))
-        words, cand = _prep_batch(stacked, self.mask, cap, self.pad_words)
+        # a shorter member's zero tail gives no candidate: its true length
+        # goes down with it
+        ns = np.asarray(true_ns or [true_n] * k, np.uint32)
+        if ns.min() == true_n:
+            true_ns = None
+        words, cand = _prep_batch(stacked, ns, self.mask, cap,
+                                  self.pad_words)
         cand.copy_to_host_async()
         return BatchJob(k=k, n=n, blocks=stacked, words=words, cand=cand,
                         cap=cap, true_n=true_n, rung=rung, true_ns=true_ns,
@@ -522,20 +554,21 @@ class ResidentReducer:
                         fused=True, tables=tables, plan=plan, _digs=digs,
                         _host=arrs, _ev=ev, _ev_sha=evs)
 
-    def _cap(self, n: int, n_pad: int, rung: int, pad_extra: int = 0) -> int:
-        """Candidate capacity (bitmap words) of ``_prep`` for an ``n``-byte
-        block padded to ``n_pad``, at ``rung`` of its ladder.  Rung 0 is the
+    def _cap(self, size: int, rung: int) -> int:
+        """Candidate capacity (bitmap words) of ``_prep`` for a block padded
+        to ``size`` bytes, at ``rung`` of its ladder.  ``size`` is the
+        padded length (a rung of the block-length ladder), never the true
+        one: ``cap`` is a static argument.  Rung 0 is the
         first-shot size for content-like data (about 2x the expected
         candidate words + slack); each further rung doubles it, up to the
-        hard ceiling ``n_pad // 32`` (every bitmap word non-zero, where no
+        hard ceiling ``size // 32`` (every bitmap word non-zero, where no
         block overflows): at most 8 rungs for a 128 MiB block.  ``cap`` is a
         jit-cache key, so these are all the ``_prep`` programs a block
         length can compile.  A high rung costs its readback (``1 + 2*cap``
         int32) and a little device time: a 128 MiB block's ``_prep`` ran
         54 ms at rung 0, 63 at rung 3, 96 at the ceiling (v5e, PR 27)."""
-        first = max(1024, (n >> max(self.cdc.mask_bits - 1, 0)) + 1024) \
-            + pad_extra
-        return max(1, min(n_pad // 32, first << rung))
+        first = max(1024, (size >> max(self.cdc.mask_bits - 1, 0)) + 1024)
+        return max(1, min(size // 32, first << rung))
 
     def _cuts_from_cand(self, cand_row: np.ndarray, cap: int, rung: int,
                         block, true_n: int) -> np.ndarray:
@@ -559,9 +592,11 @@ class ResidentReducer:
             _M.incr("prep_retries")
             _M.gauge("prep_cap_words", cap)
             with _profiler.phase("prep_wait"):
+                self.prep_shapes.add((block.shape[0], cap))
                 ev = _ledger.dispatch("resident.prep_retry",
                                       key=(block.shape, cap))
-                _, cd = _prep(block, self.mask, cap, self.pad_words)
+                _, cd = _prep(block, np.uint32(true_n), self.mask, cap,
+                              self.pad_words)
                 cand_row = np.asarray(cd)
                 _ledger.readback(ev, d2h_bytes=cand_row.nbytes)
             count = int(cand_row[0])
@@ -756,29 +791,36 @@ class ResidentReducer:
         """Start reduction of one block.  ``data`` may be host bytes or an
         already-HBM-resident u8 device array (the gRPC-streamed TPU-worker
         deployment lands packets in HBM before reduction starts; ``n`` gives
-        the true length when the device array carries pad)."""
+        the true length when the device array carries pad).  The programs
+        are keyed by the block's rung (``job.block.shape``) and ``job.cap``,
+        which follows it."""
         if isinstance(data, jax.Array):
             block, n = data, n if n is not None else data.shape[0]
-            if block.shape[0] % _PAD_GRID:
-                block = jnp.pad(
-                    block,
-                    (0, _PAD_GRID - block.shape[0] % _PAD_GRID))
         else:
-            a = (np.frombuffer(data, dtype=np.uint8)
-                 if not isinstance(data, np.ndarray) else data)
-            n = a.size
-            if n % _PAD_GRID:  # pad to the pack/DMA-row grid; candidates
-                # in the zero tail are filtered by _words_to_positions
-                a = np.concatenate(
-                    [a, np.zeros(_PAD_GRID - n % _PAD_GRID, np.uint8)])
-            block = jax.device_put(a)
+            block = (np.frombuffer(data, dtype=np.uint8)
+                     if not isinstance(data, np.ndarray) else data)
+            n = block.size
         if n == 0:
             job = BlockJob(n=0, block=None, words=None, cand=None, cap=0,
                            cuts=np.empty(0, dtype=np.uint64))
             job._sha_parts = ([], [], None)
             return job
+        # Up to its rung of the block-length ladder with zeros (_prep drops
+        # the candidates past ``n``): a rung is its own rung, so a caller
+        # that landed the block at one (the worker) pays nothing here.
+        size = block_rung(block.shape[0])
+        if isinstance(block, jax.Array):
+            if block.shape[0] != size:
+                block = jnp.pad(block, (0, size - block.shape[0]))
+        else:
+            if n != size:
+                a = np.zeros(size, np.uint8)
+                a[:n] = block
+                block = a
+            block = jax.device_put(block)
         rung = self._rung
-        cap = self._cap(n, block.shape[0], rung)
+        cap = self._cap(size, rung)
+        self.prep_shapes.add((size, cap))
         _M.gauge("prep_cap_words", cap)
         # Stage spans of the per-block path (the reduction worker's stage
         # clock, utils/profiler.py): ``prep_wait`` from this dispatch to
@@ -790,7 +832,8 @@ class ResidentReducer:
                 h2d_bytes=(0 if isinstance(data, jax.Array)
                            else block.shape[0]),
                 key=(block.shape, cap))
-            words, cand = _prep(block, self.mask, cap, self.pad_words)
+            words, cand = _prep(block, np.uint32(n), self.mask, cap,
+                                self.pad_words)
             cand.copy_to_host_async()
         return BlockJob(n=n, block=block, words=words, cand=cand, cap=cap,
                         rung=rung, _ev=ev)
@@ -819,12 +862,21 @@ class ResidentReducer:
         # clock: with both planned first, a worker's first block (the
         # kernels' lowering) read 4-7 s longer on the chip, cause not found
         # (my chip runs, PR 25; PERF.md section 7)
-        for sel, B in ((order[nb <= self._b_small], self._b_small),
-                       (order[nb > self._b_small], self._b_big)):
+        # Lane counts are shapes of the SHA programs too: floored at what
+        # content-like data needs at the rung's whole length (a small chunk
+        # an average chunk's bytes, a big one every ``max_chunk``), so the
+        # files under one rung share one program a bucket; denser data
+        # climbs ``_lane_count``'s powers of two from there.
+        size = job.block.shape[0]
+        for sel, B, floor in (
+                (order[nb <= self._b_small], self._b_small,
+                 size >> self.cdc.mask_bits),
+                (order[nb > self._b_small], self._b_big,
+                 size // self.cdc.max_chunk)):
             if not sel.size:
                 continue
             with _profiler.phase("select"):
-                L = _lane_count(sel.size)
+                L = _lane_count(max(sel.size, floor))
                 ol = np.zeros((2, L), dtype=np.int32)
                 ol[0, :sel.size] = starts[sel]
                 ol[1, :sel.size] = lens[sel]

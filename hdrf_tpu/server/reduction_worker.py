@@ -114,6 +114,11 @@ class ReductionWorker:
                        "reduce_s": 0.0, "compress_s": 0.0,
                        "hop_frames": 0, "hop_packets": 0,
                        "seal_frames": 0, "seal_segments": 0}
+        if self.backend == "tpu":
+            # zeros between a block's true length and its rung of the
+            # block-length ladder, summed over reduce ops; a native backend
+            # pads nothing and has no such key
+            self._stats["bytes_padded"] = 0
         outer = self
 
         class Handler(socketserver.BaseRequestHandler):
@@ -209,8 +214,13 @@ class ReductionWorker:
         run (a device backend's first block), ``prep_retries`` — reduce
         ops whose candidates overflowed its capacity and ran it again — and
         the gauge ``prep_cap_words``, the capacity rung in use (registry
-        ``resident``, ops/resident.py); the process's CPU seconds and a
-        wall clock to set them against."""
+        ``resident``, ops/resident.py); on a device backend
+        ``bytes_padded`` (the block-length ladder's waste, above) and
+        ``prep_shapes`` — the (padded length, capacity) pairs this worker's
+        reducers have dispatched ``_prep`` at, retries too: each a program
+        to compile or to fetch from the cache, so its growth over a window
+        is the reduce ops that met a new one; the
+        process's CPU seconds and a wall clock to set them against."""
         with self._stats_lock:
             out = dict(self._stats)
         for name, secs in profiler.cumulative().items():
@@ -219,6 +229,9 @@ class ReductionWorker:
         if "prep_cap_words" in prep["gauges"]:
             out["prep_retries"] = prep["counters"].get("prep_retries", 0)
             out["prep_cap_words"] = prep["gauges"]["prep_cap_words"]
+        if self.backend == "tpu":
+            out["prep_shapes"] = sum(len(r.prep_shapes)
+                                     for r in list(self._reducers.values()))
         out["cpu_s"] = time.process_time()
         out["wall_s"] = time.perf_counter()
         return out
@@ -326,29 +339,50 @@ class ReductionWorker:
             self._stats["hop_packets"] += segments
 
     def _reduce_streaming_tpu(self, sock: socket.socket, cdc: CdcConfig):
+        """A block lands on the device at its rung of the block-length
+        ladder (``ops.resident.block_rung``), so its programs are the
+        rung's and not its length's.  Full strides go up as they arrive,
+        as they are; what is left when the stream ends — the short last
+        frame, or a whole block under one stride — is laid into ONE host
+        buffer that reaches to the rung and goes up in one ``device_put``:
+        no zeros made, nothing joined on the device for a block under a
+        stride, and for a longer one a ``concatenate`` whose shapes the
+        count of full strides and the rung decide (a few dozen in all up to
+        128 MiB).  A frame that is not a full stride before the last one (a
+        client with odd packets) is held with everything behind it."""
         import jax
         import jax.numpy as jnp
 
+        from hdrf_tpu.ops.resident import block_rung
+
         parts: list = []        # resident device strides (uploads in flight)
-        total = 0
+        held: list = []         # host frames that wait for the rung
+        up = total = 0
         for buf in self._strides(sock):
+            total += buf.size
+            if held or buf.size != _STRIDE:
+                held.append(buf)
+                continue
             with profiler.phase("stage_h2d"):
                 parts.append(jax.device_put(buf))  # async H2D: lands in
                 # HBM while the next frame streams in
-            total += buf.size
-        if not parts:
+            up += buf.size
+        if not total:
             return _NO_CHUNKS
-        from hdrf_tpu.ops.resident import _PAD_GRID
-
         with profiler.phase("stage_h2d"):
-            pad = (-total) % _PAD_GRID
-            if pad:
-                parts.append(jnp.zeros(pad, jnp.uint8))
+            size = block_rung(total)
+            if size > up:
+                tail = np.zeros(size - up, np.uint8)
+                if held:
+                    np.concatenate(held, out=tail[:total - up])
+                parts.append(jax.device_put(tail))
             block = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
         # prep_wait, select and sha_wait are recorded where they happen
         # (ops/resident.py)
         r = self._reducer(cdc)
         job = r.submit(block, n=total)
+        with self._stats_lock:
+            self._stats["bytes_padded"] += size - total
         r.start_sha(job)
         return r.finish(job)
 

@@ -59,3 +59,23 @@ def perfbench_file():
     load.root = PERFBENCH
     return load
 
+
+
+@pytest.fixture(scope="session")
+def slive_files(perfbench_file):
+    """``slive_files(count)``: the first ``count`` files of one client's
+    window in ``small-files.create`` (SLive's create mix, sizes uniform in
+    4 KiB-4 MiB), made as the benchmark makes them."""
+    import json
+
+    with open(os.path.join(perfbench_file.root, "configs",
+                           "small-files.json")) as f:
+        data = {k: v for k, v in json.load(f)["data"].items()
+                if k != "generator"}
+    with pytest.MonkeyPatch.context() as mp:
+        # the generator imports its neighbour ``generators.teragen``
+        mp.syspath_prepend(perfbench_file.root)
+        source = perfbench_file("generators/slive_sizes.py").Source
+    src = source(dict(data, file_bytes=128 << 20), 2**31 + 31, 0)
+    first = int(data["setup_files"])
+    return lambda count: [src.file(k) for k in range(first, first + count)]

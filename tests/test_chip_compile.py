@@ -107,21 +107,23 @@ def _match_deltas(t, e):
             [((t, e), jnp.uint32)], {})
 
 
-def _prep(rung=0):
+def _prep(rung=0, block=BLOCK):
     from hdrf_tpu.ops import resident
 
     r = _reducer()
-    cap = r._cap(BLOCK, BLOCK, rung)
-    if rung == 0:     # the first shot is the program every tree has compiled
-        assert cap == (BLOCK >> (CDC.mask_bits - 1)) + 1024
-    return (resident._prep, [((BLOCK,), jnp.uint8)],
+    assert resident.block_rung(block) == block
+    cap = r._cap(block, rung)
+    if rung == 0:     # the first shot keeps the capacity every tree has had
+        assert cap == (block >> (CDC.mask_bits - 1)) + 1024
+    # the block at its rung and its true length, a traced scalar
+    return (resident._prep, [((block,), jnp.uint8), ((), jnp.uint32)],
             dict(mask=r.mask, cap=cap, pad_words=r.pad_words))
 
 
-def _bucket_sha(lanes, bucket):
+def _bucket_sha(lanes, bucket, block=BLOCK):
     from hdrf_tpu.ops import resident
 
-    nw = BLOCK // 4 + _reducer().pad_words
+    nw = block // 4 + _reducer().pad_words
     return (resident._bucket_sha_dma,
             [((nw,), jnp.uint32), ((2, lanes), jnp.int32)],
             dict(bucket=bucket))
@@ -155,6 +157,12 @@ ONE_CHIP = {
     "prep-128MiB-rung3": lambda: _prep(3),
     "bucket-sha-small": lambda: _bucket_sha(16384, _B_SMALL),
     "bucket-sha-big": lambda: _bucket_sha(4096, _B_BIG),
+    # the block-length ladder (PR 31): a small file's block at the 3 MiB
+    # rung and at the 1 MiB floor, with the lane counts the rungs give
+    "prep-3MiB": lambda: _prep(block=3 << 20),
+    "prep-1MiB": lambda: _prep(block=1 << 20),
+    "bucket-sha-small-3MiB": lambda: _bucket_sha(512, _B_SMALL, 3 << 20),
+    "bucket-sha-big-1MiB": lambda: _bucket_sha(128, _B_BIG, 1 << 20),
 }
 
 

@@ -276,7 +276,7 @@ def test_tar_stream_compiles_rungs_not_blocks(versions_tar):
     blocks = versions_tar(TAR_BYTES, 8, seed=11)
     r = ResidentReducer(CdcConfig())
     ceiling = TAR_BYTES // 32
-    ladder = sorted({r._cap(TAR_BYTES, TAR_BYTES, k) for k in range(32)})
+    ladder = sorted({r._cap(TAR_BYTES, k) for k in range(32)})
     assert ladder[0] == (TAR_BYTES >> 12) + 1024 and ladder[-1] == ceiling
     assert len(ladder) <= 8
     programs, retries = resident._prep._cache_size(), _prep_retries()
@@ -311,3 +311,132 @@ def test_random_block_stays_on_the_first_rung():
     np.testing.assert_array_equal(digs, wd)
     assert job.rung == 0 and job.cap == (a.size >> 12) + 1024
     assert _prep_retries() == before
+
+
+# ---- the block-length ladder (small files, SLive's create mix; PR 31) ----
+
+RUNG = 3 << 19           # 1.5 MiB: a rung that is no power of two
+
+
+def _ladder_bytes(n: int, tail: str, seed: int = 31) -> np.ndarray:
+    a = np.random.default_rng([seed, n]).integers(0, 256, size=n,
+                                                  dtype=np.uint8)
+    if tail == "zeros":
+        a[max(n - 3000, 0):] = 0
+    return a
+
+
+def test_the_ladder_is_fixed_and_every_power_of_two_a_rung():
+    rung = resident.block_rung
+    assert rung(RUNG) == RUNG and rung(RUNG - 1) == RUNG
+    assert rung(RUNG + 1) == 2 << 20
+    assert [rung(n) for n in (1, 511, 4096, 1 << 20)] == [1 << 20] * 4
+    for k in range(20, 31):
+        assert rung(1 << k) == 1 << k and rung((1 << k) + 1) == 3 << (k - 1)
+    lengths = range(512, (128 << 20) + 1, 4096)
+    rungs = sorted({rung(n) for n in lengths})
+    assert len(rungs) == 15 and rungs[-1] == 128 << 20
+    assert all(r % resident._PAD_GRID == 0 for r in rungs)
+    assert all(n <= rung(n) < 2 * max(n, 1 << 20) for n in lengths)
+
+
+@pytest.mark.parametrize("tail", ["random", "zeros"])
+@pytest.mark.parametrize("n", [1, 511, 2047, 4096, RUNG, RUNG - 1, RUNG + 1,
+                               1_000_003, 4_194_304])
+def test_any_length_matches_the_plain_reference(n, tail, perfbench_file):
+    """A block of any length lands at its rung, padded with zeros; cuts and
+    digests are the plain reference's, exactly — under ``min_chunk``, at a
+    rung and either side of one, and with a zero tail INSIDE the file (its
+    zeros are candidates, the pad's are not)."""
+    a = _ladder_bytes(n, tail)
+    chunking = perfbench_file("reference/chunking.py")
+    r = ResidentReducer(CdcConfig())
+    before = _prep_retries()
+    job = r.submit(a)
+    assert job.block.shape[0] == resident.block_rung(n)
+    assert job.cap == r._cap(job.block.shape[0], 0)
+    cuts, digs = r.finish(job)
+    if n >= 32:
+        wc, wd = _reference(chunking, a, r.cdc)
+    else:   # the reference's window doubling wants a whole 32-byte window
+        # (PERF.md section 7): under one the file is one chunk by definition
+        wc = np.asarray([n], np.uint64)
+        wd = np.frombuffer(hashlib.sha256(a).digest(), np.uint8)[None]
+    np.testing.assert_array_equal(cuts, wc)
+    np.testing.assert_array_equal(digs, wd)
+    assert _prep_retries() == before and r._rung == 0
+
+
+def test_a_zero_pad_adds_no_candidate_and_a_zero_tail_does(perfbench_file):
+    """100 000 bytes land at the 1 MiB rung: 948 576 pad zeros, 29 643
+    bitmap words if they counted (gear(0) == 0) against a first-shot
+    capacity of 1 280.  The device's count of candidate words is the
+    reference's over the true bytes alone; 3 000 zeros at the end of the
+    FILE add theirs."""
+    chunking = perfbench_file("reference/chunking.py")
+    r = ResidentReducer(CdcConfig())
+
+    def words(a):
+        job = r.submit(a)
+        count = int(np.asarray(job.cand)[0])
+        want = chunking.candidates(a, chunking.spread_mask(r.cdc.mask_bits))
+        assert count == np.unique((want - 1) // 32).size
+        r.finish(job)
+        return count
+
+    plain = words(_ladder_bytes(100_000, "random"))
+    tailed = words(_ladder_bytes(100_000, "zeros"))
+    assert plain < 40                      # one candidate in 8 192 positions
+    assert tailed >= plain + (3000 - 32) // 32 - 2
+    # the batched path masks each member at its own length too: a short
+    # member beside a long one overflows nothing
+    before = _prep_retries()
+    short, long_ = _ladder_bytes(30_000, "random"), _ladder_bytes(
+        400_000, "random")
+    for data, (cuts, digs) in zip((short, long_),
+                                  r.reduce_many([short, long_])):
+        wc, wd = _oracle(data, r.cdc)
+        np.testing.assert_array_equal(cuts, wc)
+        np.testing.assert_array_equal(digs, wd)
+    assert _prep_retries() == before
+
+
+def test_forty_files_of_the_cell_compile_rungs_not_files(slive_files,
+                                                        perfbench_file):
+    """Forty lengths drawn as ``small-files.create`` draws them, through one
+    reducer: ``_prep`` is compiled a rung they span, not a file; no pad
+    overflowed the first-shot capacity or moved the remembered rung."""
+    files = slive_files(40)
+    sizes = [f.size for f in files]
+    assert len(set(sizes)) == 40 and min(sizes) >= 4096 \
+        and max(sizes) <= 4 << 20
+    rungs = {resident.block_rung(n) for n in sizes}
+    chunking = perfbench_file("reference/chunking.py")
+    r = ResidentReducer(CdcConfig())
+    programs, retries = resident._prep._cache_size(), _prep_retries()
+    sha_programs = resident._bucket_sha._cache_size()
+    for a in files:
+        cuts, digs = r.finish(r.submit(a))
+        wc, wd = _reference(chunking, a, r.cdc)
+        np.testing.assert_array_equal(cuts, wc)
+        np.testing.assert_array_equal(digs, wd)
+    assert resident._prep._cache_size() - programs <= len(rungs) <= 5
+    # the lane counts are floored by the rung: one SHA program a bucket
+    assert resident._bucket_sha._cache_size() - sha_programs <= 2 * len(rungs)
+    assert _prep_retries() == retries and r._rung == 0
+
+
+@pytest.mark.parametrize("small, big, lanes", [
+    (8_968, 2_578, (16384, 4096)),      # a TeraGen block (teragen-1dn)
+    (17_411, 1_362, (32768, 2048)),     # a tar generation (versions-dedup)
+    (1, 1, (16384, 2048))])             # the floors themselves
+def test_the_lane_floors_keep_a_full_blocks_programs(small, big, lanes):
+    """At 128 MiB the rung's lane floors sit at or under what the accepted
+    cells' data needs, so their SHA programs are the ones they had."""
+    cdc = CdcConfig()
+    size = 128 << 20
+    got = (resident._lane_count(max(small, size >> cdc.mask_bits)),
+           resident._lane_count(max(big, size // cdc.max_chunk)))
+    assert got == lanes
+    assert (resident._lane_count(small), resident._lane_count(big)) == \
+        (lanes if small > 1 else (128, 128))

@@ -228,7 +228,7 @@ class TestHopCounter:
             "better": "higher", "source": "program_counter",
             "layer": "DN to worker hop", "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
-                          "versions-dedup.ingest"]}
+                          "versions-dedup.ingest", "small-files.create"]}
 
 
 class TestSealWireMetrics:
@@ -281,7 +281,8 @@ class TestSealWireMetrics:
         assert entry == {
             "name": metric, "source": "program_counter",
             "moves": "write_mb_s",
-            "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w"],
+            "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
+                          "small-files.create"],
             **self.ENTRIES[metric]}
 
 
@@ -442,7 +443,10 @@ class TestDeviceStages:
             a = rng.integers(0, 256, 600_000, dtype=np.uint8)
             a[50_000:450_000] = 0
             blocks.append(a.tobytes())
-        first_shot = (600_000 >> 12) + 1024
+        from hdrf_tpu.ops.resident import block_rung
+
+        # the capacity follows the rung the block lands at (PR 31)
+        first_shot = (block_rung(600_000) >> 12) + 1024
         w = ReductionWorker(backend="tpu").start()
         try:
             c = WorkerClient(w.addr)
@@ -466,6 +470,51 @@ class TestDeviceStages:
                            {"blocks_reduced": 2}) is None
         assert read_layer("worker.prep_retries_per_block",
                            {"prep_retries": 0, "blocks_reduced": 0}) is None
+
+    def test_the_ladder_metrics_read_the_worker_counters(self, read_layer):
+        """``worker.pad_pct`` and ``worker.prep_shapes_per_block`` (data
+        files for ``stage_ratio``, PR 31) from a device worker's ``stats``:
+        a block at a rung pads nothing; two lengths under one rung are one
+        shape."""
+        from hdrf_tpu.ops.resident import block_rung
+
+        sizes = (1 << 20, 1_400_000, 1_300_000)   # a rung; two at 1.5 MiB
+        w = ReductionWorker(backend="tpu").start()
+        try:
+            c = WorkerClient(w.addr)
+            snaps = [c.stats()]
+            for n in sizes:
+                c.reduce(_payload(n), CdcConfig())
+                snaps.append(c.stats())
+            c.close()
+        finally:
+            w.stop()
+        deltas = [{k: b[k] - a.get(k, 0) for k in b}
+                  for a, b in zip(snaps, snaps[1:])]
+        assert read_layer("worker.pad_pct", deltas[0]) == 0.0
+        assert read_layer("worker.pad_pct", deltas[1]) == pytest.approx(
+            100.0 * (block_rung(1_400_000) - 1_400_000) / 1_400_000)
+        assert [read_layer("worker.prep_shapes_per_block", d)
+                for d in deltas] == [1.0, 1.0, 0.0]
+        # a program without the counters (the parent's), an empty window
+        for metric in ("worker.pad_pct", "worker.prep_shapes_per_block"):
+            assert read_layer(metric, {"blocks_reduced": 3,
+                                       "bytes_reduced": 9}) is None
+            assert read_layer(metric, {"bytes_padded": 0, "prep_shapes": 0,
+                                       "blocks_reduced": 0,
+                                       "bytes_reduced": 0}) is None
+
+    @pytest.mark.parametrize("metric, unit", [
+        ("worker.pad_pct", "%"), ("worker.prep_shapes_per_block", "shapes")])
+    def test_the_manifest_lists_the_ladder_metrics(self, metric, unit):
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+            bench = json.load(f)
+        (entry,) = [m for m in bench["per_layer"] if m["name"] == metric]
+        assert entry == {
+            "name": metric, "unit": unit, "better": "lower",
+            "source": "program_counter", "layer": "device programs",
+            "moves": "write_mb_s",
+            "workloads": [w["name"] for w in bench["workloads"]]}
 
     def test_a_device_compress_records_scan_wait_inside_emit(self):
         from hdrf_tpu.ops.lz4_tpu import TpuLz4

@@ -232,6 +232,99 @@ class TestStrideWire:
         assert int(cuts[-1]) == len(data)
 
 
+class TestBlockLengthLadder:
+    """A stream of files of different lengths through the device backend
+    (jax on the CPU here): each block lands at its rung of the block-length
+    ladder, so the worker meets a ``_prep`` shape a rung and not a file
+    (``prep_shapes``), counts the zeros it added (``bytes_padded``), and
+    answers what the whole buffer gives."""
+
+    @pytest.fixture(scope="class")
+    def client(self):
+        w = ReductionWorker(backend="tpu").start()
+        c = WorkerClient(w.addr)
+        yield c
+        c.close()
+        w.stop()
+
+    def test_forty_files_of_the_cell_meet_rungs_not_files(self, client,
+                                                          slive_files):
+        from hdrf_tpu.ops.resident import block_rung
+
+        files = [f.tobytes() for f in slive_files(40)]
+        rungs = [block_rung(len(f)) for f in files]
+        cdc = CdcConfig()
+        before = client.stats()
+        for data in files:
+            cuts, digs = client.reduce_stream(iter(_packets(data)), cdc)
+            want_cuts, want_digs = _oracle(data, cdc)
+            np.testing.assert_array_equal(cuts, want_cuts)
+            np.testing.assert_array_equal(digs, want_digs)
+        after = client.stats()
+        took = {k: after[k] - before.get(k, 0) for k in after}
+        assert took["blocks_reduced"] == 40
+        assert took["bytes_reduced"] == sum(map(len, files))
+        assert took["bytes_padded"] == sum(rungs) - sum(map(len, files))
+        assert 1 <= took["prep_shapes"] <= len(set(rungs)) <= 5
+        assert took["prep_retries"] == 0
+        # the same lengths again: every shape has been met
+        for data in files[:5]:
+            client.reduce(data, cdc)
+        assert client.stats()["prep_shapes"] == after["prep_shapes"]
+
+    @pytest.mark.parametrize("n, frames", [
+        (_STRIDE, 1),               # a rung, one full stride: as it is
+        (_STRIDE + 1, 2),           # a stride and a tail up to 6 MiB
+        (2 * _STRIDE + 12_345, 3),  # two strides and a tail up to 12 MiB
+        (70_000, 1)])               # under a stride: one upload at 1 MiB
+    def test_a_block_lands_at_its_rung(self, client, n, frames):
+        from hdrf_tpu.ops.resident import block_rung
+
+        data = _bytes(n)
+        before = client.stats()
+        cuts, digs = client.reduce_stream(iter(_packets(data)), CdcConfig())
+        after = client.stats()
+        want_cuts, want_digs = _oracle(data, CdcConfig())
+        np.testing.assert_array_equal(cuts, want_cuts)
+        np.testing.assert_array_equal(digs, want_digs)
+        assert after["hop_frames"] - before["hop_frames"] == frames
+        assert after["bytes_padded"] - before["bytes_padded"] == \
+            block_rung(n) - n
+
+    def test_a_capacity_retry_counts_its_shape_where_it_ran(self):
+        """A zero-dense block overflows ``_prep``'s first shot and runs it
+        again at a higher capacity: both programs count in that op, and the
+        next block, dispatched at the remembered rung, meets nothing new."""
+        a = np.frombuffer(_bytes(600_000), np.uint8).copy()
+        a[50_000:450_000] = 0
+        w = ReductionWorker(backend="tpu").start()
+        try:
+            c = WorkerClient(w.addr)
+            seen = [c.stats().get("prep_shapes", 0)]
+            for _ in range(2):
+                cuts, digs = c.reduce(a.tobytes(), CdcConfig())
+                seen.append(c.stats()["prep_shapes"])
+            retries = c.stats()["prep_retries"]
+            c.close()
+        finally:
+            w.stop()
+        want_cuts, want_digs = _oracle(a.tobytes(), CdcConfig())
+        np.testing.assert_array_equal(cuts, want_cuts)
+        np.testing.assert_array_equal(digs, want_digs)
+        assert seen == [0, 2, 2] and retries >= 1
+
+    def test_a_native_worker_has_neither_counter(self):
+        w = ReductionWorker(backend="native").start()
+        try:
+            c = WorkerClient(w.addr)
+            c.reduce(_bytes(70_000), CdcConfig())
+            stats = c.stats()
+            c.close()
+        finally:
+            w.stop()
+        assert "bytes_padded" not in stats and "prep_shapes" not in stats
+
+
 # ------------------------------------------- the seal's wire (compress ops)
 
 SEG = 1 << 20
