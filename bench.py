@@ -1040,6 +1040,10 @@ def main() -> None:
                 # The image-staging pre-pass (images None) only collects
                 # payloads — scans wait for the staged common-size images,
                 # so exactly the grouped shapes compile, once.
+                # The store takes the lane's buffer back after the seal
+                # and writes a later container over it, and this pass reads
+                # the payloads after it returns: keep a copy, not the view.
+                payload = bytes(payload)
                 payloads.append((cid, payload))
                 if images is not None:
                     pend.append((cid, payload))

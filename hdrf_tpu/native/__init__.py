@@ -370,10 +370,13 @@ class PacketUnpacker:
 
 
 def gather_ranges(data: bytes | np.ndarray, starts: np.ndarray,
-                  lens: np.ndarray) -> np.ndarray:
-    """Concatenate [start, start+len) ranges of ``data`` into one buffer —
+                  lens: np.ndarray, out) -> np.ndarray:
+    """Concatenate [start, start+len) ranges of ``data`` into ``out`` —
     the commit path's chunk-byte shuffle (threadedStorer's per-chunk
-    ByteBuffer copies, DataDeduplicator.java:652-845) in one native pass."""
+    ByteBuffer copies, DataDeduplicator.java:652-845) in one native pass.
+    ``out`` (a writable bytes-like or C-contiguous uint8 array of at least
+    the ranges' total: the open container's buffer) receives the bytes
+    where it lies; the filled part of it is returned."""
     a = _as_u8(data)
     ss = np.ascontiguousarray(starts, dtype=np.uint64)
     ls = np.ascontiguousarray(lens, dtype=np.uint64)
@@ -381,7 +384,12 @@ def gather_ranges(data: bytes | np.ndarray, starts: np.ndarray,
         raise ValueError("starts/lens shape mismatch")
     if ss.size and int((ss + ls).max()) > a.size:
         raise ValueError("range exceeds data buffer")
-    out = np.empty(int(ls.sum()), dtype=np.uint8)
+    total = int(ls.sum())
+    out = _as_u8(out)
+    if not out.flags.writeable:
+        raise ValueError("destination is read-only")
+    if out.size < total:
+        raise ValueError("destination smaller than the ranges' total")
     _load().hdrf_gather_ranges(_ptr(a, _u8p), ss.size, _ptr(ss, _u64p),
                                _ptr(ls, _u64p), _ptr(out, _u8p))
-    return out
+    return out[:total]

@@ -29,6 +29,8 @@ import struct
 import threading
 from dataclasses import dataclass
 
+import numpy as np
+
 from hdrf_tpu.utils import codec as codecs
 from hdrf_tpu.utils import fault_injection, metrics, profiler
 
@@ -63,12 +65,19 @@ class _Lane:
     container_id: int = -1
     size: int = 0
     fh: object | None = None
-    image: bytearray | None = None  # in-memory mirror of the open container
+    # the open container's memory, allocated whole: ``buffer[:size]`` is the
+    # container, the rest is room (never read; a reused buffer's is stale)
+    buffer: np.ndarray | None = None
+
+    def view(self) -> memoryview:
+        """The container's bytes where they lie (under ``lock``)."""
+        return memoryview(self.buffer)[:self.size]
 
     def snapshot(self) -> bytes:
         """A reader's copy of the open container (under ``lock``): the
-        mirror goes on growing, and at the seal it is handed on as it is."""
-        return bytes(self.image)
+        buffer goes on filling, and after its seal it takes another
+        container's bytes."""
+        return bytes(self.view())
 
 
 class ContainerStore:
@@ -86,12 +95,21 @@ class ContainerStore:
         call (one device program + one grouped readback on the TPU
         backend) instead of a compressor round trip per lane.
         ``on_roll(cid, payload)`` observes each container's full
-        uncompressed payload at seal time (the open-lane memory mirror
-        itself) — the hook an async seal pipeline hangs off, sparing a disk
-        read-back.  All three are handed the lane's ``bytearray`` as it is,
-        not a copy, and may give back any bytes-like: a rolled-over lane
-        opens a new buffer, so nothing writes to the old one again, and
-        they must not either."""
+        uncompressed payload at seal time (the lane's buffer itself) — the
+        hook an async seal pipeline hangs off, sparing a disk read-back.
+        All three are handed a ``memoryview`` of the lane's own buffer,
+        ``buffer[:size]``, not a copy, and may give back any bytes-like.
+        Who may hold that memory, and until when: the lane until its
+        container rolls over; from then the hooks, the seal queue and
+        ``seal()``, and nobody else — the lane opens in another buffer.
+        When ``seal()`` has returned and ``on_seal`` has run, the buffer
+        goes to the store's free list and a later container is written
+        over it.  So the hooks must not write to what they are handed, and
+        must not keep it (nor a view or a zero-copy array of it) past
+        their return — a hook that needs the bytes later copies them
+        (``bytes(payload)``), as ``bench.py``'s does; what they give back
+        must not alias it unless it IS it (a codec that cannot shrink the
+        bytes)."""
         self._dir = directory
         os.makedirs(directory, exist_ok=True)
         self._container_size = container_size
@@ -131,6 +149,12 @@ class ContainerStore:
         self._next_id = max(self._scan_next_id(), id_base)
         self._lanes = [_Lane(threading.Lock()) for _ in range(lanes)]
         self._rr = 0
+        # Sealed containers' buffers (``container_size`` each), for the next
+        # ``_open_locked``: a container's pages are faulted in once a
+        # process and not once a container.  Under ``_alloc_lock`` with
+        # ``_sealing``, the buffers handed to a seal and not yet back.
+        self._free: list[np.ndarray] = []
+        self._sealing = 0
         # Tiny LRU of decompressed sealed containers (read amplification guard;
         # the reference re-decompresses the whole container per read).
         self._cache: dict[int, bytes] = {}
@@ -161,7 +185,8 @@ class ContainerStore:
     def append_chunks(self, chunks: list[bytes], on_seal=None,
                       sync: bool = True) -> list[tuple[int, int, int]]:
         """Append chunks to one lane's open container; returns
-        (container_id, offset, length) per chunk.  ``on_seal(cid)`` fires after
+        (container_id, offset, length) per chunk (a chunk is any bytes-like
+        whose items are single bytes).  ``on_seal(cid)`` fires after
         a rollover compresses+seals a container (index notification).
         ``sync=False`` skips the fsync — the batched commit pipeline calls
         ``sync_lanes()`` once per group instead, BEFORE the covering index
@@ -173,25 +198,25 @@ class ContainerStore:
             self._rr += 1
         out: list[tuple[int, int, int]] = []
         with lane.lock:
-            pending: list[bytes] = []
+            written = lane.size  # buffer[written:size] is not in the file yet
 
             def drain():
-                if pending:
-                    blob = b"".join(pending)
-                    if lane.fh is not None:
-                        lane.fh.write(blob)
-                    lane.image += blob
-                    pending.clear()
+                if lane.fh is not None and lane.size > written:
+                    lane.fh.write(lane.buffer[written:lane.size])
 
             for chunk in chunks:
-                if lane.image is None or (
+                if lane.buffer is None or (
                         lane.size + len(chunk) > self._container_size and lane.size > 0):
-                    if lane.image is not None:
+                    if lane.buffer is not None:
                         drain()  # before rollover seals the container
                         self._seal_locked(lane, on_seal)
                     self._open_locked(lane)
+                    written = 0
                 off = lane.size
-                pending.append(chunk)
+                if off + len(chunk) > lane.buffer.size:
+                    self._oversize_locked(lane, len(chunk))
+                lane.buffer[off:off + len(chunk)] = np.frombuffer(
+                    chunk, np.uint8)
                 lane.size += len(chunk)
                 out.append((lane.container_id, off, len(chunk)))
             # One write per batch, not per chunk (measured: per-chunk writes
@@ -208,14 +233,13 @@ class ContainerStore:
                       sync: bool = True) -> list[tuple[int, int, int]]:
         """``append_chunks`` for chunks that are RANGES of one buffer (the
         dedup commit's shape): byte movement runs as one native
-        gather_ranges per container segment instead of n memoryview
-        slices + list appends + a join — the commit half's Python byte
-        shuffling (measured ~1.2 s per 512 MiB of TeraGen-density chunks
-        on the 1-vCPU host).  Rollover semantics identical to
+        gather_ranges per container segment, straight into the lane's
+        buffer, and the raw file is written from a view of it — a new byte
+        is copied once into the open container and once to the page cache
+        (it was four copies, two of them holding the interpreter, three
+        into pages nobody had touched).  Rollover semantics identical to
         append_chunks: a chunk that doesn't fit seals the open container
         first; an oversized chunk lands alone in an empty one."""
-        import numpy as np
-
         from hdrf_tpu import native
 
         n = int(len(starts))
@@ -232,7 +256,7 @@ class ContainerStore:
         with lane.lock:
             i = 0
             while i < n:
-                if lane.image is None:
+                if lane.buffer is None:
                     self._open_locked(lane)
                 cap = self._container_size - lane.size
                 j = int(np.searchsorted(csum, csum[i] + cap,
@@ -243,22 +267,22 @@ class ContainerStore:
                         self._open_locked(lane)
                         continue
                     j = i + 1
-                blob = native.gather_ranges(data, starts[i:j],
-                                            lens[i:j]).tobytes()
+                    self._oversize_locked(lane, int(lens[i]))
+                end = lane.size + int(csum[j] - csum[i])
+                seg = lane.buffer[lane.size:end]
+                native.gather_ranges(data, starts[i:j], lens[i:j], out=seg)
                 if lane.fh is not None:
-                    lane.fh.write(blob)
+                    lane.fh.write(seg)
                 out_cid[i:j] = lane.container_id
                 out_off[i:j] = lane.size + (csum[i:j] - csum[i])
-                lane.image += blob
-                lane.size += int(csum[j] - csum[i])
+                lane.size = end
                 i = j
             if lane.fh is not None:
                 lane.fh.flush()
                 if sync and self._fsync:
                     os.fsync(lane.fh.fileno())
         _M.incr("chunks_appended", n)
-        return [(int(c), int(o), int(ln))
-                for c, o, ln in zip(out_cid, out_off, lens)]
+        return list(zip(out_cid.tolist(), out_off.tolist(), lens.tolist()))
 
     def sync_lanes(self) -> None:
         """Flush (and, under the fsync policy, fsync) every open lane — the
@@ -275,9 +299,15 @@ class ContainerStore:
         with self._alloc_lock:
             cid = self._next_id
             self._next_id += 1
+            buf = self._free.pop() if self._free else None
+        _M.incr("lane_buffer_allocs" if buf is None else "lane_buffer_reuses")
         lane.container_id = cid
         lane.size = 0
-        lane.image = bytearray()
+        # never waits for a seal to give one back: none free, allocate
+        # (``np.empty``: no fill, so a page is first touched by the bytes
+        # that land on it, inside the native gather)
+        lane.buffer = (np.empty(self._container_size, np.uint8)
+                       if buf is None else buf)
         # Write-through WITHOUT fsync (unless the strict policy is on):
         # process death loses nothing (the page cache survives), OS-crash
         # durability comes from replication — HDFS's own block-data story.
@@ -292,18 +322,58 @@ class ContainerStore:
         # ~35% of ingest host cost for codec "none").
         lane.fh.write(_SEAL_HDR.pack(_RAW_MAGIC, 0, 0))
 
+    def _oversize_locked(self, lane: _Lane, need: int) -> None:
+        """A chunk larger than ``container_size`` lands alone in an empty
+        container: that one gets a buffer of the chunk's size, and the
+        free list gets back the one nothing has seen."""
+        assert lane.size == 0 and need > lane.buffer.size
+        with self._alloc_lock:
+            self._reuse_locked(lane.buffer)
+        lane.buffer = np.empty(need, np.uint8)
+        _M.incr("lane_buffer_allocs")
+
+    def _reuse_locked(self, buf: np.ndarray) -> None:
+        """Under ``_alloc_lock``: a buffer nothing can see any more, for a
+        later ``_open_locked``.  The list keeps no more than the lane count
+        plus the seals still in flight (what a burst allocated goes as its
+        seals finish), and only buffers of ``container_size``."""
+        if buf.size == self._container_size:
+            self._free.append(buf)
+        del self._free[len(self._lanes) + self._sealing:]
+
+    def _seal_payload(self, cid: int, payload: memoryview, had_raw: bool,
+                      on_seal, comp) -> None:
+        """``seal`` + ``on_seal`` of a rolled-over lane's container (inline
+        or on the seal thread), and then — nothing can see it any more —
+        its buffer back to the free list.  A seal that raised keeps the
+        buffer out of it."""
+        sealed = False
+        try:
+            self.seal(cid, data=payload, have_raw=had_raw, comp=comp)
+            if on_seal is not None:
+                on_seal(cid)
+            sealed = True
+        finally:
+            with self._alloc_lock:
+                self._sealing -= 1
+                if sealed:
+                    self._reuse_locked(payload.obj)
+
     def _seal_locked(self, lane: _Lane, on_seal, comp=None) -> None:
         had_raw = lane.fh is not None
         if had_raw:
             lane.fh.close()
-        # the in-memory mirror spares the seal a full read-back of the file
+        # the lane's buffer spares the seal a full read-back of the file
         # (measured ~10% of ingest host cost at 32 MiB containers).  It is
-        # handed on as it is — a copy here is 32 MiB of fresh pages under
-        # the lane's lock, on the commit thread — and dropped from the lane
-        # below: ``_open_locked`` starts the next container in a new buffer
-        payload = lane.image
+        # handed on where it lies — a copy here is 32 MiB of fresh pages
+        # under the lane's lock, on the commit thread — and dropped from
+        # the lane below: ``_open_locked`` starts the next container in
+        # another buffer, and this one is free again after its seal
+        payload = lane.view()
         if self._on_roll is not None:
             self._on_roll(lane.container_id, payload)
+        with self._alloc_lock:
+            self._sealing += 1
         if self._seal_q is not None:
             # Async stage: hand the payload to the seal worker and return —
             # the appending (commit) thread never pays the compressor.  Safe
@@ -315,20 +385,18 @@ class ContainerStore:
                               comp))
             _M.incr("async_seals")
         else:
-            self.seal(lane.container_id, data=payload, have_raw=had_raw,
-                      comp=comp)
-            if on_seal is not None:
-                on_seal(lane.container_id)
+            self._seal_payload(lane.container_id, payload, had_raw, on_seal,
+                               comp)
         lane.fh = None
-        lane.image = None
+        lane.buffer = None
 
     def seal(self, cid: int, data=None, have_raw: bool | None = None,
              comp=None) -> None:
         """Compress a raw container into the sealed format (the rollover LZ4
         pass, DataDeduplicator.java:770-781).  ``data`` carries the
-        container's chunk bytes when the caller already holds them (the
-        open-lane mirror, any bytes-like); otherwise they are read from the
-        raw file.
+        container's chunk bytes when the caller already holds them (a view
+        of the lane's buffer, any bytes-like); otherwise they are read from
+        the raw file.
         ``have_raw=False`` (memory-resident lane) writes the sealed file
         directly — there is no raw file to stamp or remove.  ``comp`` is
         the already-compressed payload when the caller ran the compressor
@@ -405,19 +473,21 @@ class ContainerStore:
             sealable = []
             for lane in self._lanes:
                 stack.enter_context(lane.lock)
-                if lane.image is not None and lane.size > 0:
+                if lane.buffer is not None and lane.size > 0:
                     sealable.append(lane)
-                elif lane.image is not None:
+                elif lane.buffer is not None:
                     if lane.fh is not None:
                         lane.fh.close()
                         os.unlink(self._raw_path(lane.container_id))
                         lane.fh = None
-                    lane.image = None
+                    with self._alloc_lock:  # opened, empty: nothing saw it
+                        self._reuse_locked(lane.buffer)
+                    lane.buffer = None
             comps = None
             if (self._compress_batch_fn is not None and len(sealable) > 1
                     and self._codec != "none"):
                 comps = self._compress_batch_fn(
-                    [l.image for l in sealable])
+                    [l.view() for l in sealable])
                 _M.incr("batch_seals", len(sealable))
             for lane, comp in zip(sealable, comps or [None] * len(sealable)):
                 self._seal_locked(lane, on_seal, comp=comp)
@@ -445,11 +515,8 @@ class ContainerStore:
             if item is None:
                 self._seal_q.task_done()
                 return
-            cid, payload, had_raw, on_seal, comp = item
             try:
-                self.seal(cid, data=payload, have_raw=had_raw, comp=comp)
-                if on_seal is not None:
-                    on_seal(cid)
+                self._seal_payload(*item)
             except BaseException as e:  # noqa: BLE001 — re-raised at drain
                 self._seal_exc = e
             finally:
@@ -499,8 +566,8 @@ class ContainerStore:
 
         for lane in self._lanes:
             with lane.lock:
-                if lane.container_id == cid and lane.image is not None:
-                    accounting.record_container_decode(len(lane.image))
+                if lane.container_id == cid and lane.buffer is not None:
+                    accounting.record_container_decode(lane.size)
                     return lane.snapshot()  # open lane: serve from memory
         try:
             # Still-open container: read raw bytes directly
@@ -703,8 +770,8 @@ class ContainerStore:
         the sealed file (uncompressed size from its fsync'd header)."""
         for lane in self._lanes:
             with lane.lock:
-                if lane.container_id == cid and lane.image is not None:
-                    return len(lane.image) >= need_bytes
+                if lane.container_id == cid and lane.buffer is not None:
+                    return lane.size >= need_bytes
         try:
             sz = os.path.getsize(self._raw_path(cid))
             return sz - _SEAL_HDR.size >= need_bytes

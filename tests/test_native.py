@@ -199,3 +199,61 @@ def test_sha256_batch_bounds_check():
     with pytest.raises(ValueError):
         native.sha256_batch(data, np.array([90], dtype=np.uint64),
                             np.array([20], dtype=np.uint64))
+
+
+def _ranges(kind: str, size: int):
+    """(starts, lens) over a buffer of ``size`` bytes."""
+    if kind == "contiguous":        # every range starts where the last ended
+        cuts = np.sort(RNG.choice(np.arange(1, size), 40, replace=False))
+        edges = np.concatenate([[0], cuts, [size]])
+        return edges[:-1], np.diff(edges)
+    if kind == "fragmented":        # gaps, and some ranges out of order
+        starts = RNG.permutation(np.arange(0, size - 64, 97))[:50]
+        return starts, RNG.integers(0, 64, starts.size)
+    if kind == "runs":              # runs of adjacent ranges between gaps
+        starts, lens, at = [], [], 0
+        while at + 200 < size:
+            for ln in RNG.integers(1, 40, RNG.integers(1, 5)):
+                starts.append(at)
+                lens.append(int(ln))
+                at += int(ln)
+            at += int(RNG.integers(1, 30))
+        return np.array(starts), np.array(lens)
+    assert kind == "empty"
+    return np.array([], np.uint64), np.array([], np.uint64)
+
+
+@pytest.mark.parametrize("dest", ["exact", "roomy", "bytearray", "slice"])
+@pytest.mark.parametrize("kind", ["contiguous", "fragmented", "runs", "empty"])
+def test_gather_ranges_vs_numpy(kind, dest):
+    data = RNG.integers(0, 256, 5000, dtype=np.uint8)
+    starts, lens = _ranges(kind, data.size)
+    want = b"".join(data[int(s):int(s) + int(n)].tobytes()
+                    for s, n in zip(starts, lens))
+    # "slice": the store's shape, a destination part-way into a larger buffer
+    before, room = {"exact": (0, 0), "roomy": (0, 333), "bytearray": (0, 7),
+                    "slice": (129, 64)}[dest]
+    whole = (bytearray(b"\xee" * (len(want) + room)) if dest == "bytearray"
+             else np.full(before + len(want) + room, 0xEE, np.uint8))
+    out = whole if dest == "bytearray" else whole[before:]  # a view
+    got = native.gather_ranges(data, starts, lens, out=out)
+    # filled in place, and not a byte before it or past the ranges' total
+    assert bytes(whole[:before]) == b"\xee" * before
+    assert bytes(out[:len(want)]) == want
+    assert bytes(out[len(want):]) == b"\xee" * room
+    assert np.shares_memory(got, np.frombuffer(whole, np.uint8)) or not want
+    assert got.tobytes() == want
+
+
+def test_gather_ranges_checks_its_destination():
+    data = RNG.integers(0, 256, 1000, dtype=np.uint8)
+    starts, lens = [0, 500], [100, 200]
+    with pytest.raises(ValueError, match="smaller"):
+        native.gather_ranges(data, starts, lens, out=np.empty(299, np.uint8))
+    with pytest.raises(ValueError, match="read-only"):
+        native.gather_ranges(data, starts, lens, out=bytes(300))
+    with pytest.raises(ValueError):             # not C-contiguous
+        native.gather_ranges(data, starts, lens,
+                             out=np.empty(600, np.uint8)[::2])
+    with pytest.raises(ValueError, match="exceeds"):
+        native.gather_ranges(data, [900], [101], out=np.empty(200, np.uint8))

@@ -4,8 +4,16 @@ import os
 
 import pytest
 
-from hdrf_tpu.storage.container_store import ContainerStore
+from hdrf_tpu.storage.container_store import (_RAW_MAGIC, _SEAL_HDR,
+                                              ContainerStore)
 from hdrf_tpu.storage.replica_store import ReplicaStore
+from hdrf_tpu.utils import metrics
+
+
+def _buffer_counts() -> tuple[int, int]:
+    """(``lane_buffer_allocs``, ``lane_buffer_reuses``) of this process."""
+    m = metrics.registry("container_store")
+    return m.counter("lane_buffer_allocs"), m.counter("lane_buffer_reuses")
 
 
 class TestContainerStore:
@@ -78,16 +86,19 @@ class TestContainerStore:
 
 
 class TestSealHandOff:
-    """A rolled-over lane's ``bytearray`` goes to the seal as it is
-    (``_seal_locked``): no copy on the committing thread, and nothing the
-    lane does afterwards reaches it."""
+    """A rolled-over lane's buffer goes to the seal where it lies
+    (``_seal_locked``): a view of the lane's own memory, no copy on the
+    committing thread; nothing the lane does afterwards reaches it until its
+    seal has returned, and then a later container takes it."""
 
     @staticmethod
-    def _store(tmp_path, seen, async_seals, **kw):
+    def _store(tmp_path, seen, async_seals, gate=None, **kw):
         def compress_fn(data):
             from hdrf_tpu.utils import codec as codecs
 
-            seen.append((type(data), bytes(data), data))
+            if gate is not None:
+                assert gate.wait(30), "the test never opened the gate"
+            seen.append((type(data), bytes(data), data.obj))
             return codecs.compress("lz4", data)
 
         cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
@@ -101,11 +112,18 @@ class TestSealHandOff:
     @pytest.mark.parametrize("append", ["chunks", "ranges"])
     def test_the_seal_gets_the_lanes_buffer_and_the_lane_a_new_one(
             self, tmp_path, async_seals, append):
+        import threading
+
         import numpy as np
 
         seen, rolled = [], []
-        cs = self._store(tmp_path, seen, async_seals,
-                         on_roll=lambda cid, p: rolled.append((cid, p)))
+        # async: the seal is held until the lane has moved on, so which
+        # buffer the lane opens next does not hang on a race
+        gate = threading.Event() if async_seals else None
+        cs = self._store(tmp_path, seen, async_seals, gate=gate,
+                         on_roll=lambda cid, p: rolled.append(
+                             (cid, type(p), bytes(p), p.obj)))
+        lane = cs._lanes[0]
 
         def put(blob: bytes):
             if append == "chunks":
@@ -114,19 +132,33 @@ class TestSealHandOff:
                                     [0, 400], [400, len(blob) - 400])
 
         first, second = b"x" * 300 + b"y" * 400, b"z" * 650
+        allocs, reuses = _buffer_counts()
         locs1 = put(first)
-        open_image = cs._lanes[0].image
+        open_buf = lane.buffer
+        assert open_buf.size == 1000 and lane.size == len(first)
+        assert lane.view().obj is open_buf and bytes(lane.view()) == first
         locs2 = put(second)                       # rolls the first over
-        later = cs.append_chunks([b"w" * 100])    # same lane, new buffer
+        later = cs.append_chunks([b"w" * 100])    # same lane
+        if async_seals:
+            # its seal has not returned: the lane is in another buffer
+            assert lane.buffer is not open_buf
+            assert _buffer_counts() == (allocs + 2, reuses)
+            gate.set()
+        else:
+            # its seal returned before the lane opened again: taken back
+            assert lane.buffer is open_buf
+            assert _buffer_counts() == (allocs + 1, reuses + 1)
         cs.drain_seals()
+        # the seal saw the lane's own memory, no copy, and as much of it as
+        # was the container
         (kind, sealed_bytes, handed), = seen
-        assert kind is bytearray and handed is open_image
-        assert rolled == [(locs1[0][0], open_image)]
-        assert rolled[0][1] is open_image
+        assert kind is memoryview and handed is open_buf
+        assert rolled == [(locs1[0][0], memoryview, first, open_buf)]
         # what was appended before the rollover, and only that
-        assert sealed_bytes == first and bytes(handed) == first
-        assert cs._lanes[0].image is not handed
-        assert bytes(cs._lanes[0].image) == second + b"w" * 100
+        assert sealed_bytes == first
+        assert bytes(lane.view()) == second + b"w" * 100
+        if async_seals:
+            assert len(cs._free) == 1 and cs._free[0] is open_buf
         assert cs.read_container(locs1[0][0]) == first
         assert cs.read_chunks(locs2 + later) == \
             [second[:400], second[400:], b"w" * 100]
@@ -141,11 +173,11 @@ class TestSealHandOff:
         single, grouped = [], []
 
         def batch_fn(datas):
-            grouped.append([type(d) for d in datas])
+            grouped.append([(type(d), d.obj, len(d)) for d in datas])
             return [codecs.compress("lz4", d) for d in datas]
 
         def one(data):
-            single.append(type(data))
+            single.append((type(data), data.obj, len(data)))
             return codecs.compress("lz4", data)
 
         cs = ContainerStore(str(tmp_path), container_size=1 << 20, lanes=3,
@@ -153,21 +185,25 @@ class TestSealHandOff:
                             compress_batch_fn=batch_fn if batch else None)
         chunks = [b"a" * 5000, b"b" * 7000, b"c" * 100]
         locs = [cs.append_chunks([c])[0] for c in chunks]
-        images = [lane.image for lane in cs._lanes]
+        bufs = [lane.buffer for lane in cs._lanes]
+        # each lane's own memory, as much of it as is the container
+        want = [(memoryview, b, len(c)) for b, c in zip(bufs, chunks)]
         cs.flush_open()
         if batch:
-            assert grouped == [[bytearray] * 3] and not single
+            assert grouped == [want] and not single
         else:
-            assert single == [bytearray] * 3 and not grouped
-        assert [bytes(i) for i in images] == chunks      # left as they were
-        assert all(lane.image is None for lane in cs._lanes)
+            assert single == want and not grouped
+        assert [bytes(b[:len(c)]) for b, c in zip(bufs, chunks)] == chunks
+        assert all(lane.buffer is None for lane in cs._lanes)
+        # the tails' buffers come back like any sealed container's
+        assert sorted(map(id, cs._free)) == sorted(map(id, bufs))
         assert cs.read_chunks(locs) == chunks
         for cid, _, _ in locs:
             assert os.path.exists(tmp_path / f"{cid}.sealed")
 
     def test_a_memory_resident_lane_seals_from_its_buffer(self, tmp_path):
         """``have_raw=False``: no raw file to stamp or remove, the sealed
-        file is written from the ``bytearray`` alone."""
+        file is written from the lane's buffer alone."""
         seen = []
         cs = self._store(tmp_path, seen, async_seals=False)
         locs = cs.append_chunks([b"m" * 900])
@@ -176,7 +212,7 @@ class TestSealHandOff:
         os.unlink(tmp_path / f"{locs[0][0]}.raw")
         lane.fh = None
         cs.append_chunks([b"n" * 900])            # rolls the first over
-        assert seen[0][0] is bytearray and seen[0][1] == b"m" * 900
+        assert seen[0][0] is memoryview and seen[0][1] == b"m" * 900
         assert os.path.exists(tmp_path / f"{locs[0][0]}.sealed")
         assert not os.path.exists(tmp_path / f"{locs[0][0]}.raw")
         assert cs.read_chunks(locs) == [b"m" * 900]
@@ -200,6 +236,215 @@ class TestSealHandOff:
         got = cs.read_container(locs[0][0])
         cs.append_chunks([b"s" * 300])
         assert type(got) is bytes and got == b"r" * 300
+
+
+def _layout_oracle(chunks: list[bytes], size: int, first_cid: int,
+                   open_bytes: bytes = b""):
+    """The plain rule of the container layout, nothing of the store's: a
+    chunk that does not fit seals the open container first, an oversized
+    one lands alone in an empty one.  Returns the triples and every
+    container's bytes (a ``b"".join``), the last of them the open one."""
+    cid, parts, fill = first_cid, [open_bytes], len(open_bytes)
+    locs, containers = [], {}
+    for c in chunks:
+        if fill + len(c) > size and fill > 0:
+            containers[cid] = b"".join(parts)
+            cid, parts, fill = cid + 1, [], 0
+        locs.append((cid, fill, len(c)))
+        parts.append(c)
+        fill += len(c)
+    containers[cid] = b"".join(parts)
+    return locs, containers
+
+
+# (what, container_size, the chunk lengths of each call in turn)
+_LAYOUTS = [
+    ("contiguous", 1000, [[100, 200, 300], [150, 50]]),
+    ("fragmented", 1000, [[100, 200, 300], [150, 50]]),
+    ("rollover-inside-one-call", 1000, [[400, 400, 400, 300, 500, 600]]),
+    ("exact-fit", 1000, [[600, 400], [1000], [1]]),
+    ("oversized-chunk", 1000, [[300], [2500, 10], [1001]]),
+    ("oversized-first", 1000, [[4000], [200]]),
+]
+
+
+class TestLaneBuffer:
+    """The open container's buffer: allocated whole, filled in place, written
+    to the raw file from a view, and taken again after its seal."""
+
+    @pytest.mark.parametrize("append", ["chunks", "ranges"])
+    @pytest.mark.parametrize("what,size,calls", _LAYOUTS,
+                             ids=[c[0] for c in _LAYOUTS])
+    def test_appends_match_the_join_oracle(self, tmp_path, append, what,
+                                           size, calls):
+        """Triples, raw-file bytes and sealed files against a ``b"".join``:
+        the raw file holds every appended byte when the append returns,
+        before any seal."""
+        import numpy as np
+
+        from hdrf_tpu.utils import codec as codecs
+
+        rng = np.random.default_rng(len(what))
+        cs = ContainerStore(str(tmp_path), container_size=size, lanes=1,
+                            codec="lz4")
+        first_cid, open_bytes, all_locs, all_chunks = 0, b"", [], []
+        sealed = {}
+        for lens in calls:
+            # half-compressible bytes, so some containers seal to LZ4 frames
+            chunks = [bytes(rng.integers(0, 4, n, dtype=np.uint8))
+                      for n in lens]
+            if append == "chunks":
+                locs = cs.append_chunks(chunks)
+            else:
+                gap = 0 if what == "contiguous" else 7
+                block, starts = bytearray(), []
+                for c in chunks:
+                    block += b"\xff" * gap
+                    starts.append(len(block))
+                    block += c
+                locs = cs.append_ranges(np.frombuffer(block, np.uint8),
+                                        starts, lens)
+            want, containers = _layout_oracle(chunks, size, first_cid,
+                                              open_bytes)
+            assert locs == want
+            assert all(type(v) is int for loc in locs for v in loc)
+            first_cid, open_bytes = list(containers.items())[-1]
+            sealed.update(list(containers.items())[:-1])
+            # the open container: in the lane, and in its raw file already
+            lane = cs._lanes[0]
+            assert lane.container_id == first_cid
+            assert bytes(lane.view()) == open_bytes
+            assert cs.has_container(first_cid, len(open_bytes))
+            assert not cs.has_container(first_cid, len(open_bytes) + 1)
+            with open(tmp_path / f"{first_cid}.raw", "rb") as f:
+                assert f.read() == \
+                    _SEAL_HDR.pack(_RAW_MAGIC, 0, 0) + open_bytes
+            all_locs += locs
+            all_chunks += chunks
+        cs.flush_open()
+        sealed[first_cid] = open_bytes
+        assert cs.container_ids() == sorted(sealed)
+        for cid, want_bytes in sealed.items():
+            codec, usize, payload = cs._sealed_parse(cid)
+            assert usize == len(want_bytes)
+            assert codecs.decompress(codec, payload, usize) == want_bytes
+        assert cs.read_chunks(all_locs) == all_chunks
+
+    def test_a_parked_seal_keeps_its_buffer_to_itself(self, tmp_path):
+        """The compressor parked while two further containers roll over:
+        what it then reads is still its own container, no buffer is out
+        twice at once, and the counters read what the sequence implies."""
+        import threading
+
+        from hdrf_tpu.utils import codec as codecs
+
+        gate, parked = threading.Event(), threading.Event()
+        seen, out = [], []             # out: buffers a seal may still see
+
+        def compress_fn(data):
+            parked.set()
+            assert gate.wait(30), "the test never opened the gate"
+            seen.append(bytes(data))
+            return codecs.compress("lz4", data)
+
+        def on_seal(cid):
+            out.pop(0)                 # seals finish in the order queued
+
+        cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
+                            codec="lz4", compress_fn=compress_fn,
+                            on_roll=lambda cid, p: out.append(p.obj))
+        cs.enable_async_seals()
+        lane = cs._lanes[0]
+        allocs, reuses = _buffer_counts()
+        locs, held = [], []
+        for fill in b"abcd":           # b, c, d each roll the one before
+            locs += cs.append_chunks([bytes([fill]) * 700], on_seal=on_seal)
+            assert all(lane.buffer is not b for b in out + held)
+            held.append(lane.buffer)
+        assert parked.wait(30)
+        # a parked, b and c queued behind it, d open: four buffers, none free
+        assert len(out) == 3 and not cs._free
+        assert _buffer_counts() == (allocs + 4, reuses)
+        gate.set()
+        cs.drain_seals()
+        assert seen == [bytes([f]) * 700 for f in b"abc"]
+        # the list keeps lanes + seals in flight (1 + 2, 1 + 1, 1 + 0):
+        # what the burst allocated went as its seals finished
+        assert len(cs._free) == 1 and cs._free[0] is held[0]
+        for fill in b"ef":             # two more rollovers: both reuse
+            locs += cs.append_chunks([bytes([fill]) * 700], on_seal=on_seal)
+            assert all(lane.buffer is not b for b in out)
+            cs.drain_seals()
+        assert _buffer_counts() == (allocs + 4, reuses + 2)
+        cs.flush_open(on_seal=on_seal)
+        assert not out
+        assert cs.read_chunks(locs) == [bytes([f]) * 700 for f in b"abcdef"]
+        for (cid, _, _), fill in zip(locs, b"abcdef"):
+            codec, usize, payload = cs._sealed_parse(cid)
+            assert codecs.decompress(codec, payload, usize) == \
+                bytes([fill]) * 700
+        cs.close_async_seals()
+
+    def test_appenders_and_the_seal_thread_share_the_free_list(self, tmp_path):
+        """More appending threads than cores against one seal thread, the
+        interpreter switching every few microseconds: a buffer taken while
+        its seal still reads it would change under the compressor."""
+        import sys
+        import threading
+        import time
+        import zlib
+
+        import numpy as np
+
+        from hdrf_tpu.utils import codec as codecs
+
+        torn = []
+
+        def compress_fn(data):
+            before = zlib.crc32(data)
+            out = codecs.compress("lz4", data)
+            time.sleep(0.0005)
+            if zlib.crc32(data) != before:
+                torn.append(len(data))
+            return out
+
+        cs = ContainerStore(str(tmp_path), container_size=4096, lanes=2,
+                            codec="lz4", compress_fn=compress_fn)
+        cs.enable_async_seals()
+        n_threads = (os.cpu_count() or 4) + 2
+        wrote: list[list] = [[] for _ in range(n_threads)]
+        deadline = time.monotonic() + 1.5
+
+        def writer(k: int):
+            rng = np.random.default_rng(k)
+            while time.monotonic() < deadline and len(wrote[k]) < 400:
+                block = rng.integers(0, 8, 3000, dtype=np.uint8)
+                starts = [0, 700, 1500]
+                lens = [int(x) for x in rng.integers(1, 700, 3)]
+                for loc, s, n in zip(cs.append_ranges(block, starts, lens),
+                                     starts, lens):
+                    wrote[k].append((loc, block[s:s + n].tobytes()))
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=writer, args=(k,))
+                       for k in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+            cs.flush_open()
+        finally:
+            sys.setswitchinterval(old)
+        assert not torn
+        assert len(cs._free) <= len(cs._lanes)      # no seal in flight now
+        pairs = [p for w in wrote for p in w]
+        assert len(pairs) >= 3 * n_threads
+        assert cs.read_chunks([loc for loc, _ in pairs]) == \
+            [b for _, b in pairs]
+        cs.close_async_seals()
 
 
 class TestReplicaStore:
