@@ -43,6 +43,7 @@ import time
 from dataclasses import dataclass
 
 import msgpack
+import numpy as np
 
 from hdrf_tpu.utils import fault_injection, metrics, profiler, wal as walmod
 
@@ -90,6 +91,12 @@ class BlockEntry:
 
     logical_len: int
     hashes: list[bytes]
+    # end position of every chunk within the block (int64 prefix sums of
+    # the chunks' lengths), made by the first ranged read of the block
+    # (``ChunkIndex.block_range``) and kept with the entry: 8 bytes a chunk
+    # beside the ~100 its fingerprint takes.  A chunk's length never
+    # changes and a re-commit replaces the entry, so it cannot go stale.
+    ends: object = dataclasses.field(default=None, repr=False, compare=False)
 
 
 class _GCEntry:
@@ -489,6 +496,48 @@ class ChunkIndex:
         with self._lock:
             e = self._blocks.get(block_id)
             return BlockEntry(e.logical_len, list(e.hashes)) if e else None
+
+    def block_range(self, block_id: int, offset: int = 0,
+                    length: int = -1):
+        """The chunks of a block that overlap ``[offset, offset+length)``
+        (``length`` -1: to its end): ``(logical_len, start, [(hash,
+        ChunkLocation), ...])`` with ``start`` the first one's position in
+        the block, or None for an unindexed block.  Positions come from the
+        entry's ``ends``, so a 1 MB read of a 128 MiB block looks up its
+        128 chunks and not all 16 384, under this lock, which every commit
+        needs.  Locations are copies, as ``lookup_chunks`` gives them.
+        Raises IOError for a chunk missing from the index or lengths that
+        do not sum to the block's (index corruption), found when ``ends``
+        is made."""
+        with self._lock:
+            e = self._blocks.get(block_id)
+            if e is None:
+                return None
+            ends = e.ends
+            if ends is None:
+                lens = []
+                for h in e.hashes:
+                    loc = self._chunks.get(h)
+                    if loc is None:
+                        raise IOError(f"block {block_id}: chunk {h.hex()} "
+                                      f"missing from index")
+                    lens.append(loc.length)
+                ends = np.cumsum(lens, dtype=np.int64)
+                total = int(ends[-1]) if lens else 0
+                if total != e.logical_len:
+                    raise IOError(f"block {block_id}: chunk lengths sum to "
+                                  f"{total}, index says {e.logical_len}")
+                e.ends = ends
+            end = e.logical_len if length < 0 else min(offset + length,
+                                                       e.logical_len)
+            if offset >= end:
+                return e.logical_len, 0, []
+            i0 = int(np.searchsorted(ends, offset, side="right"))
+            i1 = int(np.searchsorted(ends, end, side="left")) + 1
+            start = int(ends[i0 - 1]) if i0 else 0
+            return e.logical_len, start, [
+                (h, dataclasses.replace(self._chunks[h]))
+                for h in e.hashes[i0:i1]]
 
     def has_block(self, block_id: int) -> bool:
         with self._lock:

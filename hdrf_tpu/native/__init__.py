@@ -256,16 +256,27 @@ def lz4_unpack_records(row: np.ndarray, p3: int, nv: int, stride: int,
     return pos[:nrec], dl[:nrec], int(nrec)
 
 
-def lz4_decompress(data: bytes | np.ndarray, decompressed_size: int) -> bytes:
+def lz4_decompress(data: bytes | np.ndarray, decompressed_size: int,
+                   out=None) -> bytes | np.ndarray:
+    """``out`` (a writable bytes-like or C-contiguous uint8 array of at
+    least ``decompressed_size``: a decoded container's buffer, kept and
+    reused by its owner) receives the bytes where it lies and the filled
+    part of it is returned; without it, fresh ``bytes`` — one more copy of
+    the whole output, made with the interpreter held."""
     a = _as_u8(data)
     if decompressed_size == 0:
-        return b""
-    out = np.empty(decompressed_size, dtype=np.uint8)
-    n = _load().hdrf_lz4_decompress(_ptr(a, _u8p), a.size, _ptr(out, _u8p),
+        return b"" if out is None else _as_u8(out)[:0]
+    dst = (np.empty(decompressed_size, dtype=np.uint8) if out is None
+           else _as_u8(out))
+    if not dst.flags.writeable:
+        raise ValueError("destination is read-only")
+    if dst.size < decompressed_size:
+        raise ValueError("destination smaller than the decompressed size")
+    n = _load().hdrf_lz4_decompress(_ptr(a, _u8p), a.size, _ptr(dst, _u8p),
                                     decompressed_size)
     if n != decompressed_size:
         raise RuntimeError(f"lz4 decompression failed: got {n}, want {decompressed_size}")
-    return out.tobytes()
+    return dst.tobytes() if out is None else dst[:decompressed_size]
 
 
 def chacha20_xor(key: bytes, nonce: bytes, data: bytes | np.ndarray,

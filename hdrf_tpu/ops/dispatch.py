@@ -211,22 +211,24 @@ def _lz4_device():
 
 
 def block_decompress_batch(codec_names: list, blobs: list, usizes: list,
-                           backend: str = "native") -> list:
+                           outs: list, backend: str = "native") -> list:
     """Batched decode dispatch for the read coalescer
     (server/read_plane.py): one call decodes a whole coalesced window of
-    sealed-container payloads.  LZ4 decode is byte-serial in its output
-    dependence (ops/reconstruct.py:1-30), so the decode itself always runs
-    the host oracle — the same one that verifies the TPU compressor's
-    output (ops/lz4_tpu.py:63); this surface is the grouped DISPATCH seam,
-    mirroring block_compress_batch's shape so per-window accounting lands
-    in one place and a future device decoder slots in without touching
-    callers."""
+    sealed-container payloads, each into its buffer of ``outs`` (uint8
+    arrays of at least the payload's ``usize`` — the container store's
+    own, reused) and gives back the filled views.  LZ4 decode is
+    byte-serial in its output dependence (ops/reconstruct.py:1-30), so
+    the decode itself always runs the host oracle — the same one that
+    verifies the TPU compressor's output (ops/lz4_tpu.py:63); this surface
+    is the grouped DISPATCH seam, mirroring block_compress_batch's shape
+    so per-window accounting lands in one place and a future device
+    decoder slots in without touching callers."""
     _M.incr(f"decompress_{backend}_total", len(blobs))
     _M.incr(f"decompress_{backend}_bytes", sum(usizes))
     from hdrf_tpu.utils import codec as codecs
 
-    return [codecs.decompress(c, b, u)
-            for c, b, u in zip(codec_names, blobs, usizes)]
+    return [codecs.decompress_into(c, b, u, o)
+            for c, b, u, o in zip(codec_names, blobs, usizes, outs)]
 
 
 def block_compress_batch(codec: str, datas: list,

@@ -317,17 +317,17 @@ class DedupScheme(ReductionScheme):
                 return bytes(out)
             if ctx.read_plane is not None:
                 # shared decoded-chunk cache + coalesced container decodes
-                # (the coalescer records its own container_decode spans)
                 chunks = ctx.read_plane.fetch_chunks(plan)
+            else:
+                chunks = ctx.containers.read_chunks(plan.wanted)
+            # (the store records its own container_load / container_decode
+            # / chunk_copy spans; this is the materialising copy)
+            with profiler.phase("chunk_copy"):
                 for chunk, (out_at, lo, n) in zip(chunks, plan.spans):
                     out[out_at:out_at + n] = chunk[lo:lo + n]
-            else:
-                with profiler.phase("container_decode"):
-                    chunks = ctx.containers.read_chunks(plan.wanted)
-                    for chunk, (out_at, lo, n) in zip(chunks, plan.spans):
-                        out[out_at:out_at + n] = chunk[lo:lo + n]
+                data = bytes(out)
         _M.incr("blocks_reconstructed")
-        return bytes(out)
+        return data
 
     def delete(self, block_id: int, ctx: ReductionContext) -> None:
         assert ctx.index is not None

@@ -58,14 +58,14 @@ class BlockSender:
         if meta is None:
             # PROVIDED replica: bytes live in the external store the alias
             # map points at (FileRegion -> ProvidedStorageLocation)
-            with dn.read_slot(), profiler.phase("container_decode"):
+            with dn.read_slot(), profiler.phase("container_load"):
                 data = dn.aliasmap.read_bytes(block_id, offset, length)
             if data is not None:
                 _M.incr("provided_serves")
                 return data
             raise KeyError(f"block {block_id} not on this datanode")
         scheme = dn.scheme(meta.scheme)
-        with profiler.phase("container_decode"):
+        with profiler.phase("container_load"):
             stored = (dn.replicas.read_data(block_id)
                       if meta.physical_len else b"")
         with dn.read_slot():  # admission control (DataXceiver.java:313-347)
@@ -86,21 +86,23 @@ class BlockSender:
                 profiler.read_timeline(block_id) as tl:
             sp.annotate("block_id", block_id)
             try:
-                # Overload gate FIRST (utils/qos.py): over-rate tenants
-                # and ops whose deadline budget can't cover the p95
-                # estimate are refused here — before the read touches a
-                # slot, the cache, or the decode plane — with a structured
-                # retryable refusal instead of a mid-pipeline timeout.
-                # Unattributed requests (DN-to-DN reconstruction fan-in)
-                # are internal and never shed.
-                if tenant is not None:
-                    dn.qos.admit(tenant, "read")
-                # Umbrella phase: gaps between the inner spans (scheme
-                # resolution, read-slot admission, the materialize copy)
-                # attribute here; nested index_lookup/cache_probe spans
-                # still win their intervals (PHASE_ORDER lists them first).
+                # Covering phase of a read's service: what no finer read
+                # phase names (the overload gate, scheme resolution, the
+                # accounting, a whole-block scheme's own decode) attributes
+                # here; the nested index_lookup / cache_probe / read_admit
+                # / container_load / container_decode / chunk_copy spans
+                # win their intervals (PHASE_ORDER lists them first).
                 with qos.bind_tenant(tenant), \
-                        profiler.phase("container_decode"):
+                        profiler.phase("read_serve"):
+                    # Overload gate FIRST (utils/qos.py): over-rate tenants
+                    # and ops whose deadline budget can't cover the p95
+                    # estimate are refused here — before the read touches
+                    # a slot, the cache, or the decode plane — with a
+                    # structured retryable refusal instead of a
+                    # mid-pipeline timeout.  Unattributed requests (DN-to-DN
+                    # reconstruction fan-in) are internal and never shed.
+                    if tenant is not None:
+                        dn.qos.admit(tenant, "read")
                     with profiler.phase("index_lookup"):
                         meta = dn.replicas.get_meta(block_id)
                         region = (dn.aliasmap.read(block_id) if meta is None

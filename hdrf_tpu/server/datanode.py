@@ -561,7 +561,9 @@ class DataNode:
 
     @contextlib.contextmanager
     def read_slot(self) -> Iterator[None]:
-        if not self._read_sem.acquire(timeout=300):
+        with profiler.phase("read_admit"):
+            admitted = self._read_sem.acquire(timeout=300)
+        if not admitted:
             raise TimeoutError("read admission timeout")
         try:
             yield

@@ -29,11 +29,15 @@ DISPATCH seam a future device decoder slots into, not a pretend TPU
 decoder; on this 1-vCPU host the honest wins are decode-once-per-container
 and fewer dispatch round trips (PERF_NOTES.md round 4).  At depth 1 / on
 the non-TPU backend the coalescer decodes inline on the caller's thread —
-bit-identical results, no extra hops.  Reads still attribute ≥95% of wall
-through the PR 11 read timelines: the worker binds the lead reader's
-timeline for the real ``container_decode`` spans and mirrors the window to
-every other member; the reader-side wait is its own ``decode_wait``
-transport phase.
+bit-identical results, no extra hops.  What crosses this plane is chunks,
+never containers: a request is the ``(container, offset, length)`` of every
+chunk it misses, the store copies those out of its decoded containers
+(``ContainerStore.read_chunks``, which records the load, decode and copy
+phases) and a whole decoded container never leaves it.  Reads still
+attribute ≥95% of wall through the PR 11 read timelines: the worker binds
+the lead reader's timeline for the real spans and mirrors the window to
+every other member as ``container_decode``; the reader-side wait is its
+own ``decode_wait`` transport phase.
 """
 
 from __future__ import annotations
@@ -96,42 +100,30 @@ class ChunkPlan:
 
 def resolve_chunk_plan(index, block_id: int, offset: int = 0,
                        length: int = -1) -> ChunkPlan:
-    """Position→chunk-range resolution over the chunk index: walk the
-    block's ordered hash list accumulating logical positions and keep only
-    the chunks overlapping [offset, offset+length) (quickBuildMT's
+    """Position→chunk-range resolution over the chunk index: the chunks
+    overlapping [offset, offset+length), found by position
+    (``ChunkIndex.block_range``: the block's hash list is not walked, only
+    the overlapping chunks are looked up) (quickBuildMT's
     group-by-container lookup, DataConstructor.java:360-417, with the
     range cut the reference never does).  ``length=-1`` means to EOF;
     a zero-length / past-EOF request resolves to an empty plan.  Raises
     KeyError for an unindexed block and IOError for a chunk missing from
     the index or a length-sum mismatch (index corruption)."""
-    entry = index.get_block(block_id)
-    if entry is None:
+    found = index.block_range(block_id, offset, length)
+    if found is None:
         raise KeyError(f"block {block_id} not in chunk index")
-    end = entry.logical_len if length < 0 else min(offset + length,
-                                                   entry.logical_len)
+    logical_len, pos, chunks = found
+    end = logical_len if length < 0 else min(offset + length, logical_len)
     plan = ChunkPlan(block_id=block_id, offset=offset, end=end,
-                     logical_len=entry.logical_len)
-    if offset >= end:
-        return plan
-    locmap = index.lookup_chunks(list(set(entry.hashes)))
-    pos = 0
-    for h in entry.hashes:
-        loc = locmap[h]
-        if loc is None:
-            raise IOError(f"block {block_id}: chunk {h.hex()} missing "
-                          f"from index")
+                     logical_len=logical_len)
+    for h, loc in chunks:
         c_start, c_len = pos, loc.length
         pos += c_len
-        if c_start >= end or c_start + c_len <= offset:
-            continue
         lo = max(offset, c_start) - c_start
         hi = min(end, c_start + c_len) - c_start
         plan.wanted.append((loc.container_id, loc.offset, loc.length))
         plan.hashes.append(h)
         plan.spans.append((max(offset, c_start) - offset, lo, hi - lo))
-    if pos != entry.logical_len:
-        raise IOError(f"block {block_id}: chunk lengths sum to {pos}, "
-                      f"index says {entry.logical_len}")
     return plan
 
 
@@ -165,33 +157,52 @@ class ChunkCache:
         return self._bytes
 
     def get(self, fp: bytes) -> bytes | None:
+        return self.get_many([fp])[0]
+
+    def get_many(self, fps: list) -> list:
+        """One probe a fingerprint (None for a miss) under ONE hold of the
+        lock, the counters moved once: a plan probes a hundred and more."""
+        out = []
         with self._lock:
-            data = self._data.pop(fp, None)
-            if data is None:
-                _M.incr("chunk_cache_miss")
-            else:
-                # true LRU: re-insert on hit (same discipline as the
-                # container LRU — FIFO evicts the hottest under cycles)
-                self._data[fp] = data
-                _M.incr("chunk_cache_hit")
+            for fp in fps:
+                data = self._data.pop(fp, None)
+                if data is not None:
+                    # true LRU: re-insert on hit (same discipline as the
+                    # container LRU — FIFO evicts the hottest under cycles)
+                    self._data[fp] = data
+                out.append(data)
+        hits = len(out) - out.count(None)
+        if hits:
+            _M.incr("chunk_cache_hit", hits)
+        if len(out) > hits:
+            _M.incr("chunk_cache_miss", len(out) - hits)
         _gauge_hit_ratio()
-        return data
+        return out
 
     def put(self, fp: bytes, data: bytes, cid: int) -> None:
-        if self._cap <= 0 or len(data) > self._cap:
-            return  # disabled, or a chunk that would evict everything
+        self.put_many([(fp, data, cid)])
+
+    def put_many(self, items: list) -> None:
+        """``(fp, data, cid)`` each, under one hold of the lock."""
+        if self._cap <= 0:
+            return  # disabled
+        evicted = 0
         with self._lock:
-            if fp in self._data:
-                self._drop_locked(fp)
-            self._data[fp] = data
-            self._cid_of[fp] = cid
-            self._by_cid.setdefault(cid, set()).add(fp)
-            self._bytes += len(data)
-            while self._bytes > self._cap:
-                victim = next(iter(self._data))
-                self._drop_locked(victim)
-                _M.incr("chunk_cache_evict")
+            for fp, data, cid in items:
+                if len(data) > self._cap:
+                    continue  # a chunk that would evict everything
+                if fp in self._data:
+                    self._drop_locked(fp)
+                self._data[fp] = data
+                self._cid_of[fp] = cid
+                self._by_cid.setdefault(cid, set()).add(fp)
+                self._bytes += len(data)
+                while self._bytes > self._cap:
+                    self._drop_locked(next(iter(self._data)))
+                    evicted += 1
             _M.gauge("chunk_cache_bytes", self._bytes)
+        if evicted:
+            _M.incr("chunk_cache_evict", evicted)
 
     def _drop_locked(self, fp: bytes) -> None:
         data = self._data.pop(fp, None)
@@ -224,11 +235,11 @@ class ChunkCache:
 
 
 class _Req:
-    __slots__ = ("cids", "future", "timeline", "tenant")
+    __slots__ = ("locs", "future", "timeline", "tenant")
 
-    def __init__(self, cids: list, future: Future, timeline,
+    def __init__(self, locs: list, future: Future, timeline,
                  tenant: str | None = None) -> None:
-        self.cids = cids
+        self.locs = locs
         self.future = future
         self.timeline = timeline
         self.tenant = tenant
@@ -264,13 +275,16 @@ class ReadCoalescer:
                                             name="read-plane", daemon=True)
             self._thread.start()
 
-    def _decomp(self, codec_names, blobs, usizes):
+    def _decomp(self, codec_names, blobs, usizes, outs):
         return dispatch.block_decompress_batch(codec_names, blobs, usizes,
-                                               self._backend)
+                                               outs, self._backend)
 
-    def fetch(self, cids: list, timeline=None,
-              tenant: str | None = None) -> dict:
-        """Decoded payloads for ``cids`` (cid -> bytes).  Blocks at the
+    def fetch(self, locs: list, timeline=None,
+              tenant: str | None = None) -> list:
+        """Decoded chunk bytes, one per ``locs`` entry (``(cid, off,
+        len)``): the store copies them out of its decoded containers
+        (``ContainerStore.read_chunks`` records the load, decode and copy
+        phases), a whole container never leaves it.  Blocks at the
         admission bound; in batched mode the call parks on the group's
         future while the worker decodes under the lead member's timeline.
         Sheds (qos.ShedError) BEFORE acquiring a permit when the ambient
@@ -281,16 +295,17 @@ class ReadCoalescer:
         # internal housekeeping — never shed them, only client traffic
         if self._qos is not None and tenant is not None:
             self._qos.admit(tenant, "read")
-        if not self._sem.acquire(timeout=300):
+        with profiler.phase("read_admit"):
+            admitted = self._sem.acquire(timeout=300)
+        if not admitted:
             raise TimeoutError("read plane admission timeout")
         try:
             if self._thread is None:
                 _M.incr("inline_decodes")
-                with profiler.phase("container_decode"):
-                    return self._containers.read_containers(
-                        cids, decompress_batch=self._decomp)
+                return self._containers.read_chunks(
+                    locs, decompress_batch=self._decomp)
             fut: Future = Future()
-            self._q.put(_Req(list(cids), fut,
+            self._q.put(_Req(list(locs), fut,
                              timeline or profiler.current_timeline(),
                              tenant))
             with profiler.phase("decode_wait"):
@@ -329,16 +344,16 @@ class ReadCoalescer:
                 return
 
     def _serve(self, group: list) -> None:
-        cids = list(dict.fromkeys(c for r in group for c in r.cids))
+        locs = [loc for r in group for loc in r.locs]
         lead = group[0].timeline
         t0 = profiler.mark()
         try:
-            # the lead reader's timeline is ambient for the real decode
-            # spans; the shared window is mirrored to the rest below
-            with profiler.bind_timeline(lead), \
-                    profiler.phase("container_decode"):
-                datas = self._containers.read_containers(
-                    cids, decompress_batch=self._decomp)
+            # the lead reader's timeline is ambient for the real load /
+            # decode / copy spans; the shared window is mirrored to the
+            # rest below
+            with profiler.bind_timeline(lead):
+                chunks = self._containers.read_chunks(
+                    locs, decompress_batch=self._decomp)
         except BaseException as e:  # noqa: BLE001 — readers unwrap
             for r in group:
                 if not r.future.done():
@@ -346,13 +361,15 @@ class ReadCoalescer:
             return
         t1 = profiler.mark()
         _M.incr("read_batches")
-        _M.observe("read_batch_containers", len(cids))
+        _M.observe("read_batch_containers", len({loc[0] for loc in locs}))
         if len(group) > 1:
             _M.incr("coalesced_reads", len(group))
+        at = 0
         for i, r in enumerate(group):
             if r.timeline is not None and i > 0:
                 r.timeline.add_span("container_decode", t0, t1, 0)
-            r.future.set_result({c: datas[c] for c in r.cids})
+            r.future.set_result(chunks[at:at + len(r.locs)])
+            at += len(r.locs)
 
 
 # ------------------------------------------------------------- the facade
@@ -386,29 +403,19 @@ class ReadPlane:
 
     def fetch_chunks(self, plan: ChunkPlan) -> list:
         """Decoded chunk bytes, one per ``plan.wanted`` entry."""
-        out: list = [None] * len(plan.wanted)
-        misses: list[int] = []
         with profiler.phase("cache_probe"):
-            for i, fp in enumerate(plan.hashes):
-                data = self.cache.get(fp)
-                if data is not None:
-                    out[i] = data
-                else:
-                    misses.append(i)
+            out = self.cache.get_many(plan.hashes)
+        misses = [i for i, data in enumerate(out) if data is None]
         decoded = 0
         if misses:
-            need: dict[int, list[int]] = {}
-            for i in misses:
-                need.setdefault(plan.wanted[i][0], []).append(i)
-            datas = self.coalescer.fetch(list(need))
-            decoded = len(need)
-            for cid, idxs in need.items():
-                payload = datas[cid]
-                for i in idxs:
-                    _, off, ln = plan.wanted[i]
-                    chunk = payload[off:off + ln]
-                    out[i] = chunk
-                    self.cache.put(plan.hashes[i], chunk, cid)
+            locs = [plan.wanted[i] for i in misses]
+            decoded = len({loc[0] for loc in locs})
+            chunks = self.coalescer.fetch(locs)
+            for i, chunk in zip(misses, chunks):
+                out[i] = chunk
+            with profiler.phase("cache_probe"):     # the back-fill
+                self.cache.put_many([(plan.hashes[i], out[i],
+                                      plan.wanted[i][0]) for i in misses])
         _M.incr("plans_served")
         _M.incr("containers_fetched", decoded)
         _M.observe("containers_decoded_per_read", decoded)

@@ -59,6 +59,19 @@ _SEAL_MAGIC = 0x48435452  # "RTCH"
 _RAW_MAGIC = 0x48435257  # "WRCH"
 
 
+class _Decoded:
+    """One decoded sealed container of the LRU: ``buf[:size]`` is its
+    payload (a reused buffer's rest is another container's).  ``pins``
+    counts the readers copying out of it right now, under the store's
+    ``_cache_lock``: the buffer goes back for the next decode only when the
+    LRU has let the entry go AND nobody is pinned to it."""
+
+    __slots__ = ("cid", "buf", "size", "pins")
+
+    def __init__(self, cid: int, buf: np.ndarray, size: int) -> None:
+        self.cid, self.buf, self.size, self.pins = cid, buf, size, 1
+
+
 @dataclass
 class _Lane:
     lock: threading.Lock
@@ -72,12 +85,6 @@ class _Lane:
     def view(self) -> memoryview:
         """The container's bytes where they lie (under ``lock``)."""
         return memoryview(self.buffer)[:self.size]
-
-    def snapshot(self) -> bytes:
-        """A reader's copy of the open container (under ``lock``): the
-        buffer goes on filling, and after its seal it takes another
-        container's bytes."""
-        return bytes(self.view())
 
 
 class ContainerStore:
@@ -156,9 +163,12 @@ class ContainerStore:
         self._free: list[np.ndarray] = []
         self._sealing = 0
         # Tiny LRU of decompressed sealed containers (read amplification guard;
-        # the reference re-decompresses the whole container per read).
-        self._cache: dict[int, bytes] = {}
+        # the reference re-decompresses the whole container per read), and
+        # the buffers it has let go (``_give_back_locked``), both under
+        # ``_cache_lock``.
+        self._cache: dict[int, _Decoded] = {}
         self._cache_cap = cache_containers
+        self._decoded_free: list[np.ndarray] = []
         self._cache_lock = threading.Lock()
         # Async seal stage (enable_async_seals): rollover compression moves
         # off the appending thread onto one worker; None = inline seals.
@@ -544,48 +554,125 @@ class ContainerStore:
 
     # -------------------------------------------------------------- reading
 
-    def _cache_probe(self, cid: int) -> bytes | None:
+    def _cache_pin(self, cid: int) -> _Decoded | None:
+        """The decoded container, pinned for the caller's copy-out (paired
+        with ``_cache_unpin``), or None."""
         with self._cache_lock:
-            if cid in self._cache:
-                _M.incr("cache_hit")
-                # true LRU: re-insert on hit so eviction drops the least
-                # RECENTLY used container, not the oldest insertion (FIFO
-                # evicted the hottest container under cyclic read sets)
-                data = self._cache.pop(cid)
-                self._cache[cid] = data
-                _gauge_hit_ratio()
-                return data
-            _M.incr("cache_miss")
+            # true LRU: re-insert on hit so eviction drops the least
+            # RECENTLY used container, not the oldest insertion (FIFO
+            # evicted the hottest container under cyclic read sets)
+            entry = self._cache.pop(cid, None)
+            if entry is not None:
+                self._cache[cid] = entry
+                entry.pins += 1
+        _M.incr("cache_miss" if entry is None else "cache_hit")
         _gauge_hit_ratio()
+        return entry
+
+    def _cache_unpin(self, entry: _Decoded) -> None:
+        with self._cache_lock:
+            entry.pins -= 1
+            if entry.pins == 0 and self._cache.get(entry.cid) is not entry:
+                self._give_back_locked(entry.buf)
+
+    def _cache_drop_locked(self, cid: int) -> None:
+        """Under ``_cache_lock``: out of the LRU (evicted, replaced or
+        retired).  Readers pinned to it finish on the bytes they have."""
+        entry = self._cache.pop(cid, None)
+        if entry is not None and entry.pins == 0:
+            self._give_back_locked(entry.buf)
+
+    def _give_back_locked(self, buf: np.ndarray) -> None:
+        """Under ``_cache_lock``: a decoded container's buffer nobody can
+        see any more, for the next decode — as many as the LRU holds, so a
+        miss decodes into pages this process has touched before (a fresh
+        32 MiB costs its 8 192 first-touch faults every time, and its
+        ``munmap`` when dropped)."""
+        if (buf.size == self._container_size
+                and len(self._decoded_free) < self._cache_cap):
+            self._decoded_free.append(buf)
+
+    def _decode_buffer(self, usize: int) -> np.ndarray:
+        if usize > self._container_size:    # an oversize chunk's container
+            return np.empty(usize, np.uint8)
+        with self._cache_lock:
+            if self._decoded_free:
+                return self._decoded_free.pop()
+        return np.empty(self._container_size, np.uint8)
+
+    def _cache_insert_pinned(self, cid: int, buf: np.ndarray,
+                             size: int) -> _Decoded:
+        entry = _Decoded(cid, buf, size)
+        with self._cache_lock:
+            self._cache_drop_locked(cid)   # two readers decoded it at once
+            self._cache[cid] = entry
+            while len(self._cache) > self._cache_cap:
+                self._cache_drop_locked(next(iter(self._cache)))
+                _M.incr("cache_evict")
+        return entry
+
+    def _open_lane(self, cid: int) -> _Lane | None:
+        """The lane whose open container ``cid`` may be.  A peek without
+        the lanes' locks (a reader of a sealed container must not queue
+        behind a writer's append for the answer): ids only grow and an
+        index entry exists only once its bytes are in a container, so a
+        ``cid`` that no lane shows now is open in none later; the caller
+        looks again under the lane's lock."""
+        for lane in self._lanes:
+            if lane.container_id == cid:
+                return lane
         return None
 
-    def _read_undecoded(self, cid: int) -> bytes | None:
-        """Open-lane memory image or raw-file bytes — the no-decompress
-        sources; None when the container is sealed (or gone)."""
-        from hdrf_tpu.reduction import accounting  # storage->reduction: leaf-only
+    @staticmethod
+    def _check_raw_header(cid: int, f) -> None:
+        """The header of a file opened under its raw name.  A container the
+        codec could not shrink is sealed by stamping that very file's
+        header and renaming it (``seal``): a reader that opened it a moment
+        before the rename holds the sealed file, whose payload is the raw
+        bytes where they were — as good as the raw file it asked for."""
+        magic, _, codec_id = _SEAL_HDR.unpack(f.read(_SEAL_HDR.size))
+        if magic != _RAW_MAGIC and not (
+                magic == _SEAL_MAGIC
+                and codec_id == codecs.CODEC_IDS["none"]):
+            raise IOError(f"container {cid}: bad raw magic {magic:#x}")
 
-        for lane in self._lanes:
+    def _read_open(self, cid: int, lo: int = 0,
+                   hi: int | None = None) -> bytes | None:
+        """Bytes ``[lo, hi)`` (``hi`` None: to its end) of a container that
+        is not sealed yet — the no-decompress sources: copied out of its
+        lane's buffer under the lane's lock (only what the read wants: the
+        writer's next append waits for that lock, and the buffer takes
+        another container's bytes after its seal) or, once rolled over and
+        still in the seal queue, read from its raw file
+        (DataConstructor.java:482-490's skip-decompress path).  None when
+        the container is sealed (or gone)."""
+        lane = self._open_lane(cid)
+        if lane is not None:
             with lane.lock:
                 if lane.container_id == cid and lane.buffer is not None:
-                    accounting.record_container_decode(lane.size)
-                    return lane.snapshot()  # open lane: serve from memory
+                    end = lane.size if hi is None else hi
+                    if end > lane.size:
+                        raise IOError(f"container {cid}: range ends at {end}, "
+                                      f"the open container at {lane.size}")
+                    return lane.buffer[lo:end].tobytes()
         try:
-            # Still-open container: read raw bytes directly
-            # (DataConstructor.java:482-490's skip-decompress path).  Open
-            # without an exists() pre-check: a concurrent seal unlinks the raw
-            # file only *after* the sealed file is in place, so on ENOENT the
-            # sealed path below is guaranteed readable.
+            # Open without an exists() pre-check: a concurrent seal unlinks
+            # the raw file only *after* the sealed file is in place, so on
+            # ENOENT the sealed path is guaranteed readable.
             with open(self._raw_path(cid), "rb") as f:
-                magic = _SEAL_HDR.unpack(f.read(_SEAL_HDR.size))[0]
-                if magic != _RAW_MAGIC:
-                    raise IOError(f"container {cid}: bad raw magic {magic:#x}")
-                data = f.read()
-                accounting.record_container_decode(len(data))
-                return data
+                self._check_raw_header(cid, f)
+                if hi is None:
+                    f.seek(_SEAL_HDR.size + lo)
+                    return f.read()
+                data = os.pread(f.fileno(), hi - lo, _SEAL_HDR.size + lo)
         except FileNotFoundError:
             return None
+        if len(data) != hi - lo:
+            raise IOError(f"container {cid}: raw file ends inside "
+                          f"[{lo}, {hi})")
+        return data
 
-    def _sealed_parse(self, cid: int) -> tuple[str, int, bytes]:
+    def _sealed_parse(self, cid: int) -> tuple[str, int, memoryview]:
         """(codec name, uncompressed size, compressed payload) of the
         sealed container — the decode deferred so the read coalescer can
         run a whole window's payloads through one batched dispatch."""
@@ -604,82 +691,111 @@ class ContainerStore:
         magic, usize, codec_id = _SEAL_HDR.unpack(blob[:_SEAL_HDR.size])
         if magic != _SEAL_MAGIC:
             raise IOError(f"container {cid}: bad magic {magic:#x}")
-        return codecs.CODEC_NAMES[codec_id], usize, blob[_SEAL_HDR.size:]
+        return (codecs.CODEC_NAMES[codec_id], usize,
+                memoryview(blob)[_SEAL_HDR.size:])
 
-    def _cache_insert(self, cid: int, data: bytes) -> None:
-        with self._cache_lock:
-            self._cache.pop(cid, None)  # keep the re-insert most-recent
-            self._cache[cid] = data
-            while len(self._cache) > self._cache_cap:
-                self._cache.pop(next(iter(self._cache)))
-                _M.incr("cache_evict")
-
-    def read_container(self, cid: int) -> bytes:
-        """Full uncompressed container bytes (open or sealed)."""
-        data = self._cache_probe(cid)
-        if data is not None:
-            return data
-        data = self._read_undecoded(cid)
-        if data is not None:
-            return data
-        codec_name, usize, payload = self._sealed_parse(cid)
-        data = codecs.decompress(codec_name, payload, usize)
+    def _decode_pinned(self, cids: list[int],
+                       decompress_batch=None) -> list[_Decoded]:
+        """Sealed containers read, decoded into buffers of the store's own
+        and put in the LRU; each comes back pinned.  The payloads run
+        through ONE ``decompress_batch(codec_names, blobs, usizes, outs)``
+        call when given (the read coalescer passes
+        ops/dispatch.block_decompress_batch)."""
         from hdrf_tpu.reduction import accounting
 
-        accounting.record_container_decode(len(data))
-        self._cache_insert(cid, data)
-        return data
-
-    def read_containers(self, cids: list[int],
-                        decompress_batch=None) -> dict[int, bytes]:
-        """Grouped form of ``read_container``: every distinct cid resolved
-        once, and the sealed payloads that actually need decompression run
-        through ONE ``decompress_batch(codec_names, blobs, usizes)`` call
-        (the read coalescer passes ops/dispatch.block_decompress_batch) —
-        the read-side sibling of flush_open's compress_batch_fn grouping.
-        LRU probes, open/raw fast paths and decode accounting are
-        identical to the per-cid path."""
-        out: dict[int, bytes] = {}
-        pending: list[tuple[int, str, int, bytes]] = []
-        for cid in dict.fromkeys(cids):
-            data = self._cache_probe(cid)
-            if data is None:
-                data = self._read_undecoded(cid)
-            if data is not None:
-                out[cid] = data
-                continue
-            codec_name, usize, payload = self._sealed_parse(cid)
-            pending.append((cid, codec_name, usize, payload))
-        if pending:
+        with profiler.phase("container_load"):
+            parsed = [self._sealed_parse(cid) for cid in cids]
+        bufs = [self._decode_buffer(usize) for _, usize, _ in parsed]
+        with profiler.phase("container_decode"):
             if decompress_batch is not None:
-                datas = decompress_batch([p[1] for p in pending],
-                                         [p[3] for p in pending],
-                                         [p[2] for p in pending])
+                decompress_batch([p[0] for p in parsed],
+                                 [p[2] for p in parsed],
+                                 [p[1] for p in parsed], bufs)
             else:
-                datas = [codecs.decompress(c, b, u)
-                         for _, c, u, b in pending]
-            from hdrf_tpu.reduction import accounting
-
-            for (cid, _c, _u, _b), data in zip(pending, datas):
-                accounting.record_container_decode(len(data))
-                self._cache_insert(cid, data)
-                out[cid] = data
+                for (codec_name, usize, payload), buf in zip(parsed, bufs):
+                    codecs.decompress_into(codec_name, payload, usize, buf)
+        out = []
+        for cid, (_, usize, _), buf in zip(cids, parsed, bufs):
+            accounting.record_container_decode(usize)
+            out.append(self._cache_insert_pinned(cid, buf, usize))
         return out
 
-    def read_chunks(self, locs: list[tuple[int, int, int]]) -> list[bytes]:
+    def read_container(self, cid: int) -> bytes:
+        """Full uncompressed container bytes (open or sealed), the caller's
+        own copy."""
+        entry = self._cache_pin(cid)
+        if entry is None:
+            data = self._read_open(cid)
+            if data is not None:
+                from hdrf_tpu.reduction import accounting
+
+                accounting.record_container_decode(len(data))
+                return data
+            (entry,) = self._decode_pinned([cid])
+        try:
+            return entry.buf[:entry.size].tobytes()
+        finally:
+            self._cache_unpin(entry)
+
+    def read_chunks(self, locs: list[tuple[int, int, int]],
+                    decompress_batch=None) -> list[bytes]:
         """Fetch many chunks, grouping by container so each container is read
         and decompressed once (quickBuildMT's grouping,
-        DataConstructor.java:375-395)."""
+        DataConstructor.java:375-395).  A decoded container never leaves
+        the store: the wanted ranges are copied out of it while it is
+        pinned, so its buffer can take the next decode once the LRU has let
+        it go.  Of an open container only the extent the chunks span is
+        copied.  ``decompress_batch`` as in ``_decode_pinned``."""
+        from hdrf_tpu.reduction import accounting
+
         by_cid: dict[int, list[int]] = {}
         for i, (cid, _, _) in enumerate(locs):
             by_cid.setdefault(cid, []).append(i)
         out: list[bytes | None] = [None] * len(locs)
+        sealed: list[int] = []
         for cid, idxs in by_cid.items():
-            data = self.read_container(cid)
+            entry = self._cache_pin(cid)
+            if entry is not None:
+                try:
+                    self._copy_out(entry, locs, idxs, out)
+                finally:
+                    self._cache_unpin(entry)
+                continue
+            lo = min(locs[i][1] for i in idxs)
+            hi = max(locs[i][1] + locs[i][2] for i in idxs)
+            with profiler.phase("container_load"):
+                data = self._read_open(cid, lo, hi)
+            if data is None:
+                sealed.append(cid)
+                continue
+            accounting.record_container_decode(len(data))
+            with profiler.phase("chunk_copy"):
+                for i in idxs:
+                    _, off, ln = locs[i]
+                    out[i] = data[off - lo:off - lo + ln]
+        if sealed:
+            entries = self._decode_pinned(sealed, decompress_batch)
+            try:
+                for entry in entries:
+                    self._copy_out(entry, locs, by_cid[entry.cid], out)
+            finally:
+                for entry in entries:
+                    self._cache_unpin(entry)
+        return out  # type: ignore[return-value]
+
+    def _copy_out(self, entry: _Decoded, locs: list, idxs: list[int],
+                  out: list) -> None:
+        """The chunks ``idxs`` of ``locs`` out of a pinned decoded
+        container, each a ``bytes`` of its own."""
+        with profiler.phase("chunk_copy"):
+            buf, size = entry.buf, entry.size
             for i in idxs:
                 _, off, ln = locs[i]
-                out[i] = data[off:off + ln]
-        return out  # type: ignore[return-value]
+                if off + ln > size:     # past it lie another's bytes
+                    raise IOError(
+                        f"container {entry.cid}: chunk [{off}, +{ln}) "
+                        f"ends past its {size} bytes")
+                out[i] = buf[off:off + ln].tobytes()
 
     # ----------------------------------------------------------- compaction
 
@@ -745,7 +861,7 @@ class ContainerStore:
             except OSError:
                 continue
         with self._cache_lock:
-            self._cache.pop(cid, None)
+            self._cache_drop_locked(cid)
         if self._on_retire is not None:
             self._on_retire(cid)
         return moved
@@ -755,7 +871,7 @@ class ContainerStore:
             if os.path.exists(p):
                 os.unlink(p)
         with self._cache_lock:
-            self._cache.pop(cid, None)
+            self._cache_drop_locked(cid)
         if self._on_retire is not None:
             self._on_retire(cid)
         if self._on_delete is not None:

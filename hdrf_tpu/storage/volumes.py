@@ -447,7 +447,7 @@ class MultiContainerStore:
     def read_container(self, cid: int) -> bytes:
         return self._vs.volume_of_cid(cid).containers.read_container(cid)
 
-    def read_chunks(self, locs):
+    def read_chunks(self, locs, decompress_batch=None):
         by_vol: dict[int, list[int]] = {}
         for i, (cid, _, _) in enumerate(locs):
             by_vol.setdefault(cid >> CID_SHIFT, []).append(i)
@@ -456,20 +456,10 @@ class MultiContainerStore:
             # route through volume_of_cid so stale cid namespaces and
             # ejected volumes raise IOError (treat-as-lost), not IndexError
             vol = self._vs.volume_of_cid(vid << CID_SHIFT)
-            got = vol.containers.read_chunks([locs[i] for i in idxs])
+            got = vol.containers.read_chunks(
+                [locs[i] for i in idxs], decompress_batch=decompress_batch)
             for i, b in zip(idxs, got):
                 out[i] = b
-        return out
-
-    def read_containers(self, cids, decompress_batch=None):
-        by_vol: dict[int, list[int]] = {}
-        for cid in cids:
-            by_vol.setdefault(cid >> CID_SHIFT, []).append(cid)
-        out: dict[int, bytes] = {}
-        for vid, ids in by_vol.items():
-            vol = self._vs.volume_of_cid(vid << CID_SHIFT)
-            out.update(vol.containers.read_containers(
-                ids, decompress_batch=decompress_batch))
         return out
 
     def copy_live(self, cid: int, live, on_seal=None):

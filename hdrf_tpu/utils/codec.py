@@ -33,6 +33,25 @@ def compress(codec: str, data: bytes) -> bytes:
     raise KeyError(f"unknown codec {codec!r}")
 
 
+def decompress_into(codec: str, data, usize: int, out):
+    """``decompress`` into ``out`` (a C-contiguous uint8 array of at least
+    ``usize``); returns ``out[:usize]``.  LZ4 — the container codec the
+    read path meets — lands there directly; the rest decode as they do and
+    are copied in."""
+    if codec == "lz4":
+        from hdrf_tpu import native
+
+        return native.lz4_decompress(data, usize, out=out)
+    import numpy as np
+
+    plain = np.frombuffer(decompress(codec, data, usize), np.uint8)
+    if plain.size != usize:
+        raise RuntimeError(f"{codec} decompression: got {plain.size}, "
+                           f"want {usize}")
+    out[:usize] = plain
+    return out[:usize]
+
+
 def decompress(codec: str, data: bytes, usize: int) -> bytes:
     if codec == "lz4":
         from hdrf_tpu import native

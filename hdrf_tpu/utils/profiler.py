@@ -86,6 +86,20 @@ PHASE_CLASS = {
     # packet run to the client are network waits the host could hide.
     "index_lookup": HOST, "cache_probe": HOST, "container_decode": HOST,
     "ec_gather": TRANSPORT, "net_send": TRANSPORT,
+    # The steps of a read's service between lookup and send, each under
+    # its own name (one span a container or a read, never one a chunk):
+    # container_load a sealed container's file read, or an open
+    # container's wanted bytes taken from its lane under the lane's lock
+    # (storage/container_store.py), a whole-block scheme's stored bytes;
+    # container_decode the codec alone; chunk_copy the wanted chunks out of
+    # the decoded container and into the reply's bytes; read_admit a
+    # handler waiting for a read slot or the read plane's permit (HOST for
+    # lock_wait's reason below: it sits inside the covering read_serve
+    # span, and as a transport wait every queued second would read as
+    # read_serve's); read_serve the covering span of serve_read's service,
+    # which owns only what none of them names.
+    "container_load": HOST, "chunk_copy": HOST, "read_admit": HOST,
+    "read_serve": HOST,
     # A reader parked on the read coalescer's shared decode future
     # (server/read_plane.py): a hideable wait — the real decode burns the
     # vCPU under the LEAD reader's mirrored container_decode span, which
@@ -131,12 +145,14 @@ PHASE_CLASS = {
 # Deterministic attribution order when several phases of the winning class
 # overlap inside one elementary interval (rare: host phases are serial on
 # this host) — first match wins.  Nested read phases (index_lookup inside a
-# container_decode window) resolve to the innermost by listing it first.
+# read_serve window) resolve to the innermost by listing it first.
 PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
                "wal_commit", "container_io", "dedup_lookup",
                "reduce_compute", "packet_verify", "checksum", "seal_write",
                "stage_h2d", "select", "emit", "seal_ingest",
-               "index_lookup", "cache_probe", "container_decode",
+               "index_lookup", "cache_probe", "read_admit",
+               "container_load", "container_decode", "chunk_copy",
+               "read_serve",
                # RPC phases: lock_wait/locked win attribution inside the
                # covering ``handler`` window; handler last among them so it
                # only owns the time no finer phase explains.

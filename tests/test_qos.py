@@ -272,7 +272,7 @@ class TestPermitLeaks:
         from hdrf_tpu.server.read_plane import ReadCoalescer
 
         class _Containers:
-            def read_containers(self, cids, decompress_batch=None):
+            def read_chunks(self, locs, decompress_batch=None):
                 raise IOError("injected container read failure")
 
         ctrl = self._shedding_ctrl()
@@ -281,11 +281,11 @@ class TestPermitLeaks:
         before = rc._sem._value
         for _ in range(100):
             with pytest.raises(qos.ShedError):
-                rc.fetch([1], tenant="hog")
+                rc.fetch([(1, 0, 8)], tenant="hog")
         # admitted tenant: the decode failure path releases via finally
         for _ in range(100):
             with pytest.raises(IOError):
-                rc.fetch([1], tenant="light")
+                rc.fetch([(1, 0, 8)], tenant="light")
         assert rc._sem._value == before
 
     def test_unattributed_write_block_is_never_shed(self):

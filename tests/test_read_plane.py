@@ -326,23 +326,31 @@ class TestChunkCacheSemantics:
 
 class TestCoalescer:
     def _commit(self, tmp_path, seed=16, n=300_000):
+        """(ctx, the block's chunk locations, the chunks' own bytes)."""
         ctx = make_ctx(tmp_path, container_size=1 << 16, with_plane=False)
         schemes.get("dedup_lz4").reduce(1, _blob(seed, n), ctx)
-        return ctx, resolve_chunk_plan(ctx.index, 1).containers()
+        locs = resolve_chunk_plan(ctx.index, 1).wanted
+        want = [ctx.containers.read_container(cid)[off:off + ln]
+                for cid, off, ln in locs]
+        return ctx, locs, want
 
     def test_inline_fallback_on_native_backend(self, tmp_path):
-        ctx, cids = self._commit(tmp_path)
+        ctx, locs, want = self._commit(tmp_path)
         co = ReadCoalescer(ctx.containers, window_ms=2.0, backend="native")
         assert co._thread is None  # non-TPU backend: no worker spun up
         i0 = _RP.counter("inline_decodes")
-        datas = co.fetch(cids[:2])
+        # chunks of the first two containers, out of order
+        pick = [i for i, loc in enumerate(locs)
+                if loc[0] in {locs[0][0], locs[-1][0]}][::-1]
+        assert len({locs[i][0] for i in pick}) == 2
+        chunks = co.fetch([locs[i] for i in pick])
         assert _RP.counter("inline_decodes") - i0 == 1
-        for cid in cids[:2]:
-            assert datas[cid] == ctx.containers.read_container(cid)
+        assert chunks == [want[i] for i in pick]
+        assert all(type(c) is bytes for c in chunks)
         co.close()
 
     def test_batched_groups_concurrent_readers(self, tmp_path):
-        ctx, cids = self._commit(tmp_path)
+        ctx, locs, want = self._commit(tmp_path)
         co = ReadCoalescer(ctx.containers, window_ms=300.0, max_inflight=8,
                            batched=True)
         try:
@@ -350,10 +358,12 @@ class TestCoalescer:
                       _RP.counter("coalesced_reads"))
             barrier = threading.Barrier(2)
             results = [None, None]
+            # two readers, overlapping ranges of the first container
+            asks = [[0, 1, 2], [2, 1]]
 
             def reader(i):
                 barrier.wait()
-                results[i] = co.fetch([cids[0]])
+                results[i] = co.fetch([locs[k] for k in asks[i]])
 
             ts = [threading.Thread(target=reader, args=(i,))
                   for i in range(2)]
@@ -364,17 +374,18 @@ class TestCoalescer:
             # both landed in ONE window: one batch, both members coalesced
             assert _RP.counter("read_batches") - b0 == 1
             assert _RP.counter("coalesced_reads") - c0 == 2
-            want = ctx.containers.read_container(cids[0])
-            assert results[0][cids[0]] == results[1][cids[0]] == want
+            # each member got its own chunks back, in its own order
+            assert results[0] == [want[k] for k in asks[0]]
+            assert results[1] == [want[k] for k in asks[1]]
         finally:
             co.close()
 
     def test_batched_propagates_errors(self, tmp_path):
-        ctx, _ = self._commit(tmp_path)
+        ctx, _, _ = self._commit(tmp_path)
         co = ReadCoalescer(ctx.containers, window_ms=1.0, batched=True)
         try:
             with pytest.raises(Exception):
-                co.fetch([987654])  # no such container
+                co.fetch([(987654, 0, 16)])  # no such container
         finally:
             co.close()
 

@@ -228,7 +228,8 @@ class TestHopCounter:
             "better": "higher", "source": "program_counter",
             "layer": "DN to worker hop", "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
-                          "versions-dedup.ingest", "small-files.create"]}
+                          "versions-dedup.ingest", "small-files.create",
+                          "teragen-1dn.pread-ingest"]}
 
 
 class TestSealWireMetrics:
@@ -282,7 +283,7 @@ class TestSealWireMetrics:
             "name": metric, "source": "program_counter",
             "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
-                          "small-files.create"],
+                          "small-files.create", "teragen-1dn.pread-ingest"],
             **self.ENTRIES[metric]}
 
 
