@@ -153,7 +153,17 @@ class ContainerStore:
         # field packed into its 3-byte ids at utilities.java:36-75), so
         # one DN-wide chunk index can route any cid to its volume.
         self._id_base = id_base
-        self._next_id = max(self._scan_next_id(), id_base)
+        # Bytes on disk of every ``.raw`` / ``.sealed`` file of the
+        # directory, by (cid, suffix), and their sum: the store is its
+        # directory's only writer, so ``physical_bytes`` and
+        # ``container_sizes`` answer from what it wrote (a walk is a
+        # ``stat`` a container file, each one the interpreter let go and
+        # won back — under every block's commit and every heartbeat).
+        # ``_sizes_lock`` guards both and is never held across I/O.
+        self._sizes_lock = threading.Lock()
+        self._sizes: dict[tuple[int, str], int] = {}
+        self._physical = 0
+        self._next_id = max(self._scan_dir(), id_base)
         self._lanes = [_Lane(threading.Lock()) for _ in range(lanes)]
         self._rr = 0
         # Sealed containers' buffers (``container_size`` each), for the next
@@ -176,13 +186,42 @@ class ContainerStore:
         self._seal_thread: threading.Thread | None = None
         self._seal_exc: BaseException | None = None
 
-    def _scan_next_id(self) -> int:
+    def _scan_dir(self) -> int:
+        """The one walk of the directory, at open: the next container id
+        (past every name a previous process left, ``.tmp`` and ``.quar``
+        too) and the size of what it left (``.raw`` and ``.sealed``
+        only)."""
+        _M.incr("dir_walks")
         mx = -1
         for name in os.listdir(self._dir):
-            stem = name.split(".")[0]
-            if stem.isdigit():
-                mx = max(mx, int(stem))
+            stem, _, suffix = name.partition(".")
+            if not stem.isdigit():
+                continue
+            mx = max(mx, int(stem))
+            if suffix in ("raw", "sealed"):
+                size = os.path.getsize(os.path.join(self._dir, name))
+                self._sizes[int(stem), suffix] = size
+                self._physical += size
         return mx + 1
+
+    def _note_sizes(self, cid: int, **sizes: int | None) -> None:
+        """What the store just did to ``<cid>.raw`` / ``<cid>.sealed``, as
+        ``raw=`` / ``sealed=``: the bytes the file now holds, or None once
+        it is gone.  One step for a reader, whatever it names."""
+        with self._sizes_lock:
+            for suffix, size in sizes.items():
+                self._physical -= self._sizes.pop((cid, suffix), 0)
+                if size is not None:
+                    self._sizes[cid, suffix] = size
+                    self._physical += size
+
+    def _note_lane(self, lane: _Lane) -> None:
+        """Under ``lane.lock``, after a write to the lane's file: header +
+        every byte of the container so far.  A lane without a file counts
+        nothing until its seal writes one."""
+        if lane.fh is not None:
+            self._note_sizes(lane.container_id,
+                             raw=_SEAL_HDR.size + lane.size)
 
     def _raw_path(self, cid: int) -> str:
         return os.path.join(self._dir, f"{cid}.raw")
@@ -213,6 +252,7 @@ class ContainerStore:
             def drain():
                 if lane.fh is not None and lane.size > written:
                     lane.fh.write(lane.buffer[written:lane.size])
+                    self._note_lane(lane)
 
             for chunk in chunks:
                 if lane.buffer is None or (
@@ -286,6 +326,7 @@ class ContainerStore:
                 out_cid[i:j] = lane.container_id
                 out_off[i:j] = lane.size + (csum[i:j] - csum[i])
                 lane.size = end
+                self._note_lane(lane)
                 i = j
             if lane.fh is not None:
                 lane.fh.flush()
@@ -331,6 +372,7 @@ class ContainerStore:
         # rename instead of a full data rewrite (measured: the rewrite was
         # ~35% of ingest host cost for codec "none").
         lane.fh.write(_SEAL_HDR.pack(_RAW_MAGIC, 0, 0))
+        self._note_lane(lane)
 
     def _oversize_locked(self, lane: _Lane, need: int) -> None:
         """A chunk larger than ``container_size`` lands alone in an empty
@@ -442,6 +484,8 @@ class ContainerStore:
                         if self._fsync:
                             os.fsync(f.fileno())
                         os.replace(raw, self._sealed_path(cid))
+                    self._note_sizes(cid, raw=None,
+                                     sealed=_SEAL_HDR.size + len(data))
                     _M.incr("sealed")
                     return
         else:
@@ -461,8 +505,10 @@ class ContainerStore:
                 if self._fsync:
                     os.fsync(f.fileno())
             os.replace(tmp, self._sealed_path(cid))
+            self._note_sizes(cid, sealed=_SEAL_HDR.size + len(out))
             if have_raw:
                 os.unlink(raw)
+                self._note_sizes(cid, raw=None)
         _M.incr("sealed")
 
     def _compress(self, data):
@@ -489,6 +535,7 @@ class ContainerStore:
                     if lane.fh is not None:
                         lane.fh.close()
                         os.unlink(self._raw_path(lane.container_id))
+                        self._note_sizes(lane.container_id, raw=None)
                         lane.fh = None
                     with self._alloc_lock:  # opened, empty: nothing saw it
                         self._reuse_locked(lane.buffer)
@@ -837,9 +884,10 @@ class ContainerStore:
         try:
             size = os.path.getsize(path)
             os.unlink(path)
-            return size
         except OSError:
             return 0
+        self._note_sizes(cid, sealed=None)
+        return size
 
     def quarantine(self, cid: int) -> int:
         """Rename the container's files aside (``.quar`` suffix) so it can
@@ -853,13 +901,15 @@ class ContainerStore:
         re-replication restores its blocks elsewhere).  Returns bytes
         quarantined."""
         moved = 0
-        for p in (self._raw_path(cid), self._sealed_path(cid)):
+        for suffix, p in (("raw", self._raw_path(cid)),
+                          ("sealed", self._sealed_path(cid))):
             try:
                 size = os.path.getsize(p)
                 os.rename(p, p + ".quar")
-                moved += size
             except OSError:
                 continue
+            self._note_sizes(cid, **{suffix: None})
+            moved += size
         with self._cache_lock:
             self._cache_drop_locked(cid)
         if self._on_retire is not None:
@@ -870,6 +920,7 @@ class ContainerStore:
         for p in (self._raw_path(cid), self._sealed_path(cid)):
             if os.path.exists(p):
                 os.unlink(p)
+        self._note_sizes(cid, raw=None, sealed=None)
         with self._cache_lock:
             self._cache_drop_locked(cid)
         if self._on_retire is not None:
@@ -909,6 +960,7 @@ class ContainerStore:
         return False
 
     def container_ids(self) -> list[int]:
+        _M.incr("dir_walks")    # the scrubber's census; on no block's path
         ids = set()
         for name in os.listdir(self._dir):
             stem = name.split(".")[0]
@@ -917,29 +969,19 @@ class ContainerStore:
         return sorted(ids)
 
     def physical_bytes(self) -> int:
-        total = 0
-        for name in os.listdir(self._dir):
-            if name.endswith(".raw") or name.endswith(".sealed"):
-                try:
-                    total += os.path.getsize(os.path.join(self._dir, name))
-                except FileNotFoundError:
-                    pass   # sealed (raw unlinked) or deleted since listdir
-        return total
+        """Bytes on disk of the ``.raw`` and ``.sealed`` files, from the
+        store's record of its own writes: no directory walk."""
+        with self._sizes_lock:
+            return self._physical
 
     def container_sizes(self) -> dict[int, int]:
         """cid -> bytes on disk (raw + sealed forms summed) — the
         denominator of the utilization accounting
-        (reduction/accounting.py:utilization_hist).  stat() calls only;
-        never opens the files."""
+        (reduction/accounting.py:utilization_hist).  From the same record:
+        no ``stat``, no file opened."""
+        with self._sizes_lock:
+            sizes = list(self._sizes.items())
         out: dict[int, int] = {}
-        for name in os.listdir(self._dir):
-            stem = name.split(".")[0]
-            if stem.isdigit() and (name.endswith(".raw")
-                                   or name.endswith(".sealed")):
-                cid = int(stem)
-                try:
-                    size = os.path.getsize(os.path.join(self._dir, name))
-                except FileNotFoundError:
-                    continue   # as in physical_bytes
-                out[cid] = out.get(cid, 0) + size
+        for (cid, _), size in sizes:
+            out[cid] = out.get(cid, 0) + size
         return out

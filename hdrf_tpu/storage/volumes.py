@@ -70,6 +70,7 @@ class Volume:
     def free_estimate(self) -> int:
         """Free bytes on the volume's filesystem (capacity heuristic for
         placement; volumes sharing one fs in tests just compare usage)."""
+        _M.incr("volume_estimates")
         try:
             st = os.statvfs(self.root)
             free = st.f_bavail * st.f_frsize
@@ -144,7 +145,10 @@ class VolumeSet:
         """Type match first (the NameNode's slot hint), then the volume
         with the most free space among candidates; round-robin breaks
         ties (FsVolumeList's AvailableSpaceVolumeChoosingPolicy over the
-        round-robin default)."""
+        round-robin default).  One candidate is the answer as it stands:
+        no estimate is asked for (a ``statvfs`` twice a block, under the
+        commit, to choose among one)."""
+        _M.incr("volume_choices")
         alive = self._alive()
         if exclude_ram:
             alive = [v for v in alive if v.storage_type != "RAM_DISK"]
@@ -156,6 +160,8 @@ class VolumeSet:
         if not alive:
             raise IOError("all volumes failed")
         cands = [v for v in alive if v.storage_type == storage_type] or alive
+        if len(cands) == 1:
+            return cands[0]
         with self._lock:
             self._rr += 1
             start = self._rr

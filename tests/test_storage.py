@@ -512,27 +512,273 @@ class TestReplicaStore:
         assert rs.scan() == []
 
 
-def test_size_accounting_tolerates_a_file_sealed_under_it(tmp_path,
-                                                          monkeypatch):
-    """physical_bytes / container_sizes stat files a concurrent seal may
-    rename away between listdir and getsize (the heartbeat's stats call
-    raced exactly so on the v5e host and killed the heartbeat thread)."""
-    import os
+def _walked(directory) -> tuple[int, dict[int, int]]:
+    """(bytes, cid -> bytes) of the ``.raw`` and ``.sealed`` files, by a
+    walk of the test's own: what ``physical_bytes`` / ``container_sizes``
+    read before the store kept a record."""
+    sizes: dict[int, int] = {}
+    for name in os.listdir(directory):
+        stem, _, suffix = name.partition(".")
+        if suffix in ("raw", "sealed"):
+            sizes[int(stem)] = sizes.get(int(stem), 0) + os.path.getsize(
+                os.path.join(directory, name))
+    return sum(sizes.values()), sizes
 
-    from hdrf_tpu.storage.container_store import ContainerStore
 
+def _record_is_the_directory(cs: ContainerStore) -> dict[int, int]:
+    total, sizes = _walked(cs._dir)
+    assert cs.physical_bytes() == total
+    assert cs.container_sizes() == sizes
+    return sizes
+
+
+_HDR = _SEAL_HDR.size
+
+
+def _rolls_and_seals_compressible(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="lz4")
+    assert same(cs) == {}
+    (cid, _, _), = cs.append_chunks([b"x" * 600])
+    assert same(cs) == {cid: _HDR + 600}
+    # the first range rolls container 0 over, the second container 1
+    locs = cs.append_ranges(b"y" * 1200, [0, 600], [600, 600])
+    sizes = same(cs)
+    assert sizes[locs[1][0]] == _HDR + 600          # the open one, whole
+    assert 0 < sizes[cid] < _HDR + 600              # sealed, and smaller
+    assert not os.path.exists(os.path.join(d, f"{cid}.raw"))
+    cs.flush_open()
+    assert len(same(cs)) == 3
+
+
+def _incompressible_is_stamped_and_renamed(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="lz4")
+    (cid, _, _), = cs.append_chunks([os.urandom(700)])
+    cs.append_chunks([os.urandom(700)])             # rolls the first over
+    assert same(cs)[cid] == _HDR + 700
+    assert os.path.exists(os.path.join(d, f"{cid}.sealed"))
+    cs.flush_open()
+    assert sorted(same(cs).values()) == [_HDR + 700] * 2
+
+
+def _flush_open_unlinks_an_opened_empty_lane(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=2, codec="lz4")
+    cs.append_chunks([b"a" * 100])
+    lane = cs._lanes[1]
+    with lane.lock:                 # what an append that then raised leaves
+        cs._open_locked(lane)
+    cs.sync_lanes()                 # the placeholder header reaches the file
+    assert same(cs)[lane.container_id] == _HDR
+    cs.flush_open()
+    assert len(same(cs)) == 1
+
+
+def _an_oversize_chunk_lands_alone(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="none")
+    (cid, _, _), = cs.append_chunks([b"o" * 5000])
+    assert same(cs) == {cid: _HDR + 5000}
+    data = os.urandom(400) + b"p" * 3000
+    locs = cs.append_ranges(data, [0, 400], [400, 3000])
+    sizes = same(cs)
+    assert sizes[locs[1][0]] == _HDR + 3000 and len(sizes) == 3
+    cs.flush_open()
+    same(cs)
+
+
+def _delete_container_and_copy_live(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="lz4")
+    (cid, off, ln), = cs.append_chunks([b"l" * 700])
+    (open_cid, _, _), = cs.append_chunks([b"m" * 700])
+    moved = cs.copy_live(cid, {b"h" * 32: (off, ln)})   # rolls the open one
+    assert len(same(cs)) == 3
+    cs.delete_container(cid)
+    assert cid not in same(cs)
+    cs.delete_container(moved[b"h" * 32][0])        # an open lane's file
+    assert list(same(cs)) == [open_cid]
+
+
+def _quarantine_moves_the_bytes_out(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="lz4")
+    (cid, _, _), = cs.append_chunks([b"q" * 700])
+    cs.append_chunks([b"r" * 700])
+    before = same(cs)
+    assert cs.quarantine(cid) == before.pop(cid)
+    assert same(cs) == before
+    assert cs.quarantine(cid) == 0                  # nothing left to move
+    assert same(cs) == before
+    assert os.path.exists(os.path.join(d, f"{cid}.sealed.quar"))
+
+
+def _drop_sealed_file_keeps_the_rest(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="lz4")
+    (cid, _, _), = cs.append_chunks([b"s" * 700])
+    cs.append_chunks([b"t" * 700])
+    before = same(cs)
+    assert cs.drop_sealed_file(cid) == before.pop(cid)
+    assert same(cs) == before
+    assert cs.drop_sealed_file(cid) == 0
+    assert same(cs) == before
+
+
+def _async_seals_then_drain(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=2, codec="lz4")
+    cs.enable_async_seals()
+    try:
+        for i in range(12):
+            cs.append_chunks([bytes([i]) * 300, os.urandom(300)])
+            cs.append_ranges(b"z" * 900, [0, 450], [450, 450])
+        cs.drain_seals()
+        assert len(same(cs)) > 8
+        cs.flush_open()
+        same(cs)
+    finally:
+        cs.close_async_seals()
+
+
+def _a_direct_seal_without_a_raw_file(d, same):
+    cs = ContainerStore(d, container_size=1000, lanes=1, codec="lz4")
+    cs.seal(41, data=b"d" * 500, have_raw=False)
+    assert 0 < same(cs)[41] < _HDR + 500
+    noise = os.urandom(500)
+    cs.seal(42, data=noise, have_raw=False)
+    assert same(cs)[42] == _HDR + 500
+    cs.seal(42, data=b"e" * 500, have_raw=False)    # over the file it wrote
+    assert 0 < same(cs)[42] < _HDR + 500 and len(same(cs)) == 2
+
+
+def _reopened_over_what_another_left(d, same):
+    first = ContainerStore(d, container_size=1000, lanes=2, codec="lz4")
+    (sealed_cid, _, _), = first.append_chunks([b"u" * 700])
+    (stray_cid, _, _), = first.append_chunks([os.urandom(300)])
+    first.append_chunks([b"v" * 700])               # lane 0 rolls, 2 opens
+    left = same(first)
+    assert len(left) == 3
+    # a crash between the seal's replace and its unlink leaves both forms;
+    # .tmp and .quar files are no container's bytes
+    with open(os.path.join(d, f"{sealed_cid}.raw"), "wb") as f:
+        f.write(_SEAL_HDR.pack(_RAW_MAGIC, 0, 0) + b"u" * 700)
+    for name in ("90.sealed.tmp", "91.raw.quar"):
+        with open(os.path.join(d, name), "wb") as f:
+            f.write(b"junk" * 10)
+    cs = ContainerStore(d, container_size=1000, lanes=2, codec="lz4")
+    sizes = same(cs)
+    assert sizes[sealed_cid] == left[sealed_cid] + _HDR + 700
+    assert sizes[stray_cid] == _HDR + 300
+    (cid, _, _), = cs.append_chunks([b"w" * 10])
+    assert cid == 92 and same(cs)[cid] == _HDR + 10
+    cs.seal(stray_cid)                              # read back from its file
+    assert same(cs)[stray_cid] == _HDR + 300
+    assert os.path.exists(os.path.join(d, f"{stray_cid}.sealed"))
+    assert cs.quarantine(sealed_cid) == sizes[sealed_cid]   # both forms
+    assert sealed_cid not in same(cs)
+
+
+@pytest.mark.parametrize("drive", [
+    _rolls_and_seals_compressible,
+    _incompressible_is_stamped_and_renamed,
+    _flush_open_unlinks_an_opened_empty_lane,
+    _an_oversize_chunk_lands_alone,
+    _delete_container_and_copy_live,
+    _quarantine_moves_the_bytes_out,
+    _drop_sealed_file_keeps_the_rest,
+    _async_seals_then_drain,
+    _a_direct_seal_without_a_raw_file,
+    _reopened_over_what_another_left,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_the_size_record_follows_every_operation(tmp_path, drive):
+    """``physical_bytes`` / ``container_sizes`` answer from the store's
+    record of its own writes; after each operation that creates, grows,
+    renames or removes a container file the record equals a walk of the
+    directory."""
+    drive(str(tmp_path), _record_is_the_directory)
+
+
+def test_sizes_are_answered_without_touching_the_directory(tmp_path,
+                                                           monkeypatch):
+    m = metrics.registry("container_store")
+    walks = m.counter("dir_walks")
+    first = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
+                           codec="lz4")
+    first.append_chunks([b"a" * 700])
     cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
                         codec="lz4")
-    cs.append_chunks([b"a" * 600])
-    cs.append_chunks([b"b" * 600])          # rolls: 0.sealed + 1.raw
-    whole = cs.physical_bytes()
-    real = os.path.getsize
+    assert m.counter("dir_walks") == walks + 2      # one an open, no more
+    total, sizes = _walked(str(tmp_path))
 
-    def racing(path):
-        if path.endswith(".raw"):
-            raise FileNotFoundError(path)
-        return real(path)
+    def refused(*a, **kw):
+        raise AssertionError("the store looked at its directory")
 
-    monkeypatch.setattr(os.path, "getsize", racing)
-    assert 0 < cs.physical_bytes() < whole
-    assert list(cs.container_sizes()) == [0]
+    monkeypatch.setattr(os, "listdir", refused)
+    monkeypatch.setattr(os, "scandir", refused)
+    monkeypatch.setattr(os.path, "getsize", refused)
+    monkeypatch.setattr(os, "stat", refused)
+    assert cs.physical_bytes() == total and cs.container_sizes() == sizes
+    cs.append_chunks([b"b" * 700])
+    cs.append_ranges(b"c" * 1400, [0, 700], [700, 700])     # seals two
+    cs.flush_open()
+    sizes = cs.container_sizes()
+    assert len(sizes) == 4 and cs.physical_bytes() == sum(sizes.values())
+    assert m.counter("dir_walks") == walks + 2
+    with pytest.raises(AssertionError, match="looked at its directory"):
+        cs.container_ids()                          # the one that still walks
+    monkeypatch.undo()
+    assert (cs.physical_bytes(), sizes) == _walked(str(tmp_path))
+
+
+def test_size_accounting_tolerates_a_file_sealed_under_it(tmp_path):
+    """The race that is left now that no reader walks the directory: one
+    thread appends and rolls containers over to the seal thread, which
+    swaps ``.raw`` sizes for ``.sealed`` ones, while another asks
+    ``physical_bytes`` / ``container_sizes`` as the heartbeat does.  No call
+    raises, no update is lost: after ``drain_seals`` both equal the
+    directory.  (The walk raced a seal's rename between its ``listdir`` and
+    its ``getsize`` and killed the heartbeat thread on the v5e host.)"""
+    import sys
+    import threading
+    import time
+
+    cs = ContainerStore(str(tmp_path), container_size=1000, lanes=2,
+                        codec="lz4")
+    cs.enable_async_seals()
+    errors: list[BaseException] = []
+    done = threading.Event()
+    reads = [0]
+
+    def write():
+        try:
+            for i in range(150):
+                cs.append_chunks([bytes([i]) * 400, os.urandom(350)])
+                cs.append_ranges(b"k" * 800, [0, 400], [400, 400])
+        except BaseException as e:  # noqa: BLE001 — shown below
+            errors.append(e)
+        finally:
+            done.set()
+
+    def read():
+        try:
+            while not done.is_set():
+                total, sizes = cs.physical_bytes(), cs.container_sizes()
+                assert total >= 0 and all(v > 0 for v in sizes.values())
+                reads[0] += 1
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    threads = [threading.Thread(target=write), threading.Thread(target=read),
+               threading.Thread(target=read)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 60
+        for t in threads:
+            t.join(max(0.0, deadline - time.monotonic()))
+    finally:
+        sys.setswitchinterval(interval)
+    try:
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        assert reads[0] > 0
+        cs.drain_seals()
+        assert len(_record_is_the_directory(cs)) > 100
+    finally:
+        cs.close_async_seals()

@@ -2,6 +2,7 @@
 storage/volumes.py): placement across volumes, per-volume storage types,
 volume-failure ejection (DN survives), and the DiskBalancer-lite planner."""
 
+import os
 import time
 
 import numpy as np
@@ -9,6 +10,7 @@ import pytest
 
 from hdrf_tpu.storage.volumes import CID_SHIFT, VolumeSet
 from hdrf_tpu.testing.minicluster import MiniCluster
+from hdrf_tpu.utils import metrics
 
 
 def _payload(seed: int, n: int = 300_000) -> bytes:
@@ -79,6 +81,80 @@ class TestVolumeSet:
         # moved replicas still serve, routed to their new volume
         for bid in range(10):
             assert vs.read_data(bid) == b"b" * 100_000
+
+
+class TestChoosingAVolume:
+    """``_choose_volume`` spreads by usage where there is a choice and asks
+    nothing where there is none (registry ``volumes``: ``volume_choices``
+    counts its calls, ``volume_estimates`` those of ``free_estimate``)."""
+
+    @staticmethod
+    def _counts() -> tuple[int, int]:
+        m = metrics.registry("volumes")
+        return m.counter("volume_choices"), m.counter("volume_estimates")
+
+    @staticmethod
+    def _no_statvfs(monkeypatch):
+        def refused(path):
+            raise AssertionError(f"statvfs({path}) to choose among one")
+
+        monkeypatch.setattr(os, "statvfs", refused)
+
+    def test_one_volume_is_chosen_without_an_estimate(self, tmp_path,
+                                                      monkeypatch):
+        vs = VolumeSet(str(tmp_path), ["DISK"], container_kw={"lanes": 1})
+        self._no_statvfs(monkeypatch)
+        choices, estimates = self._counts()
+        for bid in range(3):        # a block's two calls: append, create_rbw
+            vs.containers.append_ranges(b"n" * 2000, [0, 1000], [1000, 1000])
+            vs.containers.append_chunks([b"m" * 500])
+            w = vs.create_rbw(bid, storage_type="SSD")   # no SSD: the one
+            w.write(b"x" * 100)
+            w.finalize(100, "direct", [1], 64 * 1024)
+        assert self._counts() == (choices + 9, estimates)
+        assert {vs._where[b] for b in range(3)} == {0}
+        assert vs.volumes[0].used_bytes() == 300 + 3 * 2500 + 16
+
+    def test_two_volumes_still_follow_usage(self, tmp_path):
+        vs = VolumeSet(str(tmp_path), ["DISK", "DISK"], container_kw={})
+        choices, estimates = self._counts()
+        # container bytes count as usage: whichever volume took the first
+        # append, the next three choices go to the other one
+        (cid, _, _), = vs.containers.append_chunks([b"c" * 50_000])
+        full = cid >> CID_SHIFT
+        for bid in range(3):
+            w = vs.create_rbw(bid)
+            w.write(b"r" * 10_000)
+            w.finalize(10_000, "direct", [1], 64 * 1024)
+            assert vs._where[bid] == 1 - full
+        assert self._counts() == (choices + 4, estimates + 8)
+        # a type hint that one volume answers leaves nothing to estimate
+        vs = VolumeSet(str(tmp_path / "typed"), ["DISK", "SSD"],
+                       container_kw={})
+        choices, estimates = self._counts()
+        vs.create_rbw(7, storage_type="SSD")
+        assert vs._where[7] == 1
+        assert self._counts() == (choices + 1, estimates)
+
+    @pytest.mark.parametrize("types", [["RAM_DISK"], ["RAM_DISK", "DISK"]],
+                             ids=["ram-alone-refuses", "ram-and-disk"])
+    def test_shared_containers_never_land_in_ram(self, tmp_path, monkeypatch,
+                                                 types):
+        # keep the RAM volume under tmp_path (no /dev/shm segment to reclaim)
+        monkeypatch.setattr(os, "access", lambda *a, **kw: False)
+        vs = VolumeSet(str(tmp_path), types, container_kw={})
+        choices, estimates = self._counts()
+        if types == ["RAM_DISK"]:
+            with pytest.raises(IOError, match="no non-RAM volume"):
+                vs.containers.append_chunks([b"s" * 100])
+            with pytest.raises(IOError, match="no non-RAM volume"):
+                vs.containers.append_ranges(b"s" * 100, [0], [100])
+            assert self._counts() == (choices + 2, estimates)
+            return
+        self._no_statvfs(monkeypatch)   # one candidate once RAM is excluded
+        (cid, _, _), = vs.containers.append_chunks([b"s" * 100])
+        assert vs.volume_of_cid(cid).storage_type == "DISK"
+        assert self._counts() == (choices + 1, estimates)
 
 
 class TestMultiVolumeCluster:
