@@ -894,7 +894,7 @@ class DataNode:
             fault_injection.point("datanode.heartbeat", dn_id=self.dn_id)
             try:
                 # once a heartbeat, in the interpreter that receives
-                with profiler.phase("heartbeat_stats"):
+                with profiler.cpu_phase("heartbeat_stats"):
                     self._cdc_tick()
                     stats = self._stats()
             except Exception as e:  # noqa: BLE001
@@ -1615,7 +1615,7 @@ class DataNode:
                 cursor += 1
                 # one replica re-read and re-checksummed per tick, in the
                 # interpreter that receives
-                with profiler.phase("block_scan"):
+                with profiler.cpu_phase("block_scan"):
                     bad = self.verify_block(bid)
                 if bad:
                     _M.incr("scanner_corrupt_found")

@@ -291,8 +291,10 @@ class ShortCircuitServer:
                 return
             # The fd-grant serve is a (tiny) read too: its timeline rings
             # beside the TCP serve_read ones so short-circuit latency is
-            # attributed on the same read families.
-            with profiler.read_timeline(block_id):
+            # attributed on the same read families.  It leaves no ``dn_read``
+            # span: that is a read's whole service, and a grant comes before
+            # every local client's read, served over TCP or not.
+            with profiler.read_timeline(block_id, cover=None):
                 with profiler.phase("index_lookup"):
                     meta = self._dn.replicas.get_meta(block_id)
                 if meta is None:

@@ -398,18 +398,26 @@ class ContainerStore:
         """``seal`` + ``on_seal`` of a rolled-over lane's container (inline
         or on the seal thread), and then — nothing can see it any more —
         its buffer back to the free list.  A seal that raised keeps the
-        buffer out of it."""
+        buffer out of it.
+
+        ``seal`` is the container's covering span (its count is the
+        store's count of containers sealed, its CPU what the seal costs
+        the thread that runs it); the thread's spans between its ends
+        (``seal_send`` / ``seal_wait`` / ``seal_write``, ``seal_index``)
+        are the container's own timeline."""
         sealed = False
-        try:
-            self.seal(cid, data=payload, have_raw=had_raw, comp=comp)
-            if on_seal is not None:
-                on_seal(cid)
-            sealed = True
-        finally:
-            with self._alloc_lock:
-                self._sealing -= 1
-                if sealed:
-                    self._reuse_locked(payload.obj)
+        with profiler.cpu_phase("seal"):
+            try:
+                self.seal(cid, data=payload, have_raw=had_raw, comp=comp)
+                if on_seal is not None:
+                    with profiler.phase("seal_index"):
+                        on_seal(cid)
+                sealed = True
+            finally:
+                with self._alloc_lock:
+                    self._sealing -= 1
+                    if sealed:
+                        self._reuse_locked(payload.obj)
 
     def _seal_locked(self, lane: _Lane, on_seal, comp=None) -> None:
         had_raw = lane.fh is not None
@@ -433,8 +441,8 @@ class ContainerStore:
             # readable (read_container's raw fallback) until the worker's
             # seal renames it, and the cid is retired from the lane HERE, so
             # no later append can touch it.
-            self._seal_q.put((lane.container_id, payload, had_raw, on_seal,
-                              comp))
+            self._seal_q.put((profiler.mark(), lane.container_id, payload,
+                              had_raw, on_seal, comp))
             _M.incr("async_seals")
         else:
             self._seal_payload(lane.container_id, payload, had_raw, on_seal,
@@ -572,8 +580,12 @@ class ContainerStore:
             if item is None:
                 self._seal_q.task_done()
                 return
+            t_put, *args = item
             try:
-                self._seal_payload(*item)
+                # the container's wait for this thread: from the
+                # rollover's ``put`` to here, where its ``seal`` begins
+                profiler.record_span("seal_queue", t_put, profiler.mark())
+                self._seal_payload(*args)
             except BaseException as e:  # noqa: BLE001 — re-raised at drain
                 self._seal_exc = e
             finally:
@@ -584,7 +596,10 @@ class ContainerStore:
         raised here).  No-op with async seals disabled."""
         if self._seal_q is None:
             return
-        self._seal_q.join()
+        # a caller waiting for the queue to empty: in a benchmark's
+        # window, the tail after the last ack
+        with profiler.phase("seal_drain"):
+            self._seal_q.join()
         if self._seal_exc is not None:
             exc, self._seal_exc = self._seal_exc, None
             raise exc

@@ -3,9 +3,12 @@
 The reference measures its write path with coarse per-op rate counters
 (DataNodeMetrics.java:553-560 ``addWriteBlockOp``/``addPacketAckRoundTripTimeNanos``)
 — enough to say *that* a write was slow, never *where* the time went.  This
-module is the missing decomposition, re-designed for the one-vCPU DN host
-whose only real overlaps are host-work-under-device-compute and
-host-work-under-transport-waits (PERF_NOTES.md:round 4):
+module is the missing decomposition.  The DataNode's host has cores to
+spare (13 beside the benchmark's chip) and ONE interpreter: every handler
+thread, the seal thread and the periodic ticks take turns at it, so host
+work is the scarce class and a wait is good exactly when host work runs
+under it (PERF_NOTES.md:round 4 drew that for a one-vCPU host; it holds
+for one interpreter lock on any number of cores):
 
 - Every block write opens a :class:`BlockTimeline` (ambient via contextvar,
   the bf1-buffer lifetime of BlockReceiver.java:877-897) into which named
@@ -15,14 +18,30 @@ host-work-under-transport-waits (PERF_NOTES.md:round 4):
   hot path, no syncs).  The device ledger (utils/device_ledger.py) feeds
   ``device_wait`` spans and event-id links at its existing readback hook, so
   host phases and device work join into one timeline.
-- :func:`profile_spans` is the overlap accountant: it partitions a wall-clock
-  window into four EXCLUSIVE classes — ``host_busy`` > ``device_busy`` >
-  ``transport_wait`` > ``idle`` (priority order; host work always owns the
-  single vCPU, so wait time under it is *hidden*, the desirable state) — and
-  computes ``overlap_efficiency`` = hidden wait / hideable wait plus
-  per-phase exclusive seconds, the numbers the gap-attribution table
-  (tools/gap_report.py) and ROADMAP item 1's pipeline refactor are judged
-  against.
+- :func:`profile_spans` is the overlap accountant.  Its **exclusive
+  partition** gives every instant of a wall-clock window to one of four
+  classes — ``host_busy`` > ``device_busy`` > ``transport_wait`` > ``idle``
+  (priority order; host work always owns the interpreter, so wait time
+  under it is *hidden*, the desirable state) — and within the winning class
+  to one phase (``PHASE_ORDER``), and computes ``overlap_efficiency`` =
+  hidden wait / hideable wait: the numbers the gap-attribution table
+  (tools/gap_report.py) and the benchmark's ``*_pct`` metrics read.  A share
+  of the window says who OWNED it, not what anyone paid: with four streams
+  ``container_io`` owns every instant any thread is inside it, and a
+  background phase owns only what no foreground phase claims.
+- Beside the partition, the **inclusive table** (PR 35): by span name the
+  count of spans, their own lengths summed over whatever threads
+  (thread-wall: what the blocks, containers or ticks of a window themselves
+  paid), the longest, and — for the spans that carry it — their thread's
+  CPU, which tells what a unit cost from what it waited (for the
+  interpreter, a lock, a socket).  ``cpu_phase(name)`` takes
+  ``time.thread_time()`` at both ends; it is for spans that are few (a
+  block, a container, a read, a tick), never for a packet run's.
+- A fifth class, ``COVER``, is in the inclusive table alone: covering spans
+  of a unit of work — ``seal_queue`` / ``seal`` / ``seal_index`` a
+  container, ``seal_drain`` a caller of ``drain_seals()``, ``dn_block`` /
+  ``dn_read`` a finished timeline — which the sweep skips, so they take no
+  instant from ``idle`` or from the phases beneath them.
 - Counter tracks (in-flight blocks, outstanding dispatches, WAL queue depth)
   sample on every change into a bounded ring, rendered as Chrome ``C``
   events by tracing.chrome_trace for the /traces?format=chrome export.
@@ -70,10 +89,14 @@ _M = metrics.registry("write_profiler")
 _R = metrics.registry("read_profiler")
 
 # Overlap classes, in wall-clock partition PRIORITY order (PERF_NOTES round
-# 4: the 1-vCPU host is the scarce resource — an interval where host work
-# runs counts host_busy even when device/transport waits are in flight;
+# 4: the one interpreter is the scarce resource — an interval where host
+# work runs counts host_busy even when device/transport waits are in flight;
 # those waits are then HIDDEN, which is the state the pipeline wants).
 HOST, DEVICE, TRANSPORT = "host", "device", "transport"
+# A covering span of a unit of work (a container's whole seal, a block's
+# whole stay on its thread): counted in the inclusive table, skipped by the
+# sweep, so it takes no instant from ``idle`` or from the phases beneath it.
+COVER = "cover"
 CLASSES = ("host_busy", "device_busy", "transport_wait", "idle")
 
 PHASE_CLASS = {
@@ -140,7 +163,22 @@ PHASE_CLASS = {
     # process's seconds, and the sender's sum of the next frame runs
     # beside them.
     "seal_ingest": HOST,
+    # Units of work on the DataNode's served path, inclusive table only
+    # (PR 35).  A container: ``seal_queue`` from the rollover's ``put`` to
+    # the seal thread's ``get``; ``seal`` everything the seal thread (or,
+    # inline, the appending thread) does for it, its thread's CPU with it;
+    # ``seal_index`` the ``on_seal`` hook inside that (the index's lock).
+    # The container's own timeline needs no ring of its own: it is the seal
+    # thread's spans between its ``seal`` span's ends, and its
+    # ``seal_queue`` ends where that begins.  ``seal_drain`` is a caller
+    # inside ``drain_seals()``: in a benchmark window, the tail after the
+    # last ack.  ``dn_block`` / ``dn_read`` are one block's / one read's
+    # timeline, first instant to last, with the receiving / serving
+    # thread's CPU over it.
+    "seal_queue": COVER, "seal": COVER, "seal_index": COVER,
+    "seal_drain": COVER, "dn_block": COVER, "dn_read": COVER,
 }
+_COVERING = frozenset(n for n, c in PHASE_CLASS.items() if c == COVER)
 
 # Deterministic attribution order when several phases of the winning class
 # overlap inside one elementary interval (rare: host phases are serial on
@@ -188,6 +226,10 @@ def phase_class(name: str) -> str:
 # to the builtin itself (one call per read, not two); always looked up as
 # a module global so tests can substitute a settable clock.
 _now = time.time
+# The calling thread's CPU seconds, for the few spans that carry them
+# (``cpu_phase(name)``, a timeline's covering span); a module global
+# for the same reason.
+_thread_cpu = time.thread_time
 
 
 _PROC = f"{os.path.basename(sys.argv[0] or 'py')}:{os.getpid()}"
@@ -272,7 +314,7 @@ class BlockTimeline:
         self.trace_id = None if ctx is None else f"{ctx[0]:016x}"
         self.t0 = _now() if t0 is None else t0
         self.t1: float | None = None
-        self.spans: list[tuple] = []          # (phase, t0, t1, thread)
+        self.spans: list[tuple] = []    # (phase, t0, t1, thread[, cpu_s])
         self.ledger_ids: list[int] = []       # device-ledger event ids
 
     def add_span(self, phase: str, t0: float, t1: float,
@@ -283,15 +325,16 @@ class BlockTimeline:
         if self.t1 is None:
             self.t1 = _now() if t1 is None else t1
 
-    def profile(self) -> dict[str, Any]:
+    def profile(self, inclusive: bool = True) -> dict[str, Any]:
         end = self.t1 if self.t1 is not None else _now()
-        return profile_spans(self.spans, self.t0, end, nbytes=self.nbytes)
+        return profile_spans(self.spans, self.t0, end, nbytes=self.nbytes,
+                             inclusive=inclusive)
 
     def snapshot(self) -> dict[str, Any]:
         """JSON-safe dump (the gap_report/--input interchange shape)."""
         return {"block_id": self.block_id, "nbytes": self.nbytes,
                 "trace_id": self.trace_id, "t0": self.t0, "t1": self.t1,
-                "spans": [[p, a, b] for p, a, b, _ in self.spans],
+                "spans": [[sp[0], sp[1], sp[2]] for sp in self.spans],
                 "ledger_ids": list(self.ledger_ids),
                 "profile": self.profile()}
 
@@ -300,25 +343,51 @@ class BlockTimeline:
 
 
 def profile_spans(spans: Iterable, t0: float, t1: float,
-                  nbytes: int = 0) -> dict[str, Any]:
+                  nbytes: int = 0, inclusive: bool = True) -> dict[str, Any]:
     """Partition [t0, t1] into the four exclusive overlap classes and
-    per-phase exclusive seconds via a boundary sweep.
+    per-phase exclusive seconds via a boundary sweep, and (``inclusive``)
+    sum every span's own length by name beside it.
 
-    ``spans`` yields ``(phase, s0, s1)`` or ``(phase, s0, s1, thread)``.
+    ``spans`` yields ``(phase, s0, s1[, thread[, cpu_s]])``.
     The class partition sums exactly to the wall clock (``idle`` is the
     remainder by construction).  ``overlap_efficiency`` = wait time hidden
     under host work / total device+transport wait time (1.0 when there was
     nothing to hide); ``attributed_frac`` = share of wall covered by at
     least one named phase (the >= 95% gap_report acceptance bar).
+
+    The partition says what share of the WINDOW a phase owned (one phase an
+    instant, whatever the number of threads inside it); the ``inclusive``
+    table says what the spans of a name THEMSELVES paid: ``count``,
+    ``wall_s`` (their lengths summed, clamped to the window, from whatever
+    threads — four threads a second inside a phase are four seconds),
+    ``wall_max_s`` and, where the spans carried their thread's CPU,
+    ``cpu_s`` (a span cut by the window keeps the same share of its CPU as
+    of its wall).  Spans of class ``COVER`` are in that table alone.
     """
     wall = max(t1 - t0, 0.0)
     classes = dict.fromkeys(CLASSES, 0.0)
     phases: dict[str, float] = {}
     hidden = hideable = 0.0
     events: list[tuple[float, int, str]] = []
+    table: dict[str, list] = {}     # name -> [count, wall, wall_max, cpu]
     for sp in spans:
         name, s0, s1 = sp[0], max(sp[1], t0), min(sp[2], t1)
-        if s1 > s0:
+        # (a span too short for the clock's last digit, inside the window,
+        # still counts in the table: a drain that found the queue empty)
+        if s1 > s0 or (s1 == s0 and sp[1] == sp[2]):
+            if inclusive:
+                dur = s1 - s0
+                row = table.get(name)
+                if row is None:
+                    row = table[name] = [0, 0.0, 0.0, None]
+                row[0] += 1
+                row[1] += dur
+                if dur > row[2]:
+                    row[2] = dur
+                if len(sp) > 4:
+                    row[3] = (row[3] or 0.0) + _cut(sp, t0, t1)[4]
+            if name in _COVERING or s1 == s0:
+                continue
             events.append((s0, 1, name))
             events.append((s1, -1, name))
     events.sort(key=lambda e: e[0])
@@ -375,6 +444,12 @@ def profile_spans(spans: Iterable, t0: float, t1: float,
         "overlap_efficiency": hidden / hideable if hideable > 0 else 1.0,
         "attributed_frac": used / wall if wall > 0 else 1.0,
     }
+    if inclusive:
+        out["inclusive"] = {
+            name: ({"count": n, "wall_s": w, "wall_max_s": m} if c is None
+                   else {"count": n, "wall_s": w, "wall_max_s": m,
+                         "cpu_s": c})
+            for name, (n, w, m, c) in table.items()}
     if nbytes:
         out["bytes"] = nbytes
         out["mb_per_s"] = nbytes / wall / (1 << 20) if wall > 0 else 0.0
@@ -389,6 +464,7 @@ def block_timeline(block_id: int, nbytes: int = 0) -> Iterator[BlockTimeline]:
     """Open the ambient timeline for one block write; on exit the finished
     timeline lands in the ring and its per-phase histograms + overlap gauges
     are observed into the ``write_profiler`` registry."""
+    c0 = _thread_cpu()     # both reads outside [t0, t1]: no idle added
     tl = BlockTimeline(block_id, nbytes)
     tok = _current.set(tl)
     counter_add("inflight_blocks", 1)
@@ -398,13 +474,16 @@ def block_timeline(block_id: int, nbytes: int = 0) -> Iterator[BlockTimeline]:
         _current.reset(tok)
         counter_add("inflight_blocks", -1)
         tl.finish()
+        cpu = _thread_cpu() - c0
+        _cover("dn_block", tl, cpu)
         with _lock:
             _timelines.append(tl)
         _observe_finished(tl)
 
 
 @contextlib.contextmanager
-def read_timeline(block_id: int, nbytes: int = 0) -> Iterator[BlockTimeline]:
+def read_timeline(block_id: int, nbytes: int = 0,
+                  cover: str | None = "dn_read") -> Iterator[BlockTimeline]:
     """Open the ambient timeline for one block READ (serve_read /
     short-circuit serve / EC degraded read).  Same BlockTimeline machinery
     and exclusive-class partition as the write side — reconstruct code
@@ -413,7 +492,11 @@ def read_timeline(block_id: int, nbytes: int = 0) -> Iterator[BlockTimeline]:
     ledger's readback hook still lands ``device_wait`` spans — but finished
     timelines ring separately and observe into the ``read_profiler``
     registry as ``phase_us|op=read,phase=<name>`` histograms, so the read
-    families sit next to the write families on /prom."""
+    families sit next to the write families on /prom.  ``cover`` names the
+    covering span the finished timeline leaves in the ring; ``None`` for a
+    timeline that is no read's service (the short-circuit fd grant, which
+    comes before every local client's read and would halve the mean)."""
+    c0 = _thread_cpu()     # both reads outside [t0, t1]: no idle added
     tl = BlockTimeline(block_id, nbytes)
     tok = _current.set(tl)
     counter_add("inflight_reads", 1)
@@ -423,13 +506,24 @@ def read_timeline(block_id: int, nbytes: int = 0) -> Iterator[BlockTimeline]:
         _current.reset(tok)
         counter_add("inflight_reads", -1)
         tl.finish()
+        cpu = _thread_cpu() - c0
+        if cover is not None:
+            _cover(cover, tl, cpu)
         with _lock:
             _read_timelines.append(tl)
         _observe_finished_read(tl)
 
 
+def _cover(name: str, tl: BlockTimeline, cpu: float) -> None:
+    """A finished timeline's covering span, as long as the timeline itself
+    and with the CPU its thread spent under it: into the ring alone (the
+    timeline has ``t0`` / ``t1`` already, and the sweep that follows on
+    this thread is not the block's)."""
+    _span_ring.append((name, tl.t0, tl.t1, threading.get_ident(), cpu))
+
+
 def _observe_finished_read(tl: BlockTimeline) -> None:
-    prof = tl.profile()
+    prof = tl.profile(inclusive=False)
     for name, s in prof["phases"].items():
         _R.observe(f"phase_us|op=read,phase={name}", s * 1e6)
     _R.observe("read_wall_us", prof["wall_s"] * 1e6)
@@ -468,7 +562,7 @@ def bind_timeline(tl: BlockTimeline | None) -> Iterator[BlockTimeline | None]:
 
 
 def _observe_finished(tl: BlockTimeline) -> None:
-    prof = tl.profile()
+    prof = tl.profile(inclusive=False)
     for name, s in prof["phases"].items():
         _M.observe(f"phase_us|phase={name}", s * 1e6)
     _M.observe("block_wall_us", prof["wall_s"] * 1e6)
@@ -564,6 +658,34 @@ class phase:
         if tl is not None:
             tl.spans.append(span)
         _span_ring.append(span)
+
+
+class cpu_phase(phase):
+    """A :class:`phase` that also reads ``time.thread_time()`` at both
+    ends, inside the wall reads, and carries the difference as a fifth
+    field of its span: what the span cost its thread against what it
+    waited (for the interpreter, a lock, a socket).  Two more clock reads
+    of 6.5 us each on the benchmark's host: for a span a block, a
+    container, a read or a tick, never one a packet run, a stride or a
+    chunk.  A class of its own so that a plain span costs what it did."""
+
+    __slots__ = ("_c0",)
+
+    def __enter__(self) -> "cpu_phase":
+        phase.__enter__(self)
+        self._c0 = _thread_cpu()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        cpu = _thread_cpu() - self._c0
+        t1 = _now()
+        stack = self._close()
+        ts = self._ts
+        dur = t1 - self.t0
+        if stack:
+            stack[-1].child += dur
+        ts.cum[self.name] = ts.cum.get(self.name, 0.0) + dur - self.child
+        _record((self.name, self.t0, t1, ts.tid, cpu))
 
 
 def lap(name: str, t0: float) -> None:
@@ -735,8 +857,19 @@ def window_spans(t0: float, t1: float) -> list[tuple]:
     """Spans from ANY thread overlapping [t0, t1], clamped to it — the
     cross-thread view run-level accounting needs (the bench's commit worker
     records on its own thread; a contextvar would never see it)."""
-    return [(p, max(s0, t0), min(s1, t1), tid)
-            for p, s0, s1, tid in _span_ring.copy() if s1 > t0 and s0 < t1]
+    return [(sp[0], max(sp[1], t0), min(sp[2], t1), sp[3]) if len(sp) == 4
+            else _cut(sp, t0, t1)
+            for sp in _span_ring.copy() if sp[2] > t0 and sp[1] < t1]
+
+
+def _cut(sp: tuple, t0: float, t1: float) -> tuple:
+    """A span that carries its thread's CPU, clamped to [t0, t1]: cut by
+    an edge it keeps the same share of its CPU as of its wall."""
+    name, s0, s1, tid, cpu = sp
+    a, b = max(s0, t0), min(s1, t1)
+    if (a, b) != (s0, s1):
+        cpu *= (b - a) / (s1 - s0)
+    return (name, a, b, tid, cpu)
 
 
 def window_profile(t0: float, t1: float, nbytes: int = 0) -> dict[str, Any]:

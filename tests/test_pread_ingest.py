@@ -454,8 +454,24 @@ class TestReadPhases:
         wall = prof["wall_s"]
         assert sum(prof["phases"].values()) == pytest.approx(
             wall - prof["classes"]["idle"])
-        assert prof["attributed_frac"] >= 0.95
         (serve,) = [(a, b) for p, a, b in tl["spans"] if p == "read_serve"]
+        (send,) = [(a, b) for p, a, b in tl["spans"] if p == "net_send"]
+        # The two covering spans follow each other inside the timeline and
+        # every other span lies inside the first, so what no phase names
+        # is exactly the three seams around them (the timeline's opening,
+        # the step from service to send, its filing) — however long a busy
+        # machine makes a seam.  (This held ``attributed_frac`` to 0.95
+        # once: one lost interpreter switch is more than 5 % of a
+        # millisecond's read under six test workers.)
+        assert tl["t0"] <= serve[0] and serve[1] <= send[0] \
+            and send[1] <= tl["t1"]
+        assert all(p in ("read_serve", "net_send")
+                   or (serve[0] <= a and b <= serve[1])
+                   for p, a, b in tl["spans"])
+        assert prof["classes"]["idle"] == pytest.approx(
+            wall - (serve[1] - serve[0]) - (send[1] - send[0]), abs=1e-6)
+        assert prof["attributed_frac"] == pytest.approx(
+            1.0 - prof["classes"]["idle"] / wall)
         inner = [(p, a, b) for p, a, b in tl["spans"]
                  if p in READ_PHASES + ("index_lookup", "cache_probe")]
         assert all(serve[0] <= a and b <= serve[1] for _, a, b in inner)

@@ -180,6 +180,210 @@ class TestTimelineAssembly:
         assert "wal_commit" in prof["phases"]
 
 
+# ------------------------------------------- inclusive table, CPU, covering
+
+
+COVERS = [("seal", 1.0, 9.0, 7, 0.5), ("seal_queue", 0.0, 1.0, 7),
+          ("seal_index", 8.0, 9.0, 7), ("seal_drain", 3.0, 11.5, 1),
+          ("dn_block", -2.0, 6.25, 3, 1.5), ("dn_read", 2.5, 2.75, 4, 0.1)]
+SPAN_SETS = {
+    "empty": [],
+    "serial": [("recv", 0, 3), ("wal_commit", 3, 5), ("device_wait", 5, 9)],
+    "overlap": [("recv", 0, 4), ("device_wait", 2, 8),
+                ("wal_commit", 6, 10)],
+    "four_streams": [("container_io", 0.1 * k, 0.1 * k + 0.7, k)
+                     for k in range(4)]
+    + [("packet_verify", 0.05 + 0.3 * k, 0.3 * k + 0.33, k)
+       for k in range(12)]
+    + [("seal_wait", 0.0, 7.3, 9), ("heartbeat_stats", 2.1, 2.6, 8, 0.2)],
+    "nested_read": [("read_serve", 0.0, 1.0), ("container_load", 0.25, 0.75),
+                    ("index_lookup", 0.0, 0.125), ("net_send", 1.0, 4.0)],
+    "thirds": [("recv", k / 3.0, (k + 1) / 3.0 + 0.01, k % 3)
+               for k in range(30)]
+    + [("dedup_lookup", k / 7.0, k / 7.0 + 0.1, 5) for k in range(60)],
+}
+
+
+class TestCoveringSpans:
+    @pytest.mark.parametrize("which", sorted(SPAN_SETS))
+    def test_they_leave_the_partition_equal_to_the_last_digit(self, which):
+        """A covering span is in the inclusive table and nowhere else: no
+        class, no phase, no hidden or hideable second moves by a digit
+        (``==`` on floats: the sweep must not even see its boundaries)."""
+        spans = SPAN_SETS[which]
+        plain = W(spans, 0.0, 10.0)
+        mixed = W(COVERS[:3] + spans + COVERS[3:], 0.0, 10.0)
+        for key in ("classes", "phases", "wall_s", "hidden_wait_s",
+                    "hideable_wait_s", "overlap_efficiency",
+                    "attributed_frac"):
+            assert mixed[key] == plain[key], key
+        assert set(mixed["inclusive"]) == {sp[0] for sp in spans} | {
+            "seal", "seal_queue", "seal_index", "seal_drain", "dn_block",
+            "dn_read"}
+
+    def test_alone_they_leave_the_window_idle(self):
+        p = W(COVERS, 0.0, 10.0)
+        assert p["classes"]["idle"] == 10.0 and p["phases"] == {}
+        assert p["attributed_frac"] == 0.0
+
+    @pytest.mark.parametrize("name", ["seal_queue", "seal", "seal_index",
+                                      "seal_drain", "dn_block", "dn_read"])
+    def test_every_name_of_the_class(self, name):
+        assert profiler.phase_class(name) == profiler.COVER
+        assert name not in profiler.PHASE_ORDER
+
+
+class TestInclusiveTable:
+    def test_counts_walls_and_cpu_over_a_hand_made_set(self):
+        spans = [("seal", 1.0, 3.0, 7, 0.5), ("seal", 4.0, 4.5, 7, 0.25),
+                 ("container_io", 0.0, 2.0, 1), ("container_io", 1.0, 4.0, 2),
+                 ("container_io", 1.5, 2.0, 3), ("recv", 0.0, 0.5)]
+        prof = W(spans, 0.0, 10.0)
+        assert prof["inclusive"] == {
+            "seal": {"count": 2, "wall_s": 2.5, "wall_max_s": 2.0,
+                     "cpu_s": 0.75},
+            "container_io": {"count": 3, "wall_s": 5.5, "wall_max_s": 3.0},
+            "recv": {"count": 1, "wall_s": 0.5, "wall_max_s": 0.5}}
+        # three threads inside one phase: 5.5 s of their own time, 4 s of
+        # the window (the partition gives an instant to one phase once)
+        assert prof["phases"]["container_io"] == 4.0
+
+    def test_a_span_cut_by_the_window_keeps_its_share_of_cpu(self):
+        spans = [("seal", -2.0, 2.0, 7, 1.0),      # half inside
+                 ("seal", 9.0, 13.0, 7, 2.0),      # a quarter inside
+                 ("seal", 4.0, 5.0, 7, 0.125),     # whole
+                 ("seal", 20.0, 21.0, 7, 9.0)]     # outside: not counted
+        row = W(spans, 0.0, 10.0)["inclusive"]["seal"]
+        assert row == {"count": 3, "wall_s": 4.0, "wall_max_s": 2.0,
+                       "cpu_s": 0.5 + 0.5 + 0.125}
+
+    def test_a_span_of_no_length_inside_the_window_still_counts(self):
+        """``drain_seals()`` on an empty queue can end inside the clock's
+        last digit: counted with no seconds, and no boundary for the sweep
+        (the partition beside it equal to the last digit)."""
+        spans = SPAN_SETS["thirds"]
+        plain = W(spans, 0.0, 10.0)
+        mixed = W(spans + [("seal_drain", 4.0, 4.0, 1), ("recv", 1.7, 1.7),
+                           ("seal_drain", 10.0, 10.0, 1),
+                           ("seal_drain", 12.0, 12.0, 1),    # outside
+                           ("seal", 10.0, 12.0, 7, 1.0)],    # touches only
+                  0.0, 10.0)
+        assert mixed["phases"] == plain["phases"]
+        assert mixed["classes"] == plain["classes"]
+        assert mixed["inclusive"]["seal_drain"] == {
+            "count": 2, "wall_s": 0.0, "wall_max_s": 0.0}
+        assert "seal" not in mixed["inclusive"]
+        assert mixed["inclusive"]["recv"]["count"] == \
+            plain["inclusive"]["recv"]["count"] + 1
+
+    def test_the_timeline_sweep_may_leave_it_out(self):
+        assert "inclusive" not in W([("recv", 0, 1)], 0, 2, inclusive=False)
+        assert W([("recv", 0, 1)], 0, 2, inclusive=False)["phases"] == \
+            W([("recv", 0, 1)], 0, 2)["phases"]
+
+    def test_it_is_json_safe(self):
+        p = W(COVERS + SPAN_SETS["four_streams"], 0.0, 10.0)
+        assert json.loads(json.dumps(p))["inclusive"] == p["inclusive"]
+
+
+class TestThreadCpu:
+    def _clocks(self, monkeypatch):
+        profiler.reset()
+        clk, cpu = _Clock(), _Clock(50.0)   # wall, thread CPU
+        monkeypatch.setattr(profiler, "_now", clk)
+        monkeypatch.setattr(profiler, "_thread_cpu", cpu)
+        return clk, cpu
+
+    def test_cpu_phase_records_a_fifth_field_and_a_plain_phase_none(
+            self, monkeypatch):
+        clk, cpu = self._clocks(monkeypatch)
+        with profiler.cpu_phase("heartbeat_stats"):
+            clk.t += 4
+            cpu.t += 1.5
+        with profiler.phase("container_io"):
+            clk.t += 1
+            cpu.t += 1
+        a, b = profiler.window_spans(0.0, float("inf"))
+        assert a == ("heartbeat_stats", 100.0, 104.0, a[3], 1.5)
+        assert b == ("container_io", 104.0, 105.0, b[3])
+        inc = profiler.window_profile(100.0, 105.0)["inclusive"]
+        assert inc["heartbeat_stats"] == {"count": 1, "wall_s": 4.0,
+                                          "wall_max_s": 4.0, "cpu_s": 1.5}
+        assert "cpu_s" not in inc["container_io"]
+
+    def test_a_plain_phase_never_reads_the_cpu_clock(self, monkeypatch):
+        profiler.reset()
+
+        def boom():
+            raise AssertionError("a plain span took thread_time()")
+
+        monkeypatch.setattr(profiler, "_thread_cpu", boom)
+        with profiler.phase("recv"):
+            pass
+        profiler.lap("packet_verify", profiler.mark())
+        profiler.flush_laps()
+        profiler.record_span("seal_queue", 1.0, 2.0)
+        assert list(profiler.timed_iter("recv", [1, 2])) == [1, 2]
+
+    def test_window_spans_cuts_cpu_with_the_wall(self, monkeypatch):
+        clk, cpu = self._clocks(monkeypatch)
+        with profiler.cpu_phase("seal"):
+            clk.t += 8
+            cpu.t += 2
+        (sp,) = profiler.window_spans(102.0, 104.0)
+        assert sp[:3] == ("seal", 102.0, 104.0) and sp[4] == 0.5
+        (whole,) = profiler.window_spans(0.0, float("inf"))
+        assert whole[4] == 2.0
+        # the benchmark's sampler: cut at the release, kept by
+        # (name, end, thread), partitioned once to the window's end
+        (kept,) = profiler.window_spans(106.0, float("inf"))
+        row = W([kept], 106.0, 107.0)["inclusive"]["seal"]
+        assert row == {"count": 1, "wall_s": 1.0, "wall_max_s": 1.0,
+                       "cpu_s": 0.25}
+
+    @pytest.mark.parametrize("opener,name", [
+        (profiler.block_timeline, "dn_block"),
+        (profiler.read_timeline, "dn_read")])
+    def test_a_timeline_leaves_one_covering_span_as_long_as_itself(
+            self, monkeypatch, opener, name):
+        clk, cpu = self._clocks(monkeypatch)
+        with opener(9, nbytes=5) as tl:
+            clk.t += 1
+            with profiler.phase("container_io"):
+                clk.t += 2
+                cpu.t += 0.75
+            clk.t += 0.5
+            cpu.t += 0.25
+        covers = [sp for sp in profiler.window_spans(0.0, float("inf"))
+                  if sp[0] == name]
+        assert covers == [(name, tl.t0, tl.t1, threading.get_ident(), 1.0)]
+        assert (tl.t0, tl.t1) == (100.0, 103.5)
+        # in the ring alone: the timeline's own spans and profile (what
+        # the histograms observe) are what they were
+        assert [sp[0] for sp in tl.spans] == ["container_io"]
+        prof = profiler.window_profile(100.0, 103.5)
+        assert prof["classes"]["idle"] == 1.5
+        assert prof["inclusive"][name] == {
+            "count": 1, "wall_s": 3.5, "wall_max_s": 3.5, "cpu_s": 1.0}
+
+    def test_a_timeline_snapshot_keeps_three_fields_a_span(self,
+                                                           monkeypatch):
+        clk, cpu = self._clocks(monkeypatch)
+        with profiler.block_timeline(3):
+            with profiler.cpu_phase("seal"):      # an inline seal
+                with profiler.phase("seal_write"):
+                    clk.t += 1
+                clk.t += 1
+        snap = profiler.timelines_snapshot()[-1]
+        assert snap["spans"] == [["seal_write", 100.0, 101.0],
+                                 ["seal", 100.0, 102.0]]
+        assert snap["profile"]["phases"] == {"seal_write": 1.0}
+        assert snap["profile"]["classes"]["idle"] == 1.0
+        assert snap["profile"]["inclusive"]["seal"]["wall_s"] == 2.0
+        json.dumps(snap)
+        assert gap_report.aggregate([snap])["blocks"] == 1
+
+
 # ------------------------------------------------------- device-ledger link
 
 

@@ -139,6 +139,85 @@ class TestDataNodePhases:
             assert 1 <= n["worker_send"] <= 3
 
 
+class TestUnitsOfWork:
+    """PR 35: the covering spans of a block, a container, a read and a tick
+    on the served path, and the benchmark's reader of them."""
+
+    def _spans(self, served, name):
+        return [sp for sp in profiler.window_spans(served["t0"],
+                                                   served["t1"])
+                if sp[0] == name]
+
+    def test_a_block_leaves_one_dn_block_as_long_as_its_timeline(self,
+                                                                 served):
+        blocks = self._spans(served, "dn_block")
+        walls = sorted((sp[1], sp[2]) for sp in blocks)
+        assert walls == sorted((t["t0"], t["t1"])
+                               for t in served["timelines"])
+        assert len(blocks) >= 2
+        for sp in blocks:
+            assert len(sp) == 5 and 0.0 < sp[4] <= sp[2] - sp[1] + 1e-4
+
+    def test_the_seal_count_is_the_workers_compress_jobs(self, served):
+        seals = self._spans(served, "seal")
+        jobs = served["last"]["compress_jobs"] - \
+            served["first"]["compress_jobs"]
+        assert len(seals) == jobs >= 2
+        assert len(self._spans(served, "seal_queue")) == jobs
+        assert len(self._spans(served, "seal_index")) == jobs
+        assert len({sp[3] for sp in seals}) == 1    # the one seal thread
+        assert self._spans(served, "seal_drain")    # the fixture's own
+
+    def test_a_read_leaves_one_dn_read(self, served):
+        """The block read back: one ``dn_read`` for its ``serve_read``,
+        none for the short-circuit fd grant the local client asked first
+        (a read timeline of its own, in the ring of read timelines)."""
+        (rd,) = self._spans(served, "dn_read")
+        assert len(rd) == 5 and rd[2] > rd[1]
+        serves = self._spans(served, "read_serve")
+        assert len(serves) == 1
+        assert rd[1] <= serves[0][1] and serves[0][2] <= rd[2]
+        assert len(profiler.read_timelines_snapshot()) >= 2
+
+    @pytest.mark.parametrize("name", ["heartbeat_stats", "block_scan"])
+    def test_a_tick_carries_its_threads_cpu(self, served, name):
+        ticks = self._spans(served, name)
+        assert ticks and all(len(sp) == 5 and sp[4] >= 0.0 for sp in ticks)
+
+    @pytest.mark.parametrize("metric,per", [
+        ("seal.wall_ms_per_container", "seal"),
+        ("seal.cpu_ms_per_container", "seal"),
+        ("seal.queue_wait_ms_per_container", "seal_queue"),
+        ("seal.index_ms_per_container", "seal_index"),
+        ("dn.block_wall_ms", "dn_block"), ("dn.block_cpu_ms", "dn_block"),
+        ("dn.receive_ms_per_block", "dn_block"),
+        ("dn.commit_ms_per_block", "dn_block"),
+        ("dn.heartbeat_ms_per_tick", "heartbeat_stats"),
+        ("dn.heartbeat_cpu_ms_per_tick", "heartbeat_stats"),
+        ("dn.read_wall_ms", "dn_read"), ("dn.read_cpu_ms", "dn_read"),
+        ("seal.thread_busy_pct", "window"),
+        ("seal.drain_tail_pct", "window"), ("dn.host_busy_pct", "window")])
+    def test_the_benchmark_reads_it_from_the_served_window(
+            self, served, perfbench_file, metric, per):
+        with open(os.path.join(perfbench_file.root, "layers",
+                               metric + ".json")) as f:
+            layer = json.load(f)
+        prof = profiler.window_profile(served["t0"], served["t1"])
+        src = {"phases": prof, "window_s": prof["wall_s"]}
+        value = perfbench_file(f"readers/{layer['reader']}.py").read(
+            src, layer["params"])
+        assert value is not None and value >= 0.0
+        if per == "window":
+            assert value <= 100.0 + 1e-6
+        else:
+            assert layer["params"]["per"] == per
+            # thread-wall a unit, or the CPU under it
+            wall = sum(prof["inclusive"][n]["wall_s"]
+                       for n in layer["params"]["spans"])
+            assert value <= 1000.0 * wall / prof["inclusive"][per]["count"] \
+                + 1e-6
+
+
 class TestStrideSpans:
     def test_a_two_stride_block_sends_two_or_three_frames(self):
         """``worker_send`` is one span a frame: two full strides, and the

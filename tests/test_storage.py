@@ -447,6 +447,152 @@ class TestLaneBuffer:
         cs.close_async_seals()
 
 
+@pytest.fixture
+def sealed_three(tmp_path):
+    """Four 700-byte appends to one 1 000-byte lane with async seals: three
+    containers sealed (the compressor leaves the hop's two spans, as
+    ``WorkerClient.compress`` does), then ``drain_seals()`` from here.
+    Everything the phase clock recorded meanwhile."""
+    import threading
+    import time
+
+    from hdrf_tpu.utils import codec as codecs
+    from hdrf_tpu.utils import profiler
+
+    def compress_fn(data):
+        with profiler.phase("seal_send"):
+            time.sleep(0.001)
+        with profiler.phase("seal_wait"):
+            out = codecs.compress("lz4", data)
+        return out
+
+    indexed = []
+    profiler.reset()
+    cum0 = profiler.cumulative()
+    t0 = profiler.mark()
+    cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
+                        codec="lz4", compress_fn=compress_fn)
+    cs.enable_async_seals()
+    locs = []
+    for fill in b"abcd":
+        locs += cs.append_chunks([bytes([fill]) * 700],
+                                 on_seal=indexed.append)
+    cs.drain_seals()
+    out = {"spans": profiler.window_spans(t0, float("inf")),
+           "t0": t0, "t1": profiler.mark(),
+           "seal_tid": cs._seal_thread.ident,
+           "caller_tid": threading.get_ident(), "indexed": indexed,
+           "cids": [cid for cid, _, _ in locs[:3]],
+           "cum": {k: v - cum0.get(k, 0.0)
+                   for k, v in profiler.cumulative().items()}}
+    cs.close_async_seals()
+    return out
+
+
+INNER = ("seal_send", "seal_wait", "seal_write", "seal_index")
+
+
+class TestSealSpans:
+    """The seal pipeline on the phase clock (PR 35): a container's wait in
+    the queue, its covering ``seal`` span with the sealing thread's CPU,
+    the index hook inside it, and the caller's wait in ``drain_seals``."""
+
+    def _named(self, rec, name):
+        return sorted((sp for sp in rec["spans"] if sp[0] == name),
+                      key=lambda sp: sp[1])
+
+    def test_one_seal_span_a_container_with_its_threads_cpu(self,
+                                                            sealed_three):
+        seals = self._named(sealed_three, "seal")
+        assert len(seals) == 3 and sealed_three["indexed"] == \
+            sealed_three["cids"]
+        for sp in seals:
+            assert len(sp) == 5 and sp[3] == sealed_three["seal_tid"]
+            # CPU under its wall (two clocks: a hair of slack)
+            assert 0.0 <= sp[4] <= sp[2] - sp[1] + 1e-4
+        # one thread: the seals do not overlap
+        assert all(a[2] <= b[1] for a, b in zip(seals, seals[1:]))
+
+    def test_seal_queue_ends_where_its_seal_begins(self, sealed_three):
+        queued = self._named(sealed_three, "seal_queue")
+        seals = self._named(sealed_three, "seal")
+        assert len(queued) == 3
+        queued.sort(key=lambda sp: sp[2])
+        for k, (q, sl) in enumerate(zip(queued, seals)):
+            assert len(q) == 4 and q[3] == sealed_three["seal_tid"]
+            assert q[1] <= q[2] <= sl[1]
+            # put by the rollover on the appending thread, taken only
+            # after the seal before it has ended
+            assert k == 0 or q[2] >= seals[k - 1][2]
+
+    @pytest.mark.parametrize("name", INNER)
+    def test_the_inner_spans_lie_inside_their_seal_on_one_thread(
+            self, sealed_three, name):
+        seals = self._named(sealed_three, "seal")
+        inner = self._named(sealed_three, name)
+        assert inner and all(sp[3] == sealed_three["seal_tid"]
+                             for sp in inner)
+        for sl in seals:
+            mine = [sp for sp in inner if sl[1] <= sp[1] and sp[2] <= sl[2]]
+            assert len(mine) >= 1, (name, sl)
+            assert name == "seal_write" or len(mine) == 1
+        assert all(any(sl[1] <= sp[1] and sp[2] <= sl[2] for sl in seals)
+                   for sp in inner)
+
+    def test_seal_drain_is_the_callers(self, sealed_three):
+        (drain,) = self._named(sealed_three, "seal_drain")
+        assert drain[3] == sealed_three["caller_tid"] != \
+            sealed_three["seal_tid"]
+        # it waited for the last seal to end
+        assert drain[2] >= self._named(sealed_three, "seal")[-1][2]
+
+    def test_none_of_them_is_in_the_partition(self, sealed_three):
+        from hdrf_tpu.utils import profiler
+
+        rec = sealed_three
+        prof = profiler.profile_spans(rec["spans"], rec["t0"], rec["t1"])
+        assert not {"seal", "seal_queue", "seal_index",
+                    "seal_drain"} & set(prof["phases"])
+        bare = [sp for sp in rec["spans"]
+                if profiler.phase_class(sp[0]) != profiler.COVER]
+        again = profiler.profile_spans(bare, rec["t0"], rec["t1"])
+        assert again["phases"] == prof["phases"]
+        assert again["classes"] == prof["classes"]
+        assert prof["inclusive"]["seal"]["count"] == 3
+        assert prof["inclusive"]["seal_queue"]["count"] == 3
+        assert prof["inclusive"]["seal_index"]["count"] == 3
+        assert "cpu_s" in prof["inclusive"]["seal"]
+
+    def test_what_seal_alone_owns_is_its_wall_less_the_inner_spans(
+            self, sealed_three):
+        """The closure PERF.md section 5 reads: ``seal`` = the four inner
+        names' inclusive seconds + its own self seconds."""
+        wall = {n: sum(sp[2] - sp[1] for sp in self._named(sealed_three, n))
+                for n in INNER + ("seal",)}
+        own = sealed_three["cum"]["seal"]
+        assert own >= 0.0
+        assert wall["seal"] == pytest.approx(
+            sum(wall[n] for n in INNER) + own, abs=1e-6)
+
+    def test_an_inline_seal_is_the_appending_threads(self, tmp_path):
+        import threading
+
+        from hdrf_tpu.utils import profiler
+
+        profiler.reset()
+        cs = ContainerStore(str(tmp_path), container_size=1000, lanes=1,
+                            codec="lz4")
+        cs.append_chunks([b"x" * 700], on_seal=lambda cid: None)
+        cs.append_chunks([b"y" * 700], on_seal=lambda cid: None)
+        cs.drain_seals()                # no queue: nothing to wait for
+        names = [sp[0] for sp in profiler.window_spans(0.0, float("inf"))]
+        assert names.count("seal") == 1 and names.count("seal_index") == 1
+        assert "seal_queue" not in names and "seal_drain" not in names
+        (seal,) = [sp for sp in profiler.window_spans(0.0, float("inf"))
+                   if sp[0] == "seal"]
+        assert seal[3] == threading.get_ident() and len(seal) == 5
+
+
 class TestReplicaStore:
     def test_rbw_to_finalized(self, tmp_path):
         rs = ReplicaStore(str(tmp_path))
