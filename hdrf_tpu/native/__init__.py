@@ -83,6 +83,9 @@ def _load() -> ctypes.CDLL:
         lib.hdrf_lz4_emit.restype = ctypes.c_uint64
         lib.hdrf_crc32c.argtypes = [ctypes.c_uint32, _u8p, ctypes.c_uint64]
         lib.hdrf_crc32c.restype = ctypes.c_uint32
+        lib.hdrf_crc32c_table.argtypes = lib.hdrf_crc32c.argtypes
+        lib.hdrf_crc32c_table.restype = ctypes.c_uint32
+        lib.hdrf_crc32c_backend.restype = ctypes.c_char_p
         lib.hdrf_chacha20_xor.argtypes = [_u8p, _u8p, ctypes.c_uint32, _u8p,
                                           ctypes.c_uint64, _u8p]
         lib.hdrf_aead_seal.argtypes = [_u8p, _u8p, _u8p, ctypes.c_uint64,
@@ -324,6 +327,27 @@ def aead_open(key: bytes, nonce: bytes, aad: bytes,
 def crc32c(data: bytes | np.ndarray, crc: int = 0) -> int:
     a = _as_u8(data)
     return _load().hdrf_crc32c(crc & 0xFFFFFFFF, _ptr(a, _u8p), a.size)
+
+
+def crc32c_table(data: bytes | np.ndarray, crc: int = 0) -> int:
+    """The slice-by-8 table loop: what ``crc32c`` runs where the CPU lacks
+    the instruction, and the oracle the tests hold ``crc32c`` to."""
+    a = _as_u8(data)
+    return _load().hdrf_crc32c_table(crc & 0xFFFFFFFF, _ptr(a, _u8p), a.size)
+
+
+def crc32c_backend() -> str:
+    """The routine ``crc32c`` (and with it ``crc32c_chunks`` and the packet
+    unpacker's verify) runs in this process, chosen once at load from what
+    the CPU reports: ``"sse42x3"`` (the CRC32C instruction, three
+    interleaved streams) or ``"table"``."""
+    return _load().hdrf_crc32c_backend().decode()
+
+
+def crc32c_hw() -> int:
+    """1 / 0: the value of gauge ``crc32c_hw`` (registry ``native``), which
+    the DataNode and the worker set once at start."""
+    return int(crc32c_backend() != "table")
 
 
 def crc32c_chunks(data: bytes | np.ndarray, chunk_size: int) -> np.ndarray:

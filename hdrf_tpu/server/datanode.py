@@ -430,6 +430,10 @@ class DataNode:
         return self._server.server_address
 
     def start(self) -> "DataNode":
+        from hdrf_tpu import native
+
+        # a gauge, not a rate: the routine engages always or never
+        metrics.registry("native").gauge("crc32c_hw", native.crc32c_hw())
         self._verify_index_containers()
         t = threading.Thread(target=self._server.serve_forever,
                              name=f"{self.dn_id}-xceiver", daemon=True)

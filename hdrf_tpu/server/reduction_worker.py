@@ -147,6 +147,7 @@ class ReductionWorker:
         return self._server.server_address
 
     def start(self) -> "ReductionWorker":
+        metrics.registry("native").gauge("crc32c_hw", native.crc32c_hw())
         self._thread = threading.Thread(target=self._server.serve_forever,
                                         name="reduction-worker", daemon=True)
         self._thread.start()
@@ -219,7 +220,9 @@ class ReductionWorker:
         ``prep_shapes`` — the (padded length, capacity) pairs this worker's
         reducers have dispatched ``_prep`` at, retries too: each a program
         to compile or to fetch from the cache, so its growth over a window
-        is the reduce ops that met a new one; the
+        is the reduce ops that met a new one; ``crc32c_hw`` — 1 where this
+        process's ``native.crc32c`` runs on the CPU's instruction, 0 where
+        it is the table loop (the gauge of registry ``native``); the
         process's CPU seconds and a wall clock to set them against."""
         with self._stats_lock:
             out = dict(self._stats)
@@ -232,6 +235,7 @@ class ReductionWorker:
         if self.backend == "tpu":
             out["prep_shapes"] = sum(len(r.prep_shapes)
                                      for r in list(self._reducers.values()))
+        out["crc32c_hw"] = native.crc32c_hw()
         out["cpu_s"] = time.process_time()
         out["wall_s"] = time.perf_counter()
         return out

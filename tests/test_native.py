@@ -186,6 +186,91 @@ def test_crc32c_incremental():
     assert c1 != zlib.crc32(data)
 
 
+# hdrf_crc32c (the dispatching entry: the CPU's instruction over three
+# interleaved streams, blocks of 3 x 8 KiB then 3 x 256 B, the rest one
+# stream) against hdrf_crc32c_table (the slice-by-8 loop it replaced), bit
+# for bit.  On a CPU without the instruction both are the table loop and
+# every case still passes.
+_CRC_LONG, _CRC_SHORT = 3 * 8192, 3 * 256
+_CRC_DATA = np.random.default_rng(36).integers(
+    0, 256, (4 << 20) + 64, dtype=np.uint8)
+
+
+def _crc_same(a, crc=0, want=None):
+    got = native.crc32c(a, crc)
+    assert got == native.crc32c_table(a, crc)
+    assert want is None or got == want
+    return got
+
+
+def _crc_parts(parts):
+    """A buffer summed in ``parts`` pieces of unequal length, each piece's
+    sum carried into the next, equals the whole — by either routine."""
+    a = _CRC_DATA[3:3 + 2 * _CRC_LONG + _CRC_SHORT + 13]
+    edges = [a.size * k * k // (parts * parts) for k in range(parts + 1)]
+    crc = 0
+    for lo, hi in zip(edges, edges[1:]):
+        crc = _crc_same(a[lo:hi], crc)
+    assert crc == _crc_same(a)
+
+
+def _crc_chunks(chunk):
+    a = _CRC_DATA[5:5 + 3 * chunk + chunk // 3 + 1]    # a short last chunk
+    out = native.crc32c_chunks(a, chunk)
+    assert out.tolist() == [native.crc32c_table(a[o:o + chunk])
+                            for o in range(0, a.size, chunk)]
+    assert len(out) == 4
+
+
+_CRC_LENGTHS = [*range(65),
+                *(b + d for b in (_CRC_SHORT, 2 * _CRC_SHORT, _CRC_LONG,
+                                  _CRC_LONG + _CRC_SHORT, 2 * _CRC_LONG)
+                  for d in (-9, -1, 0, 1, 9)),
+                64 << 10, 1 << 20, (4 << 20) + 17]
+_CRC_CASES = {f"len-{n}": (lambda n=n: _crc_same(_CRC_DATA[:n]))
+              for n in _CRC_LENGTHS}
+_CRC_CASES.update({
+    f"start-{o}": (lambda o=o: [
+        _crc_same(_CRC_DATA[o:o + n])
+        for n in (5, 64, _CRC_SHORT + 3, _CRC_LONG + _CRC_SHORT + 11,
+                  64 << 10)])
+    for o in range(1, 8)})
+_CRC_CASES.update({
+    "parts-2": lambda: _crc_parts(2),
+    "parts-5": lambda: _crc_parts(5),
+    "chunks-64KiB": lambda: _crc_chunks(64 << 10),
+    "chunks-1MiB": lambda: _crc_chunks(1 << 20),
+    # RFC 3720 B.4: 32 bytes of zeros, of ones, ascending, descending
+    "vector-zeros": lambda: _crc_same(bytes(32), want=0x8A9136AA),
+    "vector-ones": lambda: _crc_same(b"\xff" * 32, want=0x62A8AB43),
+    "vector-ascending": lambda: _crc_same(bytes(range(32)), want=0x46DD794E),
+    "vector-descending":
+        lambda: _crc_same(bytes(range(31, -1, -1)), want=0x113FDB5C),
+    "vector-digits": lambda: _crc_same(b"123456789", want=0xE3069283),
+})
+
+
+@pytest.mark.parametrize("case", list(_CRC_CASES))
+def test_crc32c_equals_the_table_loop(case):
+    _CRC_CASES[case]()
+
+
+def test_crc32c_backend_is_what_the_cpu_reports():
+    backend = native.crc32c_backend()
+    assert backend in ("sse42x3", "table")
+    assert native.crc32c_hw() == (backend == "sse42x3")
+    try:
+        with open("/proc/cpuinfo") as f:
+            flags = [ln.split(":", 1)[1].split() for ln in f
+                     if ln.startswith("flags")]
+    except OSError:
+        return
+    if flags:       # x86 lists its flags; another target runs the table loop
+        assert backend == ("sse42x3" if "sse4_2" in flags[0] else "table")
+    else:
+        assert backend == "table"
+
+
 def test_gear_candidates_dense_mask_no_truncation():
     """mask=0 makes every position>=32 a candidate; wrapper must not truncate."""
     data = RNG.integers(0, 256, 1 << 14, dtype=np.uint8)

@@ -32,6 +32,17 @@ def _bytes(n):
 
 PKT = 64 * 1024
 
+# Where a flipped bit lies in a 64 KiB payload, by the part of
+# ``hdrf_crc32c``'s interleaved path that sums it (native/src/crc32c.cpp):
+# two blocks of 3 x 8 KiB, then twenty-one of 3 x 256 B, then 256 bytes one
+# stream; "payload" is the offset the older cases flipped.
+CRC_FLIPS = {"payload": 100,
+             "long-0": 24576 + 77, "long-1": 24576 + 8192 + 77,
+             "long-2": 24576 + 16384 + 77,
+             "short-0": 49152 + 5, "short-1": 49152 + 256 + 5,
+             "short-2": 49152 + 512 + 5,
+             "tail": PKT - 3}
+
 
 def _oracle(data: bytes, cdc: CdcConfig):
     """Whole-buffer cuts and digests from the native codecs."""
@@ -214,19 +225,23 @@ class TestStrideWire:
             # a carried part stays whole, one summed here is cut at a stride
             assert got[0] >= 2 and got[1] >= len(parts)
 
-    @pytest.mark.parametrize("damage", ["payload", "crc"])
+    @pytest.mark.parametrize("damage", ["crc"] + sorted(CRC_FLIPS))
     def test_an_altered_frame_is_refused(self, client, damage):
-        """A byte or a carried CRC changed on the way: the worker answers
-        with an error like any worker failure, and goes on serving."""
+        """A bit of a payload (wherever the interleaved CRC32C sums it) or
+        a carried CRC changed on the way: the worker answers with an error
+        that names the segment, like any worker failure, and goes on
+        serving."""
         data = _bytes(_STRIDE + 5 * PKT)
         parts = _with_crcs(_packets(data))
         victim, crc = parts[66]              # in the second, last frame
-        if damage == "payload":
-            parts[66] = (victim[:100] + bytes([victim[100] ^ 1])
-                         + victim[101:], crc)
-        else:
+        if damage == "crc":
             parts[66] = (victim, crc ^ 0x10)
-        with pytest.raises(WorkerError, match="checksum mismatch"):
+        else:
+            at = CRC_FLIPS[damage]
+            parts[66] = (victim[:at] + bytes([victim[at] ^ 1])
+                         + victim[at + 1:], crc)
+        with pytest.raises(WorkerError,
+                           match="stride segment 2 of 5: checksum mismatch"):
             client.reduce_stream(iter(parts), CdcConfig())
         cuts, _ = client.reduce(data, CdcConfig())
         assert int(cuts[-1]) == len(data)
@@ -594,19 +609,22 @@ class TestRunReader:
             assert len(runs) == len(packets)
         assert sock.calls <= one.calls      # never a recv more than before
 
-    @pytest.mark.parametrize("damage", ["payload", "crc"])
+    @pytest.mark.parametrize("damage", ["crc"] + sorted(CRC_FLIPS))
     @pytest.mark.parametrize("k", [0, 3, 6])
     def test_a_mismatch_ends_the_run_before_it(self, k, damage):
-        """Packet ``k`` of a run altered on the way: the packets before it
-        are a run (they may be acked), then the reader raises what
-        ``read_packet_crc`` raises, naming ``k``'s seqno."""
+        """Packet ``k`` of a run altered on the way — its carried CRC, or a
+        bit of its payload wherever the interleaved CRC32C sums it: the
+        packets before it are a run (they may be acked), nothing of ``k`` is
+        copied, then the reader raises what ``read_packet_crc`` raises,
+        naming ``k``'s seqno."""
         from hdrf_tpu.proto import datatransfer as dt
 
-        packets = _stream([5_000] * 7 + [0], base=40)
+        packets = _stream([PKT] * 7 + [0], base=40)
         seq, data, fl = packets[k]
         at = len(frame_packets(packets[:k]))
         wire = bytearray(frame_packets(packets))
-        wire[at + (13 if damage == "crc" else dt.PKT_HDR.size + 77)] ^= 0x20
+        wire[at + (13 if damage == "crc"
+                   else dt.PKT_HDR.size + CRC_FLIPS[damage])] ^= 0x20
         out = dt.BlockBuffer(1 << 20)
         runs = dt.iter_packet_runs(PiecedSocket(bytes(wire), [1 << 30]), out)
         if k:
@@ -716,6 +734,40 @@ class TestRunReader:
         if why == "OUT_FULL":       # the packet that did not fit, by name
             assert (int(unpack.seqnos[n]), int(unpack.lens[n])) == (2, 1000)
 
+    @pytest.mark.parametrize("flip", sorted(CRC_FLIPS))
+    def test_a_flipped_bit_stops_the_unpack_and_the_stride_verify(self, flip):
+        """One bit of packet 2's 64 KiB payload, wherever the interleaved
+        CRC32C sums it: ``PacketUnpacker`` stops with MISMATCH at that
+        packet's header with nothing of it copied or consumed, and
+        ``dt.verify_stride`` over the same payloads raises ``ValueError``
+        naming segment 2."""
+        from hdrf_tpu import native
+        from hdrf_tpu.proto import datatransfer as dt
+
+        packets = _stream([PKT] * 4 + [0])
+        wire = bytearray(frame_packets(packets))
+        one = dt.PKT_HDR.size + PKT
+        wire[2 * one + dt.PKT_HDR.size + CRC_FLIPS[flip]] ^= 0x04
+        unpack = native.PacketUnpacker(len(wire), 64)
+        unpack.stage[:len(wire)] = np.frombuffer(bytes(wire), np.uint8)
+        out = np.zeros(5 * PKT, np.uint8)
+        n, used, need, why = unpack(len(wire), out, 0)
+        assert (n, used, why) == (2, 2 * one, unpack.MISMATCH)
+        assert int(unpack.seqnos[n]) == 2
+        assert out[:2 * PKT].tobytes() == packets[0][1] + packets[1][1]
+        assert not out[2 * PKT:].any()
+
+        body = np.frombuffer(b"".join(d for _, d, _ in packets[:4]),
+                             np.uint8).copy()
+        body[2 * PKT + CRC_FLIPS[flip]] ^= 0x04
+        lens = np.full(4, PKT, np.uint32)
+        crcs = np.array([native.crc32c_table(d) for _, d, _ in packets[:4]],
+                        np.uint32)
+        with pytest.raises(ValueError, match="stride segment 2 of 4"):
+            dt.verify_stride(body, lens, crcs)
+        crcs[2] = native.crc32c_table(body[2 * PKT:3 * PKT])
+        dt.verify_stride(body, lens, crcs)      # the damaged bytes' own sum
+
 
 class TestWorkerProcess:
     def test_tpu_backend_without_a_chip_refuses_to_start(self, capfd):
@@ -779,6 +831,12 @@ class TestClusterWithWorker:
         try:
             assert dn.reduction_ctx.backend == "native"
             assert dn.coded.backend == "native"
+            # both daemons say which CRC32C routine their process runs
+            from hdrf_tpu import native
+            from hdrf_tpu.utils import metrics
+
+            hw = metrics.registry("native").snapshot()["gauges"]["crc32c_hw"]
+            assert hw == w.stats()["crc32c_hw"] == native.crc32c_hw()
         finally:
             dn.stop()
             nn.stop()
