@@ -4,7 +4,8 @@ run in the client processes on the very bytes they sent).
 
 Every number compared is exact, so every limit is 0 (the list is in
 ``perfbench/README.md``).  ``compare`` returns ``{name: [value, limit]}``; a
-run is correct when every value is within its limit.
+run is correct when every value is within its limit.  With N DataNodes each
+DataNode's comparisons are taken on it and summed.
 """
 
 from __future__ import annotations
@@ -52,65 +53,88 @@ def decode_sealed(dn) -> dict:
             "failures": failures, "errors": errors[:5]}
 
 
-def compare(dn, client_checks: list, ops: list, before: dict, after: dict,
-            client_counters: list, worker, fault: str,
-            parent_backends: list, block_size: int,
-            physical_bytes: int) -> tuple[dict, dict]:
-    """Returns (checks, notes).  ``before`` is the snapshot taken when the
-    cluster came up (a fresh store), ``after`` the one after the window;
-    ``physical_bytes`` is ``stored_pct``'s numerator, held here to the bytes
-    of the sealed files the reference decoder was given."""
+def compare(nodes: list, client_checks: list, ops: list,
+            client_counters: list, workers: list, fault: str,
+            parent_backends: list, block_size: int) -> tuple[dict, dict]:
+    """Returns (checks, notes).  ``nodes``: one dict a DataNode with its
+    ``dn_id``, ``before`` (the snapshot taken when the cluster came up: a
+    fresh store) and ``after`` (the one after the window), ``missing`` (the
+    reference's digests absent from its index), ``sealed``
+    (``decode_sealed``), ``orphans``, ``mirror``, ``backends`` (its process's,
+    None where that is the harness's) and ``physical_bytes`` (its part of
+    ``stored_pct``'s numerator, held here to the bytes of the sealed files
+    the reference decoder was given).  Every comparison of a DataNode is
+    taken on each and summed; ``notes["per_datanode"]`` keeps each."""
     table: dict[bytes, int] = {}
     ref_chunks = 0
     for c in client_checks:
         ref_chunks += c["chunks"]
         for d, ln in c["table"].items():
             table.setdefault(d, ln)
-    idx = after["index"]
     logical = sum(c["logical_bytes"] for c in client_checks)
-    missing = 0
-    digests = list(table)
-    for i in range(0, len(digests), 8192):
-        part = digests[i:i + 8192]
-        for d, loc in dn.index.lookup_chunks(part).items():
-            if loc is None or loc.length != table[d]:
-                missing += 1
-    sealed = decode_sealed(dn)
-    # bytes a writer appended for a chunk that a concurrent writer's commit
-    # won: in the containers, owned by no index entry, counted by the index
-    orphans = sum(dn.index.orphan_bytes().values())
-    gw0, gw1 = before["give_way"], after["give_way"]
+    ref_unique_bytes = sum(table.values())
+    per_dn = {}
+    for n in nodes:
+        idx, sealed = n["after"]["index"], n["sealed"]
+        gw = n["after"]["give_way"]
+        per_dn[n["dn_id"]] = {
+            "readback_bad": sum(c["readback_bad_by_dn"].get(n["dn_id"], 0)
+                                for c in client_checks),
+            "logical_bytes_gap": abs(idx["logical_bytes"] - logical),
+            "unique_chunks_gap": abs(idx["chunks"] - len(table)),
+            "unique_bytes_gap": abs(idx["unique_chunk_bytes"]
+                                    - ref_unique_bytes),
+            "digests_missing": n["missing"],
+            "sealed_decode_failures": sealed["failures"],
+            # bytes a writer appended for a chunk that a concurrent writer's
+            # commit won: in the containers, owned by no index entry,
+            # counted by the index
+            "sealed_bytes_gap": abs(sealed["decoded_bytes"] - n["orphans"]
+                                    - idx["unique_chunk_bytes"]),
+            "stored_bytes_gap": abs(n["physical_bytes"]
+                                    - sealed["file_bytes"]),
+            "worker_fallbacks": gw["worker_fallbacks"],
+            "degraded_writes": gw["degraded_writes"],
+            "breaker_open_total": gw["breaker_open_total"],
+            "reduction_degraded": gw["reduction_degraded"],
+            "mirror_failures": (n["mirror"]["failed_legs"]
+                                + n["mirror"]["partial_replicas"]),
+            "no_device_dispatch": int(n["backend"] == "tpu"
+                                      and n["after"]["dispatch_total"] <= 0),
+            "parent_jax_backends": len(n["backends"] or ()),
+        }
+
+    def total(name: str) -> int:
+        return sum(v[name] for v in per_dn.values())
+
     blocks = sum(-(-op["bytes"] // block_size) for op in ops
                  if op["kind"] == "write" and op["ok"])
+    # reduced mirroring: a block is reduced once, by its pipeline's first
+    # DataNode, on that DataNode's worker
+    reduced = sum(n["after"]["give_way"]["worker_reduces"]
+                  - n["before"]["give_way"]["worker_reduces"] for n in nodes)
     # a block the client had to send twice is a failed first attempt
     retries = sum(c.get("block_write_retries", 0)
                   + c.get("write_sheds_seen", 0) for c in client_counters)
-    ref_unique_bytes = sum(table.values())
     checks = {
         "ops_failed": [sum(1 for op in ops if not op["ok"]), 0],
         "readback_bad": [sum(c["readback_bad"] for c in client_checks), 0],
-        "logical_bytes_gap": [abs(idx["logical_bytes"] - logical), 0],
-        "unique_chunks_gap": [abs(idx["chunks"] - len(table)), 0],
-        "unique_bytes_gap": [abs(idx["unique_chunk_bytes"]
-                                 - ref_unique_bytes), 0],
-        "digests_missing": [missing, 0],
-        "sealed_decode_failures": [sealed["failures"], 0],
-        "sealed_bytes_gap": [abs(sealed["decoded_bytes"] - orphans
-                                 - idx["unique_chunk_bytes"]), 0],
-        "stored_bytes_gap": [abs(physical_bytes - sealed["file_bytes"]), 0],
-        "worker_fallbacks": [gw1["worker_fallbacks"], 0],
-        "degraded_writes": [gw1["degraded_writes"], 0],
-        "breaker_open_total": [gw1["breaker_open_total"], 0],
-        "reduction_degraded": [gw1["reduction_degraded"], 0],
+        **{k: [total(k), 0] for k in (
+            "logical_bytes_gap", "unique_chunks_gap", "unique_bytes_gap",
+            "digests_missing", "sealed_decode_failures", "sealed_bytes_gap",
+            "stored_bytes_gap", "worker_fallbacks", "degraded_writes",
+            "breaker_open_total", "reduction_degraded")},
         "client_block_retries": [retries, 0],
-        "blocks_not_on_worker": [max(blocks - (gw1["worker_reduces"]
-                                               - gw0["worker_reduces"]), 0),
-                                 0],
-        "parent_jax_backends": [len(parent_backends), 0],
-        "device_not_tpu": [int(worker.backend != "tpu"
-                               or worker.device.get("platform") != "tpu"), 0],
-        "no_device_dispatch": [int(worker.backend == "tpu"
-                                   and after["dispatch_total"] <= 0), 0],
+        "blocks_not_on_worker": [max(blocks - reduced, 0), 0],
+        "replicas_short": [sum(c["replicas_short"] for c in client_checks),
+                           0],
+        "mirror_failures": [total("mirror_failures"), 0],
+        "parent_jax_backends": [len(parent_backends)
+                                + total("parent_jax_backends"), 0],
+        "device_not_tpu": [sum(int(w.backend != "tpu"
+                                   or w.device.get("platform") != "tpu")
+                               for w in workers), 0],
+        "no_device_dispatch": [total("no_device_dispatch"), 0],
         "fault_planted": [int(bool(fault)), 0],
     }
     notes = {
@@ -119,7 +143,9 @@ def compare(dn, client_checks: list, ops: list, before: dict, after: dict,
                       "logical_bytes": logical,
                       "seconds": max((c["reference_s"] for c in client_checks),
                                      default=0.0)},
-        "index": idx, "sealed": sealed, "orphan_bytes": orphans,
+        "index": [n["after"]["index"] for n in nodes],
+        "sealed": [n["sealed"] for n in nodes],
+        "orphan_bytes": [n["orphans"] for n in nodes],
         "readback": {"reads": sum(c["readback_reads"] for c in client_checks),
                      "bytes": sum(c["readback_bytes"] for c in client_checks),
                      "seconds": max((c["readback_s"] for c in client_checks),
@@ -127,7 +153,11 @@ def compare(dn, client_checks: list, ops: list, before: dict, after: dict,
                      "errors": [e for c in client_checks
                                 for e in c["errors"]][:5]},
         "made_in_window": sum(c["made_in_window"] for c in client_checks),
+        "per_datanode": per_dn,
     }
+    if len(nodes) == 1:     # the notes as the one-DataNode layout gave them
+        for k in ("index", "sealed", "orphan_bytes"):
+            notes[k] = notes[k][0]
     return checks, notes
 
 

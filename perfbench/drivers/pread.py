@@ -6,7 +6,8 @@ client so that each can check what it reads), file ``j`` by client
 at uniform random offsets over all files through
 ``HdrfClient.read(path, offset, length)``.  Every read is compared with the
 generated bytes as soon as its clock has stopped: a read that differs is a
-failed operation.
+failed operation.  After the window its set-up files' blocks are held to the
+configuration's replication (``replicas.short_blocks``).
 """
 
 from __future__ import annotations
@@ -17,6 +18,7 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 import reference.chunking as ref
+import replicas
 
 
 def _path(j: int) -> str:
@@ -63,6 +65,9 @@ def run(ctx, client, until: float) -> list:
 
 
 def check(ctx, client) -> dict:
+    short, _ = replicas.short_blocks(
+        client, [_path(j) for j in ctx.state["written"]],
+        int(ctx.config["cluster"]["replication"]))
     t0 = time.time()
     table, chunks = ref.chunk_tables(
         (ctx.state["files"][j] for j in ctx.state["written"]),
@@ -73,4 +78,5 @@ def check(ctx, client) -> dict:
                                  for j in ctx.state["written"]),
             "reference_s": time.time() - t0, "readback_s": 0.0,
             "readback_reads": 0, "readback_bytes": 0, "readback_bad": 0,
-            "errors": [], "made_in_window": 0}
+            "readback_bad_by_dn": {},
+            "replicas_short": short, "errors": [], "made_in_window": 0}

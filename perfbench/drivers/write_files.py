@@ -11,7 +11,9 @@ Traffic parameters (``perfbench/traffic/<mix>.json`` → ``params``):
 - ``readback_full`` / ``readback_ranges`` / ``readback_range_bytes``: after
   the window each client reads back that many whole files, drawn from the
   seed among those it wrote, and of each other file that many ranges of
-  that many bytes at odd offsets.
+  that many bytes at odd offsets — each range from every replica the
+  NameNode names, one location at a time (``replicas.py``), after counting
+  the blocks with fewer locations than the configuration's replication.
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 import reference.chunking as ref
+import replicas
 
 
 def _n_setup(ctx) -> int:
@@ -93,10 +96,13 @@ def _reference(ctx, out: dict) -> None:
 def check(ctx, client) -> dict:
     files, written = ctx.state["files"], ctx.state["written"]
     # the reference computes (numpy and hashlib drop the interpreter lock)
-    # while the read-back waits on the DataNode
+    # while the read-back waits on the DataNodes
     refd: dict = {}
     worker = threading.Thread(target=_reference, args=(ctx, refd))
     worker.start()
+    short, locs = replicas.short_blocks(
+        client, [_path(ctx, k) for k in written],
+        int(ctx.config["cluster"]["replication"]))
     rng = np.random.default_rng([ctx.seed, ctx.idx, 5_000_000])
     first = int(ctx.params.get("setup_files", 0))
     window = [k for k in written if k >= first] or list(written)
@@ -104,6 +110,7 @@ def check(ctx, client) -> dict:
     full = set(rng.choice(window, size=n_full, replace=False).tolist()) \
         if n_full else set()
     bad = compared = reads = 0
+    bad_by_dn: dict = {}
     errors = []
     t0 = time.time()
     for k in written:
@@ -118,16 +125,23 @@ def check(ctx, client) -> dict:
                 off = int(rng.integers(0, max(len(data) - ln, 1))) | 1
                 spans.append((off, min(ln, len(data) - off)))
         for off, ln in spans:
-            rec = ctx.op("read", _path(ctx, k), ln, lambda: client.read(
-                _path(ctx, k), offset=off, length=ln))
-            reads += 1
-            compared += ln
-            if not rec["ok"]:
+            # every replica the NameNode names, one location at a time
+            got = replicas.read_each(client, locs[_path(ctx, k)], off, ln)
+            if not got:
                 bad += 1
-                errors.append(rec["err"])
-            elif rec["out"] != data[off:off + ln]:
+                errors.append(f"{_path(ctx, k)}: no location")
+            for dn_id, out in got.items():
+                reads += 1
+                compared += ln
+                if isinstance(out, Exception):
+                    err = f"{type(out).__name__}: {out}"[:300]
+                elif out != data[off:off + ln]:
+                    err = f"{_path(ctx, k)} [{off}, +{ln}) differs"
+                else:
+                    continue
                 bad += 1
-                errors.append(f"{_path(ctx, k)} [{off}, +{ln}) differs")
+                bad_by_dn[dn_id] = bad_by_dn.get(dn_id, 0) + 1
+                errors.append(f"{dn_id}: {err}")
     t_read = time.time() - t0
     worker.join()
     return {"table": refd["table"], "chunks": refd["chunks"],
@@ -135,5 +149,6 @@ def check(ctx, client) -> dict:
             "logical_bytes": sum(len(files[k]) for k in written),
             "reference_s": refd["seconds"], "readback_s": t_read,
             "readback_reads": reads, "readback_bytes": compared,
-            "readback_bad": bad, "errors": errors[:5],
+            "readback_bad": bad, "readback_bad_by_dn": bad_by_dn,
+            "replicas_short": short, "errors": errors[:5],
             "made_in_window": ctx.state["made_in_window"]}

@@ -13,13 +13,18 @@ comparison held.
   refuses every reduce, so the DataNode reduces blocks on the host.
 - ``client-half-write`` (half of the batch left out): the client library
   sends the first half of every file and acknowledges the whole.
+- ``dn-truncate-sealed`` / ``dn-drop-index-entry`` (a replica altered where
+  it is kept): after the window, on the last DataNode alone, one sealed
+  container loses the second half of its file, or one chunk entry leaves
+  the index.  Only that DataNode's comparisons may fail.
 """
 
 from __future__ import annotations
 
 WORKER_FAULTS = ("digest-flip", "digest-16bit", "host-fallback")
 CLIENT_FAULTS = ("client-half-write",)
-ALL = WORKER_FAULTS + CLIENT_FAULTS
+DATANODE_FAULTS = ("dn-truncate-sealed", "dn-drop-index-entry")
+ALL = WORKER_FAULTS + CLIENT_FAULTS + DATANODE_FAULTS
 
 
 def plant_in_worker(worker, fault: str) -> None:
@@ -61,3 +66,22 @@ def plant_in_client(fault: str) -> None:
         return real_write(self, path, data[:len(data) // 2], **kw)
 
     HdrfClient.write = write
+
+
+def plant_in_datanode(dn, fault: str) -> None:
+    """Called by the harness on the last DataNode after ``flush_open()``."""
+    if fault == "dn-drop-index-entry":
+        with dn.index._lock:
+            digest = min(dn.index._chunks)
+            del dn.index._chunks[digest]
+    elif fault == "dn-truncate-sealed":
+        import os
+
+        cid = min(c for c in dn.containers.container_ids()
+                  if dn.containers.sealed_file_bytes(c) is not None)
+        for top, _, names in os.walk(dn.config.data_dir):
+            if f"{cid}.sealed" in names:
+                path = os.path.join(top, f"{cid}.sealed")
+                os.truncate(path, os.path.getsize(path) // 2)
+                return
+        raise FileNotFoundError(f"no file for sealed container {cid}")

@@ -5,7 +5,9 @@ the metric is then left out of the line, never printed as 0.
 
 Sources (``run.py`` fills them): ``window_s``; ``clients`` (per client
 ``cpu_s``, ``ops``); ``phases`` (the DataNode's phase clock over the window:
-``phases`` exclusive seconds by name, ``classes``); ``window`` (worker
+``phases`` exclusive seconds by name, ``classes``, ``inclusive`` — by span
+name what the spans themselves paid — and, for N DataNodes, ``datanodes``:
+``cluster.merge_phases`` says how N partitions make one); ``window`` (worker
 ``stats``, ``lz4`` counters and ``compile_s`` as deltas over the window);
 ``trace`` (the reduced profiler trace with ``stats`` and ``lz4`` deltas over
 the traced seconds; ``None`` without a chip); ``peaks`` (this chip's row of

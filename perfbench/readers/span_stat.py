@@ -8,7 +8,9 @@ share of the window, one phase an instant; this reads a unit's own bill.
 ``spans``: the names summed; ``field``: ``wall_s`` (default), ``cpu_s`` or
 ``count``; ``per``: ``"window"`` (a share of the window's seconds) or a span
 name (divided by that span's count: per container, per block, per tick);
-``scale``.  ``None`` — the metric is left out of the line — where the program
+``scale``.  Where the table sums N DataNodes' spans (``"datanodes"`` in the
+partition, ``cluster.merge_phases``), a share of the window is of N
+windows: the mean DataNode's.  ``None`` — the metric is left out of the line — where the program
 has no such table, none of the named spans ended in the window, they carry
 no such field, or the divisor counts nothing.
 """
@@ -24,8 +26,8 @@ def read(src: dict, params: dict):
     if not rows or not all(field in row for row in rows):
         return None
     per = params["per"]
-    den = src["window_s"] if per == "window" else table.get(
-        per, {}).get("count", 0)
+    den = (src["window_s"] * prof.get("datanodes", 1) if per == "window"
+           else table.get(per, {}).get("count", 0))
     if not den:
         return None
     return params.get("scale", 1.0) * sum(row[field] for row in rows) / den

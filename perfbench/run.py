@@ -36,6 +36,7 @@ sys.path.insert(0, ROOT)
 
 import checks  # noqa: E402 — perfbench's own modules, found through HERE;
 import cluster  # noqa: E402   none of them imports the program or JAX
+import faults  # noqa: E402
 import layers_read  # noqa: E402
 import loadgen  # noqa: E402
 import manifest  # noqa: E402
@@ -124,36 +125,85 @@ def delta(after: dict, before: dict) -> dict:
 
 
 class Tracer(threading.Thread):
-    """Starts ``jax.profiler`` in the worker a third of the way into the
+    """Starts ``jax.profiler`` in every worker a third of the way into the
     window and stops it ``length`` seconds later (a third of the window, 15 s
     at the most: four writers in step hand the device its blocks in one
     burst per round of some 9 s, and the trace has to hold one); worker and
     DataNode counters are read at both ends, so bytes and device time cover
-    the same seconds.  The trace is reduced after the window."""
+    the same seconds.  The traces are reduced after the window."""
 
-    def __init__(self, worker, dn, t_release: float, seconds: float,
-                 trace_dir: str):
+    def __init__(self, workers, nodes, t_release: float, seconds: float,
+                 trace_dirs: list):
         super().__init__(name="perfbench-tracer", daemon=True)
-        self.worker, self.dn = worker, dn
+        self.workers, self.nodes = workers, nodes
         self.at = t_release + 0.35 * seconds
         self.length = min(15.0, seconds / 3.0)
-        self.dir = trace_dir
+        self.dirs = trace_dirs
         self.result: dict | None = None
         self.error: str | None = None
 
     def run(self) -> None:
         try:
             time.sleep(max(self.at - time.time(), 0.0))
-            s0 = cluster.snapshot(self.dn)
-            t0 = self.worker.ask(cmd="trace_start", dir=self.dir)["t_start"]
+            s0 = cluster.each(self.nodes, lambda n: n.ask(cmd="snapshot"))
+            t0 = [w.ask(cmd="trace_start", dir=d)["t_start"]
+                  for w, d in zip(self.workers, self.dirs)]
             time.sleep(self.length)
-            t1 = self.worker.ask(cmd="trace_stop")["t_stop"]
-            s1 = cluster.snapshot(self.dn)
-            self.result = {"t0": t0, "t1": t1, "window_s": t1 - t0,
-                           "stats": delta(s1["stats"], s0["stats"]),
-                           "lz4": delta(s1["lz4"], s0["lz4"])}
+            t1 = [w.ask(cmd="trace_stop")["t_stop"] for w in self.workers]
+            s1 = cluster.each(self.nodes, lambda n: n.ask(cmd="snapshot"))
+            self.result = {"t0": t0[0], "t1": t1[0],
+                           "window_s": t1[0] - t0[0],
+                           "windows_s": [b - a for a, b in zip(t0, t1)],
+                           "stats": summed(s1, s0, "stats"),
+                           "lz4": summed(s1, s0, "lz4")}
         except Exception as e:  # noqa: BLE001 — reported with the result
             self.error = f"{type(e).__name__}: {e}"
+
+
+def summed(after: list, before: list, key: str) -> dict:
+    """Window deltas of one group of counters, summed over the DataNodes
+    (each DataNode's worker is its own)."""
+    out: dict = {}
+    for a, b in zip(after, before):
+        for k, v in delta(a["snapshot"][key], b["snapshot"][key]).items():
+            out[k] = out.get(k, 0) + v
+    return out
+
+
+def merge_traces(traces: list, windows: list) -> dict:
+    """One reduced trace from every worker's, over the same seconds: busy
+    seconds such that ``1 - busy / window`` is the mean of the chips' idle
+    shares, programs' seconds and counts summed (a roofline is the summed
+    bytes over the summed device time), operations and gaps summed by name
+    (the ten largest kept), the longest gap the longest of any chip."""
+    if len(traces) == 1:
+        return traces[0]
+    window = sum(windows) / len(windows)
+    programs: dict = {}
+    for t in traces:
+        for name, p in t["programs"].items():
+            acc = programs.setdefault(name, {"seconds": 0.0, "count": 0})
+            acc["seconds"] += p["seconds"]
+            acc["count"] += p["count"]
+
+    def top(key: str) -> list:
+        acc: dict = {}
+        for t in traces:
+            for name, secs in t[key]:
+                acc[name] = acc.get(name, 0.0) + secs
+        return [[k, v] for k, v in sorted(acc.items(),
+                                          key=lambda kv: -kv[1])[:10]]
+
+    return {"chips": sum(t["chips"] for t in traces),
+            "busy_s": window * sum(t["busy_s"] / w for t, w in
+                                   zip(traces, windows)) / len(traces),
+            "busy_s_by_chip": [t["busy_s"] for t in traces],
+            "device_events": sum(t["device_events"] for t in traces),
+            "programs": programs, "device_ops": top("device_ops"),
+            "idle_gaps": top("idle_gaps"),
+            "longest_gap_s": max(t["longest_gap_s"] for t in traces),
+            "trace_bytes": sum(t["trace_bytes"] for t in traces),
+            "window_s": window}
 
 
 def main(argv=None) -> int:
@@ -165,6 +215,7 @@ def main(argv=None) -> int:
         config["cluster"][key] = int(val)
     if cell["workload"]["chips"] != config["cluster"]["chips"]:
         raise SystemExit("the cell's chips differ from its configuration's")
+    n_dn = cluster.layout(config)
     params = merged_params(config, traffic)
     build_native()
     os.environ["PYTHONPATH"] = ROOT + os.pathsep + os.environ.get(
@@ -176,56 +227,76 @@ def main(argv=None) -> int:
               "driver": traffic["driver"],
               "generator": config["data"]["generator"],
               "fault": args.fault} for i in range(n_clients)]
-    clients = worker = mc = None
-    trace_dir = None
+    clients = mc = None
+    workers, nodes, trace_dirs = [], [], []
     times = {"t_start": T_START}
     try:
         clients = loadgen.Clients(specs)           # they make their data now
-        worker = cluster.Worker(args.worker_backend, args.fault)
+        workers = cluster.start_workers(args.worker_backend, args.fault, n_dn)
+        worker = workers[0]
         times["worker_up_s"] = time.time() - T_START
         if worker.backend == "tpu":
             peaks = manifest.peaks(worker.device["kind"])
-            if worker.device["count"] < cell["workload"]["chips"]:
+            chips = cluster.device_count(workers)
+            if chips < cell["workload"]["chips"]:
                 raise SystemExit(f"the cell asks for "
                                  f"{cell['workload']['chips']} chips, JAX "
-                                 f"reports {worker.device['count']}")
+                                 f"reports {chips}")
         else:
-            peaks = None
-        mc = cluster.start_cluster(config, worker)
-        dn = mc.datanodes[0]
+            peaks, chips = None, worker.device["count"]
+        mc, nodes = cluster.start_cluster(config, workers)
         times["cluster_up_s"] = time.time() - T_START
-        before = cluster.snapshot(dn)
+        before = cluster.each(nodes, lambda n: n.ask(cmd="snapshot"))
         ready = clients.wait_ready()
         times["clients_ready_s"] = time.time() - T_START
         clients.call("connect", list(mc.nn_addrs()[0]))
         setup_ops = [op for ops in clients.call("setup") for op in ops]
-        dn.containers.drain_seals()
+        warmed = [] if n_dn == 1 else cluster.each(nodes, lambda n: n.ask(
+            cmd="warm_reduce", nbytes=config["cluster"]["block_size"],
+            seed=args.seed)["warmed"])
+        cluster.each(nodes, lambda n: n.ask(cmd="drain_seals"))
         times["setup_ops_s"] = time.time() - T_START
-        warm = cluster.snapshot(dn)
-        note(phase="setup", times=times, worker=worker.device,
-             backend=worker.backend, prepare_s=[r["prepare_s"] for r in ready],
-             setup_ops=len(setup_ops), compile_s=warm["compile_s"],
-             cache_dir=warm["cache_dir"], lz4=warm["lz4"])
+        warm = cluster.each(nodes, lambda n: n.ask(cmd="snapshot"))
+        note(phase="setup", times=times,
+             worker=[w.device for w in workers] if n_dn > 1
+             else worker.device, backend=worker.backend,
+             prepare_s=[r["prepare_s"] for r in ready],
+             setup_ops=len(setup_ops), warmed=warmed,
+             compile_s=[w["snapshot"]["compile_s"] for w in warm]
+             if n_dn > 1 else warm[0]["snapshot"]["compile_s"],
+             cache_dir=warm[0]["snapshot"]["cache_dir"],
+             lz4=[w["snapshot"]["lz4"] for w in warm] if n_dn > 1
+             else warm[0]["snapshot"]["lz4"])
 
         # ---------------------------------------------------- the window
         sampler = tracer = None
+        remote = [n for n in nodes if n.remote]
         t_release = time.time() + 0.25
         if args.trace:
-            trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-")
             sampler = cluster.PhaseSampler(t_release)
             sampler.start()
+            cluster.each(remote, lambda n: n.ask(cmd="phases_start",
+                                                 t0=t_release))
             if worker.backend == "tpu":
-                tracer = Tracer(worker, dn, t_release, args.seconds,
-                                trace_dir)
+                trace_dirs = [tempfile.mkdtemp(prefix="perfbench-trace-")
+                              for _ in workers]
+                tracer = Tracer(workers, nodes, t_release, args.seconds,
+                                trace_dirs)
                 tracer.start()
         setup_s = t_release - T_START
         runs = clients.call("run", (t_release, args.seconds),
                             timeout=args.seconds + 600)
-        dn.containers.drain_seals()
+        cluster.each(nodes, lambda n: n.ask(cmd="drain_seals"))
         t_end = time.time()
         window_s = t_end - t_release
-        after = cluster.snapshot(dn)
+        after = cluster.each(nodes, lambda n: n.ask(cmd="snapshot"))
         phases = sampler.profile(t_end) if sampler else None
+        if sampler and remote:
+            parts = cluster.each(remote, lambda n: n.ask(
+                cmd="phases", t1=t_end)["phases"])
+            phases = cluster.merge_phases(parts, phases)
+            note(phase="phases", exclusive_s={
+                n.dn_id: p["phases"] for n, p in zip(remote, parts)})
         if tracer is not None:
             tracer.join()
         ops = [op for r in runs for op in r["ops"]]
@@ -237,25 +308,45 @@ def main(argv=None) -> int:
              client_cpu_s=[r["cpu_s"] for r in runs])
 
         # ------------------------------------ after it: readings, then checks
-        memory = worker.ask(cmd="memory")["memory"]
+        memory = [w.ask(cmd="memory")["memory"] for w in workers]
+        note(phase="device", chips=[w.device.get("chip") for w in workers],
+             memory_peak_bytes=[m.get("peak_bytes") for m in memory])
         trace = None
         if tracer is not None and tracer.result is not None:
             trace = dict(tracer.result)
-            trace.update(worker.ask(cmd="trace_reduce",
-                                    window_s=trace["window_s"])["trace"])
+            trace.update(merge_traces(
+                [w.ask(cmd="trace_reduce", window_s=win)["trace"]
+                 for w, win in zip(workers, trace["windows_s"])],
+                trace["windows_s"]))
             note(phase="trace", **{k: trace[k] for k in (
                 "window_s", "busy_s", "device_events", "programs",
                 "longest_gap_s", "trace_bytes", "stats", "lz4")})
-        dn.containers.flush_open()                  # the open tail seals too
-        stored = {"physical_bytes": dn.containers.physical_bytes(),
-                  "unique_chunk_bytes": dn.index.stats()["unique_chunk_bytes"]}
-        final = cluster.snapshot(dn)
+        cluster.each(nodes, lambda n: n.ask(cmd="flush_open"))  # open tails
+        if args.fault in faults.DATANODE_FAULTS:
+            nodes[-1].ask(cmd="plant", fault=args.fault)
+        held = cluster.each(nodes, lambda n: n.ask(cmd="stored"))
+        stored = {"physical_bytes": sum(h["physical_bytes"] for h in held),
+                  "unique_chunk_bytes": sum(h["index"]["unique_chunk_bytes"]
+                                            for h in held)}
+        final = cluster.each(nodes, lambda n: n.ask(cmd="snapshot"))
         client_checks = clients.call("check")
+        table: dict = {}
+        for c in client_checks:
+            for d, ln in c["table"].items():
+                table.setdefault(d, ln)
+        per_node = cluster.each(nodes, lambda n: dict(
+            n.ask(cmd="decode_sealed"), missing=n.missing(table)))
+        for i, node in enumerate(nodes):
+            per_node[i].update(
+                dn_id=node.dn_id, before=before[i]["snapshot"],
+                after=final[i]["snapshot"], backend=workers[i].backend,
+                physical_bytes=held[i]["physical_bytes"])
+            if not node.remote:     # its process is the harness's
+                per_node[i]["backends"] = None
         chk, notes = checks.compare(
-            dn, client_checks, setup_ops + ops, before, final,
-            [r["counters"] for r in runs], worker, args.fault,
-            cluster.parent_backends(), config["cluster"]["block_size"],
-            stored["physical_bytes"])
+            per_node, client_checks, setup_ops + ops,
+            [r["counters"] for r in runs], workers, args.fault,
+            cluster.parent_backends(), config["cluster"]["block_size"])
         if args.trace and tracer is not None and tracer.error:
             chk["trace_failed"] = [1, 0]
             notes["trace_error"] = tracer.error
@@ -263,18 +354,21 @@ def main(argv=None) -> int:
     finally:
         if clients is not None:
             clients.stop()
+        for node in nodes:
+            node.stop()
         if mc is not None:
             mc.stop()
-        if worker is not None:
-            worker.stop()
-        if trace_dir is not None:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+        for w in workers:
+            w.stop()
+        for d in trace_dirs:
+            shutil.rmtree(d, ignore_errors=True)
 
     # ------------------------------------------------------------ the result
-    window_delta = {"stats": delta(after["stats"], warm["stats"]),
-                    "lz4": delta(after["lz4"], warm["lz4"]),
-                    "compile_s": sum(after["compile_s"].values())
-                    - sum(warm["compile_s"].values())}
+    window_delta = {"stats": summed(after, warm, "stats"),
+                    "lz4": summed(after, warm, "lz4"),
+                    "compile_s": sum(sum(a["snapshot"]["compile_s"].values())
+                                     - sum(w["snapshot"]["compile_s"].values())
+                                     for a, w in zip(after, warm))}
     if args.trace:
         sources = {"window_s": window_s, "clients": runs, "phases": phases,
                    "window": window_delta, "trace": trace, "peaks": peaks,
@@ -287,8 +381,11 @@ def main(argv=None) -> int:
                    for m in cell["end_to_end"] if m["name"] in have}
     device = {"platform": worker.device.get("platform"),
               "kind": worker.device.get("kind"),
-              "count": worker.device.get("count"),
-              "memory_peak_bytes": memory.get("peak_bytes", 0)}
+              "count": chips,
+              "memory_peak_bytes": max(m.get("peak_bytes", 0)
+                                       for m in memory)}
+    if n_dn > 1:
+        device["chips_used"] = len(workers)
     result = {"correct": checks.verdict(chk),
               "attempted": len(ops),
               "failed": sum(1 for op in ops if not op["ok"]),

@@ -17,6 +17,9 @@ EXPECT = {
     "host-fallback": {"worker_fallbacks", "degraded_writes"},
     # half of the batch left out
     "client-half-write": {"logical_bytes_gap", "readback_bad"},
+    # a replica altered where it is kept, after the window
+    "dn-truncate-sealed": {"sealed_decode_failures", "stored_bytes_gap"},
+    "dn-drop-index-entry": {"digests_missing"},
 }
 
 

@@ -8,7 +8,8 @@ control channel the program does not have yet: JSON lines on stdin, one JSON
 reply per line on stdout.  The harness, which never initialises JAX, uses it
 to read the device's memory peak, to start and stop ``jax.profiler`` around a
 few steady seconds of the window, and to have the trace reduced here (only
-this process can hold JAX) by ``perfbench/trace_reduce.py``.
+this process can hold JAX) by ``perfbench/trace_reduce.py``.  Its hello names
+the chip it holds (``device["chip"]``).
 
 Commands: ``{"cmd": "memory"}``, ``{"cmd": "trace_start", "dir": ...}``,
 ``{"cmd": "trace_stop"}``, ``{"cmd": "trace_reduce"}`` (after the window:
@@ -60,7 +61,8 @@ def main(argv=None) -> int:
         faults.plant_in_worker(w, args.fault)
     w.start()
     _reply({"listening": list(w.addr), "backend": w.backend,
-            "device": w.device, "pid": os.getpid()})
+            "device": dict(w.device, chip=_chip(w.backend)),
+            "pid": os.getpid()})
 
     trace_dir = None
     for line in sys.stdin:
@@ -106,6 +108,18 @@ def main(argv=None) -> int:
     # 22); nothing here needs an orderly interpreter shutdown.
     sys.stdout.flush()
     os._exit(0)
+
+
+def _chip(backend: str) -> dict | None:
+    """The chip this worker holds, as JAX and libtpu's environment name it:
+    the harness refuses two workers on one chip."""
+    if backend != "tpu":
+        return None
+    import jax
+
+    d = jax.local_devices()[0]
+    return {"id": d.id, "coords": list(getattr(d, "coords", None) or []),
+            "visible": os.environ.get("TPU_VISIBLE_CHIPS")}
 
 
 def _memory(backend: str) -> dict:
