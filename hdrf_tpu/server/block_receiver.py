@@ -26,11 +26,13 @@ end-to-end regardless of the stored form.
 
 Every ingest path opens a utils/profiler.py BlockTimeline and attributes its
 wall time to named phases (``recv``/``checksum``/``container_io``/
-``mirror_stream``/``ack`` here; ``dedup_lookup``/``wal_commit`` land from
-reduction/dedup.py and index/chunk_index.py; ``device_wait`` from the device
-ledger) — the decomposition the gap-attribution report and perfbench's
-``dn.*_pct`` metrics read.  Everything of one block — recv, acks, CRCs,
-reduction hand-off, commit — runs on its connection's thread.
+``mirror_stream``/``ack`` here, and on the reduced mirror leg
+``mirror_read``/``mirror_wait``/``mirror_recv``; ``dedup_lookup``/
+``wal_commit`` land from reduction/dedup.py and index/chunk_index.py;
+``device_wait`` from the device ledger) — the decomposition the
+gap-attribution report and perfbench's ``dn.*_pct`` metrics read.
+Everything of one block — recv, acks, CRCs, reduction hand-off, commit —
+runs on its connection's thread.
 """
 
 from __future__ import annotations
@@ -537,36 +539,54 @@ class BlockReceiver:
         Returns the dn_id of a FAILED deeper relay hop when the local leg
         succeeded anyway (propagated up through the per-hop status frame),
         None when the whole chain landed; raises :class:`MirrorLegFailed`
-        carrying the broken hop's dn_id otherwise."""
+        carrying the broken hop's dn_id otherwise.
+
+        On the phase clock: ``mirror_read`` (the needed chunks out of this
+        DataNode's index and store), ``mirror_stream`` (frames and packets
+        written), ``mirror_wait`` (the chain below answering), all under
+        one covering ``mirror_push``."""
+        with profiler.cpu_phase("mirror_push"):
+            return self._push_reduced_inner(block_id, gen_stamp, scheme_name,
+                                            logical_len, stored, crcs,
+                                            targets, throttler)
+
+    def _push_reduced_inner(self, block_id, gen_stamp, scheme_name,
+                            logical_len, stored, crcs, targets,
+                            throttler) -> str | None:
         dn = self._dn
         scheme = dn.scheme(scheme_name)
         push_t0 = time.perf_counter()
         mirror = _connect(targets[0]["addr"], dn, block_id)
         try:
+            # dedup family: hashes + need-list negotiation + chunk delta;
+            # direct/compress family: the stored bytes as they are, after a
+            # need frame that is always empty
+            hashes = None
+            if getattr(scheme, "container_codec", None) is not None:
+                entry = dn.index.get_block(block_id)
+                if entry is None:
+                    raise IOError(
+                        f"block {block_id} missing from chunk index")
+                hashes = entry.hashes
             with profiler.phase("mirror_stream"):
-                if getattr(scheme, "container_codec", None) is not None:
-                    # dedup family: hashes + need-list negotiation + chunk
-                    # delta
-                    entry = dn.index.get_block(block_id)
-                    if entry is None:
-                        raise IOError(
-                            f"block {block_id} missing from chunk index")
-                    dt.send_op(mirror, "write_reduced", block_id=block_id,
-                               gen_stamp=gen_stamp, scheme=scheme_name,
-                               logical_len=logical_len, checksums=crcs,
-                               checksum_chunk=dn.checksum_chunk,
-                               token=dn.tokens.mint(block_id, "w"),
-                               hashes=entry.hashes, targets=targets[1:])
-                    # indices into unique hash list
-                    need = recv_frame(mirror)["need"]
-                    uniq = list(dict.fromkeys(entry.hashes))
+                dt.send_op(mirror, "write_reduced", block_id=block_id,
+                           gen_stamp=gen_stamp, scheme=scheme_name,
+                           logical_len=logical_len, checksums=crcs,
+                           checksum_chunk=dn.checksum_chunk,
+                           token=dn.tokens.mint(block_id, "w"),
+                           hashes=hashes, targets=targets[1:])
+            with profiler.phase("mirror_wait"):
+                # indices into unique hash list
+                need = recv_frame(mirror)["need"]
+            if hashes is not None:
+                with profiler.phase("mirror_read"):
+                    uniq = list(dict.fromkeys(hashes))
                     needed_hashes = [uniq[i] for i in need]
-                    with profiler.phase("dedup_lookup"):
-                        locs = dn.index.lookup_chunks(needed_hashes)
+                    locs = dn.index.lookup_chunks(needed_hashes)
                     chunk_locs = [(locs[h].container_id, locs[h].offset,
                                    locs[h].length) for h in needed_hashes]
-                    with profiler.phase("container_io"):
-                        chunks = dn.containers.read_chunks(chunk_locs)
+                    chunks = dn.containers.read_chunks(chunk_locs)
+                with profiler.phase("mirror_stream"):
                     seqno = 0
                     sent_bytes = 0
                     for chunk in chunks:
@@ -582,24 +602,15 @@ class BlockReceiver:
                         sent_bytes += len(chunk)
                         seqno += 1
                     dt.write_packet(mirror, seqno, b"", last=True)
-                    hop = recv_frame(mirror)  # per-hop status frame
-                    _, status = dt.read_ack(mirror)
-                else:
-                    # direct/compress family: ship the stored bytes as-is
-                    dt.send_op(mirror, "write_reduced", block_id=block_id,
-                               gen_stamp=gen_stamp, scheme=scheme_name,
-                               logical_len=logical_len, checksums=crcs,
-                               checksum_chunk=dn.checksum_chunk,
-                               token=dn.tokens.mint(block_id, "w"),
-                               hashes=None, targets=targets[1:])
-                    # symmetric need-frame (always empty here)
-                    recv_frame(mirror)
+            else:
+                with profiler.phase("mirror_stream"):
                     dt.stream_bytes(mirror, stored, dn.config.packet_size,
                                     throttle=throttler.throttle
                                     if throttler is not None else None)
-                    sent_bytes = len(stored)
-                    hop = recv_frame(mirror)  # per-hop status frame
-                    _, status = dt.read_ack(mirror)
+                sent_bytes = len(stored)
+            with profiler.phase("mirror_wait"):
+                hop = recv_frame(mirror)  # per-hop status frame
+                _, status = dt.read_ack(mirror)
             failed_dn = hop.get("failed_dn") if isinstance(hop, dict) else None
             if status != dt.ACK_SUCCESS:
                 raise MirrorLegFailed(
@@ -616,13 +627,16 @@ class BlockReceiver:
 
     def ingest_reduced(self, sock: socket.socket, fields: dict) -> None:
         """Mirror side of push_reduced: store the reduced form WITHOUT
-        re-running reduction (the whole point of reduced block mirroring)."""
+        re-running reduction (the whole point of reduced block mirroring).
+        One covering ``mirror_ingest`` a relayed block; the delta stream's
+        packets are ``mirror_recv`` spans, not the client stream's ``recv``."""
         dn = self._dn
         block_id, gen_stamp = fields["block_id"], fields["gen_stamp"]
         scheme_name, logical_len = fields["scheme"], fields["logical_len"]
         crcs, cchunk = fields["checksums"], fields["checksum_chunk"]
         hashes, targets = fields["hashes"], fields.get("targets", [])
-        with profiler.block_timeline(block_id, nbytes=logical_len):
+        with profiler.cpu_phase("mirror_ingest"), \
+                profiler.block_timeline(block_id, nbytes=logical_len):
             self._ingest_reduced_inner(sock, dn, block_id, gen_stamp,
                                        scheme_name, logical_len, crcs, cchunk,
                                        hashes, targets)
@@ -658,7 +672,7 @@ class BlockReceiver:
                                   block_id=block_id, dn_id=dn.dn_id)
             send_frame(sock, {"need": need})
             chunks = [data for _, data, last in profiler.timed_iter(
-                "recv", dt.iter_packets(sock)) if data]
+                "mirror_recv", dt.iter_packets(sock)) if data]
             if len(chunks) != len(need):
                 raise IOError(f"expected {len(need)} chunks, got {len(chunks)}")
             with profiler.phase("container_io"):
@@ -668,7 +682,7 @@ class BlockReceiver:
             dn.index.commit_block(block_id, logical_len, hashes, new_chunks)
         else:
             send_frame(sock, {"need": []})
-            with profiler.phase("recv"):
+            with profiler.phase("mirror_recv"):
                 stored = dt.collect_packets(sock)
         with profiler.phase("container_io"):
             writer = dn.replicas.create_rbw(block_id, gen_stamp)

@@ -40,8 +40,9 @@ for one interpreter lock on any number of cores):
 - A fifth class, ``COVER``, is in the inclusive table alone: covering spans
   of a unit of work — ``seal_queue`` / ``seal`` / ``seal_index`` a
   container, ``seal_drain`` a caller of ``drain_seals()``, ``dn_block`` /
-  ``dn_read`` a finished timeline — which the sweep skips, so they take no
-  instant from ``idle`` or from the phases beneath them.
+  ``dn_read`` a finished timeline, ``mirror_push`` / ``mirror_ingest`` a
+  mirror leg's two ends — which the sweep skips, so they take no instant
+  from ``idle`` or from the phases beneath them.
 - Counter tracks (in-flight blocks, outstanding dispatches, WAL queue depth)
   sample on every change into a bounded ring, rendered as Chrome ``C``
   events by tracing.chrome_trace for the /traces?format=chrome export.
@@ -177,6 +178,17 @@ PHASE_CLASS = {
     # thread's CPU over it.
     "seal_queue": COVER, "seal": COVER, "seal_index": COVER,
     "seal_drain": COVER, "dn_block": COVER, "dn_read": COVER,
+    # The DN -> DN leg of reduced block mirroring (server/block_receiver.py),
+    # never one span a chunk.  Push side: ``mirror_read`` the needed chunks'
+    # index lookup and their read out of this DataNode's own store (the
+    # store's read phases nest inside it), ``mirror_stream`` the op frame
+    # and the delta packets written, ``mirror_wait`` the need frame, the
+    # hop-status frame and the final ack (the chain below waited on).
+    # Relay side: ``mirror_recv`` one span a packet of the delta stream.
+    # Covering, with thread CPU: ``mirror_push`` a push, ``mirror_ingest`` a
+    # relayed block (a middle relay's own push inside it).
+    "mirror_read": HOST, "mirror_wait": TRANSPORT, "mirror_recv": TRANSPORT,
+    "mirror_push": COVER, "mirror_ingest": COVER,
 }
 _COVERING = frozenset(n for n, c in PHASE_CLASS.items() if c == COVER)
 
@@ -189,6 +201,9 @@ PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
                "reduce_compute", "packet_verify", "checksum", "seal_write",
                "stage_h2d", "select", "emit", "seal_ingest",
                "index_lookup", "cache_probe", "read_admit",
+               # a push's read of the chunks it ships: ahead of the store's
+               # read phases that nest inside it
+               "mirror_read",
                "container_load", "container_decode", "chunk_copy",
                "read_serve",
                # RPC phases: lock_wait/locked win attribution inside the
@@ -199,7 +214,8 @@ PHASE_ORDER = ("device_wait", "prep_wait", "sha_wait", "scan_wait",
                # periodic ticks and the NameNode's whole request: they own
                # only host seconds no write-path phase claims
                "heartbeat_stats", "block_scan", "nn_rpc",
-               "worker_send", "recv", "mirror_stream", "ack",
+               "worker_send", "recv", "mirror_recv", "mirror_stream",
+               "mirror_wait", "ack",
                "seal_send", "seal_wait",
                "ec_gather", "decode_wait", "net_send", "frame_read", "reply",
                # the worker's covering span last: it claims only the

@@ -308,7 +308,7 @@ class TestHopCounter:
             "layer": "DN to worker hop", "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
                           "versions-dedup.ingest", "small-files.create",
-                          "teragen-1dn.pread-ingest"]}
+                          "teragen-1dn.pread-ingest", "teragen-3dn.ingest"]}
 
 
 class TestSealWireMetrics:
@@ -362,7 +362,8 @@ class TestSealWireMetrics:
             "name": metric, "source": "program_counter",
             "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
-                          "small-files.create", "teragen-1dn.pread-ingest"],
+                          "small-files.create", "teragen-1dn.pread-ingest",
+                          "teragen-3dn.ingest"],
             **self.ENTRIES[metric]}
 
 
