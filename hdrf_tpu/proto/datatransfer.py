@@ -20,16 +20,20 @@ Wire layout:
   PipelineAck.
 - Stride:    ``[u32 nseg][u8 flags]`` + nseg x ``[u32 len][u32 crc32c]`` + the
   segments back to back.  The upload legs of the DataNode -> reduction-worker
-  ops only (server/reduction_worker.py).  ``reduce``: one frame per device
-  upload stride instead of one per client packet; a segment is a client
-  packet carried with the CRC32C its producer computed for it (the DataNode
-  verified it before the ack and does not compute it again).  ``compress`` /
-  ``compress_batch``: a sealed container's bytes in frames of views of the
-  one buffer that holds them, segments of 1 MiB summed in one native call a
-  frame, landed by the worker in one buffer of the size the request states.
-  Either way the receiver checks every byte against its origin's sum, one
-  native call a frame.  ``FLAG_LAST`` ends the stream; the last frame may
-  hold no segment.
+  ops (server/reduction_worker.py) and the DN -> DN reduced mirror leg
+  (server/block_receiver.py).  ``reduce``: one frame per device upload
+  stride instead of one per client packet; a segment is a client packet
+  carried with the CRC32C its producer computed for it (the DataNode
+  verified it before the ack and does not compute it again).  Bytes the
+  sender already holds (``write_frames``): frames of ``STRIDE`` (4 MiB),
+  segments of ``SEGMENT`` (1 MiB) summed in one native call a frame, landed
+  by the receiver in one buffer of the size the sender stated
+  (``read_frames``) — ``compress`` / ``compress_batch`` carry a sealed
+  container's bytes so, the mirror leg a block's chunk delta (the chunks'
+  lengths in a frame ahead, the chunks back to back, one crossing a frame's
+  end split between two) or a stored block.  Either way the receiver checks
+  every byte against its origin's sum, one native call a frame.
+  ``FLAG_LAST`` ends the stream; the last frame may hold no segment.
 - Raw reply: one msgpack frame (a header that states lengths) and the
   payloads behind it as they are, no msgpack around them
   (``send_with_payloads`` / ``recv_payload``): the worker's answer to a
@@ -38,7 +42,7 @@ Wire layout:
 Readers of the packet stream.  ``read_packet_crc`` and the iterators over
 it take a packet at a time: two ``recv``s, one CRC32C call and two copies
 each (the direct write path, which needs a barrier at every FLUSH/SYNC
-packet, reads and the mirror legs).  ``iter_packet_runs`` — the DataNode's
+packet, and reads).  ``iter_packet_runs`` — the DataNode's
 reduced-write ingest, ``BlockReceiver.receive_reduced`` — takes a RUN:
 every whole packet that has already arrived, in one ``recv_into``, then
 one native call (``hdrf_unpack_packets``) that parses the headers,
@@ -58,9 +62,10 @@ xceiver loop.
 
 from __future__ import annotations
 
+import contextlib
 import socket
 import struct
-from typing import Any, Iterator, NamedTuple
+from typing import Any, Iterable, Iterator, NamedTuple
 
 import numpy as np
 
@@ -474,6 +479,111 @@ def verify_stride(buf: np.ndarray, lens: np.ndarray,
     if not ok.all():
         raise ValueError(f"stride segment {int(np.argmin(ok))} of "
                          f"{len(lens)}: checksum mismatch")
+
+
+# A stream of bytes the sender already holds (a sealed container on the hop
+# to the worker, a chunk delta or a stored block on the mirror leg): frames
+# of ``STRIDE`` bytes, the reduce op's upload stride, each cut into segments
+# of ``SEGMENT`` with a CRC32C each (the packet wire's granularity of the
+# check), the sums of a frame from one native call.
+STRIDE = 4 << 20
+SEGMENT = 1 << 20
+
+
+def frames_of(buf) -> list[memoryview]:
+    """``buf`` (any contiguous bytes-like) as views of ``STRIDE`` bytes,
+    the last shorter; none for an empty ``buf``."""
+    view = memoryview(buf).cast("B")
+    return [view[o:o + STRIDE] for o in range(0, len(view), STRIDE)]
+
+
+def chunk_frames(chunks: list) -> Iterator[bytes | memoryview]:
+    """``chunks`` (bytes-likes) back to back, cut into frames of ``STRIDE``
+    bytes, the last shorter: one join a frame (a frame inside one chunk is
+    a view of it), a chunk that crosses a frame's end split between the
+    two."""
+    lens = np.fromiter(map(len, chunks), np.int64, len(chunks))
+    ends = np.cumsum(lens)
+    total = int(ends[-1]) if len(ends) else 0
+    for lo in range(0, total, STRIDE):
+        hi = min(lo + STRIDE, total)
+        i = int(np.searchsorted(ends, lo, "right"))   # first past ``lo``
+        j = int(np.searchsorted(ends, hi, "left"))    # holds byte hi - 1
+        a, b = lo - int(ends[i] - lens[i]), hi - int(ends[j] - lens[j])
+        if i == j:
+            yield memoryview(chunks[i])[a:b]
+            continue
+        parts = chunks[i:j + 1]
+        parts[0], parts[-1] = memoryview(parts[0])[a:], \
+            memoryview(parts[-1])[:b]
+        yield b"".join(parts)
+
+
+def write_frame(sock: socket.socket, frame, last: bool = False) -> None:
+    """``frame`` (a contiguous bytes-like of at most ``STRIDE`` bytes) as
+    one stride frame: segments are views of ``SEGMENT`` bytes of it, their
+    CRC32Cs one native call, all in one ``sendmsg``."""
+    view = memoryview(frame).cast("B")
+    write_stride(sock, [view[o:o + SEGMENT]
+                        for o in range(0, len(view), SEGMENT)],
+                 native.crc32c_chunks(view, SEGMENT).tolist(), last)
+
+
+def write_frames(sock: socket.socket, frames: Iterable,
+                 before=None) -> None:
+    """Each of ``frames`` as a stride frame (``write_frame``), then an
+    empty ``FLAG_LAST`` one.  ``before(k, nbytes)`` runs ahead of frame
+    ``k``, the trailer's (``nbytes`` 0) included: a throttle, a fault
+    point."""
+    k = 0
+    for frame in frames:
+        if before is not None:
+            before(k, len(frame))
+        write_frame(sock, frame)
+        k += 1
+    if before is not None:
+        before(k, 0)
+    write_stride(sock, [], [], last=True)
+
+
+def read_frames(sock: socket.socket, size: int, span: str | None = None,
+                read_on: bool = False) -> tuple[np.ndarray, int, int]:
+    """What ``write_frames`` sends, landed in ONE buffer of the ``size``
+    bytes its sender stated: ``(buf, frames, segments)``, the frames and
+    segments that carried bytes.  Each frame's segments are checked
+    against their CRC32Cs (one native call a frame) before the next frame
+    is read; given ``span``, a frame's read and check are one span of that
+    name.  A frame that fails its check raises ValueError (the bytes are
+    wrong, as in ``verify_stride``): at once, or with ``read_on`` after
+    the rest of the stream is read, so that an answer leaves on a
+    connection still in step; so does a stream that ends short of
+    ``size``.  A frame that runs past ``size`` is ``read_stride``'s
+    IOError: the stream cannot go on.  Either way no byte is handed
+    on."""
+    buf = np.empty(size, np.uint8)
+    got = k = frames = segments = 0
+    bad: ValueError | None = None
+    last = False
+    while not last:
+        with profiler.phase(span) if span else contextlib.nullcontext():
+            part, lens, crcs, last = read_stride(sock, buf[got:])
+            if part.size and bad is None:
+                try:
+                    verify_stride(part, lens, crcs)
+                except ValueError as e:
+                    bad = ValueError(f"frame {k}: {e}")
+                    if not read_on:
+                        raise bad from e
+        if part.size:
+            got += part.size
+            frames += 1
+            segments += len(lens)
+        k += 1
+    if bad is not None:
+        raise bad
+    if got != size:
+        raise ValueError(f"stated {size} bytes, streamed {got}")
+    return buf, frames, segments
 
 
 # -------------------------------------------------------------- raw replies

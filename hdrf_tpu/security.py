@@ -148,9 +148,9 @@ def token_secret(token: dict) -> bytes:
 class EncryptedSocket:
     """AEAD record layer over a connected socket.
 
-    Implements the two calls the transport helpers use (``sendall`` and
-    ``recv_into``), so proto/datatransfer.py and proto/rpc.py frame codecs
-    compose unchanged.  Records: ``[u32 ct_len][ciphertext || tag]``; nonce =
+    Implements the calls the transport helpers use (``sendall``,
+    ``sendmsg`` and ``recv_into``), so proto/datatransfer.py and
+    proto/rpc.py frame codecs compose unchanged.  Records: ``[u32 ct_len][ciphertext || tag]``; nonce =
     4-byte direction tag + 8-byte LE counter (never reused per key; replay or
     reordering fails the tag because the counter is the implicit AAD)."""
 
@@ -193,7 +193,16 @@ class EncryptedSocket:
         self._recv_ctr += 1
         self._rbuf += pt
 
-    def recv_into(self, view, n: int) -> int:
+    def sendmsg(self, bufs) -> int:
+        """Scatter-gather (the stride wire's frames): the buffers as ONE
+        record; returns the bytes sent, all of them."""
+        data = b"".join(bufs)
+        self.sendall(data)
+        return len(data)
+
+    def recv_into(self, view, n: int, flags: int = 0) -> int:
+        # ``flags`` (MSG_WAITALL from the stride reader) asks for nothing a
+        # record does not give: the caller loops until its buffer is full
         while not self._rbuf:
             self._read_record()
         take = min(n, len(self._rbuf))

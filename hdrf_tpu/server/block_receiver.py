@@ -17,7 +17,8 @@ Re-expression of BlockReceiver.java:
 - Mirror-side ingest of the reduced form is ``ingest_reduced``: for dedup
   schemes the mirror receives the ordered hash list, answers with the set of
   chunks it lacks (one round trip), and receives exactly those bytes — the
-  "chunk index delta".
+  "chunk index delta" — as their lengths and stride frames of the bytes
+  back to back (proto/datatransfer.py), no call a chunk on either end.
 
 Checksums: crc32c per ``checksum_chunk`` of the LOGICAL bytes are computed on
 ingest and stored in BlockMeta (the reference writes the checksum meta file
@@ -40,6 +41,8 @@ from __future__ import annotations
 import socket
 import time
 from typing import TYPE_CHECKING
+
+import numpy as np
 
 from hdrf_tpu import native
 from hdrf_tpu.config import NameNodeConfig
@@ -373,12 +376,10 @@ class BlockReceiver:
                 # compute here WITHOUT re-trying the dead worker (the
                 # scheme would otherwise reconnect per block while the
                 # admission slot is held)
-                import numpy as _np
-
                 from hdrf_tpu.ops import dispatch as _dispatch
 
                 precomputed = _dispatch.chunk_and_fingerprint(
-                    _np.frombuffer(data, dtype=_np.uint8),
+                    np.frombuffer(data, dtype=np.uint8),
                     dn.reduction_ctx.config.cdc, dn.reduction_ctx.backend)
             # parent: the ambient xceiver span when _xceive opened one
             # (Tracer.span falls back to it), else resume the wire context
@@ -541,10 +542,17 @@ class BlockReceiver:
         None when the whole chain landed; raises :class:`MirrorLegFailed`
         carrying the broken hop's dn_id otherwise.
 
+        The wire after the need frame: the needed chunks' lengths in one
+        frame, then their bytes back to back in stride frames of
+        ``dt.STRIDE`` (``dt.write_frames``; the whole-block branch: the
+        stored bytes, their length in the op) and an empty last frame.  The
+        throttle and the fault point ``block_receiver.mirror_push`` (seqno
+        = the frame's index) come once a frame, the trailer's included.
+
         On the phase clock: ``mirror_read`` (the needed chunks out of this
-        DataNode's index and store), ``mirror_stream`` (frames and packets
-        written), ``mirror_wait`` (the chain below answering), all under
-        one covering ``mirror_push``."""
+        DataNode's index and store), ``mirror_stream`` (the op frame, the
+        lengths and the stride frames written), ``mirror_wait`` (the chain
+        below answering), all under one covering ``mirror_push``."""
         with profiler.cpu_phase("mirror_push"):
             return self._push_reduced_inner(block_id, gen_stamp, scheme_name,
                                             logical_len, stored, crcs,
@@ -574,39 +582,40 @@ class BlockReceiver:
                            logical_len=logical_len, checksums=crcs,
                            checksum_chunk=dn.checksum_chunk,
                            token=dn.tokens.mint(block_id, "w"),
-                           hashes=hashes, targets=targets[1:])
+                           hashes=hashes, stored_len=len(stored),
+                           targets=targets[1:])
             with profiler.phase("mirror_wait"):
                 # indices into unique hash list
                 need = recv_frame(mirror)["need"]
+
+            def before(k: int, nbytes: int) -> None:
+                if throttler is not None and nbytes:
+                    throttler.throttle(nbytes)
+                # the mid-delta crash window: a mirror dying between frames
+                fault_injection.point("block_receiver.mirror_push",
+                                      block_id=block_id, seqno=k,
+                                      dn_id=dn.dn_id,
+                                      peer=targets[0].get("dn_id"))
+
             if hashes is not None:
                 with profiler.phase("mirror_read"):
                     uniq = list(dict.fromkeys(hashes))
                     needed_hashes = [uniq[i] for i in need]
                     locs = dn.index.lookup_chunks(needed_hashes)
-                    chunk_locs = [(locs[h].container_id, locs[h].offset,
-                                   locs[h].length) for h in needed_hashes]
-                    chunks = dn.containers.read_chunks(chunk_locs)
+                    chunks = dn.containers.read_chunks(
+                        [(locs[h].container_id, locs[h].offset,
+                          locs[h].length) for h in needed_hashes])
                 with profiler.phase("mirror_stream"):
-                    seqno = 0
-                    sent_bytes = 0
-                    for chunk in chunks:
-                        if throttler is not None:
-                            throttler.throttle(len(chunk))
-                        # the mid-chunk-delta crash window: a mirror dying
-                        # between packets of the delta stream
-                        fault_injection.point("block_receiver.mirror_push",
-                                              block_id=block_id,
-                                              seqno=seqno, dn_id=dn.dn_id,
-                                              peer=targets[0].get("dn_id"))
-                        dt.write_packet(mirror, seqno, chunk)
-                        sent_bytes += len(chunk)
-                        seqno += 1
-                    dt.write_packet(mirror, seqno, b"", last=True)
+                    # the chunks' lengths in one frame, their bytes back to
+                    # back in stride frames
+                    lens = np.fromiter(map(len, chunks), np.uint32,
+                                       len(chunks))
+                    send_frame(mirror, {"lens": lens.tobytes()})
+                    dt.write_frames(mirror, dt.chunk_frames(chunks), before)
+                sent_bytes = int(lens.sum(dtype=np.int64))
             else:
                 with profiler.phase("mirror_stream"):
-                    dt.stream_bytes(mirror, stored, dn.config.packet_size,
-                                    throttle=throttler.throttle
-                                    if throttler is not None else None)
+                    dt.write_frames(mirror, dt.frames_of(stored), before)
                 sent_bytes = len(stored)
             with profiler.phase("mirror_wait"):
                 hop = recv_frame(mirror)  # per-hop status frame
@@ -628,8 +637,11 @@ class BlockReceiver:
     def ingest_reduced(self, sock: socket.socket, fields: dict) -> None:
         """Mirror side of push_reduced: store the reduced form WITHOUT
         re-running reduction (the whole point of reduced block mirroring).
-        One covering ``mirror_ingest`` a relayed block; the delta stream's
-        packets are ``mirror_recv`` spans, not the client stream's ``recv``."""
+        One covering ``mirror_ingest`` a relayed block; each frame read of
+        the delta stream (the lengths frame, every stride frame, the
+        trailer) is one ``mirror_recv`` span, not the client stream's
+        ``recv``.  The delta lands in one buffer and goes to the store in
+        one ``append_ranges``, in the need list's order."""
         dn = self._dn
         block_id, gen_stamp = fields["block_id"], fields["gen_stamp"]
         scheme_name, logical_len = fields["scheme"], fields["logical_len"]
@@ -639,11 +651,11 @@ class BlockReceiver:
                 profiler.block_timeline(block_id, nbytes=logical_len):
             self._ingest_reduced_inner(sock, dn, block_id, gen_stamp,
                                        scheme_name, logical_len, crcs, cchunk,
-                                       hashes, targets)
+                                       hashes, fields["stored_len"], targets)
         _M.incr("blocks_ingested_reduced")
 
     def _ingest_reduced_inner(self, sock, dn, block_id, gen_stamp, scheme_name,
-                              logical_len, crcs, cchunk, hashes,
+                              logical_len, crcs, cchunk, hashes, stored_len,
                               targets) -> None:
         # ingest-entry crash window (the fault matrix kills the mirror
         # right here, before any frame goes back upstream)
@@ -671,24 +683,38 @@ class BlockReceiver:
             fault_injection.point("block_receiver.need_frame",
                                   block_id=block_id, dn_id=dn.dn_id)
             send_frame(sock, {"need": need})
-            chunks = [data for _, data, last in profiler.timed_iter(
-                "mirror_recv", dt.iter_packets(sock)) if data]
-            if len(chunks) != len(need):
-                raise IOError(f"expected {len(need)} chunks, got {len(chunks)}")
+            # the needed chunks' lengths, then their bytes in stride frames
+            # landed in one buffer, every frame verified before any append
+            with profiler.phase("mirror_recv"):
+                lens = np.frombuffer(recv_frame(sock)["lens"], "<u4")
+            if len(lens) != len(need):
+                raise IOError(f"expected {len(need)} chunks, got {len(lens)}")
+            size = int(lens.sum(dtype=np.int64))
+            if size > logical_len:
+                raise IOError(f"a delta of {size} bytes for a block of "
+                              f"{logical_len}")
+            buf, _, _ = dt.read_frames(sock, size, "mirror_recv")
+            starts = np.cumsum(lens, dtype=np.int64) - lens
             with profiler.phase("container_io"):
-                locs = dn.containers.append_chunks(
-                    chunks, on_seal=dn.index.seal_container)
+                locs = dn.containers.append_ranges(
+                    buf, starts, lens, on_seal=dn.index.seal_container)
+            del buf     # in the store now: not held through the onward push
             new_chunks = {uniq[i]: loc for i, loc in zip(need, locs)}
             dn.index.commit_block(block_id, logical_len, hashes, new_chunks)
         else:
+            # the size the peer states is allocated whole: a stored form is
+            # at most a codec's worst case over the block (LZ4's and zstd's
+            # bounds add under n / 128 and a header)
+            if not 0 <= stored_len <= logical_len + (logical_len >> 6) + 1024:
+                raise IOError(f"a stored form of {stored_len} bytes for a "
+                              f"block of {logical_len}")
             send_frame(sock, {"need": []})
-            with profiler.phase("mirror_recv"):
-                stored = dt.collect_packets(sock)
+            stored, _, _ = dt.read_frames(sock, stored_len, "mirror_recv")
         with profiler.phase("container_io"):
             writer = dn.replicas.create_rbw(block_id, gen_stamp)
         try:
             with profiler.phase("container_io"):
-                if stored:
+                if len(stored):
                     writer.write(stored)
                 meta = writer.finalize(logical_len, scheme_name, list(crcs),
                                        cchunk)

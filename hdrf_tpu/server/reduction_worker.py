@@ -51,13 +51,10 @@ _TR = tracing.tracer("reduction_worker")
 # per-transfer cost, small enough that HBM staging overlaps the tail of
 # the network stream.  Also the frame of the reduce op's upload leg: the
 # DataNode sends one when this many bytes are pending, the worker uploads
-# each as it is.
-_STRIDE = 4 << 20
-# A sealed container crosses the hop in frames of the same length, cut into
-# segments of this many bytes, each with a CRC32C of its own (the packet
-# wire's granularity of the check): the sender sums frame k+1 while the
-# worker reads and verifies frame k.
-_SEAL_SEGMENT = 1 << 20
+# each as it is.  A sealed container crosses the hop in frames of the same
+# length (``dt.write_frames``): the sender sums frame k+1 while the worker
+# reads and verifies frame k.
+_STRIDE = dt.STRIDE
 
 # The stage clock (utils/profiler.py, PR 25): a reduce op is a run of leaf
 # ``profiler.phase`` spans no finer than one stride under one covering span
@@ -398,36 +395,17 @@ class ReductionWorker:
                                              for k in _COMPRESS_STAGES)
 
     def _seal_payload(self, sock: socket.socket, size: int) -> np.ndarray:
-        """The compress ops' upload leg: every stride frame landed in ONE
-        buffer of the ``size`` the request stated — no parts, no join — and
-        each frame's segments checked against their CRC32Cs in one native
-        call, all under the stage ``seal_ingest``.  A frame that fails its
-        check is remembered while the rest of the stream is read, so the
-        error frame leaves on a connection that is still in step."""
-        buf = np.empty(size, np.uint8)
-        got = frames = segments = 0
-        bad: ValueError | None = None
-        last = False
+        """The compress ops' upload leg (``dt.read_frames``): every stride
+        frame landed in ONE buffer of the ``size`` the request stated — no
+        parts, no join — and each frame's segments checked against their
+        CRC32Cs in one native call, all under the stage ``seal_ingest``.  A
+        frame that fails its check raises only once the rest of the stream
+        is read, so the error frame leaves on a connection still in step."""
         with profiler.phase("seal_ingest"):
-            while not last:
-                part, lens, crcs, last = dt.read_stride(sock, buf[got:])
-                if not part.size:
-                    continue
-                if bad is None:
-                    try:
-                        dt.verify_stride(part, lens, crcs)
-                    except ValueError as e:
-                        bad = e
-                got += part.size
-                frames += 1
-                segments += len(lens)
+            buf, frames, segments = dt.read_frames(sock, size, read_on=True)
         with self._stats_lock:
             self._stats["seal_frames"] += frames
             self._stats["seal_segments"] += segments
-        if bad is not None:
-            raise bad
-        if got != size:
-            raise ValueError(f"stated {size} bytes, streamed {got}")
         return buf
 
     def _op_compress(self, sock: socket.socket, req: dict) -> None:
@@ -683,28 +661,17 @@ class WorkerClient:
         a buffer of its own (phase ``seal_wait``).
 
         Upload leg: every frame is up to ``_STRIDE`` bytes of ONE payload,
-        as views of it — segments of ``_SEAL_SEGMENT``, their CRC32Cs from
+        as views of it — segments of ``dt.SEGMENT``, their CRC32Cs from
         one native call, one ``sendmsg``; nothing is sliced into a copy.
-        The last frame carries ``FLAG_LAST``; payloads that hold no byte
-        send one empty frame."""
+        An empty ``FLAG_LAST`` frame ends the stream."""
         dl = self._deadline(sum(len(d) for d in datas))
         s = self._conn(dl)
         try:
             try:
                 with profiler.phase("seal_send"):
                     send_frame(s, self._stamped(req, dl))
-                    frames = [view[o:o + _STRIDE]
-                              for view in (memoryview(d).cast("B")
-                                           for d in datas)
-                              for o in range(0, len(view), _STRIDE)]
-                    for k, frame in enumerate(frames):
-                        crcs = native.crc32c_chunks(frame, _SEAL_SEGMENT)
-                        dt.write_stride(
-                            s, [frame[o:o + _SEAL_SEGMENT] for o in
-                                range(0, len(frame), _SEAL_SEGMENT)],
-                            crcs.tolist(), last=k == len(frames) - 1)
-                    if not frames:
-                        dt.write_stride(s, [], [], last=True)
+                    dt.write_frames(s, [f for d in datas
+                                        for f in dt.frames_of(d)])
                 dl.check(what)
                 s.settimeout(dl.timeout())
                 with profiler.phase("seal_wait"):

@@ -59,6 +59,25 @@ _SEAL_MAGIC = 0x48435452  # "RTCH"
 _RAW_MAGIC = 0x48435257  # "WRCH"
 
 
+def _pread_exact(fd: int, n: int, off: int) -> bytes:
+    """``n`` bytes of ``fd`` at ``off``, fewer only where the file ends.
+    One ``pread`` may return fewer bytes than asked short of the end (the
+    kernel stops a copy it cannot finish), so the read goes on from where
+    it stopped until the range is read or a call returns nothing."""
+    data = os.pread(fd, n, off)
+    if len(data) in (0, n):
+        return data
+    parts = [data]
+    got = len(data)
+    while got < n:
+        part = os.pread(fd, n - got, off + got)
+        if not part:
+            break
+        parts.append(part)
+        got += len(part)
+    return b"".join(parts)
+
+
 class _Decoded:
     """One decoded sealed container of the LRU: ``buf[:size]`` is its
     payload (a reused buffer's rest is another container's).  ``pins``
@@ -726,12 +745,13 @@ class ContainerStore:
                 if hi is None:
                     f.seek(_SEAL_HDR.size + lo)
                     return f.read()
-                data = os.pread(f.fileno(), hi - lo, _SEAL_HDR.size + lo)
+                data = _pread_exact(f.fileno(), hi - lo, _SEAL_HDR.size + lo)
+                if len(data) != hi - lo:
+                    held = os.fstat(f.fileno()).st_size - _SEAL_HDR.size
+                    raise IOError(f"container {cid}: raw file ends inside "
+                                  f"[{lo}, {hi}): it holds {held} bytes")
         except FileNotFoundError:
             return None
-        if len(data) != hi - lo:
-            raise IOError(f"container {cid}: raw file ends inside "
-                          f"[{lo}, {hi})")
         return data
 
     def _sealed_parse(self, cid: int) -> tuple[str, int, memoryview]:

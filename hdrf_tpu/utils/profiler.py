@@ -181,10 +181,11 @@ PHASE_CLASS = {
     # The DN -> DN leg of reduced block mirroring (server/block_receiver.py),
     # never one span a chunk.  Push side: ``mirror_read`` the needed chunks'
     # index lookup and their read out of this DataNode's own store (the
-    # store's read phases nest inside it), ``mirror_stream`` the op frame
-    # and the delta packets written, ``mirror_wait`` the need frame, the
-    # hop-status frame and the final ack (the chain below waited on).
-    # Relay side: ``mirror_recv`` one span a packet of the delta stream.
+    # store's read phases nest inside it), ``mirror_stream`` the op frame,
+    # the lengths frame and the delta's stride frames written,
+    # ``mirror_wait`` the need frame, the hop-status frame and the final
+    # ack (the chain below waited on).  Relay side: ``mirror_recv`` one span
+    # a frame read of the delta stream (lengths, stride frames, trailer).
     # Covering, with thread CPU: ``mirror_push`` a push, ``mirror_ingest`` a
     # relayed block (a middle relay's own push inside it).
     "mirror_read": HOST, "mirror_wait": TRANSPORT, "mirror_recv": TRANSPORT,

@@ -3,8 +3,10 @@
 import socket
 import threading
 
+import numpy as np
 import pytest
 
+from hdrf_tpu import native
 from hdrf_tpu.proto import datatransfer as dt
 from hdrf_tpu.proto.rpc import RpcClient, RpcError, RpcServer
 
@@ -176,3 +178,110 @@ class TestDataTransfer:
         assert dt.read_ack(b) == (42, dt.ACK_SUCCESS)
         assert dt.read_ack(b) == (43, dt.ACK_ERROR)
         a.close(), b.close()
+
+
+# chunk lengths of a delta, from a seeded generator
+_DELTAS = {
+    # unequal chunks of 2-64 KiB over two frames and a tail: some cross a
+    # frame's end
+    "straddling": lambda rng: rng.integers(2 << 10, (64 << 10) + 1,
+                                           300).tolist(),
+    # 64 chunks of 64 KiB fill a frame exactly: none crosses
+    "on-the-boundary": lambda rng: [64 << 10] * 128,
+    "one-chunk": lambda rng: [5000],
+    # every chunk known: no frame with bytes, the trailer alone
+    "empty": lambda rng: [],
+}
+
+
+def _delta(case: str) -> list[bytes]:
+    rng = np.random.default_rng(2**31 + 39)
+    return [rng.integers(0, 256, n, np.uint8).tobytes()
+            for n in _DELTAS[case](rng)]
+
+
+class TestStrideFrames:
+    """The mirror leg's wire for bytes the sender holds: ``write_frames``
+    of ``chunk_frames`` / ``frames_of`` on one end, ``read_frames`` into
+    one buffer on the other."""
+
+    @pytest.mark.parametrize("case", sorted(_DELTAS))
+    def test_a_chunk_delta_round_trips_one_sum_call_a_frame_each_end(
+            self, case, monkeypatch):
+        chunks = _delta(case)
+        total = sum(map(len, chunks))
+        calls = {"crc32c": 0, "crc32c_chunks": 0}
+        for name in calls:
+            real = getattr(native, name)
+
+            def counted(*a, _real=real, _name=name, **kw):
+                calls[_name] += 1
+                return _real(*a, **kw)
+            monkeypatch.setattr(native, name, counted)
+        a, b = socket.socketpair()
+        seen = []
+        t = threading.Thread(target=dt.write_frames, args=(
+            a, dt.chunk_frames(chunks), lambda k, n: seen.append((k, n))))
+        t.start()
+        buf, nframes, nsegs = dt.read_frames(b, total, "mirror_recv")
+        t.join(timeout=10)
+        a.close(), b.close()
+        assert buf.tobytes() == b"".join(chunks)
+        frames = -(-total // dt.STRIDE)
+        assert nframes == frames and nsegs == -(-total // dt.SEGMENT)
+        assert seen == [(k, min(dt.STRIDE, total - k * dt.STRIDE))
+                        for k in range(frames)] + [(frames, 0)]
+        # one native sum a frame on each end, none a chunk
+        assert calls == {"crc32c": 0, "crc32c_chunks": 2 * frames}
+        ends = set(np.cumsum([len(c) for c in chunks]).tolist())
+        crossed = set(range(dt.STRIDE, total, dt.STRIDE)) - ends
+        assert bool(crossed) == (case == "straddling")
+
+    @pytest.mark.parametrize("k", [0, 1, 2])
+    def test_a_flipped_byte_in_frame_k_raises_and_hands_nothing_on(self, k):
+        chunks = _delta("straddling")
+        frames = list(dt.chunk_frames(chunks))
+        assert len(frames) == 3
+        a, b = socket.socketpair()
+
+        def send():
+            try:
+                for j, frame in enumerate(frames):
+                    crcs = native.crc32c_chunks(frame, dt.SEGMENT).tolist()
+                    buf = bytearray(frame)
+                    if j == k:
+                        buf[len(buf) // 2] ^= 0x40
+                    dt.write_stride(a, [buf[o:o + dt.SEGMENT] for o in
+                                        range(0, len(buf), dt.SEGMENT)],
+                                    crcs)
+                dt.write_stride(a, [], [], last=True)
+            except OSError:
+                pass            # the reader hung up, as a relay does
+
+        t = threading.Thread(target=send)
+        t.start()
+        with pytest.raises(ValueError, match=f"frame {k}: .*checksum mismatch"):
+            dt.read_frames(b, sum(map(len, chunks)), "mirror_recv")
+        b.close()
+        t.join(timeout=10)
+        a.close()
+
+    @pytest.mark.parametrize("stated,error", [(+1, ValueError),
+                                              (-1, IOError)])
+    def test_a_stream_that_is_not_the_stated_size_raises(self, stated, error):
+        data = bytes(range(256)) * 20_000           # 5 MB: two frames
+        a, b = socket.socketpair()
+
+        def send():
+            try:
+                dt.write_frames(a, dt.frames_of(data))
+            except OSError:
+                pass            # the reader hung up at the long frame
+
+        t = threading.Thread(target=send)
+        t.start()
+        with pytest.raises(error, match="stated|left of the size"):
+            dt.read_frames(b, len(data) + stated, "mirror_recv")
+        b.close()
+        t.join(timeout=10)
+        a.close()

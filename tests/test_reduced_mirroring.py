@@ -9,8 +9,9 @@ reduced form (hash list, then the chunks the next DataNode lacks) is pushed
 to the second, which relays it to the third.  Push side: ``mirror_read`` (the
 needed chunks out of the pusher's index and store), ``mirror_stream`` (frames
 and packets written), ``mirror_wait`` (the chain below answering), under one
-covering ``mirror_push``.  Relay side: ``mirror_recv`` a packet of the delta
-stream, under one covering ``mirror_ingest`` a relayed block.
+covering ``mirror_push``.  Relay side: ``mirror_recv`` a frame read of the
+delta stream (its lengths, each stride frame of the chunks' bytes, the
+trailer), under one covering ``mirror_ingest`` a relayed block.
 
 Everything here stops by counts (files written), never by seconds.
 """
@@ -18,8 +19,10 @@ Everything here stops by counts (files written), never by seconds.
 import json
 import os
 
+import numpy as np
 import pytest
 
+from hdrf_tpu.proto import datatransfer as dt
 from hdrf_tpu.testing.minicluster import MiniCluster
 from hdrf_tpu.utils import metrics, profiler
 
@@ -160,11 +163,19 @@ class TestMirrorSpans:
         assert len(got) == 2 * FILES        # two hops a block
         assert all(len(sp) == 5 and 0.0 <= sp[4] for sp in got)
 
-    def test_a_relay_leaves_a_packet_span_a_needed_chunk_and_one_more(
-            self, written):
-        # rows never repeat, so each relay lacks every chunk of the block
-        assert len(_named(written["spans"], "mirror_recv")) == \
-            2 * (len(written["table"]) + FILES)
+    def test_a_relay_leaves_a_span_a_frame_it_reads(self, written):
+        """The lengths frame, ceil(delta / ``dt.STRIDE``) stride frames and
+        the trailer a relayed block, never a span a chunk: rows never
+        repeat, so each relay lacks every chunk of the block (1 MiB, one
+        frame)."""
+        spans = written["spans"]
+        recv = _named(spans, "mirror_recv")
+        ingests = _named(spans, "mirror_ingest")
+        assert len(ingests) == 2 * FILES
+        for _, a, b, tid, _ in ingests:
+            assert sum(1 for sp in recv if sp[3] == tid and a <= sp[1]
+                       and sp[2] <= b) == -(-BLOCK // dt.STRIDE) + 2
+        assert len(recv) == 2 * FILES * 3 < len(written["table"])
 
     def test_the_push_records_none_of_the_commits_phases(self, written):
         spans = written["spans"]
@@ -176,6 +187,34 @@ class TestMirrorSpans:
         assert set(PUSH) <= inside
         assert inside <= set(PUSH) | set(STORE_READS)
         assert not inside & {"dedup_lookup", "container_io", "recv"}
+
+    def test_every_push_reads_what_it_sends_out_of_its_own_store(
+            self, written):
+        """The head's push and the middle DataNode's (inside its
+        ``mirror_ingest``) alike take the needed chunks out of their own
+        index and store: the relay reads back containers its append has
+        just rolled over."""
+        spans = written["spans"]
+        ingests = _named(spans, "mirror_ingest")
+
+        def inside(sp, outer):
+            return sp[3] == outer[3] and outer[1] <= sp[1] and sp[2] <= outer[2]
+
+        pushes = _named(spans, "mirror_push")
+        relayed = [p for p in pushes if any(inside(p, i) for i in ingests)]
+        assert len(relayed) == FILES and len(pushes) == 2 * FILES
+        for p in pushes:
+            assert {sp[0] for sp in spans if inside(sp, p)} & set(STORE_READS)
+
+    def test_the_relays_check_of_a_frame_is_on_the_mirror_legs_clock(
+            self, written):
+        """A frame's CRC check runs inside its ``mirror_recv`` span, as the
+        packet's did: nothing on a relay's thread under ``mirror_ingest``
+        is the client stream's ``packet_verify``."""
+        spans = written["spans"]
+        for _, a, b, tid, _ in _named(spans, "mirror_ingest"):
+            assert not [sp for sp in _named(spans, "packet_verify")
+                        if sp[3] == tid and a <= sp[1] and sp[2] <= b]
 
     @pytest.mark.parametrize("name", PUSH)
     def test_each_push_phase_is_a_few_spans_a_push(self, written, name):
@@ -194,6 +233,156 @@ class TestMirrorSpans:
         assert not set(STORE_READS) & set(prof["phases"])
         assert "mirror_recv" in prof["inclusive"]
         assert "mirror_push" not in prof["phases"]
+
+
+def _random(n: int) -> bytes:
+    return np.random.default_rng(SEED).integers(0, 256, n, np.uint8).tobytes()
+
+
+def _block_of(c, path: str) -> dict:
+    return c._nn.call("get_block_locations", path=path)["blocks"][0]
+
+
+def _holders(mc, block_id: int) -> list:
+    return [dn for dn in mc.datanodes
+            if dn.replicas.get_meta(block_id) is not None]
+
+
+class TestTheDeltaWire:
+    """The leg end to end at two DataNodes, no worker: what a relay keeps
+    when a frame is bad, when it needs nothing, and what a throttled push
+    meters.  Blocks of 16 MiB, so a delta is several 4 MiB frames."""
+
+    BIG = 16 << 20
+
+    @pytest.mark.parametrize("k", [0, 2])
+    def test_a_flipped_byte_in_frame_k_leaves_the_relay_nothing(
+            self, k, monkeypatch):
+        """The relay raises at frame ``k``'s check, before any append: no
+        replica, no index entry, no container byte there; the push side
+        attributes the relay; the head's replica serves the file."""
+        data = _random(2 * dt.STRIDE + 300_000)     # three data frames
+        real, frames = dt.write_stride, {}
+
+        def flipping(sock, segs, crcs, last=False):
+            j = frames[sock] = frames.get(sock, -1) + 1
+            if j == k and segs:
+                seg = bytearray(segs[0])
+                seg[len(seg) // 2] ^= 0x40
+                segs = [seg, *segs[1:]]
+            real(sock, segs, crcs, last)
+
+        with MiniCluster(n_datanodes=2, replication=2,
+                         block_size=self.BIG) as mc:
+            monkeypatch.setattr(dt, "write_stride", flipping)
+            with mc.client("flip") as c:
+                c.write("/flip/f", data, scheme="dedup_lz4")
+                bid = _block_of(c, "/flip/f")["block_id"]
+                (head,) = _holders(mc, bid)
+                (relay,) = [dn for dn in mc.datanodes if dn is not head]
+                assert relay.index.get_block(bid) is None
+                assert relay.index.stats()["chunks"] == 0
+                assert relay.containers.physical_bytes() == 0
+                assert {p for dn in mc.datanodes
+                        for p in dn._mirror_fail} == {relay.dn_id}
+                assert c.read("/flip/f") == data
+
+    def test_an_empty_delta_commits_with_nothing_appended(self):
+        """A block whose chunks the relay holds already: the lengths frame
+        is empty and the trailer follows, two frame reads; the relay
+        commits the block and appends no chunk."""
+        data = _random(3 << 20)
+        with MiniCluster(n_datanodes=2, replication=2,
+                         block_size=self.BIG) as mc:
+            with mc.client("empty") as c:
+                c.write("/empty/a", data, scheme="dedup_lz4")
+                appended = metrics.registry("container_store").counter(
+                    "chunks_appended")
+                t0 = profiler.mark()
+                c.write("/empty/b", data, scheme="dedup_lz4")
+                spans = profiler.window_spans(t0, profiler.mark())
+                bid = _block_of(c, "/empty/b")["block_id"]
+                assert metrics.registry("container_store").counter(
+                    "chunks_appended") == appended
+                assert len(_holders(mc, bid)) == 2
+                assert all(dn.index.get_block(bid) is not None
+                           for dn in mc.datanodes)
+                assert len(_named(spans, "mirror_ingest")) == 1
+                assert len(_named(spans, "mirror_recv")) == 2
+                assert c.read("/empty/b") == data
+
+    def test_a_throttled_push_meters_the_delta_once_a_frame(
+            self, monkeypatch):
+        """Re-replication (the balancer's throttle): ``throttle(n)`` once a
+        stride frame, the ``n`` summing to the delta the relay lacked."""
+        data = _random(2 * dt.STRIDE + 12_345)
+        with MiniCluster(n_datanodes=2, replication=1,
+                         block_size=self.BIG) as mc:
+            with mc.client("thr") as c:
+                c.write("/thr/f", data, scheme="dedup_lz4")
+                bid = _block_of(c, "/thr/f")["block_id"]
+                (holder,) = _holders(mc, bid)
+                metered: list[int] = []
+                real = holder.balance_throttler.throttle
+                monkeypatch.setattr(
+                    holder.balance_throttler, "throttle",
+                    lambda n: (metered.append(n), real(n))[1])
+                c.set_replication("/thr/f", 2)
+                mc.wait_for_replication("/thr/f", 2)
+                hashes = holder.index.get_block(bid).hashes
+                delta = sum(loc.length for loc in holder.index.lookup_chunks(
+                    list(dict.fromkeys(hashes))).values())
+                assert metered == [min(dt.STRIDE, delta - o)
+                                   for o in range(0, delta, dt.STRIDE)]
+                (relay,) = [dn for dn in mc.datanodes if dn is not holder]
+                assert relay.index.stats()["unique_chunk_bytes"] == delta
+
+    def test_a_block_without_a_container_codec_crosses_in_frames(self):
+        """The whole-block branch (``lz4``: the stored bytes as they are,
+        their length in the op) lands the same bytes on the relay."""
+        data = _random(dt.STRIDE) + bytes(dt.STRIDE)   # 8 MiB, half zeros
+        with MiniCluster(n_datanodes=2, replication=2,
+                         block_size=self.BIG) as mc:
+            with mc.client("lz4") as c:
+                t0 = profiler.mark()
+                c.write("/lz4/f", data, scheme="lz4")
+                spans = profiler.window_spans(t0, profiler.mark())
+                bid = _block_of(c, "/lz4/f")["block_id"]
+                a, b = (dn.replicas.read_data(bid)
+                        for dn in _holders(mc, bid))
+                assert a == b and 0 < len(a) < len(data)
+                assert len(_named(spans, "mirror_recv")) == \
+                    -(-len(a) // dt.STRIDE) + 1
+                assert c.read("/lz4/f") == data
+
+    @pytest.mark.parametrize("stored_len", [(1 << 20) + (1 << 14) + 1024,
+                                            (1 << 20) + (1 << 14) + 1025,
+                                            1 << 40, -1])
+    def test_a_stored_length_past_any_codecs_bound_is_refused(
+            self, stored_len):
+        """The whole-block branch allocates the length the op states: one
+        past a codec's worst case over the block (1 MiB + 1/64 + 1 KiB) is
+        refused before a frame goes back, and the relay keeps nothing; the
+        bound itself is answered with the empty need frame."""
+        import socket
+
+        from hdrf_tpu.proto.rpc import recv_frame
+
+        with MiniCluster(n_datanodes=1, replication=1,
+                         block_size=self.BIG) as mc:
+            (dn,) = mc.datanodes
+            with socket.create_connection(tuple(dn.addr), timeout=10) as s:
+                dt.send_op(s, "write_reduced", block_id=77, gen_stamp=1,
+                           scheme="lz4", logical_len=1 << 20,
+                           checksums=[], checksum_chunk=dn.checksum_chunk,
+                           token=dn.tokens.mint(77, "w"), hashes=None,
+                           stored_len=stored_len, targets=[])
+                if stored_len == (1 << 20) + (1 << 14) + 1024:
+                    assert recv_frame(s) == {"need": []}
+                else:
+                    with pytest.raises((ConnectionError, OSError)):
+                        recv_frame(s)
+            assert dn.replicas.get_meta(77) is None
 
 
 def test_one_datanode_records_no_mirror_phase(perfbench_file):
@@ -322,7 +511,7 @@ class TestTheMirrorLegsReaders:
                               "cpu_s": 6.0},
             "mirror_read": {"count": 8, "wall_s": 2.0, "wall_max_s": 0.5},
             "mirror_wait": {"count": 24, "wall_s": 20.0, "wall_max_s": 5.0},
-            "mirror_recv": {"count": 131_080, "wall_s": 30.0,
+            "mirror_recv": {"count": 272, "wall_s": 30.0,
                             "wall_max_s": 0.1}}}}
     WANT = {"dn.mirror_pct": 100.0 * 5.0 / 10.0,
             "mirror.push_ms_per_block": 5000.0,
@@ -330,7 +519,7 @@ class TestTheMirrorLegsReaders:
             "mirror.read_ms_per_push": 250.0,
             "mirror.wait_ms_per_push": 2500.0,
             "mirror.ingest_ms_per_block": 6000.0,
-            "mirror.packets_per_block": 16_385.0}
+            "mirror.packets_per_block": 34.0}
 
     def _read(self, perfbench_file, metric: str, src: dict):
         with open(os.path.join(perfbench_file.root, "layers",

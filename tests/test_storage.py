@@ -447,6 +447,128 @@ class TestLaneBuffer:
         cs.close_async_seals()
 
 
+def _delta(seed: int, n: int) -> tuple:
+    """A relayed block's shape: ``n`` chunks of 2-16 KiB back to back in
+    one buffer, ``(buf, starts, lens)``."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(2 << 10, (16 << 10) + 1, n)
+    buf = rng.integers(0, 256, int(lens.sum()), np.uint8)
+    return buf, np.cumsum(lens) - lens, lens
+
+
+class TestReadAfterRollover:
+    """A chunk read back straight after the ``append_ranges`` that rolled
+    its container over, while that container waits in the seal queue: its
+    bytes come out of the ``.raw`` file, every one of them."""
+
+    CONTAINER = 64 << 10
+
+    @staticmethod
+    def _chunks(buf, starts, lens) -> list[bytes]:
+        return [buf[s:s + n].tobytes() for s, n in zip(starts, lens)]
+
+    def test_every_chunk_of_a_rolled_container_reads_from_its_raw_file(
+            self, tmp_path):
+        import threading
+
+        from hdrf_tpu.utils import codec as codecs
+
+        go = threading.Event()
+
+        def stalled(data):
+            go.wait(30)
+            return codecs.compress("lz4", data)
+
+        cs = ContainerStore(str(tmp_path), container_size=self.CONTAINER,
+                            lanes=2, codec="lz4", compress_fn=stalled)
+        cs.enable_async_seals()
+        try:
+            buf, starts, lens = _delta(39, 60)          # some 9 containers
+            locs = cs.append_ranges(buf, starts, lens)
+            cids = sorted({cid for cid, _, _ in locs})
+            assert len(cids) > 3
+            # every container but the lane's open one rolled over and is
+            # still a .raw file: no seal has run
+            assert all((tmp_path / f"{c}.raw").exists() for c in cids)
+            assert not any((tmp_path / f"{c}.sealed").exists() for c in cids)
+            assert cs.read_chunks(locs) == self._chunks(buf, starts, lens)
+            # the whole of each container in one read, as a push asks
+            for c in cids[:-1]:
+                ends = [o + n for cid, o, n in locs if cid == c]
+                assert max(ends) > self.CONTAINER - (16 << 10)
+        finally:
+            go.set()
+            cs.close_async_seals()
+        assert cs.read_chunks(locs) == self._chunks(buf, starts, lens)
+
+    def test_appenders_read_back_what_they_rolled_while_the_seals_lag(
+            self, tmp_path):
+        """Six threads, each appending a delta and reading it back at once,
+        against one seal thread that takes a few ms a container."""
+        import threading
+        import time
+
+        from hdrf_tpu.utils import codec as codecs
+
+        def slow(data):
+            time.sleep(0.003)
+            return codecs.compress("lz4", data)
+
+        cs = ContainerStore(str(tmp_path), container_size=self.CONTAINER,
+                            lanes=4, codec="lz4", compress_fn=slow)
+        cs.enable_async_seals()
+        bad: list = []
+
+        def relay(k: int):
+            for r in range(12):
+                buf, starts, lens = _delta(1000 * k + r, 25)
+                locs = cs.append_ranges(buf, starts, lens)
+                try:
+                    if cs.read_chunks(locs) != self._chunks(buf, starts,
+                                                             lens):
+                        bad.append((k, r, "bytes"))
+                except IOError as e:
+                    bad.append((k, r, str(e)))
+
+        threads = [threading.Thread(target=relay, args=(k,))
+                   for k in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        cs.close_async_seals()
+        assert not any(t.is_alive() for t in threads)
+        assert not bad
+
+    @pytest.mark.parametrize("most", [1, 4096, 65_521])
+    def test_a_read_the_kernel_cuts_short_goes_on(self, tmp_path,
+                                                  monkeypatch, most):
+        """``pread`` may return fewer bytes than asked short of the file's
+        end: the store reads on from there, not calling it the end."""
+        buf, starts, lens = _delta(7, 30)
+        cs = ContainerStore(str(tmp_path), container_size=1 << 20, lanes=1)
+        locs = cs.append_ranges(buf, starts, lens)
+        cs._lanes[0].container_id = -1     # read it as a rolled container
+        real = os.pread
+        monkeypatch.setattr(os, "pread",
+                            lambda fd, n, off: real(fd, min(n, most), off))
+        assert cs.read_chunks(locs) == self._chunks(buf, starts, lens)
+
+    def test_a_raw_file_that_is_short_says_how_short(self, tmp_path):
+        buf, starts, lens = _delta(8, 10)
+        cs = ContainerStore(str(tmp_path), container_size=1 << 20, lanes=1)
+        locs = cs.append_ranges(buf, starts, lens)
+        cs._lanes[0].container_id = -1
+        (cid,) = {c for c, _, _ in locs}
+        total = int(lens.sum())
+        os.truncate(tmp_path / f"{cid}.raw", _SEAL_HDR.size + total - 5)
+        with pytest.raises(IOError, match=rf"ends inside \[0, {total}\): "
+                                          rf"it holds {total - 5} bytes"):
+            cs.read_chunks(locs)
+
+
 @pytest.fixture
 def sealed_three(tmp_path):
     """Four 700-byte appends to one 1 000-byte lane with async seals: three
