@@ -31,7 +31,13 @@ wall time to named phases (``recv``/``checksum``/``container_io``/
 ``mirror_read``/``mirror_wait``/``mirror_recv``; ``dedup_lookup``/
 ``wal_commit`` land from reduction/dedup.py and index/chunk_index.py;
 ``device_wait`` from the device ledger) — the decomposition the
-gap-attribution report and perfbench's ``dn.*_pct`` metrics read.
+gap-attribution report and perfbench's ``dn.*_pct`` metrics read.  A relay's
+covering spans (inclusive table only, with thread CPU) are ``mirror_ingest``
+a relayed block and, inside it, ``mirror_need`` (offered hashes to need
+frame sent) and ``mirror_commit`` (the delta's append, the index commit, the
+replica's ``create_rbw`` + ``finalize``); registry ``block_receiver``
+counts what the leg offered, needed and shipped (``mirror_chunks_offered``,
+``mirror_chunks_needed``, ``mirror_delta_bytes``).
 Everything of one block — recv, acks, CRCs, reduction hand-off, commit —
 runs on its connection's thread.
 """
@@ -641,7 +647,17 @@ class BlockReceiver:
         the delta stream (the lengths frame, every stride frame, the
         trailer) is one ``mirror_recv`` span, not the client stream's
         ``recv``.  The delta lands in one buffer and goes to the store in
-        one ``append_ranges``, in the need list's order."""
+        one ``append_ranges``, in the need list's order.
+
+        Inside ``mirror_ingest`` two more covering spans with thread CPU:
+        ``mirror_need`` from the offered hash list (decoded, made unique,
+        probed under ``dedup_lookup``) to the need frame sent, and
+        ``mirror_commit`` around the delta's ``append_ranges``, the
+        ``commit_block`` and the replica's ``create_rbw`` + ``finalize``; a
+        middle relay's onward push is outside both.  Counters (registry
+        ``block_receiver``) a relayed dedup block: ``mirror_chunks_offered``
+        its unique hashes, ``mirror_chunks_needed`` those this DataNode
+        lacked, ``mirror_delta_bytes`` their bytes."""
         dn = self._dn
         block_id, gen_stamp = fields["block_id"], fields["gen_stamp"]
         scheme_name, logical_len = fields["scheme"], fields["logical_len"]
@@ -673,16 +689,19 @@ class BlockReceiver:
                           f"{existing.gen_stamp} for block {block_id}")
         stored = b""
         if hashes is not None:
-            hashes = [bytes(h) for h in hashes]
-            uniq = list(dict.fromkeys(hashes))
-            with profiler.phase("dedup_lookup"):
-                known = dn.index.lookup_chunks(uniq)
-            need = [i for i, h in enumerate(uniq) if known[h] is None]
-            # torn need-frame window: the mirror dying mid-negotiation
-            # (upstream sees a half-written frame / reset socket)
-            fault_injection.point("block_receiver.need_frame",
-                                  block_id=block_id, dn_id=dn.dn_id)
-            send_frame(sock, {"need": need})
+            with profiler.cpu_phase("mirror_need"):
+                hashes = [bytes(h) for h in hashes]
+                uniq = list(dict.fromkeys(hashes))
+                with profiler.phase("dedup_lookup"):
+                    known = dn.index.lookup_chunks(uniq)
+                need = [i for i, h in enumerate(uniq) if known[h] is None]
+                # torn need-frame window: the mirror dying mid-negotiation
+                # (upstream sees a half-written frame / reset socket)
+                fault_injection.point("block_receiver.need_frame",
+                                      block_id=block_id, dn_id=dn.dn_id)
+                send_frame(sock, {"need": need})
+            _M.incr("mirror_chunks_offered", len(uniq))
+            _M.incr("mirror_chunks_needed", len(need))
             # the needed chunks' lengths, then their bytes in stride frames
             # landed in one buffer, every frame verified before any append
             with profiler.phase("mirror_recv"):
@@ -694,13 +713,19 @@ class BlockReceiver:
                 raise IOError(f"a delta of {size} bytes for a block of "
                               f"{logical_len}")
             buf, _, _ = dt.read_frames(sock, size, "mirror_recv")
+            _M.incr("mirror_delta_bytes", size)
             starts = np.cumsum(lens, dtype=np.int64) - lens
-            with profiler.phase("container_io"):
-                locs = dn.containers.append_ranges(
-                    buf, starts, lens, on_seal=dn.index.seal_container)
-            del buf     # in the store now: not held through the onward push
-            new_chunks = {uniq[i]: loc for i, loc in zip(need, locs)}
-            dn.index.commit_block(block_id, logical_len, hashes, new_chunks)
+            with profiler.cpu_phase("mirror_commit"):
+                with profiler.phase("container_io"):
+                    locs = dn.containers.append_ranges(
+                        buf, starts, lens, on_seal=dn.index.seal_container)
+                del buf     # in the store now: not held through the push
+                new_chunks = {uniq[i]: loc for i, loc in zip(need, locs)}
+                dn.index.commit_block(block_id, logical_len, hashes,
+                                      new_chunks)
+                meta = self._commit_replica(dn, block_id, gen_stamp, stored,
+                                            logical_len, scheme_name, crcs,
+                                            cchunk)
         else:
             # the size the peer states is allocated whole: a stored form is
             # at most a codec's worst case over the block (LZ4's and zstd's
@@ -708,28 +733,13 @@ class BlockReceiver:
             if not 0 <= stored_len <= logical_len + (logical_len >> 6) + 1024:
                 raise IOError(f"a stored form of {stored_len} bytes for a "
                               f"block of {logical_len}")
-            send_frame(sock, {"need": []})
+            with profiler.cpu_phase("mirror_need"):
+                send_frame(sock, {"need": []})
             stored, _, _ = dt.read_frames(sock, stored_len, "mirror_recv")
-        with profiler.phase("container_io"):
-            writer = dn.replicas.create_rbw(block_id, gen_stamp)
-        try:
-            with profiler.phase("container_io"):
-                if len(stored):
-                    writer.write(stored)
-                meta = writer.finalize(logical_len, scheme_name, list(crcs),
-                                       cchunk)
-        except (OSError, ValueError) as e:
-            # same contract as _store_and_mirror: typed cleanup + traced
-            # log + re-raise (no silent broad catch)
-            _LOG.error("reduced ingest failed", dn_id=dn.dn_id,
-                       block_id=block_id,
-                       trace=tracing.current_context(),
-                       error=f"{type(e).__name__}: {e}")
-            if dn._crashed:
-                writer.detach()   # crash sim: dead processes delete nothing
-            else:
-                writer.abort()
-            raise
+            with profiler.cpu_phase("mirror_commit"):
+                meta = self._commit_replica(dn, block_id, gen_stamp, stored,
+                                            logical_len, scheme_name, crcs,
+                                            cchunk)
         with profiler.phase("ack"):
             dn.notify_block_received(block_id, meta.logical_len,
                                      meta.gen_stamp)
@@ -755,3 +765,29 @@ class BlockReceiver:
             # broken peer, not targets[0]
             send_frame(sock, {"status": int(status), "failed_dn": failed_dn})
             dt.send_ack(sock, 0, status)
+
+    @staticmethod
+    def _commit_replica(dn, block_id, gen_stamp, stored, logical_len,
+                        scheme_name, crcs, cchunk):
+        """A relayed block's replica: ``create_rbw`` + ``finalize`` (with the
+        stored bytes of a whole-block scheme written between them)."""
+        with profiler.phase("container_io"):
+            writer = dn.replicas.create_rbw(block_id, gen_stamp)
+        try:
+            with profiler.phase("container_io"):
+                if len(stored):
+                    writer.write(stored)
+                return writer.finalize(logical_len, scheme_name, list(crcs),
+                                       cchunk)
+        except (OSError, ValueError) as e:
+            # same contract as _store_and_mirror: typed cleanup + traced
+            # log + re-raise (no silent broad catch)
+            _LOG.error("reduced ingest failed", dn_id=dn.dn_id,
+                       block_id=block_id,
+                       trace=tracing.current_context(),
+                       error=f"{type(e).__name__}: {e}")
+            if dn._crashed:
+                writer.detach()   # crash sim: dead processes delete nothing
+            else:
+                writer.abort()
+            raise

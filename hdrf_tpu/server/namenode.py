@@ -332,6 +332,12 @@ class NameNode:
         self._pending_moves: dict[int, dict] = {}
         self._pending_ibr: dict[int, list] = {}    # standby: IBRs ahead of tail
         self._alloc_charge: dict[int, tuple[str, int]] = {}  # bid -> (path, bytes)
+        # write pipelines' first DataNodes (_lead_least_busy): block under
+        # construction -> the DataNode leading it; dn_id -> the allocation
+        # number of the last block it was given to lead
+        self._leading: dict[int, str] = {}
+        self._led_at: dict[str, int] = {}
+        self._leads = 0
         self._events: list[dict] = []   # inotify ring (active only)
         self._events_cap = 10_000
         self._decommissioning: set[str] = set()
@@ -1585,6 +1591,7 @@ class NameNode:
                                            slots=slots)
             if not targets:
                 raise IOError("no datanodes available")
+            self._lead_least_busy(bid, targets, slots)
             self._log(["add_block", path, bid, gs])
             self._blocks[bid].expected = [d.dn_id for d in targets]
             self._charge_alloc(path, bid, self.config.block_size)
@@ -3605,6 +3612,33 @@ class NameNode:
         if slots is not None:
             slots.extend(slot_of.get(d.dn_id, d.storage_type) for d in out)
         return out
+
+    def _lead_least_busy(self, bid: int, targets: list,
+                         slots: list) -> None:
+        """Order a new block's pipeline, in place (``slots`` kept aligned):
+        first the target that leads the fewest blocks under construction,
+        of equals the one that led least recently.  The first DataNode
+        takes the client's stream and reduces the block on its device, the
+        others ingest the reduced form, so the lead is where a write's work
+        lands; HDFS's considerLoad keeps busy nodes out of a placement, but
+        at replication N over N DataNodes every node is in every pipeline
+        and only the order is left to choose.  A block stops leading once a
+        replica of it is reported or its length is known."""
+        busy: dict[str, int] = {}
+        for b, dn_id in list(self._leading.items()):
+            info = self._blocks.get(b)
+            if info is None or info.locations or info.length >= 0:
+                del self._leading[b]
+            else:
+                busy[dn_id] = busy.get(dn_id, 0) + 1
+        i = min(range(len(targets)),
+                key=lambda k: (busy.get(targets[k].dn_id, 0),
+                               self._led_at.get(targets[k].dn_id, -1)))
+        targets.insert(0, targets.pop(i))
+        slots.insert(0, slots.pop(i))
+        self._leads += 1
+        self._leading[bid] = targets[0].dn_id
+        self._led_at[targets[0].dn_id] = self._leads
 
     # -------------------------------------------------------------------- HA
 

@@ -41,8 +41,9 @@ for one interpreter lock on any number of cores):
   of a unit of work — ``seal_queue`` / ``seal`` / ``seal_index`` a
   container, ``seal_drain`` a caller of ``drain_seals()``, ``dn_block`` /
   ``dn_read`` a finished timeline, ``mirror_push`` / ``mirror_ingest`` a
-  mirror leg's two ends — which the sweep skips, so they take no instant
-  from ``idle`` or from the phases beneath them.
+  mirror leg's two ends, ``mirror_need`` / ``mirror_commit`` a relay's need
+  step and commit — which the sweep skips, so they take no instant from
+  ``idle`` or from the phases beneath them.
 - Counter tracks (in-flight blocks, outstanding dispatches, WAL queue depth)
   sample on every change into a bounded ring, rendered as Chrome ``C``
   events by tracing.chrome_trace for the /traces?format=chrome export.
@@ -187,9 +188,14 @@ PHASE_CLASS = {
     # ack (the chain below waited on).  Relay side: ``mirror_recv`` one span
     # a frame read of the delta stream (lengths, stride frames, trailer).
     # Covering, with thread CPU: ``mirror_push`` a push, ``mirror_ingest`` a
-    # relayed block (a middle relay's own push inside it).
+    # relayed block (a middle relay's own push inside it), and inside that
+    # ``mirror_need`` (offered hashes to need frame sent: the probe's
+    # ``dedup_lookup`` nests in it) and ``mirror_commit`` (the delta's
+    # append, the index commit, the replica's create_rbw + finalize: their
+    # ``container_io`` / ``wal_commit`` nest in it).
     "mirror_read": HOST, "mirror_wait": TRANSPORT, "mirror_recv": TRANSPORT,
     "mirror_push": COVER, "mirror_ingest": COVER,
+    "mirror_need": COVER, "mirror_commit": COVER,
 }
 _COVERING = frozenset(n for n, c in PHASE_CLASS.items() if c == COVER)
 

@@ -205,6 +205,55 @@ class TestBlockManagement:
             nn.rpc_add_block("/f", client="c1")
 
 
+class TestPipelineLead:
+    """A write pipeline's first DataNode reduces the block: the NameNode
+    gives the lead to the target leading the fewest blocks under
+    construction, of equals to the one that led least recently."""
+
+    @pytest.mark.parametrize("in_flight", [True, False],
+                             ids=["writers-abreast", "one-after-another"])
+    def test_four_writers_over_three_datanodes_lead_on_each(self, nn,
+                                                             in_flight):
+        register(nn, 3)
+        leads = []
+        for k in range(7):
+            nn.rpc_create(f"/f{k}", client=f"c{k}", replication=3)
+            a = nn.rpc_add_block(f"/f{k}", client=f"c{k}")
+            assert sorted(t["dn_id"] for t in a["targets"]) == [
+                "dn-0", "dn-1", "dn-2"]
+            leads.append(a["targets"][0]["dn_id"])
+            if not in_flight:
+                complete(nn, f"/f{k}", {a["block_id"]: 9}, client=f"c{k}")
+            elif k >= 3:     # four abreast: the oldest block of four ends
+                old = k - 3
+                bid = nn._file(f"/f{old}").blocks[-1]
+                complete(nn, f"/f{old}", {bid: 9}, client=f"c{old}")
+        assert set(leads[:3]) == {"dn-0", "dn-1", "dn-2"}
+        # either way the lead goes round: abreast, the fourth joins the
+        # earliest lead and each later block goes where the one that just
+        # ended was led; one after another, the least recent lead is next
+        assert leads[3:] == leads[:4]
+
+    def test_a_reported_block_stops_leading_and_slots_stay_aligned(
+            self, nn):
+        nn.rpc_register_datanode("dn-0", ["h0", 1000], storage_type="SSD")
+        nn.rpc_register_datanode("dn-1", ["h1", 1001], storage_type="DISK")
+        nn.rpc_set_storage_policy("/", "one_ssd")
+        allocs = {}
+        for p in ("/a", "/b", "/c"):
+            nn.rpc_create(p, client=p, replication=2)
+            allocs[p] = nn.rpc_add_block(p, client=p)
+            if p == "/b":    # /a still leads on dn-0; /b's replica is in
+                nn.rpc_block_received("dn-1", allocs[p]["block_id"], 9)
+        leads = [allocs[p]["targets"][0]["dn_id"] for p in allocs]
+        # /b: dn-0 leads /a, so dn-1; /c: /b reported, so dn-1 leads none
+        # (were /b still counted, dn-0 would lead: it led longer ago)
+        assert leads == ["dn-0", "dn-1", "dn-1"]
+        for a in allocs.values():   # each target keeps its own slot
+            assert [t["storage_type"] for t in a["targets"]] == [
+                nn._datanodes[t["dn_id"]].storage_type for t in a["targets"]]
+
+
 class TestWalIntegrity:
     def test_rejected_op_does_not_poison_wal(self, nn, tmp_path):
         """mkdir over an existing file must fail *without* leaving a WAL

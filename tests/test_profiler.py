@@ -227,7 +227,8 @@ class TestCoveringSpans:
         assert p["attributed_frac"] == 0.0
 
     @pytest.mark.parametrize("name", ["seal_queue", "seal", "seal_index",
-                                      "seal_drain", "dn_block", "dn_read"])
+                                      "seal_drain", "dn_block", "dn_read",
+                                      "mirror_need", "mirror_commit"])
     def test_every_name_of_the_class(self, name):
         assert profiler.phase_class(name) == profiler.COVER
         assert name not in profiler.PHASE_ORDER

@@ -37,6 +37,8 @@ NEW_METRICS = ("dn.mirror_pct", "mirror.push_ms_per_block",
                "mirror.push_cpu_ms_per_block", "mirror.read_ms_per_push",
                "mirror.wait_ms_per_push", "mirror.ingest_ms_per_block",
                "mirror.packets_per_block")
+# the relay's need step and commit, covers of their own since the leg
+RELAY_METRICS = ("mirror.need_ms_per_block", "mirror.commit_ms_per_block")
 CELL = "teragen-3dn.ingest"
 
 
@@ -157,7 +159,8 @@ def _named(spans, name):
 
 
 class TestMirrorSpans:
-    @pytest.mark.parametrize("name", ["mirror_push", "mirror_ingest"])
+    @pytest.mark.parametrize("name", ["mirror_push", "mirror_ingest",
+                                      "mirror_need", "mirror_commit"])
     def test_one_covering_span_a_leg_end_with_its_cpu(self, written, name):
         got = _named(written["spans"], name)
         assert len(got) == 2 * FILES        # two hops a block
@@ -232,7 +235,9 @@ class TestMirrorSpans:
         # their instants: none of them owns any of the window
         assert not set(STORE_READS) & set(prof["phases"])
         assert "mirror_recv" in prof["inclusive"]
-        assert "mirror_push" not in prof["phases"]
+        for cover in ("mirror_push", "mirror_need", "mirror_commit"):
+            assert cover not in prof["phases"]
+            assert cover in prof["inclusive"]
 
 
 def _random(n: int) -> bytes:
@@ -403,7 +408,8 @@ class TestPhaseTables:
         ("mirror_read", profiler.HOST), ("mirror_stream", profiler.TRANSPORT),
         ("mirror_wait", profiler.TRANSPORT),
         ("mirror_recv", profiler.TRANSPORT), ("mirror_push", profiler.COVER),
-        ("mirror_ingest", profiler.COVER)])
+        ("mirror_ingest", profiler.COVER), ("mirror_need", profiler.COVER),
+        ("mirror_commit", profiler.COVER)])
     def test_every_name_has_a_class_and_a_rank(self, name, cls):
         assert profiler.phase_class(name) == cls
         assert (name in profiler.PHASE_ORDER) == (cls != profiler.COVER)
@@ -484,13 +490,16 @@ class TestTheCellsConfiguration:
             if m["name"] in ("write_mb_s", "stored_pct"):
                 assert CELL in m["workloads"]
         for m in bench["per_layer"]:
-            if m["name"] in NEW_METRICS:
-                assert m["workloads"] == [CELL]
-                assert (m["layer"], m["moves"], m["source"]) == \
-                    ("DN mirror leg", "write_mb_s", "program_span")
+            if m["layer"] == "DN mirror leg":
+                # the leg's metrics read the two r=3 cells alone
+                assert m["workloads"] == [CELL, "versions-3dn.ingest"]
+                assert (m["moves"], m["source"]) == \
+                    ("write_mb_s", "program_span")
             else:
                 assert (CELL in m["workloads"]) == \
                     ("teragen-1dn.ingest" in m["workloads"]), m["name"]
+        assert set(NEW_METRICS) <= {m["name"] for m in bench["per_layer"]
+                                    if m["layer"] == "DN mirror leg"}
 
 
 class TestTheMirrorLegsReaders:
@@ -512,14 +521,20 @@ class TestTheMirrorLegsReaders:
             "mirror_read": {"count": 8, "wall_s": 2.0, "wall_max_s": 0.5},
             "mirror_wait": {"count": 24, "wall_s": 20.0, "wall_max_s": 5.0},
             "mirror_recv": {"count": 272, "wall_s": 30.0,
-                            "wall_max_s": 0.1}}}}
+                            "wall_max_s": 0.1},
+            "mirror_need": {"count": 8, "wall_s": 0.8, "wall_max_s": 0.2,
+                            "cpu_s": 0.5},
+            "mirror_commit": {"count": 8, "wall_s": 2.4, "wall_max_s": 0.4,
+                              "cpu_s": 1.0}}}}
     WANT = {"dn.mirror_pct": 100.0 * 5.0 / 10.0,
             "mirror.push_ms_per_block": 5000.0,
             "mirror.push_cpu_ms_per_block": 500.0,
             "mirror.read_ms_per_push": 250.0,
             "mirror.wait_ms_per_push": 2500.0,
             "mirror.ingest_ms_per_block": 6000.0,
-            "mirror.packets_per_block": 34.0}
+            "mirror.packets_per_block": 34.0,
+            "mirror.need_ms_per_block": 100.0,
+            "mirror.commit_ms_per_block": 300.0}
 
     def _read(self, perfbench_file, metric: str, src: dict):
         with open(os.path.join(perfbench_file.root, "layers",
@@ -529,16 +544,28 @@ class TestTheMirrorLegsReaders:
         return perfbench_file(f"readers/{layer['reader']}.py").read(
             src, layer["params"])
 
-    @pytest.mark.parametrize("metric", NEW_METRICS)
+    @pytest.mark.parametrize("metric", NEW_METRICS + RELAY_METRICS)
     def test_it_is_the_layer_files_arithmetic(self, perfbench_file, metric):
         assert self._read(perfbench_file, metric, self.SRC) == \
             pytest.approx(self.WANT[metric])
 
-    @pytest.mark.parametrize("metric", NEW_METRICS[1:])
+    @pytest.mark.parametrize("metric", NEW_METRICS[1:] + RELAY_METRICS)
     def test_a_clock_without_the_leg_is_nothing_to_read(self, perfbench_file,
                                                         metric):
         """A one-DataNode cell, or a program older than these spans."""
         prof = dict(self.SRC["phases"], inclusive={
             "recv": {"count": 9, "wall_s": 3.0, "wall_max_s": 1.0}})
+        assert self._read(perfbench_file, metric,
+                          dict(self.SRC, phases=prof)) is None
+
+    @pytest.mark.parametrize("metric", RELAY_METRICS)
+    def test_a_leg_without_the_relays_covers_is_nothing_to_read(
+            self, perfbench_file, metric):
+        """A program whose relays leave the leg's spans but not
+        ``mirror_need`` / ``mirror_commit``: the metric is left out of the
+        line, never 0."""
+        inclusive = {k: v for k, v in self.SRC["phases"]["inclusive"].items()
+                     if k not in ("mirror_need", "mirror_commit")}
+        prof = dict(self.SRC["phases"], inclusive=inclusive)
         assert self._read(perfbench_file, metric,
                           dict(self.SRC, phases=prof)) is None

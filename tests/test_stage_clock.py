@@ -308,7 +308,8 @@ class TestHopCounter:
             "layer": "DN to worker hop", "moves": "write_mb_s",
             "workloads": ["teragen-1dn.ingest", "teragen-1dn.ingest-1w",
                           "versions-dedup.ingest", "small-files.create",
-                          "teragen-1dn.pread-ingest", "teragen-3dn.ingest"]}
+                          "teragen-1dn.pread-ingest", "teragen-3dn.ingest",
+                          "versions-3dn.ingest"]}
 
 
 class TestSealWireMetrics:
