@@ -37,7 +37,11 @@ a relayed block and, inside it, ``mirror_need`` (offered hashes to need
 frame sent) and ``mirror_commit`` (the delta's append, the index commit, the
 replica's ``create_rbw`` + ``finalize``); registry ``block_receiver``
 counts what the leg offered, needed and shipped (``mirror_chunks_offered``,
-``mirror_chunks_needed``, ``mirror_delta_bytes``).
+``mirror_chunks_needed``, ``mirror_delta_bytes``).  A push sends what its
+DataNode already holds: the head the block it just received, a middle relay
+the delta it just landed (``HeldChunks``); only a needed chunk held in
+neither is read back out of its store (``mirror_chunks_held`` /
+``mirror_chunks_reread``, push side).
 Everything of one block — recv, acks, CRCs, reduction hand-off, commit —
 runs on its connection's thread.
 """
@@ -46,7 +50,7 @@ from __future__ import annotations
 
 import socket
 import time
-from typing import TYPE_CHECKING
+from typing import TYPE_CHECKING, NamedTuple
 
 import numpy as np
 
@@ -67,6 +71,40 @@ _LOG = log.get_logger("block_receiver")
 
 def _checksums(data: bytes, chunk: int) -> list[int]:
     return [int(c) for c in native.crc32c_chunks(data, chunk)]
+
+
+class HeldChunks(NamedTuple):
+    """Chunk bytes a pushing DataNode already holds, by unique chunk: the
+    ``i``-th of the block's hashes made unique in order (what a need list
+    counts) is ``buf[starts[i]:starts[i] + lens[i]]``, or not held where
+    ``starts[i]`` is -1."""
+
+    buf: object          # any contiguous bytes-like
+    starts: np.ndarray   # int64, one a unique chunk
+    lens: np.ndarray     # int64, one a unique chunk
+
+    @classmethod
+    def of_block(cls, data, ends, digests) -> "HeldChunks":
+        """The head's: every unique chunk at its first occurrence in the
+        block ``data`` it received, ``ends`` where each chunk ends in it
+        and ``digests`` their fingerprints, a row of 32 bytes each."""
+        ends = np.asarray(ends, np.int64)
+        starts = np.concatenate([[0], ends[:-1]]).astype(np.int64)
+        first = np.sort(np.unique(
+            np.ascontiguousarray(digests).view("V32").reshape(-1),
+            return_index=True)[1])
+        return cls(data, starts[first], (ends - starts)[first])
+
+    @classmethod
+    def of_delta(cls, buf, n_uniq: int, need, starts,
+                 lens) -> "HeldChunks":
+        """A relay's: the chunks of its own need list (indices into the
+        block's ``n_uniq`` unique chunks) where they landed in ``buf``."""
+        at = np.full(n_uniq, -1, np.int64)
+        ln = np.zeros(n_uniq, np.int64)
+        idx = np.asarray(need, np.int64)
+        at[idx], ln[idx] = starts, lens
+        return cls(buf, at, ln)
 
 
 class MirrorLegFailed(IOError):
@@ -487,8 +525,11 @@ class BlockReceiver:
         status = dt.ACK_SUCCESS
         if targets:
             try:
-                failed_dn = dn.mirror.push(block_id, gen_stamp, scheme_name,
-                                           len(data), stored, crcs, targets)
+                failed_dn = dn.mirror.push(
+                    block_id, gen_stamp, scheme_name, len(data), stored,
+                    crcs, targets,
+                    held=self._held_block(block_id, scheme, data,
+                                          precomputed))
                 if failed_dn:
                     # every leg we drove landed, but a deeper relay hop
                     # broke: the per-hop status frame carried its dn_id up
@@ -505,6 +546,28 @@ class BlockReceiver:
                                            getattr(e, "dn_id", None)),
                         block_id, e)
         return status
+
+    def _held_block(self, block_id: int, scheme, data,
+                    precomputed) -> HeldChunks | None:
+        """The block the head just received as its push's source: chunk
+        boundaries and fingerprints the reduction produced (the worker's,
+        or those computed here after it failed), else the index entry's
+        hashes and its chunks' lengths (a DataNode without a worker reduced
+        in-process)."""
+        if getattr(scheme, "container_codec", None) is None:
+            return None
+        if precomputed is not None:
+            cuts, digs = precomputed
+            return HeldChunks.of_block(data, cuts, digs)
+        entry = self._dn.index.get_block(block_id)
+        if entry is None:
+            return None
+        locs = self._dn.index.lookup_chunks(list(dict.fromkeys(entry.hashes)))
+        ends = np.cumsum([locs[h].length for h in entry.hashes],
+                         dtype=np.int64)
+        return HeldChunks.of_block(
+            data, ends,
+            np.frombuffer(b"".join(entry.hashes), np.uint8).reshape(-1, 32))
 
     @staticmethod
     def _target_named(targets: list, dn_id: str | None) -> dict:
@@ -535,7 +598,8 @@ class BlockReceiver:
 
     def push_reduced(self, block_id: int, gen_stamp: int, scheme_name: str,
                      logical_len: int, stored: bytes, crcs: list[int],
-                     targets: list, throttler=None) -> str | None:
+                     targets: list, throttler=None,
+                     held: HeldChunks | None = None) -> str | None:
         """Ship the reduced form to targets[0], which relays to the rest.
         Used by both pipeline mirroring and NN-commanded re-replication
         (transferBlock, DataNode.java:2361 — which the reference serves by
@@ -555,18 +619,24 @@ class BlockReceiver:
         throttle and the fault point ``block_receiver.mirror_push`` (seqno
         = the frame's index) come once a frame, the trailer's included.
 
-        On the phase clock: ``mirror_read`` (the needed chunks out of this
-        DataNode's index and store), ``mirror_stream`` (the op frame, the
+        ``held`` is the bytes the caller already holds of the block's
+        chunks (the head's received block, a relay's landed delta): a
+        needed chunk held there is sent from there, and only the rest is
+        looked up and read out of this DataNode's store, which is all of it
+        for a caller that holds nothing (re-replication, a balancer move).
+
+        On the phase clock: ``mirror_read`` (the delta assembled from the
+        held bytes and the store), ``mirror_stream`` (the op frame, the
         lengths and the stride frames written), ``mirror_wait`` (the chain
         below answering), all under one covering ``mirror_push``."""
         with profiler.cpu_phase("mirror_push"):
             return self._push_reduced_inner(block_id, gen_stamp, scheme_name,
                                             logical_len, stored, crcs,
-                                            targets, throttler)
+                                            targets, throttler, held)
 
     def _push_reduced_inner(self, block_id, gen_stamp, scheme_name,
                             logical_len, stored, crcs, targets,
-                            throttler) -> str | None:
+                            throttler, held) -> str | None:
         dn = self._dn
         scheme = dn.scheme(scheme_name)
         push_t0 = time.perf_counter()
@@ -605,19 +675,12 @@ class BlockReceiver:
 
             if hashes is not None:
                 with profiler.phase("mirror_read"):
-                    uniq = list(dict.fromkeys(hashes))
-                    needed_hashes = [uniq[i] for i in need]
-                    locs = dn.index.lookup_chunks(needed_hashes)
-                    chunks = dn.containers.read_chunks(
-                        [(locs[h].container_id, locs[h].offset,
-                          locs[h].length) for h in needed_hashes])
+                    lens, pieces = self._delta(dn, hashes, need, held)
                 with profiler.phase("mirror_stream"):
                     # the chunks' lengths in one frame, their bytes back to
                     # back in stride frames
-                    lens = np.fromiter(map(len, chunks), np.uint32,
-                                       len(chunks))
                     send_frame(mirror, {"lens": lens.tobytes()})
-                    dt.write_frames(mirror, dt.chunk_frames(chunks), before)
+                    dt.write_frames(mirror, dt.chunk_frames(pieces), before)
                 sent_bytes = int(lens.sum(dtype=np.int64))
             else:
                 with profiler.phase("mirror_stream"):
@@ -638,6 +701,47 @@ class BlockReceiver:
         finally:
             mirror.close()
 
+    @staticmethod
+    def _delta(dn, hashes: list, need: list,
+               held: HeldChunks | None) -> tuple[np.ndarray, list]:
+        """The needed chunks (``need``: indices into ``hashes`` made
+        unique) as the wire carries them: their lengths (``uint32``, in
+        need order) and their bytes back to back as pieces, each a view of
+        a run of chunks that lie one after another in ``held``, or a chunk
+        held nowhere but in this DataNode's store, read from there."""
+        uniq = list(dict.fromkeys(hashes))
+        idx = np.asarray(need, np.int64)
+        if held is not None and len(held.starts) == len(uniq):
+            at, lens = held.starts[idx], held.lens[idx]
+        else:
+            at, lens = np.full(len(idx), -1, np.int64), \
+                np.zeros(len(idx), np.int64)
+        miss = np.flatnonzero(at < 0)
+        _M.incr("mirror_chunks_held", len(idx) - len(miss))
+        _M.incr("mirror_chunks_reread", len(miss))
+        reread: list = []
+        if len(miss):
+            wanted = [uniq[i] for i in idx[miss].tolist()]
+            locs = dn.index.lookup_chunks(wanted)
+            reread = dn.containers.read_chunks(
+                [(locs[h].container_id, locs[h].offset, locs[h].length)
+                 for h in wanted])
+            lens[miss] = [len(c) for c in reread]
+        # a piece opens at every chunk that does not go on from the held
+        # bytes of the one before it
+        on = np.zeros(len(idx), bool)
+        on[1:] = (at[1:] >= 0) & (at[:-1] >= 0) & \
+            (at[1:] == at[:-1] + lens[:-1])
+        opens = np.flatnonzero(~on).tolist()
+        view = memoryview(held.buf).cast("B") if held is not None else None
+        pieces, rest = [], iter(reread)
+        for a, b in zip(opens, opens[1:] + [len(idx)]):
+            if at[a] < 0:
+                pieces.append(next(rest))
+            else:
+                pieces.append(view[at[a]:at[b - 1] + lens[b - 1]])
+        return lens.astype(np.uint32), pieces
+
     # ------------------------------------------- reduced mirroring (ingest side)
 
     def ingest_reduced(self, sock: socket.socket, fields: dict) -> None:
@@ -647,7 +751,9 @@ class BlockReceiver:
         the delta stream (the lengths frame, every stride frame, the
         trailer) is one ``mirror_recv`` span, not the client stream's
         ``recv``.  The delta lands in one buffer and goes to the store in
-        one ``append_ranges``, in the need list's order.
+        one ``append_ranges``, in the need list's order; a middle relay
+        keeps that buffer as its onward push's source until the push is
+        done, before its status frame and ack go upstream.
 
         Inside ``mirror_ingest`` two more covering spans with thread CPU:
         ``mirror_need`` from the offered hash list (decoded, made unique,
@@ -688,6 +794,7 @@ class BlockReceiver:
             raise IOError(f"stale gen_stamp {gen_stamp} < "
                           f"{existing.gen_stamp} for block {block_id}")
         stored = b""
+        held = None
         if hashes is not None:
             with profiler.cpu_phase("mirror_need"):
                 hashes = [bytes(h) for h in hashes]
@@ -719,7 +826,11 @@ class BlockReceiver:
                 with profiler.phase("container_io"):
                     locs = dn.containers.append_ranges(
                         buf, starts, lens, on_seal=dn.index.seal_container)
-                del buf     # in the store now: not held through the push
+                if targets:
+                    # held through the onward push, its source
+                    held = HeldChunks.of_delta(buf, len(uniq), need, starts,
+                                               lens)
+                del buf
                 new_chunks = {uniq[i]: loc for i, loc in zip(need, locs)}
                 dn.index.commit_block(block_id, logical_len, hashes,
                                       new_chunks)
@@ -752,12 +863,14 @@ class BlockReceiver:
             try:
                 failed_dn = self.push_reduced(block_id, gen_stamp,
                                               scheme_name, logical_len,
-                                              stored, list(crcs), targets)
+                                              stored, list(crcs), targets,
+                                              held=held)
             except (OSError, ConnectionError, retry.DeadlineExceeded) as e:
                 failed_dn = getattr(e, "dn_id", None) \
                     or targets[0].get("dn_id")
                 self._note_mirror_failure(
                     self._target_named(targets, failed_dn), block_id, e)
+        held = None     # the delta is not held past the onward push
         with profiler.phase("ack"):
             # per-hop status frame ahead of the fixed 9-byte ack: carries
             # the failing downstream dn_id so upstream hops (and

@@ -198,22 +198,23 @@ class MirrorPlane:
 
     def push(self, block_id: int, gen_stamp: int, scheme_name: str,
              logical_len: int, stored: bytes, crcs: list[int],
-             targets: list, throttler=None):
+             targets: list, throttler=None, held=None):
         """Mirror the reduced form to ``targets``.
 
         ``mirror_parity = 0`` or a single target falls through to the
-        serial relay (server/block_receiver.py:521 push_reduced) verbatim;
-        otherwise the payload is coded across the mirror set and the call
-        returns once any k legs land.  Returns the downstream failing
-        dn_id propagated by the serial relay (None on the coded path —
-        per-leg attribution happens inline here)."""
+        serial relay (server/block_receiver.py push_reduced) verbatim,
+        ``held`` (the chunk bytes the caller holds, its source) with it;
+        otherwise the payload is coded across the mirror set out of the
+        store and the call returns once any k legs land.  Returns the
+        downstream failing dn_id propagated by the serial relay (None on
+        the coded path — per-leg attribution happens inline here)."""
         dn = self._dn
         receiver = dn._receiver
         m_cfg = int(dn.config.reduction.mirror_parity)
         if m_cfg <= 0 or len(targets) < 2:
             return receiver.push_reduced(block_id, gen_stamp, scheme_name,
                                          logical_len, stored, crcs, targets,
-                                         throttler=throttler)
+                                         throttler=throttler, held=held)
         n = len(targets)
         m = min(m_cfg, n - 1)
         k = n - m
@@ -224,7 +225,7 @@ class MirrorPlane:
             _M.incr("coded_fallbacks")
             return receiver.push_reduced(block_id, gen_stamp, scheme_name,
                                          logical_len, stored, crcs, targets,
-                                         throttler=throttler)
+                                         throttler=throttler, held=held)
         segments, seg_len = encode_segments(payload, k, m)
         common = dict(
             block_id=block_id, gen_stamp=gen_stamp, scheme=scheme_name,

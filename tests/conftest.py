@@ -27,6 +27,15 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: full-size kernel runs excluded from the tier-1 "
         "sweep (the Pallas interpreter pays ~1 min per full-width network)")
+    if not hasattr(config, "workerinput"):
+        # The native library builds itself on first load when it is missing
+        # or older than its sources.  Build it here, once, in the process
+        # that starts any xdist workers and before them: workers that each
+        # found it missing would each run ``make``, and one would load the
+        # file while another's link was rewriting it ("file too short").
+        from hdrf_tpu import native
+
+        native._load()
 
 
 @pytest.fixture(autouse=True)

@@ -26,8 +26,25 @@ def _run(argv, cwd=REPO, script=None):
     return out, lines
 
 
-def test_rehearsal_runs_every_phase_then_fails_on_the_platform():
-    out, lines = _run(TINY + ["--worker-backend", "native"])
+@pytest.fixture
+def checkout(tmp_path):
+    """The script and the package in a directory of their own: the script
+    rebuilds the native library of its checkout (``make -B``), and the
+    checkout's own library is the one every other test process loads, so
+    the rebuild must not rewrite it under them."""
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    shutil.copytree(os.path.join(REPO, "hdrf_tpu"), tmp_path / "hdrf_tpu",
+                    ignore=shutil.ignore_patterns("__pycache__", "*.so"))
+    return tmp_path
+
+
+def _run_in(checkout, argv):
+    return _run(argv, cwd=str(checkout),
+                script=str(checkout / "chip_smoke.py"))
+
+
+def test_rehearsal_runs_every_phase_then_fails_on_the_platform(checkout):
+    out, lines = _run_in(checkout, TINY + ["--worker-backend", "native"])
     assert out.returncode != 0
     last = json.loads(lines[-1])
     assert last["ok"] is False and "not 'tpu'" in last["error"]
@@ -42,8 +59,9 @@ def test_rehearsal_runs_every_phase_then_fails_on_the_platform():
     assert not any('"ok": true' in ln for ln in lines)
 
 
-def test_no_accelerator_means_the_worker_refuses_and_the_smoke_fails():
-    out, lines = _run(TINY)          # the default worker backend: tpu
+def test_no_accelerator_means_the_worker_refuses_and_the_smoke_fails(
+        checkout):
+    out, lines = _run_in(checkout, TINY)   # the default worker backend: tpu
     assert out.returncode != 0
     assert json.loads(lines[-1])["ok"] is False
     assert "--backend tpu but JAX reports platform 'cpu'" in out.stderr

@@ -7,9 +7,11 @@ the mirror leg's own spans on the phase clock.
 A block is received and reduced once, by its pipeline's first DataNode; its
 reduced form (hash list, then the chunks the next DataNode lacks) is pushed
 to the second, which relays it to the third.  Push side: ``mirror_read`` (the
-needed chunks out of the pusher's index and store), ``mirror_stream`` (frames
-and packets written), ``mirror_wait`` (the chain below answering), under one
-covering ``mirror_push``.  Relay side: ``mirror_recv`` a frame read of the
+delta assembled from the bytes the pusher holds: the head's received block,
+the relay's landed delta; a needed chunk held in neither is read out of the
+pusher's index and store), ``mirror_stream`` (frames and packets written),
+``mirror_wait`` (the chain below answering), under one covering
+``mirror_push``.  Relay side: ``mirror_recv`` a frame read of the
 delta stream (its lengths, each stride frame of the chunks' bytes, the
 trailer), under one covering ``mirror_ingest`` a relayed block.
 
@@ -18,11 +20,13 @@ Everything here stops by counts (files written), never by seconds.
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
 
 from hdrf_tpu.proto import datatransfer as dt
+from hdrf_tpu.storage.container_store import ContainerStore
 from hdrf_tpu.testing.minicluster import MiniCluster
 from hdrf_tpu.utils import metrics, profiler
 
@@ -55,6 +59,36 @@ def _delta(after: dict, before: dict, name: str) -> int:
     return after.get(name, 0) - before.get(name, 0)
 
 
+def _settled(before: dict, relayed: int, wait_s: float = 30.0) -> dict:
+    """The counters once ``relayed`` more blocks are counted as ingested: a
+    relay counts its block (and closes its ``mirror_ingest``) after its ack
+    has gone upstream, so the write that sent it may return first."""
+    deadline = time.monotonic() + wait_s
+    while True:
+        now = _counters()
+        if _delta(now, before, "blocks_ingested_reduced") >= relayed \
+                or time.monotonic() > deadline:
+            return now
+        time.sleep(0.01)
+
+
+def _store_reads(mp, datanodes) -> list:
+    """Every ``ContainerStore.read_chunks`` from here on, as ``(the dn_id of
+    the DataNode whose store it is, the locations asked for)``."""
+    calls, real = [], ContainerStore.read_chunks
+    roots = {os.path.join(dn.config.data_dir, ""): dn.dn_id
+             for dn in datanodes}
+
+    def counted(self, locs, *args, **kwargs):
+        (dn_id,) = [d for root, d in roots.items()
+                    if self._dir.startswith(root)]
+        calls.append((dn_id, list(locs)))
+        return real(self, locs, *args, **kwargs)
+
+    mp.setattr(ContainerStore, "read_chunks", counted)
+    return calls
+
+
 @pytest.fixture(scope="module")
 def written(perfbench_file):
     """Four one-block files written at r=3 through one native worker; the
@@ -72,13 +106,14 @@ def written(perfbench_file):
                      container_size=CONTAINER, tpu_worker=True,
                      worker_backend="native") as mc:
         t0 = profiler.mark()
-        with mc.client("writer") as c:
+        with mc.client("writer") as c, pytest.MonkeyPatch.context() as mp:
+            store_reads = _store_reads(mp, mc.datanodes)
             for k, data in enumerate(files):
                 c.write(f"/tg/f{k}", data, scheme="dedup_lz4")
+            after = _settled(before, 2 * FILES)
         for dn in mc.datanodes:
             dn.containers.drain_seals()
         t1 = profiler.mark()
-        after = _counters()
         spans = profiler.window_spans(t0, t1)
         ranges = [(k, off, n) for k in range(FILES)
                   for off, n in ((0, BLOCK), (12_345, 300_001))]
@@ -101,7 +136,8 @@ def written(perfbench_file):
         table, _ = ref.chunk_tables(files, CDC, BLOCK)
         found = [n["index"].lookup_chunks(list(table)) for n in nodes]
     yield {"files": files, "spans": spans, "t0": t0, "t1": t1,
-           "counters": (before, after), "readback": readback,
+           "counters": (before, after), "store_reads": store_reads,
+           "readback": readback,
            "locs": locs, "nodes": nodes, "table": table, "found": found}
 
 
@@ -193,10 +229,11 @@ class TestMirrorSpans:
 
     def test_every_push_reads_what_it_sends_out_of_its_own_store(
             self, written):
-        """The head's push and the middle DataNode's (inside its
-        ``mirror_ingest``) alike take the needed chunks out of their own
-        index and store: the relay reads back containers its append has
-        just rolled over."""
+        """What each push sends, it reads out of what its DataNode holds
+        already, and no longer out of its containers: the head's push
+        (the block it received) and the middle DataNode's inside its
+        ``mirror_ingest`` (the delta it landed) leave no span of the
+        store's reads, and no push asks the store for a chunk."""
         spans = written["spans"]
         ingests = _named(spans, "mirror_ingest")
 
@@ -207,7 +244,20 @@ class TestMirrorSpans:
         relayed = [p for p in pushes if any(inside(p, i) for i in ingests)]
         assert len(relayed) == FILES and len(pushes) == 2 * FILES
         for p in pushes:
-            assert {sp[0] for sp in spans if inside(sp, p)} & set(STORE_READS)
+            seen = {sp[0] for sp in spans if inside(sp, p)}
+            assert "mirror_read" in seen
+            assert not seen & set(STORE_READS)
+        assert written["store_reads"] == []
+
+    def test_every_needed_chunk_goes_out_of_held_bytes(self, written):
+        """Rows never repeat, so each relay lacks every chunk of the block
+        and each push holds every chunk its next DataNode needs: ``held``
+        sums to the relays' ``needed``, and nothing is read again."""
+        before, after = written["counters"]
+        needed = _delta(after, before, "mirror_chunks_needed")
+        assert needed == 2 * len(written["table"])
+        assert _delta(after, before, "mirror_chunks_held") == needed
+        assert _delta(after, before, "mirror_chunks_reread") == 0
 
     def test_the_relays_check_of_a_frame_is_on_the_mirror_legs_clock(
             self, written):
@@ -388,6 +438,140 @@ class TestTheDeltaWire:
                     with pytest.raises((ConnectionError, OSError)):
                         recv_frame(s)
             assert dn.replicas.get_meta(77) is None
+
+
+def _pipeline_in_order(mp, mc, order: list) -> None:
+    """The NameNode places every new block on the DataNodes named in
+    ``order[0]`` (a list the test may change between writes), in that
+    order, head first."""
+    nn = mc.namenode
+
+    def choose(n, exclude, policy=None, slots=None):
+        out = [nn._datanodes[d] for d in order[0] if d not in exclude][:n]
+        if slots is not None:
+            slots.extend(d.storage_type for d in out)
+        return out
+
+    mp.setattr(nn, "_choose_targets", choose)
+    mp.setattr(nn, "_lead_least_busy", lambda bid, targets, slots: None)
+
+
+def _each_replica(perfbench_file, c, path: str, data: bytes, want: int):
+    replicas = perfbench_file("replicas.py")
+    short, locs = replicas.short_blocks(c, [path], want)
+    assert short == 0
+    got = replicas.read_each(c, locs[path], 0, len(data))
+    assert len(got) == want
+    assert all(v == data for v in got.values()), sorted(got)
+
+
+class TestTheHeldSource:
+    """Where a push takes its delta from: the bytes its DataNode holds (the
+    head's received block, the middle relay's landed delta), and the store
+    only for a chunk held in neither — or for all of it when the caller
+    holds nothing (re-replication)."""
+
+    BIG = 16 << 20
+
+    def test_a_chunk_the_relay_had_already_is_the_one_read_again(
+            self, perfbench_file):
+        """``dn-1`` holds the front of the file from an earlier write, so
+        in the pipeline ``dn-0`` -> ``dn-1`` -> ``dn-2`` the middle relay
+        lands only the rest, and ``dn-2`` lacks what ``dn-1`` had: exactly
+        those chunks come out of ``dn-1``'s store, and nothing out of any
+        other DataNode's."""
+        front, back = _random(600_000), _random(900_000)[::-1]
+        with MiniCluster(n_datanodes=3, replication=3,
+                         block_size=self.BIG) as mc, \
+                pytest.MonkeyPatch.context() as mp:
+            order = [["dn-1"]]
+            _pipeline_in_order(mp, mc, order)
+            with mc.client("held") as c:
+                c.write("/held/front", front, scheme="dedup_lz4",
+                        replication=1)
+                order[0] = ["dn-0", "dn-1", "dn-2"]
+                head, relay, tail = mc.datanodes
+                before = _counters()
+                reads = _store_reads(mp, mc.datanodes)
+                c.write("/held/f", front + back, scheme="dedup_lz4")
+                after = _settled(before, 2)
+                bid = _block_of(c, "/held/f")["block_id"]
+                uniq = set(head.index.get_block(bid).hashes)
+                had = uniq & set(relay.index.get_block(
+                    _block_of(c, "/held/front")["block_id"]).hashes)
+                assert 0 < len(had) < len(uniq)
+                locs = relay.index.lookup_chunks(sorted(had))
+                assert [dn_id for dn_id, _ in reads] == [relay.dn_id]
+                assert sorted(reads[0][1]) == sorted(
+                    (loc.container_id, loc.offset, loc.length)
+                    for loc in locs.values())
+                # the head holds what the relay needs; the relay, of what
+                # the tail needs, all but what it had
+                assert _delta(after, before, "mirror_chunks_needed") == \
+                    2 * len(uniq) - len(had)
+                assert _delta(after, before, "mirror_chunks_held") == \
+                    2 * (len(uniq) - len(had))
+                assert _delta(after, before, "mirror_chunks_reread") == \
+                    len(had)
+                assert _delta(after, before, "mirror_failures") == 0
+                _each_replica(perfbench_file, c, "/held/f", front + back, 3)
+                assert tail.index.stats()["unique_chunk_bytes"] == \
+                    sum(loc.length for loc in tail.index.lookup_chunks(
+                        sorted(uniq)).values())
+
+    def test_re_replication_holds_nothing_and_reads_the_store(
+            self, perfbench_file):
+        """A NameNode-commanded transfer (``_replicate``) passes no held
+        bytes: every needed chunk comes out of the holder's store, and the
+        new replica reads back as the first."""
+        data = _random(2 * dt.STRIDE + 54_321)
+        with MiniCluster(n_datanodes=2, replication=1,
+                         block_size=self.BIG) as mc, \
+                pytest.MonkeyPatch.context() as mp:
+            with mc.client("rerep") as c:
+                c.write("/rerep/f", data, scheme="dedup_lz4")
+                bid = _block_of(c, "/rerep/f")["block_id"]
+                (holder,) = _holders(mc, bid)
+                uniq = set(holder.index.get_block(bid).hashes)
+                before = _counters()
+                reads = _store_reads(mp, mc.datanodes)
+                c.set_replication("/rerep/f", 2)
+                mc.wait_for_replication("/rerep/f", 2)
+                after = _settled(before, 1)
+                assert {dn_id for dn_id, _ in reads} == {holder.dn_id}
+                assert sum(len(locs) for _, locs in reads) == len(uniq)
+                assert _delta(after, before, "mirror_chunks_held") == 0
+                assert _delta(after, before, "mirror_chunks_reread") == \
+                    _delta(after, before, "mirror_chunks_needed") == len(uniq)
+                _each_replica(perfbench_file, c, "/rerep/f", data, 2)
+
+    @pytest.mark.parametrize("head", ["worker_failed", "no_worker"])
+    def test_a_head_without_its_workers_cuts_still_sends_what_it_holds(
+            self, perfbench_file, head):
+        """The head's chunk boundaries come from wherever its reduction
+        ran: the cuts computed on its own thread after the worker failed
+        (the degraded write), or, with no worker at all, the index entry's
+        ends.  Either way it sends what it received, and every replica
+        reads back the same bytes."""
+        data = _random(3 << 20)
+        with MiniCluster(n_datanodes=3, replication=3, block_size=self.BIG,
+                         tpu_worker=head == "worker_failed",
+                         worker_backend="native") as mc, \
+                pytest.MonkeyPatch.context() as mp:
+            if head == "worker_failed":
+                mc.kill_worker()
+            with mc.client("degraded") as c:
+                before = _counters()
+                reads = _store_reads(mp, mc.datanodes)
+                c.write("/degraded/f", data, scheme="dedup_lz4")
+                after = _settled(before, 2)
+                assert _delta(after, before, "worker_fallbacks") == \
+                    (head == "worker_failed")
+                needed = _delta(after, before, "mirror_chunks_needed")
+                assert needed > 0 and reads == []
+                assert _delta(after, before, "mirror_chunks_held") == needed
+                assert _delta(after, before, "mirror_chunks_reread") == 0
+                _each_replica(perfbench_file, c, "/degraded/f", data, 3)
 
 
 def test_one_datanode_records_no_mirror_phase(perfbench_file):

@@ -11,13 +11,17 @@ index, to the need frame sent) and ``mirror_commit`` (the delta's append,
 the index commit, the replica's ``create_rbw`` + ``finalize``), both inside
 the relayed block's ``mirror_ingest``.  The ``block_receiver`` counters
 ``mirror_chunks_offered`` / ``mirror_chunks_needed`` / ``mirror_delta_bytes``
-are held to what the reference predicts, block by block.
+are held to what the reference predicts, block by block; on the push side
+``mirror_chunks_held`` / ``mirror_chunks_reread`` split each push's needed
+chunks by where it sent them from (the bytes the pusher already holds, or
+its store).
 
 Everything here stops by counts (generations written), never by seconds.
 """
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -33,6 +37,7 @@ SEED = 2**31 + 40
 CDC = {"mask_bits": 13, "min_chunk": 2048, "max_chunk": 65536}
 COUNTERS = ("mirror_chunks_offered", "mirror_chunks_needed",
             "mirror_delta_bytes", "blocks_ingested_reduced")
+PUSHED = ("mirror_chunks_held", "mirror_chunks_reread")
 RELAY = ("mirror_need", "mirror_commit")
 CELL = "versions-3dn.ingest"
 SIBLINGS = ("versions-dedup.ingest", "teragen-3dn.ingest")
@@ -53,7 +58,20 @@ def _bench(perfbench_file) -> dict:
 
 def _counters() -> dict:
     got = metrics.registry("block_receiver").snapshot()["counters"]
-    return {name: got.get(name, 0) for name in COUNTERS}
+    return {name: got.get(name, 0) for name in COUNTERS + PUSHED}
+
+
+def _settled(before: dict, relayed: int, wait_s: float = 30.0) -> dict:
+    """The counters once ``relayed`` more blocks are counted as ingested: a
+    relay counts its block after its ack has gone upstream, so the write
+    that sent it may return first."""
+    deadline = time.monotonic() + wait_s
+    while True:
+        now = _counters()
+        if now["blocks_ingested_reduced"] - before["blocks_ingested_reduced"] \
+                >= relayed or time.monotonic() > deadline:
+            return now
+        time.sleep(0.01)
 
 
 def _named(spans, name):
@@ -111,7 +129,7 @@ def written(perfbench_file, trees):
         checks = perfbench_file("checks.py")
     paths = {(k, g): f"/v{k}/g{g}.tar" for k in range(CLIENTS)
              for g in range(GENERATIONS)}
-    moved = {}
+    moved, pushed = {}, {}
     with MiniCluster(n_datanodes=3, replication=3, block_size=BLOCK,
                      container_size=CONTAINER, tpu_worker=True,
                      worker_backend="native") as mc:
@@ -121,9 +139,11 @@ def written(perfbench_file, trees):
                 for k in range(CLIENTS):
                     before = _counters()
                     c.write(paths[(k, g)], trees[k][g], scheme="dedup_lz4")
-                    after = _counters()
+                    after = _settled(before, 2)
                     moved[(k, g)] = {n: after[n] - before[n]
                                      for n in COUNTERS}
+                    pushed[(k, g)] = {n: after[n] - before[n]
+                                      for n in PUSHED}
         for dn in mc.datanodes:
             dn.containers.drain_seals()
         t1 = profiler.mark()
@@ -145,7 +165,7 @@ def written(perfbench_file, trees):
         table, _ = ref.chunk_tables([d for gens in trees for d in gens], CDC,
                                     BLOCK)
         found = [dn.index.lookup_chunks(list(table)) for dn in mc.datanodes]
-    yield {"moved": moved, "spans": spans, "t0": t0, "t1": t1,
+    yield {"moved": moved, "pushed": pushed, "spans": spans, "t0": t0, "t1": t1,
            "readback": readback, "locs": locs, "nodes": nodes,
            "table": table, "found": found}
 
@@ -204,6 +224,18 @@ class TestTheLegsCounters:
             "mirror_chunks_offered": 2 * len(table),
             "mirror_chunks_needed": 2 * len(new),
             "mirror_delta_bytes": 2 * sum(new.values())}
+
+    @pytest.mark.parametrize("k,g", [(k, g) for g in range(GENERATIONS)
+                                     for k in range(CLIENTS)])
+    def test_each_push_sends_what_it_holds_and_reads_nothing_again(
+            self, written, predicted, k, g):
+        """Both relays hold every earlier block, so the third DataNode
+        needs exactly what the second needed: the head sends it out of the
+        block it received, the middle relay out of the delta it landed,
+        and neither reads a chunk back out of its store."""
+        _, new = predicted[(k, g)]
+        assert written["pushed"][(k, g)] == {
+            "mirror_chunks_held": 2 * len(new), "mirror_chunks_reread": 0}
 
     def test_a_later_generation_needs_few_of_the_chunks_it_offers(
             self, predicted):
