@@ -60,6 +60,10 @@ def _load() -> ctypes.CDLL:
                                         ctypes.c_uint64, ctypes.c_uint64, _u64p,
                                         ctypes.c_uint64]
         lib.hdrf_cdc_select.restype = ctypes.c_uint64
+        lib.hdrf_cdc_select_words.argtypes = [
+            _u32p, _u32p, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.c_uint64, _u64p, ctypes.c_uint64]
+        lib.hdrf_cdc_select_words.restype = ctypes.c_uint64
         lib.hdrf_cdc_chunk.argtypes = [_u8p, ctypes.c_uint64, ctypes.c_uint32,
                                        ctypes.c_uint64, ctypes.c_uint64, _u64p,
                                        ctypes.c_uint64]
@@ -172,6 +176,24 @@ def cdc_select(candidates: np.ndarray, length: int, min_chunk: int,
     out = np.empty(cap, dtype=np.uint64)
     n = _load().hdrf_cdc_select(_ptr(cand, _u64p), cand.size, length, min_chunk,
                                 max_chunk, _ptr(out, _u64p), cap)
+    return out[:n].copy()
+
+
+def cdc_select_words(idx: np.ndarray, vals: np.ndarray, length: int,
+                     min_chunk: int, max_chunk: int) -> np.ndarray:
+    """``cdc_select`` over the candidate bitmap's non-zero words: ``idx``
+    strictly ascending word indices, ``vals`` their values, bit b of word w
+    the cut-point ``32*w + b + 1`` (what ``_prep`` reads back).  The same
+    cuts as ``cdc_select`` of the expanded positions, without expanding."""
+    i = np.ascontiguousarray(idx, dtype=np.uint32)
+    v = np.ascontiguousarray(vals, dtype=np.uint32)
+    if i.shape != v.shape:
+        raise ValueError("idx/vals shape mismatch")
+    cap = length // max(min_chunk, 1) + 2
+    out = np.empty(cap, dtype=np.uint64)
+    n = _load().hdrf_cdc_select_words(_ptr(i, _u32p), _ptr(v, _u32p), i.size,
+                                      length, min_chunk, max_chunk,
+                                      _ptr(out, _u64p), cap)
     return out[:n].copy()
 
 

@@ -210,9 +210,11 @@ class ReductionWorker:
         seconds of every phase this process recorded, a stage its backend
         does not run left out; the three sums of them; once ``_prep`` has
         run (a device backend's first block), ``prep_retries`` — reduce
-        ops whose candidates overflowed its capacity and ran it again — and
-        the gauge ``prep_cap_words``, the capacity rung in use (registry
-        ``resident``, ops/resident.py); on a device backend
+        ops whose candidates overflowed its capacity and ran it again —,
+        ``select_words`` — the non-zero candidate words cut selection
+        walked, summed over blocks — and the gauge ``prep_cap_words``, the
+        capacity rung in use (registry ``resident``, ops/resident.py); on a
+        device backend
         ``bytes_padded`` (the block-length ladder's waste, above) and
         ``prep_shapes`` — the (padded length, capacity) pairs this worker's
         reducers have dispatched ``_prep`` at, retries too: each a program
@@ -228,6 +230,7 @@ class ReductionWorker:
         prep = metrics.registry("resident").snapshot()
         if "prep_cap_words" in prep["gauges"]:
             out["prep_retries"] = prep["counters"].get("prep_retries", 0)
+            out["select_words"] = prep["counters"].get("select_words", 0)
             out["prep_cap_words"] = prep["gauges"]["prep_cap_words"]
         if self.backend == "tpu":
             out["prep_shapes"] = sum(len(r.prep_shapes)

@@ -120,6 +120,97 @@ def test_cdc_content_defined_shift_invariance():
     assert len(tail_a & cuts_b) / max(len(tail_a), 1) > 0.8
 
 
+def _candidate_words(pos: np.ndarray):
+    """Sparse (word index, word value) pairs of a sorted candidate list, as
+    the device's ``_prep`` reads them back: bit b of word w is cut-point
+    32*w + b + 1."""
+    if pos.size == 0:
+        return np.empty(0, np.uint32), np.empty(0, np.uint32)
+    w, b = (pos - 1) >> 5, (pos - 1) & 31
+    idx, first = np.unique(w, return_index=True)
+    vals = np.bitwise_or.reduceat(np.uint32(1) << b.astype(np.uint32), first)
+    return idx.astype(np.uint32), vals.astype(np.uint32)
+
+
+MASK13 = 0x1FFF
+
+
+def _versions_block(perfbench_file) -> np.ndarray:
+    """Generation 1 of client 0's tree as an 8 MiB tar stream, as
+    ``versions-dedup.ingest`` makes it: zero runs of header and content
+    padding, where every position is a candidate."""
+    import json
+
+    with open(os.path.join(perfbench_file.root, "configs",
+                           "versions-dedup.json")) as f:
+        data = {k: v for k, v in json.load(f)["data"].items()
+                if k != "generator"}
+    src = perfbench_file("generators/versions.py").Source(
+        dict(data, file_bytes=8 << 20), 2147498023, 0)
+    return np.frombuffer(src.file(1), np.uint8)
+
+
+# name -> (bytes, mask, min_chunk, max_chunk, length, extra words)
+_WALK_CASES = {
+    "random": lambda pf: (RNG.integers(0, 256, 1 << 20, dtype=np.uint8),
+                          MASK13, 2048, 65536, None, None),
+    "all_zero": lambda pf: (np.zeros(300_000, np.uint8),
+                            MASK13, 2048, 65536, None, None),
+    "no_candidate": lambda pf: (
+        np.random.default_rng(43).integers(0, 256, 1 << 18, dtype=np.uint8),
+        0xFFFFFFFF, 2048, 65536, None, None),
+    "versions_block": lambda pf: (_versions_block(pf),
+                                  MASK13, 2048, 65536, None, None),
+    "odd_length": lambda pf: (RNG.integers(0, 256, 777_777, dtype=np.uint8),
+                              0xFF, 64, 4096, None, None),
+    "past_len": lambda pf: (
+        np.concatenate([RNG.integers(0, 256, 200_000, dtype=np.uint8),
+                        np.zeros(4096, np.uint8)]),
+        MASK13, 2048, 65536, 200_013, [(200_000 // 32 + 500, 0xFFFFFFFF)]),
+    "min_chunk_0": lambda pf: (RNG.integers(0, 256, 50_000, dtype=np.uint8),
+                               0xFF, 0, 1024, None, None),
+    "min_chunk_1": lambda pf: (
+        np.concatenate([RNG.integers(0, 256, 40_000, dtype=np.uint8),
+                        np.zeros(3000, np.uint8)]),
+        0xFF, 1, 1024, None, None),
+    "one_word": lambda pf: (np.zeros(32, np.uint8), MASK13, 1, 64, None,
+                            None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_cdc_select_words_equals_the_positions_path(case, perfbench_file):
+    """The walk over the candidate words gives exactly ``cdc_select``'s cuts
+    over the expanded positions, and both the fused chunker's."""
+    from hdrf_tpu.ops import gear
+
+    data, mask, mn, mx, length, extra = _WALK_CASES[case](perfbench_file)
+    length = data.size if length is None else length
+    idx, vals = _candidate_words(native.gear_candidates(data, mask))
+    if extra:
+        idx = np.concatenate([idx, np.array([w for w, _ in extra], np.uint32)])
+        vals = np.concatenate([vals,
+                               np.array([v for _, v in extra], np.uint32)])
+    assert (np.diff(idx.astype(np.int64)) > 0).all()
+    if case == "one_word":
+        assert idx.size == 1
+    if case == "no_candidate":
+        assert idx.size == 0
+    if case == "past_len":
+        assert int(idx[-1]) * 32 + 1 > length
+    want = native.cdc_chunk(data[:length], mask, mn, mx)
+    old = native.cdc_select(gear._words_to_positions(idx, vals, length),
+                            length, mn, mx)
+    got = native.cdc_select_words(idx, vals, length, mn, mx)
+    np.testing.assert_array_equal(old, want)
+    np.testing.assert_array_equal(got, want)
+    sizes = np.diff(np.concatenate([[0], got.astype(np.int64)]))
+    if case == "all_zero":
+        assert (sizes[:-1] == mn).all()
+    if case == "no_candidate":
+        assert (sizes[:-1] == mx).all()
+
+
 def test_cdc_empty_and_tiny():
     assert native.cdc_chunk(b"", 0xFF, 64, 1024).tolist() == []
     assert native.cdc_chunk(b"x" * 10, 0xFF, 64, 1024).tolist() == [10]

@@ -269,6 +269,44 @@ def test_zero_dense_blocks_match_the_plain_reference(corpus, path,
         np.testing.assert_array_equal(digs, wd)
 
 
+def _nonzero_words(data: np.ndarray, cdc: CdcConfig) -> int:
+    pos = native.gear_candidates(data, gear_mask(cdc))
+    return int(np.unique((pos - 1) >> 5).size)
+
+
+@pytest.mark.parametrize("path", ["submit", "submit_many"])
+def test_cut_selection_walks_the_candidate_words(path, versions_tar):
+    """A tar block's cuts are selected on ``_prep``'s candidate words: the
+    same cuts and digests as ``native.cdc_chunk`` + ``hashlib``, after the
+    first shot's capacity overflowed and ``_prep`` ran again, and counter
+    ``select_words`` moves by the blocks' non-zero words exactly."""
+    blocks = versions_tar(TAR_BYTES, 2, seed=43)
+    r = ResidentReducer(CdcConfig())
+    reg = metrics.registry("resident")
+    retries, words = _prep_retries(), reg.counter("select_words")
+    if path == "submit":
+        jobs = [r.submit(b) for b in blocks]
+        for j in jobs:
+            r.start_sha(j)
+        got = [r.finish(j) for j in jobs]
+    else:
+        bj = r.submit_many(blocks)
+        r.start_sha_many(bj)
+        got = r.finish_many(bj)
+    assert _prep_retries() > retries
+    for b, (cuts, digs) in zip(blocks, got):
+        want = native.cdc_chunk(b, gear_mask(r.cdc), r.cdc.min_chunk,
+                                r.cdc.max_chunk)
+        np.testing.assert_array_equal(cuts, want)
+        starts = np.concatenate([[0], want[:-1]]).astype(np.int64)
+        assert [d.tobytes() for d in digs] == [
+            hashlib.sha256(b[s:e].tobytes()).digest()
+            for s, e in zip(starts, want.astype(np.int64))]
+    dense = sum(_nonzero_words(b, r.cdc) for b in blocks)
+    assert dense > TAR_BYTES >> 12     # beyond the first shot's capacity
+    assert reg.counter("select_words") - words == dense
+
+
 def test_tar_stream_compiles_rungs_not_blocks(versions_tar):
     """Eight releases with eight different candidate counts: the retry is
     paid once, later blocks are dispatched at the rung it found, and _prep

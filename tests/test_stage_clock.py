@@ -553,6 +553,31 @@ class TestDeviceStages:
         assert read_layer("worker.prep_retries_per_block",
                            {"prep_retries": 0, "blocks_reduced": 0}) is None
 
+    def test_stats_count_the_candidate_words_cut_selection_walked(self):
+        """``select_words`` in ``stats`` beside ``prep_retries``: a
+        reduce op moves it by its block's non-zero candidate words, which a
+        zero run makes dense."""
+        from hdrf_tpu import native
+        from hdrf_tpu.ops.dispatch import gear_mask
+
+        a = np.random.default_rng(43).integers(0, 256, 600_000,
+                                               dtype=np.uint8)
+        a[50_000:450_000] = 0
+        pos = native.gear_candidates(a, gear_mask(CdcConfig()))
+        words = int(np.unique((pos - 1) >> 5).size)
+        w = ReductionWorker(backend="tpu").start()
+        try:
+            c = WorkerClient(w.addr)
+            c.reduce(_payload(600_000), CdcConfig())
+            s0 = c.stats()
+            c.reduce(a.tobytes(), CdcConfig())
+            s1 = c.stats()
+            c.close()
+        finally:
+            w.stop()
+        assert words > 12_000      # 400 000 zero bytes: 12 500 full words
+        assert s1["select_words"] - s0["select_words"] == words
+
     def test_the_ladder_metrics_read_the_worker_counters(self, read_layer):
         """``worker.pad_pct`` and ``worker.prep_shapes_per_block`` (data
         files for ``stage_ratio``, PR 31) from a device worker's ``stats``:
